@@ -113,8 +113,9 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
         "direct ref-discharge (kernels/common.py; ~2-5 s compile/site)"),
     "DAS_TPU_XLA_CACHE": (
         None,
-        "persistent XLA compile cache dir (das_tpu/__init__.py, "
-        "CapStore placement in query/fused.py); =0 disables"),
+        "=0 disables the persistent XLA compile cache and the CapStore "
+        "(das_tpu/__init__.py cache_root: JAX_COMPILATION_CACHE_DIR "
+        "when set, else <checkout>/.jax_cache)"),
     "DAS_TPU_COALESCE": (
         None, "=0 disables serving-edge query coalescing "
               "(service/server.py)"),

@@ -30,15 +30,25 @@ terms) stay on the kernel route instead of falling back to the lowered
 op chains.
 
 Routing: `DasConfig.use_pallas_kernels` ("auto" | "on" | "off", env
-override DAS_TPU_PALLAS).  "auto" = on for TPU (compiled Mosaic kernels),
-off elsewhere; an explicit "on" off-TPU executes the SAME kernel bodies
-in interpret mode — by direct ref-discharge to ordinary XLA ops
-(kernels/common.py run_kernel / run_grid_kernel; DAS_TPU_PALLAS_INTERPRET=1
-forces the full Pallas interpreter) — answer-identical and
-tier-1-testable under JAX_PLATFORMS=cpu (the differential suites in
-tests/test_zkernels.py and tests/test_ztiled.py and the bench A/Bs all
-run that way).  Off-TPU execution is a correctness vehicle, not a fast
-path, which is why "auto" does not enable it suite-wide on CPU.  The
+override DAS_TPU_PALLAS).  "auto" = the LOWERED XLA route on every
+platform: the chip's compiler (Mosaic, v5e, JAX 0.9.0) refuses every
+kernel here today — join / anti-join / multiway with
+`NotImplementedError: 64-bit types are not supported`, the probe with a
+`RecursionError` under `pallas_call_tpu_lowering_rule` reached from
+common.unrolled_search — and the lowered route is the one that compiles
+(tests/test_tpu_compile.py pins each verdict; the PR that makes a kernel
+Mosaic-clean flips its case there and `auto` here together, ROADMAP
+"Mosaic-clean kernels").  "on" on a TPU issues the real `pl.pallas_call`
+and RAISES WHAT THE COMPILER RAISES — it never discharges, never
+interprets and never gives way to the lowered route.  "on" off-TPU
+executes the SAME kernel bodies in interpret mode — by direct
+ref-discharge to ordinary XLA ops (kernels/common.py run_kernel /
+run_grid_kernel; DAS_TPU_PALLAS_INTERPRET=1 forces the full Pallas
+interpreter) — answer-identical and tier-1-testable under
+JAX_PLATFORMS=cpu (the differential suites in tests/test_zkernels.py and
+tests/test_ztiled.py and the bench A/Bs all run that way).  Interpret
+mode is a CPU-test facility only: a correctness vehicle, not a fast
+path, and never taken when the platform is `tpu`.  The
 sharded mesh programs route their shard-LOCAL probe/join bodies through
 the same kernels (parallel/fused_sharded.py, ShardedPlanSig.use_kernels;
 collectives stay lowered), and the vmapped count-batch groups route
@@ -115,7 +125,9 @@ def _platform() -> str:
 
 def interpret_mode() -> bool:
     """True off-TPU: the kernel bodies discharge to plain XLA ops — same
-    answers, no Mosaic compile (kernels/common.py run_kernel)."""
+    answers, no Mosaic compile (kernels/common.py run_kernel).  A
+    CPU-test facility: on a TPU it is False, and a kernel launch is the
+    real `pl.pallas_call`, which raises whatever Mosaic raises."""
     return _platform() != "tpu"
 
 
@@ -126,13 +138,10 @@ def enabled(config=None) -> bool:
     if mode is None and config is not None:
         mode = getattr(config, "use_pallas_kernels", "auto")
     mode = str("auto" if mode is None else mode).lower()
-    if mode in ("on", "1", "true"):
-        return True
-    if mode in ("off", "0", "false"):
-        return False
-    # auto: compiled kernels on TPU; off elsewhere (explicit "on" runs
-    # them through the interpreter — see module docstring)
-    return _platform() == "tpu"
+    # auto: the lowered route everywhere, until a kernel passes the
+    # Mosaic compile (see module docstring; tests/test_tpu_compile.py
+    # pins today's verdicts)
+    return mode in ("on", "1", "true")
 
 
 def route_label(config=None) -> str:

@@ -52,7 +52,7 @@ Two consumers close standing ROADMAP loops:
     calibration contract is for TPU runs (ARCHITECTURE §15).
   * **cold-start accounting** — a jax monitoring listener classifies
     each compile as fresh or served by the persistent XLA cache
-    (`DAS_TPU_XLA_CACHE`); `snapshot()["cold_start_s"]` sums the wall
+    (`das_tpu.enable_compile_cache`); `snapshot()["cold_start_s"]` sums the wall
     time of the FRESH compiles only — the time-to-first-answer compile
     cost a warm replica (ROADMAP replica-fleet item) would not pay.
 

@@ -205,11 +205,9 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
     if use_k or mw:
         from das_tpu import kernels as _kernels
 
+        # no separate lowered chain for the multiway step (query/fused.py
+        # _trace_conj): discharge off-TPU, the real pallas_call on a TPU
         _interp = _kernels.interpret_mode()
-        # no separate lowered chain for the multiway step: kernel route
-        # off still traces its body by direct discharge (query/fused.py
-        # build_fused's _mw_interp rationale)
-        _mw_interp = _interp if use_k else True
 
     # blocks arrive with a leading [1, ...] slab dim; the probe kernel
     # itself is the single-device one (query/fused.py _probe) — probes
@@ -263,7 +261,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             mw_tails.append(_gather_packed(tv, tm))
         acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
             acc_vals, acc_valid, mw_tails, mw_vcol0, mw_meta,
-            sig.join_caps[0], interpret=_mw_interp,
+            sig.join_caps[0], interpret=_interp,
         )
         # partial totals are per-shard: the reference's reseed rule
         # asks about GLOBAL intermediate emptiness, the capacity

@@ -68,6 +68,13 @@ def multiway_mode(config=None) -> str:
         return "on"
     if mode in ("off", "0", "false"):
         return "off"
+    from das_tpu import kernels
+
+    if not kernels.interpret_mode() and not kernels.enabled(config):
+        # on a TPU the multiway step is a real pallas_call (it has no
+        # lowered chain, and never discharges there): while the kernel
+        # route is off, auto keeps the binary chain
+        return "off"
     return "auto"
 
 

@@ -804,15 +804,11 @@ class CapStore:
     def __init__(self, tag: str):
         import os
 
-        base = os.environ.get(
-            "DAS_TPU_XLA_CACHE",
-            os.path.join(
-                os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-                "das_tpu", "xla",
-            ),
-        )
-        self.path = None if base == "0" else os.path.join(
-            os.path.dirname(base) or ".", f"caps_{tag}.json"
+        import das_tpu
+
+        root = das_tpu.cache_root()
+        self.path = None if root is None else os.path.join(
+            root, f"caps_{tag}.json"
         )
         self._data = {}
         if self.path and os.path.exists(self.path):
@@ -882,12 +878,12 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
     if use_k or mw:
         from das_tpu import kernels as _kernels
 
+        # the multiway step has no separate lowered chain: off-TPU its
+        # body traces by direct discharge whether or not the kernel
+        # route is on; on a TPU it is the real pallas_call like every
+        # other kernel (the planner's auto mode keeps the chain there
+        # while the kernel route is off — planner/search.py)
         _interp = _kernels.interpret_mode()
-        # the multiway step has no separate lowered chain: with the
-        # kernel route off its body still traces — by direct discharge
-        # to ordinary XLA ops (interpret=True works on ANY backend; the
-        # pallas_call lowering is reserved for the kernel route)
-        _mw_interp = _interp if use_k else True
 
     tables = {}
     term_ranges = []
@@ -949,7 +945,7 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
             acc_vals, acc_valid,
             [tables[i] for i in positives[1:mw]],
             mw_vcol0, mw_meta, sig.join_caps[0],
-            interpret=_mw_interp,
+            interpret=_interp,
         )
         join_counts.append(mw_totals[mw - 2])
         for t in range(max(0, min(mw - 1, len(positives) - 2))):

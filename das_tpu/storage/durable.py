@@ -81,6 +81,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import msgpack
 
+import das_tpu
+
 from das_tpu.core.exceptions import SnapshotCorruptError
 
 MANIFEST_FILE = "MANIFEST.json"
@@ -658,7 +660,7 @@ def write_snapshot(db, root: str, keep: Optional[int] = None) -> str:
                 "sections": sections,
                 "wal": WAL_FILE,
                 "warm_delta_version": None if warm is None else version,
-                "xla_cache_dir": os.environ.get("DAS_TPU_XLA_CACHE"),
+                "xla_cache_dir": das_tpu.compile_cache_dir(),
                 "created_unix": time.time(),
             }
             atomic_write_bytes(

@@ -297,8 +297,7 @@ def test_persistent_cache_hit_excluded_from_cold_start(ledger, tmp_path):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # the persistent cache binds its directory at first use; earlier
-    # tests in the process may have initialized it already (das_tpu
-    # enables DAS_TPU_XLA_CACHE's default dir at import)
+    # tests in the process may have initialized it already
     reset_cache()
     try:
         das, _db = _tensor_das()
