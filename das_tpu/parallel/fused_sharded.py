@@ -169,6 +169,18 @@ def _gather_packed(vals, valid):
     return full[:, :k], full[:, k].astype(bool)
 
 
+def _worst_shard(n):
+    """The worst shard's row count (a capacity-retry figure), as int32 —
+    a declared collective helper (parallel/mesh.py COLLECTIVE_SITES).
+    Pair totals are int64, and the TPU compiler lowers 64-bit all-reduce
+    for Sum only ("UNIMPLEMENTED: Supported lowering only of Sum all
+    reduce", v5e 2x2 AOT, tests/test_tpu_compile.py): the max runs on
+    the value clamped to int32, which loses nothing — the figure is only
+    ever compared with capacities <= max_result_capacity."""
+    n = jnp.minimum(n, jnp.iinfo(jnp.int32).max).astype(jnp.int32)
+    return lax.pmax(n, SHARD_AXIS)
+
+
 def _global_count(valid):
     """Global surviving-row count of a row-sharded validity mask (ONE
     psum) — a declared collective helper (parallel/mesh.py
@@ -234,7 +246,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
         )
         tables[i] = (vals, mask)
         pos_count[i] = lax.psum(mask.sum(dtype=jnp.int32), SHARD_AXIS)
-        term_ranges.append(lax.pmax(rng, SHARD_AXIS))
+        term_ranges.append(_worst_shard(rng))
 
     any_pos_empty = jnp.bool_(False)
     for i in positives:
@@ -267,7 +279,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
         # asks about GLOBAL intermediate emptiness, the capacity
         # retry about the worst shard's output
         g_totals = lax.psum(mw_totals, SHARD_AXIS)
-        join_totals.append(lax.pmax(mw_totals[mw - 2], SHARD_AXIS))
+        join_totals.append(_worst_shard(mw_totals[mw - 2]))
         exch_stats.append(jnp.int32(0))
         for t in range(max(0, min(mw - 1, len(positives) - 2))):
             reseed = reseed | (g_totals[t] == 0)
@@ -296,9 +308,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
                     pairs, sig.terms[i].var_cols, extra, jc,
                 )
             exch_stats.append(jnp.int32(0))
-            join_totals.append(
-                lax.pmax(total, SHARD_AXIS)
-            )
+            join_totals.append(_worst_shard(total))
             if n < len(positives) - 2:
                 global_n = lax.psum(
                     acc_valid.sum(dtype=jnp.int32), SHARD_AXIS
@@ -331,10 +341,8 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             acc_vals, acc_valid, total = join_impl(
                 lv2, lm2, rv2, rm2, pairs, extra, jc
             )
-            exch_stats.append(
-                lax.pmax(jnp.maximum(l_occ, r_occ), SHARD_AXIS)
-            )
-        join_totals.append(lax.pmax(total, SHARD_AXIS))
+            exch_stats.append(_worst_shard(jnp.maximum(l_occ, r_occ)))
+        join_totals.append(_worst_shard(total))
         if n < len(positives) - 2:
             global_n = lax.psum(
                 acc_valid.sum(dtype=jnp.int32), SHARD_AXIS

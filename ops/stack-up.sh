@@ -19,7 +19,12 @@ cd "$(dirname "$0")/.."
 PORT="${DAS_STACK_PORT:-7025}"
 STACK_DIR="${DAS_STACK_DIR:-/tmp/das_stack}"
 PIDFILE="$STACK_DIR/service.pid"
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+# The SERVICE takes whatever device the environment gives it (the chip
+# on a TPU host, the CPU where JAX_PLATFORMS=cpu is exported).  A chip
+# belongs to one process at a time, so everything else this script
+# starts — the checkpoint seeder, the probing clients — is pinned to the
+# CPU and can never take it from the service.
+OFF_CHIP=(env JAX_PLATFORMS=cpu)
 
 have_compose() {
   command -v docker >/dev/null 2>&1 && docker compose version >/dev/null 2>&1
@@ -41,8 +46,8 @@ if have_compose; then
   docker compose -f ops/compose.yml up -d --build
   echo "waiting for the service on :$PORT ..."
   for _ in $(seq 1 60); do
-    if python -m das_tpu.service.client --port "$PORT" create "probe_$RANDOM" \
-        >/dev/null 2>&1; then
+    if "${OFF_CHIP[@]}" python -m das_tpu.service.client --port "$PORT" \
+        create "probe_$RANDOM" >/dev/null 2>&1; then
       break
     fi
     sleep 2
@@ -56,7 +61,7 @@ mkdir -p "$STACK_DIR"
 make -C native >/dev/null
 
 # seed the checkpoint "volume" (idempotent)
-python -m das_tpu.service.seed_checkpoint "$STACK_DIR/kb"
+"${OFF_CHIP[@]}" python -m das_tpu.service.seed_checkpoint "$STACK_DIR/kb"
 
 # start the service bound to the checkpoint
 if [ -f "$PIDFILE" ] && kill -0 "$(cat "$PIDFILE")" 2>/dev/null; then
@@ -69,8 +74,8 @@ else
 fi
 
 for _ in $(seq 1 60); do
-  if python -m das_tpu.service.client --port "$PORT" create "probe_$RANDOM" \
-      >/dev/null 2>&1; then
+  if "${OFF_CHIP[@]}" python -m das_tpu.service.client --port "$PORT" \
+      create "probe_$RANDOM" >/dev/null 2>&1; then
     break
   fi
   sleep 1

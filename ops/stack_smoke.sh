@@ -6,7 +6,8 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 PORT="${1:-7025}"
 CLI=(python -m das_tpu.service.client --port "$PORT")
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+# clients only: the service holds the chip, a client must never ask for it
+export JAX_PLATFORMS=cpu
 
 fail() { echo "SMOKE FAIL: $1" >&2; exit 1; }
 
