@@ -36,8 +36,8 @@ def _cumsum_i64(x):
     — s64 is emulated as u32 pairs — and inside a fused `fori_loop` count
     body that reduce-window's stack allocation overflows the v5e 16MB
     scoped-vmem budget (observed: "reduce-window ... (u32[4,128],
-    u32[4,128]) ... 19.10M and limit 16.00M", BENCH_r03 tail) even though
-    the identical body compiles standalone.  associative_scan lowers to
+    u32[4,128]) ... 19.10M and limit 16.00M", the r03 driver record) even
+    though the identical body compiles standalone.  associative_scan lowers to
     slice+add steps with no scoped scratch.  The summed arrays here are
     left-table row counts (≤ the term capacity), so the log-depth cost is
     noise."""

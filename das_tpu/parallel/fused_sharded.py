@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from das_tpu import obs
@@ -48,7 +48,7 @@ from das_tpu.ops.join import (
     _join_tables_impl,
     _mix_columns,
 )
-from das_tpu.parallel.mesh import SHARD_AXIS, shard_map
+from das_tpu.parallel.mesh import SHARD_AXIS
 from das_tpu.query.fused import (
     ROUTE_CTYPE,
     ROUTE_TYPE,

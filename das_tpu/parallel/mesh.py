@@ -10,39 +10,11 @@ round-trips.  Multi-host pods extend the same mesh over DCN via
 
 from __future__ import annotations
 
-import inspect
 from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # modern API
-    from jax import shard_map as _raw_shard_map  # jax.shard_map is the fn
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map  # type: ignore
-
-#: the replication-check kwarg was renamed check_rep -> check_vma across
-#: jax versions; inspect ONCE which spelling this jax accepts so callers
-#: can use the modern name everywhere (a TypeError here used to be a seed
-#: failure in parallel/sharded_tree.py's replicate())
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_raw_shard_map).parameters)
-_REP_CHECK_KWARGS = ("check_vma", "check_rep")
-
-
-def shard_map(f, **kwargs):
-    """`jax.shard_map` with a version-compat shim for the replication
-    checker kwarg: `check_vma`/`check_rep` are translated to whichever
-    spelling this jax version supports, or dropped when neither exists
-    (the check is an assertion aid, never a semantics change)."""
-    for name in _REP_CHECK_KWARGS:
-        if name in kwargs and name not in _SHARD_MAP_PARAMS:
-            value = kwargs.pop(name)
-            other = [k for k in _REP_CHECK_KWARGS if k != name][0]
-            if other in _SHARD_MAP_PARAMS:
-                kwargs[other] = value
-    return _raw_shard_map(f, **kwargs)
-
 
 SHARD_AXIS = "shards"
 

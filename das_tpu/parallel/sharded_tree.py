@@ -47,10 +47,11 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from das_tpu.core.exceptions import CapacityOverflowError
-from das_tpu.parallel.mesh import SHARD_AXIS, shard_map
+from das_tpu.parallel.mesh import SHARD_AXIS
 from das_tpu.ops import composite as comp_ops
 from das_tpu.ops import posting
 from das_tpu.ops.join import _anti_join_impl, _dedup_table_impl, _join_tables_impl
