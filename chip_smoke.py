@@ -40,10 +40,6 @@ import sys
 import tempfile
 import time
 
-REPO = os.path.dirname(os.path.abspath(__file__))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
 #: the reference-scale KB shape (bench.py FLYBASE: 2.58 M nodes /
 #: 27.9 M links, SimplePatternMiner.ipynb cell 0), multiplied by --scale
 FLYBASE = dict(
@@ -106,7 +102,6 @@ class PlainKB:
         self.interacts = {}       # gene -> {gene} (stored orientation a->b)
         self.lists = set()        # (gene, process)
         self.evals = set()        # (predicate, gene, process)
-        n_interacts = 0
         with open(path) as fh:
             for line in fh:
                 line = line.rstrip("\n")
@@ -116,7 +111,7 @@ class PlainKB:
                     continue
                 m = self._INTERACTS.match(line)
                 if m:
-                    n_interacts += self.add_interacts(*m.groups())
+                    self.add_interacts(*m.groups())
                     continue
                 m = self._EVAL.match(line)
                 if m:
@@ -130,7 +125,6 @@ class PlainKB:
                     continue
                 check(line.startswith("(: ") and line.endswith(" Type)"),
                       f"plain parse: unexpected line {line!r}")
-        self.n_interacts = n_interacts
 
     def add_member(self, g, p) -> bool:
         s = self.procs_of.setdefault(g, set())
@@ -613,7 +607,6 @@ def phase_commit(s: Smoke) -> None:
 def phase_placement(s: Smoke) -> None:
     """Four chips: the row-sharded tables really live on `chips`
     distinct devices, about 1/chips of the rows each."""
-    import jax
     import numpy as np
 
     report = {}
@@ -634,7 +627,7 @@ def phase_placement(s: Smoke) -> None:
           == s.plain.counts()[1], "sharded rows do not add up to the links")
     stats = memory_stats()[: s.chips]
     used = [m["bytes_in_use"] for m in stats]
-    if jax.devices()[0].platform == "tpu":
+    if device_info()["platform"] == "tpu":
         check(all(u for u in used), f"a device holds nothing: {used}")
         check(max(used) <= 1.5 * min(used),
               f"device memory is not spread evenly: {used}")

@@ -30,7 +30,9 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
         "checkpoint dir auto-loaded by a bare DistributedAtomSpace()"),
     "DAS_TPU_PALLAS": (
         "use_pallas_kernels",
-        "kernel routing: auto (TPU-only) / on / off "
+        "kernel routing: auto (the lowered XLA route on every platform "
+        "until a kernel passes the Mosaic compile) / on (real pallas_call "
+        "on a TPU, raising the compiler's error; discharge off-TPU) / off "
         "(das_tpu/kernels/__init__.py enabled())"),
     "DAS_TPU_PLANNER": (
         "use_planner",
@@ -212,9 +214,12 @@ class DasConfig:
     # the store is fully re-finalized (storage/tensor_db.py refresh)
     delta_merge_threshold: int = 1 << 16
     # Pallas fused probe→gather→join kernels (das_tpu/kernels/):
-    # "auto" = on for TPU, off elsewhere; "on" forces them (off-TPU they
-    # run in interpret mode — answer-identical, used by the differential
-    # suite and the bench A/B); "off" forces the lowered op chains.
+    # "auto" = the lowered op chains on every platform (Mosaic refuses
+    # the kernels today — tests/test_tpu_compile.py pins the verdicts);
+    # "on" forces them (on a TPU the real pallas_call, which raises the
+    # compiler's error; off-TPU interpret mode — answer-identical, used
+    # by the differential suite and the bench A/B); "off" forces the
+    # lowered op chains.
     # Env DAS_TPU_PALLAS overrides (see das_tpu/kernels/__init__.py).
     use_pallas_kernels: str = "auto"
     # cost-based whole-plan query planner (das_tpu/planner/): cardinality

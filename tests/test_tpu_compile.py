@@ -116,29 +116,36 @@ def test_lowered_join_at_max_capacity(compile_for_chip):
     compile_for_chip(f, lv, lm, rv, rm)
 
 
-@pytest.fixture(scope="module")
-def grounded_job():
-    """The executor's own job for the smoke's grounded 3-clause
-    conjunction, planned on a tiny CPU store: the plan signature is
-    scale-free, only capacities and bucket lengths grow with the KB."""
+def _tiny_store_and_query(make_db):
+    """A tiny CPU store and the smoke's grounded 3-clause conjunction on
+    it: the plan signature is scale-free, only capacities and bucket
+    lengths grow with the KB."""
     from das_tpu.models.bio import build_bio_atomspace
     from das_tpu.query import compiler
     from das_tpu.query.ast import And, Link, Node, Variable
-    from das_tpu.query.fused import get_executor
-    from das_tpu.storage.tensor_db import TensorDB
 
     data, _, _ = build_bio_atomspace(
         n_genes=400, n_processes=40, members_per_gene=10,
         n_interactions=300, n_evaluations=60, seed=0,
     )
-    db = TensorDB(data, DasConfig())
+    db = make_db(data)
     g = db.get_all_nodes("Gene", names=True)[0]
     query = And([
         Link("Member", [Node("Gene", g), Variable("V3")], True),
         Link("Member", [Variable("V2"), Variable("V3")], True),
         Link("Interacts", [Node("Gene", g), Variable("V2")], True),
     ])
-    job = get_executor(db)._exec_job(compiler.plan_query(db, query), False)
+    return db, compiler.plan_query(db, query)
+
+
+@pytest.fixture(scope="module")
+def grounded_job():
+    """The executor's own job for the grounded conjunction."""
+    from das_tpu.query.fused import get_executor
+    from das_tpu.storage.tensor_db import TensorDB
+
+    db, plans = _tiny_store_and_query(lambda data: TensorDB(data, DasConfig()))
+    job = get_executor(db)._exec_job(plans, False)
     assert job is not None
     return job
 
@@ -208,31 +215,18 @@ def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
     smoke store's per-shard size.  The collectives must be there."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from das_tpu.models.bio import build_bio_atomspace
     from das_tpu.parallel.fused_sharded import (
         build_fused_sharded,
         get_sharded_executor,
     )
     from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh
     from das_tpu.parallel.sharded_db import ShardedDB
-    from das_tpu.query import compiler
-    from das_tpu.query.ast import And, Link, Node, Variable
     from das_tpu.storage.delta import capacity_class
 
-    data, _, _ = build_bio_atomspace(
-        n_genes=400, n_processes=40, members_per_gene=10,
-        n_interactions=300, n_evaluations=60, seed=0,
+    db, plans = _tiny_store_and_query(
+        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4))
     )
-    db = ShardedDB(data, DasConfig(), mesh=make_mesh(4))
-    g = db.get_all_nodes("Gene", names=True)[0]
-    query = And([
-        Link("Member", [Node("Gene", g), Variable("V3")], True),
-        Link("Member", [Variable("V2"), Variable("V3")], True),
-        Link("Interacts", [Node("Gene", g), Variable("V2")], True),
-    ])
-    job = get_sharded_executor(db)._exec_job(
-        compiler.plan_query(db, query), False
-    )
+    job = get_sharded_executor(db)._exec_job(plans, False)
     assert job is not None
     sig = job.plan_sig()
     assert sig.n_shards == 4 and not sig.use_kernels
