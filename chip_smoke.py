@@ -678,12 +678,20 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     args = ap.parse_args(argv)
 
+    # the DEFAULT path is what is brought up: the program ledger swaps
+    # jit for AOT compiles and tracing adds spans to every stage
+    for name in ("DAS_TPU_PROFLOG", "DAS_TPU_TRACE"):
+        if os.environ.get(name, "0").lower() not in ("", "0", "off", "false"):
+            print(f"chip_smoke: unset {name} — it changes the path under "
+                  "test", file=sys.stderr)
+            return 2
+
     import das_tpu  # noqa: F401  (x64 on before the first jax use)
 
     device = device_info()
     on_chip = device["platform"] == "tpu"
-    emit("gate", device=device, scale=args.scale, seed=args.seed,
-         chips=args.chips)
+    # a refusal prints nothing on stdout: no line there can be read as a
+    # result
     if not on_chip and args.scale > REHEARSAL_MAX_SCALE:
         print(f"chip_smoke: no accelerator (platform {device['platform']}); "
               f"only a rehearsal at --scale <= {REHEARSAL_MAX_SCALE} runs "
@@ -698,6 +706,8 @@ def main(argv=None) -> int:
               f"{device['count']} or expose {args.chips}", file=sys.stderr)
         return EXIT_NO_ACCELERATOR
 
+    emit("gate", device=device, scale=args.scale, seed=args.seed,
+         chips=args.chips)
     run_phases(args.scale, args.seed, args.chips)
 
     if not on_chip:
