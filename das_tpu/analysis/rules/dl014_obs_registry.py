@@ -4,14 +4,17 @@ Contract: the obs layer's value is that dashboards, Perfetto queries
 and the bench's percentile headlines key on STABLE names.  A typo'd
 literal (`obs.span("serve.dipsatch")`) records into a lane nobody
 watches while the declared name goes silent — the DL004 failure mode,
-re-created one layer up.  `das_tpu/obs/registry.py` declares the three
+re-created one layer up.  `das_tpu/obs/registry.py` declares the
 closed sets (SPAN_NAMES / COUNTER_NAMES / HISTOGRAM_NAMES; the metric
-dicts are BUILT from them), and this rule pins the literals both ways:
+dicts are BUILT from them — and PROGRAM_NAMES, the module names the
+device trace shows, which the benchmark's device-time readers key on),
+and this rule pins the literals both ways:
 
   * every string literal passed as the NAME argument of a recording
     call — `span(...)`, `event(...)`, `annotation(...)`, `record(...)`
-    (first arg) and `counter(...)` / `histogram(...)` — anywhere in the
-    analyzed set must be a declared member of the matching registry;
+    (first arg), `counter(...)` / `histogram(...)` and
+    `named_program(...)` — anywhere in the analyzed set must be a
+    declared member of the matching registry;
   * every declared name must be used by at least one recording call
     site (full-set runs only — a --changed-only subset may simply not
     include the caller): a stale entry is dead vocabulary the docs and
@@ -50,9 +53,12 @@ _CALL_TO_REGISTRY = {
     "record": "SPAN_NAMES",
     "counter": "COUNTER_NAMES",
     "histogram": "HISTOGRAM_NAMES",
+    "named_program": "PROGRAM_NAMES",
 }
 
-_REGISTRY_NAMES = ("SPAN_NAMES", "COUNTER_NAMES", "HISTOGRAM_NAMES")
+_REGISTRY_NAMES = (
+    "SPAN_NAMES", "COUNTER_NAMES", "HISTOGRAM_NAMES", "PROGRAM_NAMES",
+)
 
 
 def _find_registries(ctx: AnalysisContext):

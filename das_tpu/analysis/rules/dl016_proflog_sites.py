@@ -16,7 +16,7 @@ that constructs a device program — attributed to its OUTERMOST
 enclosing function, module-qualified like DL013 ("fused.build_fused",
 "common.run_kernel") — to its ledger site label, or None for a
 DECLARED-EXEMPT scope (per-op staged programs, kernel wrappers that
-trace inside instrumented programs, ingest-time builders).  Four legs:
+trace inside instrumented programs, ingest-time builders).  Five legs:
 
   * a jit/pallas reference in an UNdeclared scope fails lint — every
     program-construction site stays a reviewed decision in one list;
@@ -29,7 +29,12 @@ trace inside instrumented programs, ingest-time builders).  Four legs:
     lane nobody aggregates (the DL004/DL014 failure mode);
   * a declared scope with NO jit/pallas reference is a stale entry
     (full-set runs only — a --changed-only subset may not include the
-    module).
+    module);
+  * where the analyzed set declares PROGRAM_NAMES (obs/registry.py), a
+    scope that calls `instrument("<label>")` must name its program
+    `named_program("das_<label>", ...)`: the ledger site and the module
+    name the device trace shows stay one vocabulary (DL014 pins the
+    name literals against PROGRAM_NAMES in both directions).
 
 Attribution counts ANY AST reference to `jax.jit` or `pl.pallas_call`
 (call, decorator, `partial(jax.jit, ...)` argument) — the construction
@@ -49,6 +54,7 @@ from das_tpu.analysis.core import (
     const_str,
     module_assign,
     register,
+    str_collection,
 )
 
 #: the program-construction primitives this registry closes over —
@@ -142,8 +148,9 @@ def _outermost_scopes(sf) -> Iterable[Tuple[str, ast.AST]]:
     yield from walk(sf.tree, [])
 
 
-def _hook_literals(fn: ast.AST) -> Iterable[Tuple[int, str]]:
-    """(line, label literal) for every ledger hook call under `fn`."""
+def _call_literals(fn: ast.AST, names) -> Iterable[Tuple[int, str]]:
+    """(line, first-argument literal) for every call under `fn` to a
+    function whose bare name is in `names`."""
     for node in ast.walk(fn):
         if not isinstance(node, ast.Call):
             continue
@@ -151,15 +158,24 @@ def _hook_literals(fn: ast.AST) -> Iterable[Tuple[int, str]]:
         name = f.id if isinstance(f, ast.Name) else (
             f.attr if isinstance(f, ast.Attribute) else None
         )
-        if name in _HOOK_CALLS and node.args:
+        if name in names and node.args:
             lit = const_str(node.args[0])
             if lit is not None:
                 yield node.lineno, lit
 
 
+def _hook_literals(fn: ast.AST) -> Iterable[Tuple[int, str]]:
+    """(line, label literal) for every ledger hook call under `fn`."""
+    return _call_literals(fn, _HOOK_CALLS)
+
+
 @register("DL016", "program-construction sites vs PROGRAM_SITES registry")
 def check(ctx: AnalysisContext) -> Iterable[Finding]:
     registry = _find_registry(ctx)
+    names_declared = any(
+        str_collection(module_assign(sf.tree, "PROGRAM_NAMES")) is not None
+        for sf in ctx.modules()
+    )
     used_scopes: Set[str] = set()
     used_labels: Set[str] = set()
     any_ref = False
@@ -188,6 +204,21 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
                         "typo'd site records into an aggregate nobody "
                         "reads while the declared lane goes silent",
                     )
+            if names_declared:
+                named = {
+                    lit for _l, lit in _call_literals(fn, ("named_program",))
+                }
+                for line, lit in _call_literals(fn, ("instrument",)):
+                    if "das_" + lit not in named:
+                        yield Finding(
+                            "DL016", sf.posix, line,
+                            f"scope `{scope}` instruments ledger site "
+                            f"{lit!r} but names no program "
+                            f"named_program('das_{lit}', ...) — its "
+                            "module shows as jit_fn in the device trace "
+                            "and the benchmark's device-time readers "
+                            "cannot tell it from a merge",
+                        )
             if not ref_lines:
                 continue
             any_ref = True

@@ -22,6 +22,7 @@ import json
 from enum import Enum, auto
 from typing import Dict, List, Optional, Tuple, Union
 
+from das_tpu import obs
 from das_tpu.core.config import DasConfig
 from das_tpu.core.exceptions import BreakerOpenError
 from das_tpu.core.schema import UNORDERED_LINK_TYPES, WILDCARD
@@ -66,7 +67,7 @@ class _QueryManyJob:
 
     __slots__ = ("das", "queries", "output_format", "plans_lists", "idxs",
                  "pending", "db_ref", "version", "sharded", "settle_rtt_ms",
-                 "cache_only")
+                 "cache_only", "stale_round")
 
     def __init__(self, das, queries, output_format, cache_only=False):
         self.das = das
@@ -78,6 +79,10 @@ class _QueryManyJob:
         # entries the cache cannot answer yield a typed, retryable
         # BreakerOpenError instead
         self.cache_only = cache_only
+        # a commit overtook the dispatched round (dropped whole at
+        # settle, or broken mid-stream): its unanswered queries re-run
+        # one by one, counted as `exec.stale_reruns`
+        self.stale_round = False
         self.plans_lists: List = []
         self.idxs: List[int] = []
         self.pending = None
@@ -100,8 +105,6 @@ class _QueryManyJob:
         self.db_ref = das.db
         self.version = getattr(das.db, "delta_version", None)
         if (hasattr(das.db, "dev") or self.sharded) and queries:
-            from das_tpu import obs
-
             with obs.span("serve.plan", queries=len(queries)) as sp:
                 for i, q in enumerate(queries):
                     plans = query_compiler.plan_query(das.db, q)
@@ -149,6 +152,7 @@ class _QueryManyJob:
             if self.settle_rtt_ms is None and pending.fetch_ms:
                 self.settle_rtt_ms = pending.fetch_ms[0]
             if self._stale():
+                self.stale_round = True
                 break
             try:
                 out_s = answer_fn(j, res)
@@ -188,6 +192,7 @@ class _QueryManyJob:
             # window ran, each group re-checks its dispatch-time version
             # here before materializing anything.
             self.pending = None
+            self.stale_round = True
         if self.pending is not None and self.sharded:
             from das_tpu import kernels as _kernels
             from das_tpu.parallel.sharded_db import ShardedTable
@@ -214,7 +219,7 @@ class _QueryManyJob:
                     )
                 answer = PatternMatchingAnswer()
                 matched = das.db.materialize(table, answer)
-                out_s = das._format_answer(
+                out_s = das._formatted(
                     matched, answer, self.output_format
                 )
                 query_compiler.ROUTE_COUNTS["sharded"] += 1
@@ -254,7 +259,7 @@ class _QueryManyJob:
                     route = "staged"
                 answer = PatternMatchingAnswer()
                 matched = query_compiler.materialize(das.db, table, answer)
-                out_s = das._format_answer(
+                out_s = das._formatted(
                     matched, answer, self.output_format
                 )
                 # counted only once the answer exists: a failure re-runs
@@ -281,6 +286,10 @@ class _QueryManyJob:
                 # (the coalescer stamps the retry-after hint)
                 yield i, BreakerOpenError()
                 continue
+            if obs.enabled():
+                obs.counter("exec.per_query_fallbacks").inc()
+                if self.stale_round and i in self.idxs:
+                    obs.counter("exec.stale_reruns").inc()
             try:
                 yield i, das.query(q, self.output_format)
             except Exception as exc:  # noqa: BLE001 — per-query isolation
@@ -629,7 +638,7 @@ class DistributedAtomSpace:
     ) -> str:
         answer = PatternMatchingAnswer()
         matched = self._dispatch_query(query, answer)
-        return self._format_answer(matched, answer, output_format)
+        return self._formatted(matched, answer, output_format)
 
     def query_many(
         self,
@@ -669,6 +678,19 @@ class DistributedAtomSpace:
         typed retryable BreakerOpenError."""
         return _QueryManyJob(self, queries, output_format,
                              cache_only=cache_only)
+
+    def _formatted(
+        self, matched, answer: PatternMatchingAnswer, output_format
+    ) -> str:
+        """`_format_answer` under span `exec.format` (attrs: rows,
+        bytes) — the per-query answer path's last host stage.  With
+        tracing off: the bare call."""
+        if not obs.enabled():
+            return self._format_answer(matched, answer, output_format)
+        with obs.span("exec.format", rows=len(answer.assignments)) as sp:
+            out = self._format_answer(matched, answer, output_format)
+            sp.set(bytes=len(out))
+        return out
 
     def _format_answer(
         self, matched, answer: PatternMatchingAnswer, output_format

@@ -45,6 +45,7 @@ from das_tpu.obs.recorder import NOOP_SPAN, TraceRecorder  # noqa: F401
 from das_tpu.obs.registry import (  # noqa: F401
     COUNTER_NAMES,
     HISTOGRAM_NAMES,
+    PROGRAM_NAMES,
     SPAN_NAMES,
 )
 
@@ -109,3 +110,24 @@ def mark() -> Optional[Tuple[int, float]]:
 
 def events():
     return REC.events()
+
+
+def origin() -> float:
+    """`time.perf_counter()` at the recorder's origin: an event's
+    timestamp plus this is its perf_counter time, which the `obs.sync`
+    annotation (obs/jaxprof.py) ties to a device trace's clock."""
+    return REC.origin()
+
+
+def named_program(name: str, fn=None, count_only: bool = False):
+    """Give the function about to be jitted its declared module name
+    (obs/registry.py PROGRAM_NAMES; `_count` appended for a count-only
+    variant), so the device trace shows `jit_<name>` instead of
+    `jit_fn`.  Trace-time only.  Without `fn`: a decorator, to sit
+    under `@jax.jit`."""
+    if name not in PROGRAM_NAMES:
+        raise KeyError(f"undeclared device program name {name!r}")
+    if fn is None:
+        return lambda f: named_program(name, f, count_only)
+    fn.__name__ = fn.__qualname__ = name + ("_count" if count_only else "")
+    return fn

@@ -24,10 +24,17 @@ from typing import Dict, List, Optional, Tuple
 from das_tpu.obs import metrics as _metrics
 
 
-def chrome_trace(events: List[Tuple]) -> Dict:
+def chrome_trace(events: List[Tuple], origin: Optional[float] = None) -> Dict:
     """Render recorder event tuples (TraceRecorder.events()) into a
     Chrome trace-event dict — `json.dumps` of it loads in Perfetto /
-    chrome://tracing."""
+    chrome://tracing.  `origin` (default: the process recorder's) is
+    the perf_counter second that `ts` 0 stands for; it goes into the
+    metadata so the file can be laid over a device trace that holds
+    the `obs.sync` annotation."""
+    if origin is None:
+        from das_tpu import obs
+
+        origin = obs.origin()
     lanes: Dict[Optional[str], int] = {}
     threads: Dict[str, int] = {}
     out: List[Dict] = []
@@ -64,7 +71,8 @@ def chrome_trace(events: List[Tuple]) -> Dict:
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
                 "args": {"name": thread},
             })
-    return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
+    return {"traceEvents": meta + out, "displayTimeUnit": "ms",
+            "metadata": {"perf_counter_origin_s": origin}}
 
 
 def dump_chrome_trace(events: List[Tuple], path: str) -> str:

@@ -14,16 +14,25 @@ recorder's disabled-path contract.
 `jax.profiler.start_trace`/`stop_trace`: the hardware-closeout runbook
 is "set DAS_TPU_TRACE=1 DAS_TPU_TRACE_JAX=1 DAS_TPU_TRACE_DIR=/tmp/tb,
 run the workload, open both the obs trace and the device trace in
-Perfetto" (ARCHITECTURE §13).
+Perfetto" (ARCHITECTURE §13).  The two files share one axis through
+the `obs.sync` annotation (SYNC_NAME below) and the origin the obs
+trace carries in its metadata.
 """
 
 from __future__ import annotations
 
 import os
+import time
 
 from das_tpu.obs.recorder import NOOP_SPAN, TRUTHY
 
 _started = {"dir": None}
+
+#: the host annotation `maybe_start_trace` writes into the device trace
+#: right after it starts: its `t_ns` stat is `time.perf_counter_ns()`
+#: at that moment, so (t_ns - the event's trace time) carries any
+#: recorder timestamp + `obs.origin()` onto the device trace's axis
+SYNC_NAME = "obs.sync"
 
 #: memoized on the RAW env string: annotation() sits on the dispatch
 #: and settle-fetch hot paths outside the obs.enabled() guard, so the
@@ -66,6 +75,9 @@ def maybe_start_trace(config=None) -> bool:
 
     jax.profiler.start_trace(trace_dir)
     _started["dir"] = trace_dir
+    with jax.profiler.TraceAnnotation(
+            SYNC_NAME, t_ns=time.perf_counter_ns()):
+        pass
     return True
 
 

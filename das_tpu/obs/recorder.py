@@ -16,7 +16,8 @@ timestamps (tests/test_zobs.py pins the no-allocation contract
 structurally).  Hot call sites (the executor dispatch halves) guard on
 `enabled()` so even their attribute packing is skipped.
 
-Timing discipline: `time.perf_counter()` only — host-monotonic, no
+Timing discipline: `time.perf_counter()` for wall time and
+`time.thread_time()` for a span's `cpu_ms` — host clocks only, no
 device sync (DL001/DL010: the dispatch halves stay sync-free; the
 recorder never calls into jax).  Ring bound: env `DAS_TPU_TRACE_RING`
 (default 65536 events); past it the OLDEST events drop (a long-running
@@ -50,9 +51,16 @@ LOCK_DISCIPLINE = {
     "TraceRecorder._ring": "_lock",
     "TraceRecorder._next": "_lock",
     "TraceRecorder._t_origin": "_lock",
+    # a span is never shared: it lives and dies on the thread that
+    # opened it, which is the only one to touch its clocks and attrs
+    "_Span.t0": "worker",
+    "_Span._cpu0": "worker",
+    "_Span.attrs": "worker",
 }
 
-WORKER_METHODS: Dict[str, Tuple[str, ...]] = {}
+WORKER_METHODS: Dict[str, Tuple[str, ...]] = {
+    "_Span": ("__enter__", "__exit__"),
+}
 
 #: the accepted "on" spellings for obs env switches — ONE definition
 #: (jaxprof's DAS_TPU_TRACE_JAX gate reuses it), so the two flags
@@ -98,18 +106,25 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """One live span: created with its start timestamp, records itself
-    on __exit__.  No post-construction mutation of recorder state —
-    the single ring append happens at exit."""
+    """One live span: its clocks start at `__enter__` (a span built
+    ahead of a `with lock, span:` pair must not time the wait for the
+    lock) and it records itself on `__exit__`.  Besides wall time it
+    takes the calling thread's CPU time (`time.thread_time`) at both
+    ends and records the difference as attr `cpu_ms`: on a span that
+    never blocks on I/O, a lock or the device, wall minus CPU is the
+    time the thread waited for the interpreter.  No post-construction
+    mutation of recorder state — the single ring append happens at
+    exit."""
 
-    __slots__ = ("_rec", "name", "trace", "attrs", "t0")
+    __slots__ = ("_rec", "name", "trace", "attrs", "t0", "_cpu0")
 
     def __init__(self, rec: "TraceRecorder", name: str, trace: int, attrs):
         self._rec = rec
         self.name = name
         self.trace = trace
         self.attrs = attrs
-        self.t0 = time.perf_counter()
+        self.t0 = 0.0
+        self._cpu0 = 0.0
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. the drained
@@ -117,12 +132,15 @@ class _Span:
         self.attrs.update(attrs)
 
     def __enter__(self):
+        self._cpu0 = time.thread_time()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *_exc):
+        dur = time.perf_counter() - self.t0
+        self.attrs["cpu_ms"] = (time.thread_time() - self._cpu0) * 1e3
         self._rec.record(
-            self.name, "X", self.t0,
-            time.perf_counter() - self.t0, self.trace, self.attrs,
+            self.name, "X", self.t0, dur, self.trace, self.attrs,
         )
         return False
 
@@ -217,6 +235,11 @@ class TraceRecorder:
         self.record(name, "i", time.perf_counter(), 0.0, trace, attrs)
 
     # -- readout ----------------------------------------------------------
+
+    def origin(self) -> float:
+        """The `time.perf_counter()` reading every recorded timestamp
+        is relative to (construction or the last reset)."""
+        return self._t_origin
 
     def events(self) -> List[Tuple]:
         with self._lock:

@@ -56,6 +56,9 @@ SPAN_NAMES = (
     "exec.settle_fetch",
     #: span: binding table -> frozen assignments (query/compiler.py)
     "exec.materialize",
+    #: span: assignments -> the answer string of one query
+    #: (api/atomspace.py _formatted) — attrs: rows, bytes
+    "exec.format",
     #: instants: delta-versioned result/tree/count cache traffic
     #: (query/fused.py ResultCache)
     "cache.hit",
@@ -65,8 +68,22 @@ SPAN_NAMES = (
     #: incremental commit vs full rebuild
     "commit.delta",
     "commit.rebuild",
+    #: spans: one incremental commit where the work happens
+    #: (storage/delta.py _apply_delta) — commit.apply (attrs: version,
+    #: nodes, links) holds commit.stage (intern + columnize + ENQUEUE
+    #: of the device merges: host cost only), dur.wal_append when a WAL
+    #: is armed, and commit.swap (the visible half); a failed stage
+    #: records no commit.swap
+    "commit.apply",
+    "commit.stage",
+    "commit.swap",
     #: instant: planner est-vs-actual at job settle (das_tpu/planner)
     "planner.observe",
+    #: span: planner statistics recomputed — the estimator's rebuild
+    #: after delta_version moved (planner/stats.py estimator_for) and
+    #: each uncached whole-table extraction (distinct_at,
+    #: query/starcount.py _table_sparse) — attrs: version, rows
+    "planner.stats",
     #: instant: one query expired past its serving deadline
     #: (service/coalesce.py, DasConfig.query_deadline_ms)
     "serve.deadline",
@@ -87,13 +104,20 @@ SPAN_NAMES = (
     #: span: one warm-state restore — newest valid generation + WAL
     #: replay + warm bundle (storage/durable.py restore)
     "dur.restore",
-    #: instant: one write-ahead delta-log record appended + fsynced
-    #: (storage/durable.py DeltaLog.append) — attrs: version, kind,
-    #: framed bytes
+    #: span: one write-ahead delta-log record — capture + pack + write
+    #: + flush + fsync (storage/durable.py DeltaLog.append) — attrs:
+    #: version, kind, framed bytes
     "dur.wal_append",
     #: instant: a torn WAL tail record truncated at the last valid
     #: frame boundary (storage/durable.py _truncate_wal)
     "dur.wal_truncate",
+    #: span: one query RPC on its gRPC thread, parse to reply
+    #: (service/server.py DasService.query) — the trace id is born
+    #: here and rides the mark into coalescer.submit, so it is the id
+    #: of serve.submit ... serve.answer too
+    "wire.query",
+    #: span: DSL text -> query AST (child of wire.query)
+    "wire.parse",
 )
 
 #: monotone counters (obs/metrics.py COUNTERS is built from this)
@@ -109,6 +133,13 @@ COUNTER_NAMES = (
     "commit.rebuilds",
     "exec.dispatches",
     "exec.fetches",
+    #: queries re-run one by one because a commit overtook their
+    #: dispatched round (api/atomspace.py settle_iter, `_stale()`)
+    "exec.stale_reruns",
+    #: every query the coalesced path hands to the per-query
+    #: dispatcher `das.query`, whatever the cause: settle_iter's last
+    #: loop and the coalescer's fall-back
+    "exec.per_query_fallbacks",
     #: queries expired past their serving deadline (service/coalesce.py)
     "serve.deadline_misses",
     #: circuit-breaker trips CLOSED->OPEN / recoveries HALF_OPEN->CLOSED
@@ -138,6 +169,9 @@ HISTOGRAM_NAMES = (
     "serve.dispatch_ms",
     #: per-group streamed settle wall time
     "serve.settle_ms",
+    #: wait for one acquire of the tenant lock on the coalescer worker
+    #: (dispatch, each settle step, each fall-back query)
+    "serve.lock_wait_ms",
     #: submit -> answer delivery (the open-loop latency the bench
     #: derives its p50/p95/p99 headline from)
     "serve.answer_ms",
@@ -152,4 +186,24 @@ HISTOGRAM_NAMES = (
     #: replay + warm bundle (storage/durable.py restore, ISSUE 15):
     #: the replica-fleet cold-start figure
     "dur.restore_ms",
+)
+
+#: module names of the jitted device programs on the served and commit
+#: paths, as a device trace shows them (`jit_<name>` on the XLA Modules
+#: line): `obs.named_program("<name>", fn)` sets the traced function's
+#: `__name__` before `jax.jit`, count-only variants append `_count`.
+#: The whole-plan builders are `das_<ledger site>` (obs/proflog.py
+#: PROGRAM_SITES); the commit programs are `das_merge*` / `das_insert*`.
+#: daslint DL014 pins the literals both ways, like the span names.
+PROGRAM_NAMES = (
+    "das_fused",
+    "das_fused_tree",
+    "das_fused_exact",
+    "das_count_batch",
+    "das_count_loop",
+    "das_sharded",
+    "das_sharded_tree",
+    "das_merge_padded",
+    "das_insert_rows",
+    "das_merge_sharded",
 )

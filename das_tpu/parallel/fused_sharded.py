@@ -896,7 +896,9 @@ class _ShardedExecJob:
                 obs.proflog.instrument(
                     "sharded",
                     obs.proflog.sig_digest(plan_sig, self.count_only),
-                    jax.jit(fn),
+                    jax.jit(obs.named_program(
+                        "das_sharded", fn, self.count_only
+                    )),
                     model_bytes=partial(program_model_bytes, plan_sig),
                 ),
                 out_names,
@@ -1041,7 +1043,8 @@ class _ShardedTreeExecJob(_TreeExecJob):
         fn, out_names = build_sharded_tree_fused(tree_sig, self.ex.mesh)
         return obs.proflog.instrument(
             "sharded_tree", obs.proflog.sig_digest(tree_sig, False),
-            jax.jit(fn), model_bytes=partial(tree_model_bytes, tree_sig),
+            jax.jit(obs.named_program("das_sharded_tree", fn)),
+            model_bytes=partial(tree_model_bytes, tree_sig),
         ), out_names
 
     def _blk_len(self, j) -> int:

@@ -36,6 +36,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from das_tpu import obs
 from das_tpu.core.config import DasConfig
 from das_tpu.core.exceptions import CapacityOverflowError
 from das_tpu.ops.join import _anti_join_impl, _join_tables_impl, _build_term_table_impl
@@ -297,11 +298,11 @@ class ShardedTables:
                 return cols, idx
 
             spec = P(SHARD_AXIS)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(obs.named_program("das_merge_sharded", shard_map(
                 kernel, mesh=self.mesh,
                 in_specs=(spec, spec, spec, spec, spec),
                 out_specs=(spec, spec),
-            ))
+            )))
             self._merge_cache[(arity, m_local, dcap)] = fn
         base_cols = [base.type_id, base.ctype, base.targets, base.targets_sorted]
         starts = jax.device_put(base.slab_sizes, shard)

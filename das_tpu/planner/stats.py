@@ -37,6 +37,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from das_tpu import obs
 from das_tpu.query.fused import estimate_plan_rows
 
 
@@ -115,15 +116,22 @@ class CardinalityEstimator:
         if hit is not None:
             return hit
         base = np.int64(type_id) << 32
-        total = 0
-        for b in host_segments(self.db, arity):
-            keys = b.key_type_pos[pos]
-            lo = int(np.searchsorted(keys, base, side="left"))
-            hi = int(np.searchsorted(
-                keys, base + (np.int64(1) << 31), side="left"
-            ))
-            if hi > lo:
-                total += 1 + int(np.count_nonzero(np.diff(keys[lo:hi])))
+        total = rows = 0
+        # an uncached whole-table pass: what a commit makes the planner
+        # pay again (the estimator is rebuilt per delta_version)
+        with obs.span("planner.stats", what="distinct_at") as sp:
+            for b in host_segments(self.db, arity):
+                keys = b.key_type_pos[pos]
+                lo = int(np.searchsorted(keys, base, side="left"))
+                hi = int(np.searchsorted(
+                    keys, base + (np.int64(1) << 31), side="left"
+                ))
+                if hi > lo:
+                    rows += hi - lo
+                    total += 1 + int(
+                        np.count_nonzero(np.diff(keys[lo:hi]))
+                    )
+            sp.set(version=self.version, rows=rows)
         self._distinct[key] = total
         return total
 
@@ -307,6 +315,11 @@ def estimator_for(db) -> Optional[CardinalityEstimator]:
     est = getattr(db, "_planner_estimator", None)
     version = getattr(db, "delta_version", None)
     if est is None or est.version != version or est.db is not db:
-        est = CardinalityEstimator(db)
-        db._planner_estimator = est
+        # the rebuild itself is cheap (empty memo dicts); the span marks
+        # WHEN the statistics went cold — the uncached extractions that
+        # follow record their own planner.stats spans
+        with obs.span("planner.stats", what="rebuild", version=version,
+                      rows=0):
+            est = CardinalityEstimator(db)
+            db._planner_estimator = est
     return est

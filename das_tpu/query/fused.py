@@ -561,18 +561,26 @@ def _probe(sig: FusedTermSig, arrays, key, fixed_vals, cap: int,
             extra_fixed=sig.extra_fixed,
             interpret=kernels.interpret_mode(),
         )
-    lo = jnp.searchsorted(sorted_keys, key, side="left")
-    hi = jnp.searchsorted(sorted_keys, key, side="right")
-    range_count = (hi - lo).astype(jnp.int32)
-    offs = jnp.arange(cap, dtype=jnp.int32)
-    valid = offs < range_count
-    idx = jnp.clip(lo.astype(jnp.int32) + offs, 0, sorted_keys.shape[0] - 1)
-    local = jnp.where(valid, perm[idx], jnp.int32(2**31 - 1))
-    safe = jnp.clip(local, 0, targets.shape[0] - 1)
-    mask = valid
-    for i, pos in enumerate(sig.extra_fixed):
-        mask = mask & (targets[safe, pos] == fixed_vals[i])
-    vals, mask = _build_term_table_impl(targets, local, mask, sig.var_cols, sig.eq_pairs)
+    # named scopes: the stages an operator reads in XProf (trace-time
+    # only — they label the ops, they add none)
+    with jax.named_scope("probe"):
+        lo = jnp.searchsorted(sorted_keys, key, side="left")
+        hi = jnp.searchsorted(sorted_keys, key, side="right")
+        range_count = (hi - lo).astype(jnp.int32)
+        offs = jnp.arange(cap, dtype=jnp.int32)
+        valid = offs < range_count
+        idx = jnp.clip(
+            lo.astype(jnp.int32) + offs, 0, sorted_keys.shape[0] - 1
+        )
+    with jax.named_scope("gather"):
+        local = jnp.where(valid, perm[idx], jnp.int32(2**31 - 1))
+        safe = jnp.clip(local, 0, targets.shape[0] - 1)
+        mask = valid
+        for i, pos in enumerate(sig.extra_fixed):
+            mask = mask & (targets[safe, pos] == fixed_vals[i])
+        vals, mask = _build_term_table_impl(
+            targets, local, mask, sig.var_cols, sig.eq_pairs
+        )
     return vals, mask, range_count
 
 
@@ -897,8 +905,11 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
             # type's key range, and it exerts no capacity pressure.
             keys_sorted = bucket_arrays[i][0]
             tid = jnp.asarray(keys[i], jnp.int64)
-            lo = jnp.searchsorted(keys_sorted, tid << 32, side="left")
-            hi = jnp.searchsorted(keys_sorted, (tid + 1) << 32, side="left")
+            with jax.named_scope("probe"):
+                lo = jnp.searchsorted(keys_sorted, tid << 32, side="left")
+                hi = jnp.searchsorted(
+                    keys_sorted, (tid + 1) << 32, side="left"
+                )
             pos_count[i] = (hi - lo).astype(jnp.int32)
             tables[i] = None
             term_ranges.append(jnp.int32(0))
@@ -942,12 +953,13 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
         # t-th internal join triggers iff its absolute position is
         # before the LAST join of the whole program (the chain's
         # `n < len(positives) - 2` rule).
-        acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
-            acc_vals, acc_valid,
-            [tables[i] for i in positives[1:mw]],
-            mw_vcol0, mw_meta, sig.join_caps[0],
-            interpret=_interp,
-        )
+        with jax.named_scope("join"):
+            acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
+                acc_vals, acc_valid,
+                [tables[i] for i in positives[1:mw]],
+                mw_vcol0, mw_meta, sig.join_caps[0],
+                interpret=_interp,
+            )
         join_counts.append(mw_totals[mw - 2])
         for t in range(max(0, min(mw - 1, len(positives) - 2))):
             reseed = reseed | (mw_totals[t] == 0)
@@ -959,42 +971,46 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
         # duplicate-free (output row <-> (left row, right row) is a
         # bijection: shared columns agree, extras come from exactly one
         # side, and each side's rows are unique)
-        if index_joins[t] >= 0:
-            ks, perm, targets, _tid = bucket_arrays[i]
-            if use_k:
-                acc_vals, acc_valid, total = _kernels.index_join_impl(
-                    acc_vals, acc_valid, ks, perm, targets, keys[i],
-                    pairs, sig.terms[i].var_cols, extra,
-                    jc, interpret=_interp,
-                )
+        with jax.named_scope("join"):
+            if index_joins[t] >= 0:
+                ks, perm, targets, _tid = bucket_arrays[i]
+                if use_k:
+                    acc_vals, acc_valid, total = _kernels.index_join_impl(
+                        acc_vals, acc_valid, ks, perm, targets, keys[i],
+                        pairs, sig.terms[i].var_cols, extra,
+                        jc, interpret=_interp,
+                    )
+                else:
+                    acc_vals, acc_valid, total = _index_join_impl(
+                        acc_vals, acc_valid, ks, perm, targets, keys[i],
+                        pairs, sig.terms[i].var_cols, extra, jc,
+                    )
             else:
-                acc_vals, acc_valid, total = _index_join_impl(
-                    acc_vals, acc_valid, ks, perm, targets, keys[i],
-                    pairs, sig.terms[i].var_cols, extra, jc,
-                )
-        else:
-            rv, rm = tables[i]
-            if use_k:
-                acc_vals, acc_valid, total = _kernels.join_tables_impl(
-                    acc_vals, acc_valid, rv, rm, pairs, extra,
-                    jc, interpret=_interp,
-                )
-            else:
-                acc_vals, acc_valid, total = _join_tables_impl(
-                    acc_vals, acc_valid, rv, rm, pairs, extra, jc
-                )
+                rv, rm = tables[i]
+                if use_k:
+                    acc_vals, acc_valid, total = _kernels.join_tables_impl(
+                        acc_vals, acc_valid, rv, rm, pairs, extra,
+                        jc, interpret=_interp,
+                    )
+                else:
+                    acc_vals, acc_valid, total = _join_tables_impl(
+                        acc_vals, acc_valid, rv, rm, pairs, extra, jc
+                    )
         join_counts.append(total)
         if n < len(positives) - 2:
             reseed = reseed | (acc_valid.sum(dtype=jnp.int32) == 0)
 
     for i, pairs in anti_meta:
         rv, rm = tables[i]
-        if use_k:
-            acc_valid = _kernels.anti_join_impl(
-                acc_vals, acc_valid, rv, rm, pairs, interpret=_interp
-            )
-        else:
-            acc_valid = _anti_join_impl(acc_vals, acc_valid, rv, rm, pairs)
+        with jax.named_scope("anti_join"):
+            if use_k:
+                acc_valid = _kernels.anti_join_impl(
+                    acc_vals, acc_valid, rv, rm, pairs, interpret=_interp
+                )
+            else:
+                acc_valid = _anti_join_impl(
+                    acc_vals, acc_valid, rv, rm, pairs
+                )
 
     count = acc_valid.sum(dtype=jnp.int32)
     reseed = reseed & ~any_pos_empty
@@ -1039,7 +1055,8 @@ def build_fused(sig: FusedPlanSig, count_only: bool = False):
     # on, the first call per shape AOT-compiles and records wall time +
     # cost/memory analysis under this signature's digest
     return obs.proflog.instrument(
-        "fused", obs.proflog.sig_digest(sig, count_only), jax.jit(fn),
+        "fused", obs.proflog.sig_digest(sig, count_only),
+        jax.jit(obs.named_program("das_fused", fn, count_only)),
         model_bytes=partial(program_model_bytes, sig),
     ), names
 
@@ -1131,9 +1148,10 @@ def build_fused_tree(sig: FusedTreeSig, count_only: bool = False):
             # tables over one variable set, so positional row equality
             # over the canonical columns IS the reference assignment
             # identity
-            out_vals, out_valid, count = _dedup_table_impl(
-                union_vals, union_valid
-            )
+            with jax.named_scope("dedup"):
+                out_vals, out_valid, count = _dedup_table_impl(
+                    union_vals, union_valid
+                )
         stats = jnp.stack(
             [count] + [s for block in blocks for s in block]
         )
@@ -1143,7 +1161,8 @@ def build_fused_tree(sig: FusedTreeSig, count_only: bool = False):
 
     return obs.proflog.instrument(
         "fused_tree", obs.proflog.sig_digest(sig, count_only),
-        jax.jit(fn), model_bytes=partial(tree_model_bytes, sig),
+        jax.jit(obs.named_program("das_fused_tree", fn, count_only)),
+        model_bytes=partial(tree_model_bytes, sig),
     ), out_names
 
 
@@ -1552,7 +1571,7 @@ def build_fused_exact(sig: FusedExactSig, count_only: bool = False):
     # calibrate) but its compiles are ledger-visible like every program
     return obs.proflog.instrument(
         "fused_exact", obs.proflog.sig_digest(sig, count_only),
-        jax.jit(fn),
+        jax.jit(obs.named_program("das_fused_exact", fn, count_only)),
     ), names_per_state, cols_per_state
 
 
@@ -2578,13 +2597,14 @@ class FusedExecutor:
                 entry = obs.proflog.instrument(
                     "count_batch",
                     obs.proflog.sig_digest(plan_sig, key_axes, fval_axes),
-                    jax.jit(
+                    jax.jit(obs.named_program(
+                        "das_count_batch",
                         fn if all_const
                         else jax.vmap(
                             fn,
                             in_axes=(None, tuple(key_axes), tuple(fval_axes)),
-                        )
-                    ),
+                        ),
+                    )),
                     model_bytes=partial(program_model_bytes, plan_sig),
                 )
                 cache[cache_key] = entry
@@ -2728,6 +2748,7 @@ class FusedExecutor:
             )
 
             @jax.jit
+            @obs.named_program("das_count_loop")
             def looped(arrays, keys_stacked, fvals_stacked):
                 def body(i, carry):
                     counts, flags, mx = carry

@@ -88,6 +88,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from das_tpu import obs
+
 FETCHES = {"n": 0}
 
 
@@ -424,34 +426,40 @@ def _table_sparse(db, spec):
         and all(a is b for a, b in zip(hit[0], segments))
     ):
         return hit[1]
-    base = np.int64(type_id) << 32
-    parts = []  # (idx, cnt) per segment
-    for b in segments:
-        keys = b.key_type_pos[v0_pos]
-        lo = int(np.searchsorted(keys, base, side="left"))
-        hi = int(np.searchsorted(keys, base + (np.int64(1) << 31), side="left"))
-        if hi <= lo:
-            continue
-        vals = keys[lo:hi] - base  # sorted, dangling-free by construction
-        starts = np.r_[0, np.flatnonzero(np.diff(vals)) + 1]
-        parts.append((vals[starts], np.diff(np.r_[starts, vals.size])))
-    if not parts:
-        ent = ((np.empty(0, np.int64), np.empty(0, np.int64)), 0)
-    elif len(parts) == 1:
-        idx, cnt = parts[0]
-        ent = ((idx, cnt.astype(np.int64)), int(cnt.sum()))
-    else:
-        # overlay segments: merge run-length pairs (same value can appear
-        # in several segments)
-        allv = np.concatenate([p[0] for p in parts])
-        allc = np.concatenate([p[1] for p in parts]).astype(np.int64)
-        order = np.argsort(allv, kind="stable")
-        sv, sc = allv[order], allc[order]
-        starts = np.r_[0, np.flatnonzero(np.diff(sv)) + 1]
-        csum = np.r_[0, np.cumsum(sc)]
-        bounds = np.r_[starts, sv.size]
-        cnt = csum[bounds[1:]] - csum[bounds[:-1]]
-        ent = ((sv[starts], cnt), int(cnt.sum()))
+    # an uncached whole-table extraction (the planner's
+    # exact_join_rows reads it; a commit swaps the segment list, so
+    # every commit pays it again)
+    with obs.span("planner.stats", what="table_sparse") as sp:
+        base = np.int64(type_id) << 32
+        parts = []  # (idx, cnt) per segment
+        for b in segments:
+            keys = b.key_type_pos[v0_pos]
+            lo = int(np.searchsorted(keys, base, side="left"))
+            hi = int(np.searchsorted(keys, base + (np.int64(1) << 31), side="left"))
+            if hi <= lo:
+                continue
+            vals = keys[lo:hi] - base  # sorted, dangling-free by construction
+            starts = np.r_[0, np.flatnonzero(np.diff(vals)) + 1]
+            parts.append((vals[starts], np.diff(np.r_[starts, vals.size])))
+        if not parts:
+            ent = ((np.empty(0, np.int64), np.empty(0, np.int64)), 0)
+        elif len(parts) == 1:
+            idx, cnt = parts[0]
+            ent = ((idx, cnt.astype(np.int64)), int(cnt.sum()))
+        else:
+            # overlay segments: merge run-length pairs (same value can appear
+            # in several segments)
+            allv = np.concatenate([p[0] for p in parts])
+            allc = np.concatenate([p[1] for p in parts]).astype(np.int64)
+            order = np.argsort(allv, kind="stable")
+            sv, sc = allv[order], allc[order]
+            starts = np.r_[0, np.flatnonzero(np.diff(sv)) + 1]
+            csum = np.r_[0, np.cumsum(sc)]
+            bounds = np.r_[starts, sv.size]
+            cnt = csum[bounds[1:]] - csum[bounds[:-1]]
+            ent = ((sv[starts], cnt), int(cnt.sum()))
+        sp.set(version=getattr(db, "delta_version", None),
+               rows=int(ent[1]))
     if len(cache) > 256:
         _evict_oldest(cache, lambda k: k[0] in ("sparse", "tsparse"), 192)
     cache.pop(key, None)  # refresh -> FIFO back

@@ -149,6 +149,21 @@ _EWMA_ALPHA = 0.25
 _HISTORY_K = 64
 
 
+def _acquire(lock) -> float:
+    """Take the tenant lock on the worker; returns the wait in ms and
+    observes it in `serve.lock_wait_ms`.  With tracing off: a bare
+    acquire, no clock read, 0.0.  The caller releases.  No span per
+    acquire: the settle loop takes the lock once per answer."""
+    if not obs.enabled():
+        lock.acquire()
+        return 0.0
+    t0 = time.perf_counter()
+    lock.acquire()
+    ms = (time.perf_counter() - t0) * 1e3
+    obs.histogram("serve.lock_wait_ms").observe(ms)
+    return ms
+
+
 class QueryCoalescer:
     def __init__(self, max_batch: int = None, pipeline_depth: int = None,
                  pipeline_depth_max: int = None, queue_max: int = None,
@@ -243,12 +258,16 @@ class QueryCoalescer:
         #: is atomic, readers snapshot via snapshot()
         self.history: deque = deque(maxlen=_HISTORY_K)
 
-    def submit(self, tenant, query, output_format) -> Future:
+    def submit(self, tenant, query, output_format, mark=None) -> Future:
         fut: Future = Future()
-        # trace birth (ISSUE 12): the mark (trace id + submit time)
-        # rides the queue tuple to the worker, which closes it at
-        # answer delivery; None (zero cost) when tracing is off
-        mark = obs.mark()
+        # the trace's mark (trace id + birth time) rides the queue
+        # tuple to the worker, which closes it at answer delivery; None
+        # (zero cost) when tracing is off.  The RPC handler passes the
+        # one it made for its wire.query span, so one id covers the
+        # request from the gRPC thread to the device dispatch; a direct
+        # caller gets one born here
+        if mark is None:
+            mark = obs.mark()
         # deadline stamp (ISSUE 13): an absolute monotonic expiry rides
         # the tuple; None when deadlines are off so the disabled path
         # costs one comparison
@@ -561,11 +580,18 @@ class QueryCoalescer:
             # degrades the whole group to settle's per-query fallbacks —
             # the host seam, NOT inside the DL001 dispatch halves
             fault.maybe_fail("dispatch_enqueue")
-            with tenant.lock, sp:
-                job = tenant.das.query_many_dispatch(
-                    [item[1] for item in group], fmt,
-                    cache_only=degraded,
-                )
+            lock_ms = _acquire(tenant.lock)
+            try:
+                # the span's clock starts here, after the lock: the
+                # wait is its attr, not its duration
+                with sp:
+                    sp.set(lock_wait_ms=lock_ms)
+                    job = tenant.das.query_many_dispatch(
+                        [item[1] for item in group], fmt,
+                        cache_only=degraded,
+                    )
+            finally:
+                tenant.lock.release()
         except Exception:  # noqa: BLE001 — settle's fallback isolates
             job = None
         pending = getattr(job, "pending", None)
@@ -696,6 +722,7 @@ class QueryCoalescer:
                           degraded=degraded)
         t_settle0 = time.perf_counter()
         streamed = 0
+        lock_ms = 0.0           # summed waits for the tenant lock
         delivered_last = False
         settle_broke = False    # the streamed settle died mid-iteration
         retryable_errors = 0    # transport-class per-query failures
@@ -704,8 +731,11 @@ class QueryCoalescer:
                 it = job.settle_iter()
                 while True:
                     try:
-                        with tenant.lock:
+                        lock_ms += _acquire(tenant.lock)
+                        try:
                             i, answer = next(it)
+                        finally:
+                            tenant.lock.release()
                     except StopIteration:
                         break
                     except Exception:  # noqa: BLE001 — per-query fallback
@@ -768,8 +798,13 @@ class QueryCoalescer:
                     )
                     continue
                 try:
-                    with tenant.lock:
+                    lock_ms += _acquire(tenant.lock)
+                    try:
+                        if obs.enabled():
+                            obs.counter("exec.per_query_fallbacks").inc()
                         answer = tenant.das.query(item[1], fmt)
+                    finally:
+                        tenant.lock.release()
                 except Exception as exc:  # noqa: BLE001 — per-future
                     answer = exc
                 if isinstance(answer, Exception) and (
@@ -778,7 +813,7 @@ class QueryCoalescer:
                     retryable_errors += 1
                 if self._resolve(fut, answer, self._mark_of(item)):
                     fellback += 1
-            sp.set(fallbacks=fellback)
+            sp.set(fallbacks=fellback, lock_wait_ms=lock_ms)
             # breaker verdict for this group (worker-side, ISSUE 13):
             # transport-class failures — a broken streamed settle or
             # retryable per-query errors — count against the tenant;

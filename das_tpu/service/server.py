@@ -34,6 +34,7 @@ from typing import Dict, Optional
 
 import grpc
 
+from das_tpu import obs
 from das_tpu.api.atomspace import DistributedAtomSpace, QueryOutputFormat
 from das_tpu.core.exceptions import (
     BreakerOpenError,
@@ -297,8 +298,6 @@ class DasService:
         state.  Served over HTTP when env DAS_TPU_METRICS_PORT is set
         (serve() starts the exposition thread); also callable in-process
         by tests/benches."""
-        from das_tpu import obs
-
         stats = self.coalescer_stats()
         gauges = {
             f"serving.{k}": float(stats[k])
@@ -468,7 +467,17 @@ class DasService:
         )
 
     def query(self, request):
-        query = parse_query(request.get("query", ""))
+        # the trace id of a query is born here, on the gRPC thread: the
+        # mark rides into coalescer.submit, so wire.query / wire.parse
+        # share it with serve.submit ... serve.answer.  None (and the
+        # shared no-op span) when tracing is off
+        mark = obs.mark()
+        with obs.span("wire.query", trace=mark[0] if mark else 0):
+            return self._query(request, mark)
+
+    def _query(self, request, mark):
+        with obs.span("wire.parse", trace=mark[0] if mark else 0):
+            query = parse_query(request.get("query", ""))
         if query is None:
             return protocol.status(False, "Invalid query")
         if self.coalesce_enabled:
@@ -476,7 +485,9 @@ class DasService:
             if err:
                 return err
             coalescer = tenant.get_coalescer()
-            future = coalescer.submit(tenant, query, self._format(request))
+            future = coalescer.submit(
+                tenant, query, self._format(request), mark
+            )
             # BOUNDED wait (ISSUE 13): the worker resolves every future
             # (deadline expiry included), so the timeout is a backstop —
             # with a deadline configured it tracks it with slack, and
@@ -615,8 +626,6 @@ def serve(
     # Prometheus exposition (ISSUE 12): env DAS_TPU_METRICS_PORT opens
     # GET /metrics with the obs metric layer + serving gauges; unset/0
     # keeps the old surface exactly
-    from das_tpu import obs
-
     metrics_port = os.environ.get("DAS_TPU_METRICS_PORT")
     if metrics_port and int(metrics_port) > 0:
         # asking for exposition IS asking for the metric layer: every

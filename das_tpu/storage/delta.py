@@ -27,6 +27,7 @@ from __future__ import annotations
 from itertools import islice
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -58,21 +59,27 @@ def merge_sorted_index(base_keys, base_perm, delta_keys, delta_perm):
     delta_perm must already be offset into the merged row space."""
     nb = base_keys.shape[0]
     nd = delta_keys.shape[0]
-    ins = jnp.searchsorted(base_keys, delta_keys, side="right").astype(jnp.int32)
-    counts = jnp.zeros(nb + 1, dtype=jnp.int32).at[ins].add(1)
-    shift = jnp.cumsum(counts)[:nb]          # deltas inserted at or before i
-    pos_b = jnp.arange(nb, dtype=jnp.int32) + shift
-    pos_d = ins + jnp.arange(nd, dtype=jnp.int32)
-    keys = (
-        jnp.zeros(nb + nd, dtype=base_keys.dtype)
-        .at[pos_b].set(base_keys)
-        .at[pos_d].set(delta_keys)
-    )
-    perm = (
-        jnp.zeros(nb + nd, dtype=jnp.int32)
-        .at[pos_b].set(base_perm)
-        .at[pos_d].set(delta_perm)
-    )
+    # named scopes label the two stages in a device trace (trace-time
+    # only): where the delta lands, and the write of the merged arrays
+    with jax.named_scope("searchsorted"):
+        ins = jnp.searchsorted(
+            base_keys, delta_keys, side="right"
+        ).astype(jnp.int32)
+        counts = jnp.zeros(nb + 1, dtype=jnp.int32).at[ins].add(1)
+        shift = jnp.cumsum(counts)[:nb]      # deltas inserted at or before i
+        pos_b = jnp.arange(nb, dtype=jnp.int32) + shift
+        pos_d = ins + jnp.arange(nd, dtype=jnp.int32)
+    with jax.named_scope("scatter"):
+        keys = (
+            jnp.zeros(nb + nd, dtype=base_keys.dtype)
+            .at[pos_b].set(base_keys)
+            .at[pos_d].set(delta_keys)
+        )
+        perm = (
+            jnp.zeros(nb + nd, dtype=jnp.int32)
+            .at[pos_b].set(base_perm)
+            .at[pos_d].set(delta_perm)
+        )
     return keys, perm
 
 
@@ -247,61 +254,78 @@ class IncrementalCommitMixin:
         absorb a commit triggers growth (tensor) or early LSM compaction
         (sharded) on its own — both raised while staging, i.e. before
         anything became visible."""
-        from das_tpu import fault
+        from das_tpu import fault, obs
         from das_tpu.storage.atom_table import build_bucket
 
         fin = self.fin
-        by_arity = self._intern_delta(new_node_hexes, new_link_hexes)
-        # -- fallible half: stage (no visible mutation) -------------------
-        staged = []
-        for arity, entries in sorted(by_arity.items()):
-            # (target_rows, link_rows) array chunks from build_bucket
-            incoming_pairs: list = []
-            commit_bucket = build_bucket(
-                arity, entries, fin.row_of_hex, self._intern_type,
-                incoming_pairs, fin.dangling_hexes,
-            )
-            swap, became_base, slots = self._stage_delta_merge(commit_bucket)
-            staged.append(
-                (arity, commit_bucket, incoming_pairs, swap,
-                 became_base, slots)
-            )
-        fault.maybe_fail("commit_apply")
-        # -- write-ahead log (ISSUE 15): the interned delta is framed,
-        # checksummed and FSYNCED before the swap makes anything
-        # visible, so a crash on either side of the swap is recoverable
-        # (logged-but-unswapped replays at restore; swapped-and-logged
-        # is simply durable).  A WAL failure lands in the fallible half
-        # — store untouched, the shared RetryPolicy re-stages, and a
-        # retried append's duplicate record dedups by delta_version at
-        # replay (durable.replay_wal).  No WAL configured (`_wal` is
-        # the class-level None): one attribute read, zero new work.
-        wal = self._wal
-        if wal is not None:
-            wal.append(self.data, self.delta_version + 1)
-        # -- infallible half: swap (pure assignments) ---------------------
-        slot_growth = 0
-        for arity, commit_bucket, incoming_pairs, swap, became_base, \
-                slots in staged:
-            swap()
-            self._record_delta_incoming(incoming_pairs)
-            slot_growth += slots
-            if became_base:
-                # first links of this arity: the delta bucket is the base
-                # for THIS backend (fin.buckets may be shared with another
-                # backend whose device tables differ)
-                self._base_buckets[arity] = commit_bucket
-            else:
-                self._host_delta.setdefault(arity, []).append(commit_bucket)
-        self._base_counts = (len(self.data.nodes), len(self.data.links))
-        self._delta_total += max(
-            slot_growth, len(new_node_hexes) + len(new_link_hexes)
-        )
-        # the device tables just changed under any live executor: answers
-        # cached against the pre-commit version must stop hitting
-        self.delta_version += 1
-        from das_tpu import obs
-
+        # spans where the work happens: commit.apply holds commit.stage
+        # (the host cost of enqueueing the merges: device dispatch is
+        # asynchronous), dur.wal_append (durable.py) and commit.swap; a
+        # failure in the fallible half leaves no commit.swap behind
+        with obs.span(
+            "commit.apply", version=self.delta_version + 1,
+            nodes=len(new_node_hexes), links=len(new_link_hexes),
+        ):
+            # -- fallible half: stage (no visible mutation) ---------------
+            with obs.span("commit.stage"):
+                by_arity = self._intern_delta(new_node_hexes, new_link_hexes)
+                staged = []
+                for arity, entries in sorted(by_arity.items()):
+                    # (target_rows, link_rows) array chunks from build_bucket
+                    incoming_pairs: list = []
+                    commit_bucket = build_bucket(
+                        arity, entries, fin.row_of_hex, self._intern_type,
+                        incoming_pairs, fin.dangling_hexes,
+                    )
+                    swap, became_base, slots = self._stage_delta_merge(
+                        commit_bucket
+                    )
+                    staged.append(
+                        (arity, commit_bucket, incoming_pairs, swap,
+                         became_base, slots)
+                    )
+            fault.maybe_fail("commit_apply")
+            # -- write-ahead log (ISSUE 15): the interned delta is
+            # framed, checksummed and FSYNCED before the swap makes
+            # anything visible, so a crash on either side of the swap is
+            # recoverable (logged-but-unswapped replays at restore;
+            # swapped-and-logged is simply durable).  A WAL failure lands
+            # in the fallible half — store untouched, the shared
+            # RetryPolicy re-stages, and a retried append's duplicate
+            # record dedups by delta_version at replay
+            # (durable.replay_wal).  No WAL configured (`_wal` is the
+            # class-level None): one attribute read, zero new work.
+            wal = self._wal
+            if wal is not None:
+                wal.append(self.data, self.delta_version + 1)
+            # -- infallible half: swap (pure assignments) -----------------
+            with obs.span("commit.swap"):
+                slot_growth = 0
+                for arity, commit_bucket, incoming_pairs, swap, \
+                        became_base, slots in staged:
+                    swap()
+                    self._record_delta_incoming(incoming_pairs)
+                    slot_growth += slots
+                    if became_base:
+                        # first links of this arity: the delta bucket is
+                        # the base for THIS backend (fin.buckets may be
+                        # shared with another backend whose device
+                        # tables differ)
+                        self._base_buckets[arity] = commit_bucket
+                    else:
+                        self._host_delta.setdefault(arity, []).append(
+                            commit_bucket
+                        )
+                self._base_counts = (
+                    len(self.data.nodes), len(self.data.links)
+                )
+                self._delta_total += max(
+                    slot_growth, len(new_node_hexes) + len(new_link_hexes)
+                )
+                # the device tables just changed under any live executor:
+                # answers cached against the pre-commit version must stop
+                # hitting
+                self.delta_version += 1
         if obs.enabled():
             obs.event(
                 "commit.delta", version=self.delta_version,

@@ -379,23 +379,25 @@ class DeltaLog:
         from das_tpu import fault, obs
 
         fault.maybe_fail("wal_append")
-        fragment, sizes = self._capture(data)
-        fragment["v"] = int(version)
-        fragment["kind"] = kind
-        payload = msgpack.packb(fragment, use_bin_type=True)
-        rec = _WAL_HEADER.pack(
-            WAL_MAGIC, len(payload), zlib.crc32(payload)
-        ) + payload
-        with open(self.path, "ab") as f:
-            f.write(rec)
-            f.flush()
-            fault.maybe_fail("wal_fsync")
-            os.fsync(f.fileno())
+        # the span covers capture + pack + write + flush + fsync; a
+        # failed append records it too (no bytes attr, no counter)
+        with obs.span("dur.wal_append", version=version, kind=kind) as sp:
+            fragment, sizes = self._capture(data)
+            fragment["v"] = int(version)
+            fragment["kind"] = kind
+            payload = msgpack.packb(fragment, use_bin_type=True)
+            rec = _WAL_HEADER.pack(
+                WAL_MAGIC, len(payload), zlib.crc32(payload)
+            ) + payload
+            with open(self.path, "ab") as f:
+                f.write(rec)
+                f.flush()
+                fault.maybe_fail("wal_fsync")
+                os.fsync(f.fileno())
+            sp.set(bytes=len(rec))
         self._sizes = sizes
         DUR_STATS["wal_records"] = int(DUR_STATS["wal_records"]) + 1
         if obs.enabled():
-            obs.event("dur.wal_append", version=version, kind=kind,
-                      bytes=len(rec))
             obs.counter("dur.wal_records").inc()
 
 

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from das_tpu import obs
 from das_tpu.core.config import DasConfig
 from das_tpu.core.schema import UNORDERED_LINK_TYPES, WILDCARD
 from das_tpu.ops import posting
@@ -140,6 +141,7 @@ class DeviceTables:
 # live bucket referencing deleted buffers, bricking the store.  The transient cost is one extra copy of one array
 # at a time.
 @jax.jit
+@obs.named_program("das_merge_padded")
 def _merge_padded(base_keys, base_perm, delta_keys, delta_perm):
     """Fixed-shape sorted-index merge into a capacity-padded base: delta
     pad entries (dtype-max keys) sort past the base's pad region and fall
@@ -151,6 +153,7 @@ def _merge_padded(base_keys, base_perm, delta_keys, delta_perm):
 
 
 @jax.jit
+@obs.named_program("das_insert_rows")
 def _insert_rows(col, block, n):
     """Write a fixed-size delta block at (traced) row offset n — the
     column's shape is static, so this never recompiles per commit."""

@@ -469,6 +469,10 @@ def test_obs_registries_pinned():
         "cache.hit", "cache.miss", "cache.invalidate",
         "commit.delta", "commit.rebuild", "planner.observe",
         "serve.deadline", "serve.breaker", "fault.inject",
+        # ISSUE 26: the commit path, the answer path, the planner's
+        # statistics and the wire
+        "commit.apply", "commit.stage", "commit.swap", "dur.wal_append",
+        "exec.format", "planner.stats", "wire.query", "wire.parse",
     }
     assert set(obs.COUNTER_NAMES) >= {
         "serve.submitted", "serve.answers", "serve.rejections",
@@ -476,10 +480,19 @@ def test_obs_registries_pinned():
         "commit.deltas", "exec.dispatches", "exec.fetches",
         "serve.deadline_misses", "serve.breaker_trips",
         "serve.breaker_recoveries", "fault.injected", "fault.retries",
+        "exec.stale_reruns", "exec.per_query_fallbacks",
     }
     assert set(obs.HISTOGRAM_NAMES) >= {
         "serve.queue_ms", "serve.dispatch_ms", "serve.settle_ms",
-        "serve.answer_ms", "exec.settle_fetch_ms",
+        "serve.answer_ms", "exec.settle_fetch_ms", "serve.lock_wait_ms",
+    }
+    # the module names a device trace shows: a rename moves the
+    # benchmark's device-time readers (benchmark/harness/readers.py)
+    assert set(obs.PROGRAM_NAMES) == {
+        "das_fused", "das_fused_tree", "das_fused_exact",
+        "das_count_batch", "das_count_loop", "das_sharded",
+        "das_sharded_tree", "das_merge_padded", "das_insert_rows",
+        "das_merge_sharded",
     }
     # the metric dicts are BUILT from the registry
     assert set(obs.metrics.COUNTERS) == set(obs.COUNTER_NAMES)
@@ -541,3 +554,320 @@ def test_reject_event_and_counter(traced):
     # unblock the stuffed queue entry so the worker (spawned by the
     # rejected submit path? no — rejects never spawn) stays idle
     coal._queue.get_nowait()
+
+
+# -- ISSUE 26: clocks, the commit path, the answer path, the wire ----------
+
+
+def test_span_clock_starts_at_enter_not_construction(traced):
+    """A span built ahead of `with lock, span:` must not time the wait
+    for the lock: both clocks start at __enter__."""
+    sp = obs.span("serve.dispatch")
+    time.sleep(0.05)                    # "the wait for the lock"
+    t_enter = time.perf_counter()
+    with sp:
+        pass
+    (ev,) = [e for e in obs.events() if e[0] == "serve.dispatch"]
+    assert ev[3] < 0.04, f"the span timed the wait: {ev[3]:.3f} s"
+    assert ev[2] + obs.origin() >= t_enter - 1e-6
+    assert obs.origin() == obs.REC._t_origin
+
+
+def test_span_cpu_ms_against_wall(traced):
+    """cpu_ms is the thread's CPU time inside the span: far under the
+    wall time of a sleeping span, the wall time (give or take the
+    scheduler) of a spinning one."""
+    with obs.span("exec.format"):
+        time.sleep(0.08)
+    with obs.span("exec.materialize"):
+        t_cpu = time.thread_time()
+        while time.thread_time() - t_cpu < 0.03:   # burn 30 ms of CPU
+            pass
+    evs = {e[0]: e for e in obs.events()}
+    slept, spun = evs["exec.format"], evs["exec.materialize"]
+    assert slept[3] >= 0.08 and slept[8]["cpu_ms"] < 0.5 * slept[3] * 1e3
+    assert spun[8]["cpu_ms"] >= 29.0
+    assert spun[8]["cpu_ms"] <= spun[3] * 1e3 + 1.0   # never over wall
+
+
+class _NoClock:
+    """Stands in for the `time` module of the obs layer: any clock read
+    on the disabled path fails the test."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"time.{name} read with tracing off")
+
+
+def test_new_sites_disabled_path_allocates_nothing(monkeypatch, tmp_path):
+    """With DAS_TPU_TRACE off every site ISSUE 26 added (wire.query /
+    wire.parse, exec.format, planner.stats, commit.apply / stage / swap,
+    dur.wal_append, the lock-wait histogram, the two counters) builds
+    no span object and reads no clock of the obs layer."""
+    from das_tpu.obs import recorder
+    from das_tpu.service import coalesce
+    from das_tpu.service.server import DasService
+    from das_tpu.storage import durable
+
+    assert not obs.enabled()
+
+    def no_span(*_a, **_k):
+        raise AssertionError("a span object was built with tracing off")
+
+    monkeypatch.setattr(recorder._Span, "__init__", no_span)
+    monkeypatch.setattr(recorder, "time", _NoClock())
+    monkeypatch.setattr(obs, "time", _NoClock())
+    das, db = _tensor_das()
+    durable.attach(db, str(tmp_path / "snap"))          # WAL armed
+    service = DasService()
+    token = service.attach_tenant("zobs-off26", das)
+    before = {k: c.value for k, c in obs.metrics.COUNTERS.items()}
+    hist_before = obs.histogram("serve.lock_wait_ms").total
+    dsl = "Node n1 Concept mammal, Link Inheritance $1 n1"
+    reply = service.query({"key": token, "query": dsl})
+    assert reply["success"], reply
+    das.load_metta_text(COMMIT)                         # commit + WAL
+    assert service.query({"key": token, "query": dsl})["success"]
+    # the lock helper: a bare acquire, no clock, nothing observed
+    lock = service.tenants[token].lock
+    monkeypatch.setattr(coalesce, "time", _NoClock())
+    assert coalesce._acquire(lock) == 0.0
+    lock.release()
+    assert obs.events() == []
+    assert {k: c.value for k, c in obs.metrics.COUNTERS.items()} == before
+    assert obs.histogram("serve.lock_wait_ms").total == hist_before
+
+
+def _children(evs, parent):
+    """Spans lying inside `parent`'s interval, by start time."""
+    lo, hi = parent[2], parent[2] + parent[3]
+    inside = [e for e in evs if e is not parent and e[1] == "X"
+              and lo <= e[2] and e[2] + e[3] <= hi + 1e-9]
+    return sorted(inside, key=lambda e: e[2])
+
+
+def test_commit_path_spans_nest_in_order(traced, tmp_path):
+    from das_tpu.storage import durable
+
+    das, db = _tensor_das()
+    durable.attach(db, str(tmp_path / "snap"))
+    obs.reset()
+    das.load_metta_text(COMMIT)
+    evs = obs.events()
+    (apply_,) = [e for e in evs if e[0] == "commit.apply"]
+    assert [e[0] for e in _children(evs, apply_)] == [
+        "commit.stage", "dur.wal_append", "commit.swap"]
+    assert apply_[8]["version"] == db.delta_version
+    assert apply_[8]["nodes"] == 1 and apply_[8]["links"] == 1
+    (wal,) = [e for e in evs if e[0] == "dur.wal_append"]
+    # a span with a duration now, its old attrs kept
+    assert wal[1] == "X" and wal[3] > 0
+    assert wal[8]["version"] == db.delta_version
+    assert wal[8]["kind"] == "delta" and wal[8]["bytes"] > 0
+    assert all("cpu_ms" in e[8] for e in evs if e[1] == "X")
+    # the instant that closes the commit comes after the swap
+    (delta,) = [e for e in evs if e[0] == "commit.delta"]
+    assert delta[2] >= apply_[2] + apply_[3] - 1e-9
+
+
+def test_failed_stage_records_no_commit_swap(traced):
+    from das_tpu import fault
+
+    das, db = _tensor_das()
+    before = db.delta_version
+    obs.reset()
+    fault.configure("seed=1;sites=commit_apply;every=1;max=10")
+    try:
+        with pytest.raises(Exception):
+            das.load_metta_text(COMMIT)
+    finally:
+        fault.configure(None)
+    names = [e[0] for e in obs.events()]
+    assert "commit.stage" in names and "commit.apply" in names
+    assert "commit.swap" not in names and "commit.delta" not in names
+    assert db.delta_version == before
+
+
+def test_stale_reruns_count_the_rerun_queries(traced):
+    """The commit race of test_zpipeline under speculation: two groups
+    dispatched, a commit overtakes both, every one of their three
+    queries is re-run through the per-query dispatcher — counted once
+    each; a mid-stream commit counts only the queries not yet
+    answered."""
+    das, _db = _tensor_das()
+    q = _pair_query()
+    job1 = das.query_many_dispatch([q, q])
+    job2 = das.query_many_dispatch([q])      # speculative second group
+    das.load_metta_text(COMMIT)
+    obs.reset()
+    expected = das.query(q)                  # a direct call: not counted
+    assert job1.settle() == [expected, expected]
+    assert job2.settle() == [expected]
+    assert obs.counter("exec.stale_reruns").value == 3
+    assert obs.counter("exec.per_query_fallbacks").value == 3
+    # an undisturbed round re-runs nothing
+    assert das.query_many_dispatch([q, q]).settle() == [expected] * 2
+    assert obs.counter("exec.stale_reruns").value == 3
+    assert obs.counter("exec.per_query_fallbacks").value == 3
+    # mid-stream: the first answer was delivered before the commit
+    job = das.query_many_dispatch([q, q])
+    it = job.settle_iter()
+    next(it)
+    das.load_metta_text('(: "echidna" Concept)\n'
+                        '(Inheritance "echidna" "chimp")')
+    assert len(dict(it)) == 1
+    assert obs.counter("exec.stale_reruns").value == 4
+    assert obs.counter("exec.per_query_fallbacks").value == 4
+    # every answer path above recorded its exec.format span
+    assert sum(1 for e in obs.events() if e[0] == "exec.format") >= 8
+
+
+def test_wire_span_shares_the_request_id(traced):
+    """One id from the gRPC thread to the answer: wire.query, its child
+    wire.parse, serve.submit and serve.answer."""
+    from das_tpu.service.server import DasService
+
+    das, _db = _tensor_das()
+    service = DasService()
+    token = service.attach_tenant("zobs-wire", das)
+    reply = service.query({
+        "key": token,
+        "query": "Node n1 Concept mammal, Link Inheritance $1 n1"})
+    assert reply["success"], reply
+    # the future resolves INSIDE serve.settle: wait for the span to land
+    deadline = time.time() + 10
+    while time.time() < deadline and not any(
+            e[0] == "serve.settle" for e in obs.events()):
+        time.sleep(0.01)
+    evs = obs.events()
+    ids = {name: {e[4] for e in evs if e[0] == name}
+           for name in ("wire.query", "wire.parse", "serve.submit",
+                        "serve.answer")}
+    assert len(ids["wire.query"]) == 1 and 0 not in ids["wire.query"]
+    assert all(v == ids["wire.query"] for v in ids.values()), ids
+    (wire,) = [e for e in evs if e[0] == "wire.query"]
+    assert [e[0] for e in _children(evs, wire)][:1] == ["wire.parse"]
+    # the worker's spans of that request: lock wait on the enclosing
+    # spans, the histogram fed once per acquire
+    disp = [e for e in evs if e[0] == "serve.dispatch"][0]
+    settle = [e for e in evs if e[0] == "serve.settle"][0]
+    assert disp[8]["lock_wait_ms"] >= 0 and settle[8]["lock_wait_ms"] >= 0
+    assert obs.histogram("serve.lock_wait_ms").total >= 2
+    assert any(e[0] == "planner.stats" for e in evs)
+    # an invalid query still closes its wire span
+    obs.reset()
+    assert not service.query({"key": token, "query": "(("})["success"]
+    assert [e[0] for e in obs.events() if e[0].startswith("wire.")] == [
+        "wire.parse", "wire.query"]
+
+
+def test_sync_annotation_and_origin_in_chrome_trace(traced, tmp_path):
+    """One clock for an operator's two files: the obs trace carries the
+    recorder's origin, the device trace the `obs.sync` annotation."""
+    from das_tpu.obs import jaxprof
+
+    obs.event("serve.submit", trace=1)
+    doc = obs.chrome_trace(obs.events())
+    assert doc["metadata"]["perf_counter_origin_s"] == obs.origin()
+    cfg = DasConfig(profiler_trace_dir=str(tmp_path / "tb"))
+    assert obs.maybe_start_trace(cfg) is True
+    assert obs.maybe_stop_trace() is True
+    from jax.profiler import ProfileData
+
+    (xplane,) = (tmp_path / "tb").glob("plugins/profile/*/*.xplane.pb")
+    sync = [
+        {key: value for key, value in ev.stats}
+        for plane in ProfileData.from_file(str(xplane)).planes
+        for line in plane.lines for ev in line.events
+        if ev.name == jaxprof.SYNC_NAME
+    ]
+    assert len(sync) == 1 and int(sync[0]["t_ns"]) > 0
+
+
+def test_program_names_in_lowered_modules(monkeypatch):
+    """On CPU the lowered module of every PROGRAM_NAMES builder carries
+    its declared name: `jit_<name>` is what the device trace's modules
+    line shows, and what the benchmark's readers key on."""
+    import jax.numpy as jnp
+
+    from das_tpu.query import compiler
+    from das_tpu.query.ast import Or
+    from das_tpu.query.fused import FusedExecutor
+    from das_tpu.parallel.sharded_db import ShardedDB
+    from das_tpu.storage import tensor_db
+
+    monkeypatch.setenv("DAS_TPU_XLA_CACHE", "0")
+    seen = {}
+
+    def spy(site, _digest, fn, **_kw):
+        def call(*args, **kwargs):
+            text = fn.lower(*args, **kwargs).as_text()
+            seen.setdefault(site, set()).add(
+                text.split("module @", 1)[1].split(" ", 1)[0])
+            return fn(*args, **kwargs)
+        return call
+
+    monkeypatch.setattr(obs.proflog, "instrument", spy)
+    das, db = _tensor_das()
+    q, tree = _matching_query(), Or([_pair_query(), _matching_query()])
+    das.query(q)
+    das.query(tree)
+    ex = FusedExecutor(db)
+    plans = compiler.plan_query(db, q)
+    ex.execute_exact(plans)
+    ex.count_batch([plans, plans])
+    run, _w = ex.build_count_loop([plans, plans])
+    run()
+    sdb = ShardedDB(load_metta_text(animals_metta()), DasConfig())
+    sdas = DistributedAtomSpace(database_name="zobs-s", db=sdb)
+    sdas.query(q)
+    sdas.query(tree)
+    sdas.load_metta_text(COMMIT)
+    want = {
+        "fused": "jit_das_fused", "fused_tree": "jit_das_fused_tree",
+        "fused_exact": "jit_das_fused_exact",
+        "count_batch": "jit_das_count_batch",
+        "count_loop": "jit_das_count_loop",
+        "sharded": "jit_das_sharded",
+        "sharded_tree": "jit_das_sharded_tree",
+    }
+    for site, name in want.items():
+        assert site in seen, (site, sorted(seen))
+        assert any(n.startswith(name) for n in seen[site]), (site, seen)
+    # the commit-path programs, lowered directly
+    k = jnp.arange(8, dtype=jnp.int64)
+    o = jnp.arange(8, dtype=jnp.int32)
+    merge = tensor_db._merge_padded.lower(k, o, k[:2], o[:2])
+    assert "module @jit_das_merge_padded" in merge.as_text()
+    # the named scopes of the merge's two stages ride the debug info
+    dbg = merge.as_text(debug_info=True)
+    assert "searchsorted" in dbg and "scatter" in dbg
+    text = tensor_db._insert_rows.lower(k, k[:2], jnp.int32(1)).as_text()
+    assert "module @jit_das_insert_rows" in text
+    merges = list(sdb.tables._merge_cache.values())
+    assert merges and all(
+        m.__name__ == "das_merge_sharded" for m in merges)
+    # every declared name was met
+    met = {n[len("jit_"):] for names in seen.values() for n in names}
+    met |= {"das_merge_padded", "das_insert_rows", "das_merge_sharded"}
+    for name in obs.PROGRAM_NAMES:
+        assert any(m.startswith(name) for m in met), name
+
+
+def test_dl014_pins_program_names(tmp_path):
+    """DL014's new registry: an undeclared `named_program` literal
+    fires, and so does a declared name no builder uses."""
+    from das_tpu.analysis import run_analysis
+
+    src = tmp_path / "progs.py"
+    src.write_text(
+        'from das_tpu import obs\n'
+        'PROGRAM_NAMES = ("das_a", "das_stale")\n'
+        'def build(fn):\n'
+        '    obs.named_program("das_a", fn)\n'
+        '    return obs.named_program("das_typo", fn)\n'
+    )
+    msgs = "\n".join(
+        f.message for f in run_analysis([src], rules=["DL014"]))
+    assert "das_typo" in msgs and "das_stale" in msgs
+    with pytest.raises(KeyError):
+        obs.named_program("das_typo", lambda: None)

@@ -492,8 +492,8 @@ def test_dl016_catches_deleted_hook_on_real_builder(tmp_path):
     src = (REPO / "das_tpu/query/fused.py").read_text()
     needle = (
         "    return obs.proflog.instrument(\n"
-        '        "fused", obs.proflog.sig_digest(sig, count_only), '
-        "jax.jit(fn),\n"
+        '        "fused", obs.proflog.sig_digest(sig, count_only),\n'
+        '        jax.jit(obs.named_program("das_fused", fn, count_only)),\n'
         "        model_bytes=partial(program_model_bytes, sig),\n"
         "    ), names"
     )
@@ -514,6 +514,20 @@ def test_dl016_catches_deleted_hook_on_real_builder(tmp_path):
         rules=["DL016"], partial=True,
     )
     assert clean == [], "\n".join(f.render() for f in clean)
+    # the program-name leg: next to a PROGRAM_NAMES registry, a builder
+    # that keeps its ledger hook but drops its declared module name
+    # would show as jit_fn in the device trace
+    named = 'jax.jit(obs.named_program("das_fused", fn, count_only))'
+    assert src.count(named) == 1
+    mutated.write_text(src.replace(named, "jax.jit(fn)"))
+    findings = run_analysis(
+        [mutated, REPO / "das_tpu/obs/proflog.py",
+         REPO / "das_tpu/obs/registry.py"],
+        rules=["DL016"], partial=True,
+    )
+    assert [f for f in findings if "das_fused" in f.message], "\n".join(
+        f.render() for f in findings
+    )
 
 
 def test_program_sites_registry_pinned():
