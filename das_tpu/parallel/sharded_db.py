@@ -292,9 +292,10 @@ class ShardedTables:
                 ]
                 idx = []
                 for (bk, bo), (dk, do) in zip(base_idx, delta_idx):
-                    cap = bk.shape[1]
-                    k, o = merge_sorted_index(bk[0], bo[0], dk[0], do[0])
-                    idx.append((k[:cap][None], o[:cap][None]))
+                    k, o = merge_sorted_index(
+                        bk[0], bo[0], dk[0], do[0], size=bk.shape[1]
+                    )
+                    idx.append((k[None], o[None]))
                 return cols, idx
 
             spec = P(SHARD_AXIS)

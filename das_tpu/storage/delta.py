@@ -14,9 +14,10 @@ deltas instead:
     delta incoming-set overlay consulted by `get_incoming`;
   * the device-side part differs by layout: TensorDB extends flat
     `[m]` sorted indexes, ShardedDB extends stacked `[S, m_local]`
-    slab-local indexes under `shard_map` — both with the same O(n)
-    two-sorted-array merge (`merge_sorted_index`: merge-path positions
-    from |delta| binary searches plus one cumsum, no re-sort).
+    slab-local indexes under `shard_map` — both with the same
+    two-sorted-array merge (`merge_sorted_index`: |delta| binary
+    searches, then shift networks of O(log |delta|) elementwise passes
+    and one cumsum — no re-sort, no scatter, no whole-table gather).
 
 Deltas accumulate LSM-style; past `config.delta_merge_threshold` total new
 atoms the caller fully re-finalizes and clears the overlay.
@@ -51,35 +52,128 @@ def delta_class(d: int) -> int:
     return max(64, 1 << (d - 1).bit_length()) if d > 1 else 64
 
 
-def merge_sorted_index(base_keys, base_perm, delta_keys, delta_perm):
-    """Extend a device-resident sorted index by a small sorted delta in
-    O(n): merge-path positions come from |delta| binary searches into the
-    base plus one cumsum over the base — no re-sort of the big side.
+def _shifted(x, step: int, fill):
+    """x read `step` slots to the left (step > 0: out[q] = x[q - step]) or
+    to the right (step < 0: out[q] = x[q + |step|]); `fill` where that
+    slot does not exist.  A static shift: one pad and one slice."""
+    n = x.shape[0]
+    pad = jnp.full((min(abs(step), n),), fill, x.dtype)
+    if step > 0:
+        return jnp.concatenate([pad, x[: max(n - step, 0)]])
+    return jnp.concatenate([x[min(-step, n):], pad])
+
+
+def _bit_set(s, k: int):
+    return ((s >> k) & 1) == 1
+
+
+def _stage(owed, k: int, step: int):
+    """One stage of a shift network: an element moves by `step` slots
+    (signed: > 0 to the right) iff bit k of what it owes is set.  Returns
+    the mask of slots an element arrives at, and `owed` after the move
+    (-1 where no element is left)."""
+    source = _shifted(owed, step, -1)
+    arrives = (source >= 0) & _bit_set(source, k)
+    stays = (owed >= 0) & ~_bit_set(owed, k)
+    return arrives, jnp.where(arrives, source, jnp.where(stays, owed, -1))
+
+
+def _expand(vals, owed, nbits: int):
+    """Shift network, moving right: the element at slot q still owes
+    `owed[q]` slots (-1: the slot is a hole) and ends at q + owed[q]; its
+    values ride along in `vals`.  One stage per bit, most significant
+    first, each a select between a slot and the slot 2^k to its left.
+    The shifts must not decrease from one element to the next: then no
+    two elements ever meet, whatever prefix of the bits has been walked.
+    Holes keep whatever value was there; `owed` says which slots count."""
+    for k in reversed(range(nbits)):
+        arrives, owed = _stage(owed, k, 1 << k)
+        vals = [jnp.where(arrives, _shifted(v, 1 << k, 0), v) for v in vals]
+    return vals, owed
+
+
+def _compress(owed, nbits: int):
+    """The inverse walk, moving left: the element at slot q moves to
+    q - owed[q] (-1: no element) and takes its count with it.  Least
+    significant bit first; the counts must not decrease from one element
+    to the next and may grow by at most the holes between them."""
+    for k in range(nbits):
+        _, owed = _stage(owed, k, -(1 << k))
+    return owed
+
+
+def _fit(x, n: int, fill=0):
+    """x cut to n rows, or extended to them with `fill`."""
+    if x.shape[0] >= n:
+        return x[:n]
+    return jnp.concatenate([x, jnp.full((n - x.shape[0],), fill, x.dtype)])
+
+
+def merge_sorted_index(base_keys, base_perm, delta_keys, delta_perm,
+                       size=None):
+    """Extend a device-resident sorted index by a small sorted delta and
+    return the first `size` slots of the merge (default: all nb + nd).
     Ties place base elements first (side='right'), preserving stability.
-    delta_perm must already be offset into the merged row space."""
-    nb = base_keys.shape[0]
-    nd = delta_keys.shape[0]
+    delta_perm must already be offset into the merged row space.
+
+    Every output slot is built by READING: no operation of the program
+    is a scatter, and the only gathers are the nd binary searches.  The
+    cost is O(size * log nd) in elementwise passes over static shifts:
+
+      * `ins` = where each delta row lands in the base (nd searches);
+        delta row i ends at slot ins_i + i;
+      * the delta rows travel there.  The shift ins_i is split at the
+        delta width w = 2^lw: the high part names the w-wide stretch
+        of slots the row starts in (one broadcast compare: stretch m
+        holds row i at its i-th slot iff ins_i >> lw == m), the low
+        part (< w) is walked by an expand network of lw stages;
+      * the slots they reached are the marks; one cumsum counts the
+        marks before each slot, a compress network carries that count
+        from each base element's final slot back to its index, and a
+        second expand network moves keys and perm of the base by it;
+      * a select puts the two together.
+    """
+    nb, nd = base_keys.shape[0], delta_keys.shape[0]
+    if size is None:
+        size = nb + nd
+    if not 0 <= size <= nb + nd:
+        raise ValueError(f"size {size} outside the merge's {nb + nd} slots")
+    if nd == 0:
+        return base_keys[:size], base_perm[:size]
+    lw = (nd - 1).bit_length()
+    w = 1 << lw
     # named scopes label the two stages in a device trace (trace-time
-    # only): where the delta lands, and the write of the merged arrays
+    # only): where the delta lands, and the networks that build the
+    # merged arrays
     with jax.named_scope("searchsorted"):
         ins = jnp.searchsorted(
             base_keys, delta_keys, side="right"
         ).astype(jnp.int32)
-        counts = jnp.zeros(nb + 1, dtype=jnp.int32).at[ins].add(1)
-        shift = jnp.cumsum(counts)[:nb]      # deltas inserted at or before i
-        pos_b = jnp.arange(nb, dtype=jnp.int32) + shift
-        pos_d = ins + jnp.arange(nd, dtype=jnp.int32)
-    with jax.named_scope("scatter"):
-        keys = (
-            jnp.zeros(nb + nd, dtype=base_keys.dtype)
-            .at[pos_b].set(base_keys)
-            .at[pos_d].set(delta_keys)
+    with jax.named_scope("shift_network"):
+        slot = jnp.arange(size, dtype=jnp.int32)
+
+        def per_stretch(x, fill=0):
+            # slot q reads x[q mod w]: the delta block, once per stretch
+            return jnp.tile(_fit(x, w, fill), -(-size // w))[:size]
+
+        start = per_stretch(ins, -1)
+        owed = jnp.where(
+            (start >= 0) & ((start >> lw) == (slot >> lw)),
+            start & (w - 1), -1,
         )
-        perm = (
-            jnp.zeros(nb + nd, dtype=jnp.int32)
-            .at[pos_b].set(base_perm)
-            .at[pos_d].set(delta_perm)
+        (d_keys, d_perm), owed = _expand(
+            [per_stretch(delta_keys), per_stretch(delta_perm)], owed, lw,
         )
+        is_delta = owed >= 0
+        marks = is_delta.astype(jnp.int32)
+        before = jnp.cumsum(marks) - marks   # delta slots left of each slot
+        nbits = nd.bit_length()              # a base element moves 0..nd
+        shift = _compress(jnp.where(is_delta, -1, before), nbits)
+        (b_keys, b_perm), _ = _expand(
+            [_fit(base_keys, size), _fit(base_perm, size)], shift, nbits
+        )
+        keys = jnp.where(is_delta, d_keys, b_keys)
+        perm = jnp.where(is_delta, d_perm, b_perm)
     return keys, perm
 
 

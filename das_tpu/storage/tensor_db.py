@@ -144,12 +144,14 @@ class DeviceTables:
 @obs.named_program("das_merge_padded")
 def _merge_padded(base_keys, base_perm, delta_keys, delta_perm):
     """Fixed-shape sorted-index merge into a capacity-padded base: delta
-    pad entries (dtype-max keys) sort past the base's pad region and fall
-    off the final slice, so the array length never changes.  Compiled once
-    per (capacity, delta-class) shape — commits after the first reuse it."""
-    cap = base_keys.shape[0]
-    k, p = merge_sorted_index(base_keys, base_perm, delta_keys, delta_perm)
-    return k[:cap], p[:cap]
+    pad entries (dtype-max keys) sort past the base's pad region, beyond
+    the `size` slots the merge builds, so the array length never changes.
+    Compiled once per (capacity, delta-class, key dtype) shape — commits
+    after the first reuse it."""
+    return merge_sorted_index(
+        base_keys, base_perm, delta_keys, delta_perm,
+        size=base_keys.shape[0],
+    )
 
 
 @jax.jit
@@ -194,9 +196,10 @@ class TensorDB(IncrementalCommitMixin, MemoryDB):
         commits).  Small deltas take the INCREMENTAL path: only the new
         records are columnized (a small delta bucket per arity), only those
         columns travel to the device, and each device-resident sorted probe
-        index is extended by an O(n) two-sorted-array merge (merge-path
-        positions from a handful of binary searches + one cumsum — no
-        re-sort, no full re-upload).  The reference's update path is
+        index is extended by a two-sorted-array merge (a handful of
+        binary searches, then shift networks and one cumsum — no
+        re-sort, no scatter, no full re-upload; storage/delta.py
+        merge_sorted_index).  The reference's update path is
         likewise incremental (das/das_update_test.py:141-192); a full
         re-finalize at millions of links costs minutes.  Deltas accumulate
         LSM-style; past config.delta_merge_threshold total new atoms the

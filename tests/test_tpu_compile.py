@@ -31,14 +31,19 @@ import numpy as np
 import pytest
 
 from das_tpu.core.config import DasConfig
+from das_tpu.storage.delta import capacity_class, delta_class
 
 #: chip_smoke.py's default store: links of arity 2 at --scale 0.1
 #: (2.4 M Member + ~0.3 M Interacts + 43.5 k List + 43.5 k Evaluation)
 SMOKE_ARITY2_ROWS = 2_786_998
+SMOKE_ARITY2_CAPACITY = capacity_class(SMOKE_ARITY2_ROWS)
 #: capacities the executor settles on there for the grounded 3-clause
 #: conjunction (recorded from a CPU run of chip_smoke's phases at 0.1)
 SMOKE_TERM_CAPS = (16, 16, 16)
 SMOKE_JOIN_CAPS = (2048, 64)
+#: cell 2 of the benchmark (`wal-mixed95-closed`, FlyBase shape x 0.1):
+#: the arity-2 bucket's capacity there
+CELL2_ARITY2_CAPACITY = 2_961_251
 
 
 @pytest.fixture(scope="module")
@@ -153,9 +158,7 @@ def grounded_job():
 def _smoke_shapes(job):
     """The job's argument shapes with every bucket array stretched to
     the smoke store's capacity class."""
-    from das_tpu.storage.delta import capacity_class
-
-    cap = capacity_class(SMOKE_ARITY2_ROWS)
+    cap = SMOKE_ARITY2_CAPACITY
 
     def stretch(a):
         a = np.asarray(a) if not hasattr(a, "shape") else a
@@ -188,19 +191,34 @@ def test_fused_grounded3_at_smoke_shapes(compile_for_chip, grounded_job,
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_commit_merge_programs(compile_for_chip):
-    """The fixed-shape commit programs (storage/tensor_db.py): the
-    sorted-index merge of one delta class into the capacity-padded base,
-    and the row-block insert."""
-    from das_tpu.storage.delta import capacity_class, delta_class
-    from das_tpu.storage.tensor_db import _insert_rows, _merge_padded
+@pytest.mark.parametrize("cap,dcap,key_dtype", [
+    (SMOKE_ARITY2_CAPACITY, delta_class(10), jnp.int64),  # the smoke's
+    (CELL2_ARITY2_CAPACITY, 64, jnp.int64),    # cell 2: 5 of a commit's 8
+    (CELL2_ARITY2_CAPACITY, 64, jnp.int32),    # cell 2: the other 3
+    (CELL2_ARITY2_CAPACITY, 65536, jnp.int64),  # the widest delta class
+    (CELL2_ARITY2_CAPACITY, 65536, jnp.int32),
+])
+def test_commit_merge_programs(compile_for_chip, cap, dcap, key_dtype):
+    """The fixed-shape sorted-index merge of one delta class into the
+    capacity-padded base (storage/tensor_db.py).  The merge builds every
+    slot by reading: the compiled program holds no scatter at any delta
+    class (a whole-table scatter was 1.27 s of device time per commit,
+    PERF.md PR 27)."""
+    from das_tpu.storage.tensor_db import _merge_padded
 
-    cap, dcap = capacity_class(SMOKE_ARITY2_ROWS), delta_class(10)
-    compile_for_chip(
+    merge = compile_for_chip(
         _merge_padded,
-        _shape((cap,), jnp.int64), _shape((cap,), jnp.int32),
-        _shape((dcap,), jnp.int64), _shape((dcap,), jnp.int32),
+        _shape((cap,), key_dtype), _shape((cap,), jnp.int32),
+        _shape((dcap,), key_dtype), _shape((dcap,), jnp.int32),
     )
+    assert "scatter" not in merge.as_text()
+
+
+def test_commit_insert_program(compile_for_chip):
+    """The commit's row-block insert at a traced offset."""
+    from das_tpu.storage.tensor_db import _insert_rows
+
+    cap, dcap = SMOKE_ARITY2_CAPACITY, delta_class(10)
     compile_for_chip(
         _insert_rows,
         _shape((cap, 2), jnp.int32), _shape((dcap, 2), jnp.int32),
@@ -221,7 +239,6 @@ def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
     )
     from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh
     from das_tpu.parallel.sharded_db import ShardedDB
-    from das_tpu.storage.delta import capacity_class
 
     db, plans = _tiny_store_and_query(
         lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4))

@@ -840,7 +840,7 @@ def test_program_names_in_lowered_modules(monkeypatch):
     assert "module @jit_das_merge_padded" in merge.as_text()
     # the named scopes of the merge's two stages ride the debug info
     dbg = merge.as_text(debug_info=True)
-    assert "searchsorted" in dbg and "scatter" in dbg
+    assert "searchsorted" in dbg and "shift_network" in dbg
     text = tensor_db._insert_rows.lower(k, k[:2], jnp.int32(1)).as_text()
     assert "module @jit_das_insert_rows" in text
     merges = list(sdb.tables._merge_cache.values())
