@@ -222,13 +222,17 @@ class _QueryManyJob:
                 out_s = das._formatted(
                     matched, answer, self.output_format
                 )
-                query_compiler.ROUTE_COUNTS["sharded"] += 1
-                # staged-fallback answers (res None) ran the lowered
-                # mesh pipeline — only fused-answered entries count
-                # as kernel-routed (exact program counts live in
-                # kernels.DISPATCH_COUNTS)
-                if kernel_route and res is not None:
-                    query_compiler.ROUTE_COUNTS["sharded_kernel"] += 1
+                # counted once the answer exists, as what it is: an
+                # answer of the staged mesh pipeline is "staged" here
+                # as on one chip (fused_answer below), so a limit on
+                # staged answers holds the mesh too; its twin under
+                # tracing is counter mesh.staged_fallbacks
+                if res is None:
+                    query_compiler.ROUTE_COUNTS["staged"] += 1
+                else:
+                    query_compiler.ROUTE_COUNTS["sharded"] += 1
+                    if kernel_route:
+                        query_compiler.ROUTE_COUNTS["sharded_kernel"] += 1
                 return out_s
 
             settled = self._stream_settled(

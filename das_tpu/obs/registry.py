@@ -118,6 +118,15 @@ SPAN_NAMES = (
     "wire.query",
     #: span: DSL text -> query AST (child of wire.query)
     "wire.parse",
+    #: span: one settle round's pull of the mesh programs' per-shard
+    #: result slabs and stats to the host (parallel/fused_sharded.py
+    #: settle_many_iter; the same interval as exec.settle_fetch, which
+    #: the mesh shares with one chip) — attrs: jobs, shards, bytes
+    "mesh.fetch",
+    #: span: stacked per-shard rows -> the distinct valid rows of a mesh
+    #: answer (parallel/sharded_db.py materialize; child of
+    #: exec.materialize) — attrs: rows in, rows out
+    "mesh.dedup",
 )
 
 #: monotone counters (obs/metrics.py COUNTERS is built from this)
@@ -158,6 +167,17 @@ COUNTER_NAMES = (
     "dur.snapshots",
     "dur.wal_records",
     "dur.recovery_replayed",
+    #: bytes the collectives of each dispatched fused mesh program move
+    #: between chips, summed over the shards: tallied from the operand
+    #: shapes when the program is traced (parallel/fused_sharded.py
+    #: _moved), added per dispatch
+    "mesh.collective_bytes",
+    #: re-dispatches of a fused mesh program after a shard overflowed a
+    #: capacity (a job's dispatch rounds past its first)
+    "mesh.retries",
+    #: answers the STAGED mesh pipeline gave because the fused mesh
+    #: program declined — twin of ROUTE_COUNTS["staged"] on the mesh
+    "mesh.staged_fallbacks",
 )
 
 #: fixed log-bucket latency histograms (obs/metrics.py HISTOGRAMS) —

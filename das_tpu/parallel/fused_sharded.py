@@ -132,7 +132,51 @@ class ShardedFusedResult:
     multiway: bool = False   # answered by a k-way multiway mesh program
 
 
-def _repartition(vals, valid, cols, sentinel, S: int, q: int):
+class _Moved:
+    """Trace-time tally of the bytes ONE mesh program's collectives move
+    between chips, summed over the S shards, from the per-shard operand
+    shapes: an all_gather hands every shard the other S-1 operands, an
+    all_to_all sends (S-1)/S of each shard's buffer, an all-reduce (as
+    a ring) sends 2(S-1)/S of the operand from every shard.  The
+    collective helpers below add to it as the program is traced;
+    `_MeshProgram` adds the sum to counter `mesh.collective_bytes` per
+    dispatch."""
+
+    __slots__ = ("S", "bytes")
+
+    def __init__(self, n_shards: int):
+        self.S = n_shards
+        self.bytes = 0
+
+    def gathered(self, x) -> None:
+        self.bytes += self.S * (self.S - 1) * x.size * x.dtype.itemsize
+
+    def exchanged(self, buf) -> None:
+        self.bytes += (self.S - 1) * buf.size * buf.dtype.itemsize
+
+    def reduced(self, x) -> None:
+        self.bytes += 2 * (self.S - 1) * x.size * x.dtype.itemsize
+
+
+class _MeshProgram:
+    """A jitted mesh program beside the tally of what its collectives
+    move: a call enqueues it (async, no sync) and, with tracing on,
+    adds the tally to counter `mesh.collective_bytes`."""
+
+    __slots__ = ("fn", "moved")
+
+    def __init__(self, fn, moved: _Moved):
+        self.fn = fn
+        self.moved = moved
+
+    def __call__(self, *args):
+        out = self.fn(*args)
+        if obs.enabled():
+            obs.counter("mesh.collective_bytes").inc(self.moved.bytes)
+        return out
+
+
+def _repartition(vals, valid, cols, sentinel, S: int, q: int, moved: _Moved):
     """Scatter rows to shard `mix(cols) % S` via one all_to_all.
 
     Returns ([S*q, k] rows now resident on the key-owning shard, their
@@ -155,21 +199,25 @@ def _repartition(vals, valid, cols, sentinel, S: int, q: int):
     buf = jnp.zeros((S, q, k + 1), dtype=vals.dtype).at[dest, slot].set(
         packed, mode="drop"
     )
-    recv = lax.all_to_all(buf, SHARD_AXIS, split_axis=0, concat_axis=0)
+    with jax.named_scope("mesh.repartition"):
+        recv = lax.all_to_all(buf, SHARD_AXIS, split_axis=0, concat_axis=0)
+    moved.exchanged(buf)
     recv = recv.reshape(S * q, k + 1)
     return recv[:, :k], recv[:, k].astype(bool), dest_counts.max()
 
 
-def _gather_packed(vals, valid):
+def _gather_packed(vals, valid, moved: _Moved):
     """Broadcast a table to every shard with ONE collective (validity
     packed as an extra column)."""
     k = vals.shape[1]
     packed = jnp.concatenate([vals, valid.astype(vals.dtype)[:, None]], axis=1)
-    full = lax.all_gather(packed, SHARD_AXIS, tiled=True)
+    with jax.named_scope("mesh.gather_packed"):
+        full = lax.all_gather(packed, SHARD_AXIS, tiled=True)
+    moved.gathered(packed)
     return full[:, :k], full[:, k].astype(bool)
 
 
-def _worst_shard(n):
+def _worst_shard(n, moved: _Moved):
     """The worst shard's row count (a capacity-retry figure), as int32 —
     a declared collective helper (parallel/mesh.py COLLECTIVE_SITES).
     Pair totals are int64, and the TPU compiler lowers 64-bit all-reduce
@@ -178,17 +226,27 @@ def _worst_shard(n):
     the value clamped to int32, which loses nothing — the figure is only
     ever compared with capacities <= max_result_capacity."""
     n = jnp.minimum(n, jnp.iinfo(jnp.int32).max).astype(jnp.int32)
-    return lax.pmax(n, SHARD_AXIS)
+    moved.reduced(n)
+    with jax.named_scope("mesh.worst_shard"):
+        return lax.pmax(n, SHARD_AXIS)
 
 
-def _global_count(valid):
-    """Global surviving-row count of a row-sharded validity mask (ONE
-    psum) — a declared collective helper (parallel/mesh.py
-    COLLECTIVE_SITES, daslint DL009)."""
-    return lax.psum(valid.sum(dtype=jnp.int32), SHARD_AXIS)
+def _global_sum(n, moved: _Moved):
+    """The sum over the shards of a per-shard int32 figure (ONE psum) —
+    a declared collective helper (parallel/mesh.py COLLECTIVE_SITES,
+    daslint DL009)."""
+    moved.reduced(n)
+    with jax.named_scope("mesh.global_sum"):
+        return lax.psum(n, SHARD_AXIS)
 
 
-def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
+def _global_count(valid, moved: _Moved):
+    """Global surviving-row count of a row-sharded validity mask."""
+    return _global_sum(valid.sum(dtype=jnp.int32), moved)
+
+
+def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
+                        moved: _Moved):
     """Trace ONE conjunction inside a shard_map body — shard-local
     probes/joins, the per-step collective choice, and the in-program
     stat reductions.  Returns (acc_vals, acc_valid, stats_list) with
@@ -197,10 +255,10 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
     destination occupancy] as traced scalars.  This is
     build_fused_sharded's whole body, extracted so the sharded
     whole-tree program (build_sharded_tree_fused, ISSUE 10) can trace
-    several sites in one mesh executable.  Declared collective site
-    (parallel/mesh.py COLLECTIVE_SITES, daslint DL009): the stats
-    reductions (psum/pmax) and the gather/exchange helpers live here,
-    never in shard-local kernel bodies."""
+    several sites in one mesh executable.  Every collective goes
+    through a declared helper (parallel/mesh.py COLLECTIVE_SITES,
+    daslint DL009) that names its scope for the device trace and adds
+    its bytes to `moved`; none lives in a shard-local kernel body."""
     S = sig.n_shards
     positives, _negatives, names, join_meta, anti_meta = fold_join_meta(sig.terms)
     mw = sig.multiway
@@ -236,7 +294,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             tid = jnp.asarray(keys[i], jnp.int64)
             lo = jnp.searchsorted(keys_sorted, tid << 32, side="left")
             hi = jnp.searchsorted(keys_sorted, (tid + 1) << 32, side="left")
-            pos_count[i] = lax.psum((hi - lo).astype(jnp.int32), SHARD_AXIS)
+            pos_count[i] = _global_sum((hi - lo).astype(jnp.int32), moved)
             tables[i] = None
             term_ranges.append(jnp.int32(0))
             continue
@@ -245,8 +303,8 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             use_kernels=use_k,
         )
         tables[i] = (vals, mask)
-        pos_count[i] = lax.psum(mask.sum(dtype=jnp.int32), SHARD_AXIS)
-        term_ranges.append(_worst_shard(rng))
+        pos_count[i] = _global_count(mask, moved)
+        term_ranges.append(_worst_shard(rng, moved))
 
     any_pos_empty = jnp.bool_(False)
     for i in positives:
@@ -270,7 +328,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
         mw_tails = []
         for i in positives[1:mw]:
             tv, tm = tables[i]
-            mw_tails.append(_gather_packed(tv, tm))
+            mw_tails.append(_gather_packed(tv, tm, moved))
         acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
             acc_vals, acc_valid, mw_tails, mw_vcol0, mw_meta,
             sig.join_caps[0], interpret=_interp,
@@ -278,8 +336,8 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
         # partial totals are per-shard: the reference's reseed rule
         # asks about GLOBAL intermediate emptiness, the capacity
         # retry about the worst shard's output
-        g_totals = lax.psum(mw_totals, SHARD_AXIS)
-        join_totals.append(_worst_shard(mw_totals[mw - 2]))
+        g_totals = _global_sum(mw_totals, moved)
+        join_totals.append(_worst_shard(mw_totals[mw - 2], moved))
         exch_stats.append(jnp.int32(0))
         for t in range(max(0, min(mw - 1, len(positives) - 2))):
             reseed = reseed | (g_totals[t] == 0)
@@ -292,7 +350,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             # broadcast the SMALL left once; every shard probes its own
             # slab's posting index — union over shards is the full join
             # (each link lives in exactly one slab)
-            lv_full, lm_full = _gather_packed(acc_vals, acc_valid)
+            lv_full, lm_full = _gather_packed(acc_vals, acc_valid, moved)
             ks, perm, targets, _tid = (
                 a[0] for a in bucket_arrays[i]
             )
@@ -308,12 +366,9 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
                     pairs, sig.terms[i].var_cols, extra, jc,
                 )
             exch_stats.append(jnp.int32(0))
-            join_totals.append(_worst_shard(total))
+            join_totals.append(_worst_shard(total, moved))
             if n < len(positives) - 2:
-                global_n = lax.psum(
-                    acc_valid.sum(dtype=jnp.int32), SHARD_AXIS
-                )
-                reseed = reseed | (global_n == 0)
+                reseed = reseed | (_global_count(acc_valid, moved) == 0)
             continue
         rv, rm = tables[i]
         join_impl = (
@@ -324,7 +379,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
         if q == 0:
             # broadcast-right: ONE tiled all_gather of the small side
             # (validity packed as an extra column)
-            rv_full, rm_full = _gather_packed(rv, rm)
+            rv_full, rm_full = _gather_packed(rv, rm, moved)
             acc_vals, acc_valid, total = join_impl(
                 acc_vals, acc_valid, rv_full, rm_full,
                 pairs, extra, jc,
@@ -335,23 +390,24 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
             lcols = tuple(lc for lc, _ in pairs)
             rcols = tuple(rc for _, rc in pairs)
             lv2, lm2, l_occ = _repartition(
-                acc_vals, acc_valid, lcols, _SENTINEL_L, S, q
+                acc_vals, acc_valid, lcols, _SENTINEL_L, S, q, moved
             )
-            rv2, rm2, r_occ = _repartition(rv, rm, rcols, _SENTINEL_R, S, q)
+            rv2, rm2, r_occ = _repartition(
+                rv, rm, rcols, _SENTINEL_R, S, q, moved
+            )
             acc_vals, acc_valid, total = join_impl(
                 lv2, lm2, rv2, rm2, pairs, extra, jc
             )
-            exch_stats.append(_worst_shard(jnp.maximum(l_occ, r_occ)))
-        join_totals.append(_worst_shard(total))
-        if n < len(positives) - 2:
-            global_n = lax.psum(
-                acc_valid.sum(dtype=jnp.int32), SHARD_AXIS
+            exch_stats.append(
+                _worst_shard(jnp.maximum(l_occ, r_occ), moved)
             )
-            reseed = reseed | (global_n == 0)
+        join_totals.append(_worst_shard(total, moved))
+        if n < len(positives) - 2:
+            reseed = reseed | (_global_count(acc_valid, moved) == 0)
 
     for i, pairs in anti_meta:
         rv, rm = tables[i]
-        rv_full, rm_full = _gather_packed(rv, rm)
+        rv_full, rm_full = _gather_packed(rv, rm, moved)
         if use_k:
             acc_valid = _kernels.anti_join_impl(
                 acc_vals, acc_valid, rv_full, rm_full, pairs,
@@ -362,7 +418,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
                 acc_vals, acc_valid, rv_full, rm_full, pairs
             )
 
-    count = _global_count(acc_valid)
+    count = _global_count(acc_valid, moved)
     reseed = reseed & ~any_pos_empty
     stats_list = [
         count,
@@ -375,7 +431,8 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals):
     return acc_vals, acc_valid, stats_list
 
 
-def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False):
+def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False,
+                        moved: Optional[_Moved] = None):
     """Lower one sharded plan signature to a single shard_map program.
 
     Call convention: fn(bucket_arrays, keys, fixed_vals) like
@@ -385,13 +442,16 @@ def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False):
        *per-term worst shard ranges, *per-join worst shard totals,
        *per-partitioned-join worst destination occupancy]
     The conjunction body itself lives in _trace_sharded_conj (shared
-    with the whole-tree mesh program builder).
+    with the whole-tree mesh program builder).  `moved`, when given,
+    holds after the first call what the program's collectives move.
     """
     _pos, _neg, names, _jm, _am = fold_join_meta(sig.terms)
+    moved = moved if moved is not None else _Moved(sig.n_shards)
 
     def body(bucket_arrays, keys, fixed_vals):
+        moved.bytes = 0  # a re-trace counts the program once
         acc_vals, acc_valid, stats_list = _trace_sharded_conj(
-            sig, bucket_arrays, keys, fixed_vals
+            sig, bucket_arrays, keys, fixed_vals, moved
         )
         stats = jnp.stack(stats_list)
         if count_only:
@@ -422,7 +482,8 @@ class ShardedTreeSig:
     neg: Optional[ShardedPlanSig] = None
 
 
-def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False):
+def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False,
+                             moved: Optional[_Moved] = None):
     """Lower a whole Or/negation plan tree to ONE shard_map program:
     every conjunction site traces via _trace_sharded_conj (shard-local
     bodies, declared collectives), the positive branches union with a
@@ -454,26 +515,30 @@ def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False
             "tree fusion requires one shared variable universe"
         )
         perms.append(tuple(names.index(v) for v in out_names))
+    moved = moved if moved is not None else _Moved(sig.sites[0].n_shards)
 
     def body(*site_inputs):
+        moved.bytes = 0  # a re-trace counts the program once
         blocks = []
         parts = []
         for i, ssig in enumerate(sig.sites):
             ba, ks, fv = site_inputs[i]
-            v, m, sl = _trace_sharded_conj(ssig, ba, ks, fv)
+            v, m, sl = _trace_sharded_conj(ssig, ba, ks, fv, moved)
             blocks.append(sl)
             parts.append((v[:, jnp.asarray(perms[i], dtype=jnp.int32)], m))
         union_vals = jnp.concatenate([v for v, _ in parts], axis=0)
         union_valid = jnp.concatenate([m for _, m in parts], axis=0)
         if sig.neg is not None:
             ba, ks, fv = site_inputs[len(sig.sites)]
-            nv, nm, nsl = _trace_sharded_conj(sig.neg, ba, ks, fv)
+            nv, nm, nsl = _trace_sharded_conj(sig.neg, ba, ks, fv, moved)
             blocks.append(nsl)
             nv = nv[:, jnp.asarray(perms[-1], dtype=jnp.int32)]
             # replicate the minus side (tree.py difference() contract);
             # the union is only a membership set here — duplicates are
             # harmless, so the raw concat gathers without a dedup sort
-            uv_full, um_full = _gather_packed(union_vals, union_valid)
+            uv_full, um_full = _gather_packed(
+                union_vals, union_valid, moved
+            )
             all_pairs = tuple((c, c) for c in range(K))
             nm = _anti_join_impl(nv, nm, uv_full, um_full, all_pairs)
             out_vals, out_valid = nv, nm
@@ -483,7 +548,7 @@ def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False
             out_vals, out_valid, _local = _dedup_table_impl(
                 union_vals, union_valid
             )
-        count = _global_count(out_valid)
+        count = _global_count(out_valid, moved)
         stats = jnp.stack(
             [count] + [s for block in blocks for s in block]
         )
@@ -764,8 +829,19 @@ class ShardedFusedExecutor:
         """Streaming phase 2 (ISSUE 6): yields (index, ShardedFusedResult)
         as each query's verdict lands — the shared streaming settle loop
         (query/fused.py settle_pending_iter), so mesh tenants' first rows
-        reach their clients one RTT after their own dispatch too."""
-        return settle_pending_iter(self.results, pending)
+        reach their clients one RTT after their own dispatch too.  Each
+        round's transfer pulls every job's per-shard result slabs to the
+        host: span `mesh.fetch` (with tracing on)."""
+        return settle_pending_iter(
+            self.results, pending, on_fetch=self._record_fetch
+        )
+
+    def _record_fetch(self, t0: float, seconds: float, fetched) -> None:
+        obs.REC.record(
+            "mesh.fetch", "X", t0, seconds, 0,
+            {"jobs": len(fetched), "shards": self.n_shards,
+             "bytes": sum(a.nbytes for a in jax.tree.leaves(fetched))},
+        )
 
     def execute_many(
         self, plans_lists, count_only: bool = False
@@ -884,8 +960,9 @@ class _ShardedExecJob:
         use_k, tiled = plan_sig.use_kernels, plan_sig.tiled
         entry = ex._cache.get((plan_sig, self.count_only))
         if entry is None:
+            moved = _Moved(ex.n_shards)
             fn, out_names = build_fused_sharded(
-                plan_sig, ex.mesh, self.count_only
+                plan_sig, ex.mesh, self.count_only, moved
             )
             # program ledger (ISSUE 14): identity when DAS_TPU_PROFLOG
             # is off; the mesh program's compile/cost/memory record
@@ -893,14 +970,14 @@ class _ShardedExecJob:
             # twin (host-side bookkeeping only — dispatch stays
             # sync-free, DL001/DL010)
             entry = (
-                obs.proflog.instrument(
+                _MeshProgram(obs.proflog.instrument(
                     "sharded",
                     obs.proflog.sig_digest(plan_sig, self.count_only),
                     jax.jit(obs.named_program(
                         "das_sharded", fn, self.count_only
                     )),
                     model_bytes=partial(program_model_bytes, plan_sig),
-                ),
+                ), moved),
                 out_names,
             )
             ex._cache[(plan_sig, self.count_only)] = entry
@@ -926,6 +1003,9 @@ class _ShardedExecJob:
                 route = "sharded_multiway"
             elif use_k:
                 route = "sharded_kernel"
+            if self.rounds > 1:
+                # a shard overflowed a capacity: the program again
+                obs.counter("mesh.retries").inc()
             sp = obs.span(
                 "exec.dispatch", route=route, round=self.rounds,
                 count_only=self.count_only,
@@ -1040,12 +1120,15 @@ class _ShardedTreeExecJob(_TreeExecJob):
         )
 
     def _build(self, tree_sig):
-        fn, out_names = build_sharded_tree_fused(tree_sig, self.ex.mesh)
-        return obs.proflog.instrument(
+        moved = _Moved(self.ex.n_shards)
+        fn, out_names = build_sharded_tree_fused(
+            tree_sig, self.ex.mesh, moved=moved
+        )
+        return _MeshProgram(obs.proflog.instrument(
             "sharded_tree", obs.proflog.sig_digest(tree_sig, False),
             jax.jit(obs.named_program("das_sharded_tree", fn)),
             model_bytes=partial(tree_model_bytes, tree_sig),
-        ), out_names
+        ), moved), out_names
 
     def _blk_len(self, j) -> int:
         return conj_stats_len(
@@ -1070,6 +1153,8 @@ class _ShardedTreeExecJob(_TreeExecJob):
         record_dispatch("sharded_tree_fused")
         sp = obs.NOOP_SPAN
         if obs.enabled():
+            if self.rounds:
+                obs.counter("mesh.retries").inc()
             sp = obs.span("exec.dispatch", route="sharded_tree_fused",
                           sites=len(self.site_jobs))
         with sp, obs.annotation("exec.dispatch"):

@@ -31,9 +31,8 @@ SHARD_AXIS = "shards"
 COLLECTIVE_SITES = (
     "fused_sharded._repartition",
     "fused_sharded._gather_packed",
-    "fused_sharded._global_count",
+    "fused_sharded._global_sum",
     "fused_sharded._worst_shard",
-    "fused_sharded._trace_sharded_conj",
     "sharded_db.ShardedDB._join",
     "sharded_db.ShardedDB._anti_join",
     "sharded_tree.ShardedTreeOps._gather_table",

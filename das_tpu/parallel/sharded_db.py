@@ -415,8 +415,14 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         sharded fused executor's result cache keys on
         (parallel/fused_sharded.py); the FULL path (threshold or slab
         exhaustion) replaces `self.tables`, dropping the executor and its
-        cache wholesale."""
-        self.prefetch()
+        cache wholesale.
+
+        The host scan lists of MemoryDB (`prefetch`: one Python list
+        entry per link and index) are NOT rebuilt here: queries answer
+        from the mesh and never read them, every host scan that does
+        (`get_matched_*`) brings them up to date itself, and at the
+        FlyBase shape x 0.3 building them eagerly was 116 s of a 155 s
+        load (sandbox CPU run, PR 29)."""
         action = self._plan_refresh()
         if action == NOOP:
             return
@@ -533,8 +539,9 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         while True:
             def kernel(lv, lm, rv, rm):
                 # broadcast-right: gather the full right table to this shard
-                rv_full = jax.lax.all_gather(rv[0], SHARD_AXIS, tiled=True)
-                rm_full = jax.lax.all_gather(rm[0], SHARD_AXIS, tiled=True)
+                with jax.named_scope("mesh.all_gather_right"):
+                    rv_full = jax.lax.all_gather(rv[0], SHARD_AXIS, tiled=True)
+                    rm_full = jax.lax.all_gather(rm[0], SHARD_AXIS, tiled=True)
                 vals, valid, total = _join_tables_impl(
                     lv[0], lm[0], rv_full, rm_full, pairs, extra, cap
                 )
@@ -566,8 +573,9 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         spec = P(SHARD_AXIS)
 
         def kernel(lv, lm, rv, rm):
-            rv_full = jax.lax.all_gather(rv[0], SHARD_AXIS, tiled=True)
-            rm_full = jax.lax.all_gather(rm[0], SHARD_AXIS, tiled=True)
+            with jax.named_scope("mesh.all_gather_tabu"):
+                rv_full = jax.lax.all_gather(rv[0], SHARD_AXIS, tiled=True)
+                rm_full = jax.lax.all_gather(rm[0], SHARD_AXIS, tiled=True)
             return _anti_join_impl(lv[0], lm[0], rv_full, rm_full, pairs)[None]
 
         fn = shard_map(
@@ -579,6 +587,12 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         )
 
     def sharded_execute(self, plans: List[qc.TermPlan]) -> Optional[ShardedTable]:
+        """The STAGED mesh pipeline: one shard_map program per stage and
+        a host sync between stages.  The served path comes here only
+        when the fused mesh program declined (counter
+        `mesh.staged_fallbacks`)."""
+        if obs.enabled():
+            obs.counter("mesh.staged_fallbacks").inc()
         tabu: List[ShardedTable] = []
         accumulated: Optional[ShardedTable] = None
         for plan in plans:
@@ -601,33 +615,38 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         return accumulated
 
     def materialize(self, table: Optional[ShardedTable], answer: PatternMatchingAnswer) -> bool:
+        """The stacked per-shard rows of a mesh answer -> frozen
+        assignments: the same span as one chip's (`exec.materialize`,
+        query/compiler.py), with the mesh's own step inside it — the
+        valid rows of all shards, each once (`mesh.dedup`: two Or
+        branches may ground one answer on two shards)."""
         if table is None or table.count == 0:
             return False
-        if table.host_vals is not None:
-            vals, valid = table.host_vals, table.host_valid
-        else:
-            # one transfer for both arrays (each fetch is a host sync)
-            from das_tpu.query.fused import FETCH_COUNTS
+        with obs.span("exec.materialize", rows=table.count,
+                      prefetched=table.host_vals is not None):
+            if table.host_vals is not None:
+                vals, valid = table.host_vals, table.host_valid
+            else:
+                # one transfer for both arrays (each fetch is a host sync)
+                from das_tpu.query.fused import FETCH_COUNTS
 
-            FETCH_COUNTS["n"] += 1
-            vals, valid = jax.device_get((table.vals, table.valid))
-        vals = np.asarray(vals).reshape(-1, len(table.var_names))
-        valid = np.asarray(valid).reshape(-1)
-        hexes = self.fin.hex_of_row
-        seen = set()
-        for row in vals[valid]:
-            key = tuple(int(v) for v in row)
-            if key in seen:
-                continue
-            seen.add(key)
-            a = OrderedAssignment()
-            ok = True
-            for name, val in zip(table.var_names, row):
-                if not a.assign(name, hexes[int(val)]):
-                    ok = False
-                    break
-            if ok and a.freeze():
-                answer.assignments.add(a)
+                FETCH_COUNTS["n"] += 1
+                vals, valid = jax.device_get((table.vals, table.valid))
+            with obs.span("mesh.dedup") as sp:
+                vals = np.asarray(vals).reshape(-1, len(table.var_names))
+                rows = vals[np.asarray(valid).reshape(-1)]
+                distinct = np.unique(rows, axis=0)
+                sp.set(rows=len(rows), distinct=len(distinct))
+            hexes = self.fin.hex_of_row
+            for row in distinct:
+                a = OrderedAssignment()
+                ok = True
+                for name, val in zip(table.var_names, row):
+                    if not a.assign(name, hexes[int(val)]):
+                        ok = False
+                        break
+                if ok and a.freeze():
+                    answer.assignments.add(a)
         return bool(answer.assignments)
 
     def _run_conjunctive(self, plans: List[qc.TermPlan]) -> Optional[ShardedTable]:

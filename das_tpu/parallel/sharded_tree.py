@@ -254,7 +254,8 @@ class ShardedTreeOps(TreeOps):
         """Move one row-sharded table whole to every shard in ONE tiled
         all_gather (validity packed into the value block)."""
         packed = jnp.concatenate([v, m[:, None].astype(v.dtype)], axis=1)
-        full = jax.lax.all_gather(packed, SHARD_AXIS, tiled=True)
+        with jax.named_scope("mesh.gather_table"):
+            full = jax.lax.all_gather(packed, SHARD_AXIS, tiled=True)
         return full[:, :-1], full[:, -1] != 0
 
     def _join_fn(self, pairs, extra, cap, gather_left=False, perm=None):
@@ -366,7 +367,8 @@ class ShardedTreeOps(TreeOps):
                 packed = jnp.concatenate(
                     [v, m[:, None].astype(v.dtype)], axis=1
                 )
-                full = jax.lax.all_gather(packed, SHARD_AXIS, tiled=True)
+                with jax.named_scope("mesh.replicate"):
+                    full = jax.lax.all_gather(packed, SHARD_AXIS, tiled=True)
                 return full[:, :-1], full[:, -1] != 0
 
             spec = P(SHARD_AXIS)

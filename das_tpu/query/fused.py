@@ -418,7 +418,7 @@ def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
     return _PendingMany(results, jobs, outs, version)
 
 
-def settle_pending_iter(results_cache, pending):
+def settle_pending_iter(results_cache, pending, on_fetch=None):
     """Streaming settle of a _PendingMany (ISSUE 6 early-settle): yields
     `(index, result)` as each query's answer becomes FINAL — cache hits
     first (they were answered at dispatch with zero transfer), then, per
@@ -432,7 +432,9 @@ def settle_pending_iter(results_cache, pending):
     iterator and read `pending.results` (None = declined), or use
     settle_pending.  Shared by the single-device and sharded executors —
     their jobs expose the same dispatch()/settle() halves, so the
-    serving pipeline's second phase is ONE implementation."""
+    serving pipeline's second phase is ONE implementation.  With
+    tracing on, `on_fetch(t0, seconds, fetched)` hears of every round's
+    transfer (the mesh executor records its own span there)."""
     for i, hit in enumerate(pending.results):
         if hit is not None:
             yield i, hit
@@ -468,6 +470,8 @@ def settle_pending_iter(results_cache, pending):
                 "exec.settle_fetch", "X", t0, fetch_s, 0,
                 {"jobs": len(jobs)},
             )
+            if on_fetch is not None:
+                on_fetch(t0, fetch_s, fetched)
         nxt = []
         for (idxs, job, key), host, out in zip(jobs, fetched, outs):
             if job.settle(host, out):

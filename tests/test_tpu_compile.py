@@ -121,10 +121,11 @@ def test_lowered_join_at_max_capacity(compile_for_chip):
     compile_for_chip(f, lv, lm, rv, rm)
 
 
-def _tiny_store_and_query(make_db):
+def _tiny_store_and_query(make_db, n_clauses=3):
     """A tiny CPU store and the smoke's grounded 3-clause conjunction on
-    it: the plan signature is scale-free, only capacities and bucket
-    lengths grow with the KB."""
+    it (or its first `n_clauses`: two are the benchmark's `shared2`): the
+    plan signature is scale-free, only capacities and bucket lengths
+    grow with the KB."""
     from das_tpu.models.bio import build_bio_atomspace
     from das_tpu.query import compiler
     from das_tpu.query.ast import And, Link, Node, Variable
@@ -139,8 +140,37 @@ def _tiny_store_and_query(make_db):
         Link("Member", [Node("Gene", g), Variable("V3")], True),
         Link("Member", [Variable("V2"), Variable("V3")], True),
         Link("Interacts", [Node("Gene", g), Variable("V2")], True),
-    ])
+    ][:n_clauses])
     return db, compiler.plan_query(db, query)
+
+
+def _compile_on_described_mesh(topo, job, sig, per_shard):
+    """The fused shard_map program of `sig`, compiled against a Mesh
+    built from the described v5e:2x2 devices, the job's row-sharded
+    bucket arrays stretched to `per_shard` rows a shard."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from das_tpu.parallel.fused_sharded import build_fused_sharded
+    from das_tpu.parallel.mesh import SHARD_AXIS
+
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    sharded, replicated = NamedSharding(mesh, P(SHARD_AXIS)), NamedSharding(mesh, P())
+
+    def slab(a):  # [S, m(, a)] -> the store's per-shard rows
+        return jax.ShapeDtypeStruct(
+            (4, per_shard, *a.shape[2:]), a.dtype, sharding=sharded
+        )
+
+    def scalar_or_vec(x):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated)
+
+    fn, _names = build_fused_sharded(sig, mesh, False)
+    return jax.jit(fn).lower(
+        jax.tree.map(slab, job.arrays),
+        jax.tree.map(scalar_or_vec, job.keys),
+        jax.tree.map(scalar_or_vec, job.fvals),
+    ).compile()
 
 
 @pytest.fixture(scope="module")
@@ -231,13 +261,8 @@ def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
     grounded conjunction, compiled against a Mesh built from the
     described v5e:2x2 devices with the row-sharded bucket arrays at the
     smoke store's per-shard size.  The collectives must be there."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from das_tpu.parallel.fused_sharded import (
-        build_fused_sharded,
-        get_sharded_executor,
-    )
-    from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+    from das_tpu.parallel.fused_sharded import get_sharded_executor
+    from das_tpu.parallel.mesh import make_mesh
     from das_tpu.parallel.sharded_db import ShardedDB
 
     db, plans = _tiny_store_and_query(
@@ -247,28 +272,50 @@ def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
     assert job is not None
     sig = job.plan_sig()
     assert sig.n_shards == 4 and not sig.use_kernels
-
-    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
-    sharded, replicated = NamedSharding(mesh, P(SHARD_AXIS)), NamedSharding(mesh, P())
-    per_shard = capacity_class(-(-SMOKE_ARITY2_ROWS // 4))
-
-    def slab(a):  # [S, m(, a)] -> the smoke store's per-shard rows
-        return jax.ShapeDtypeStruct(
-            (4, per_shard, *a.shape[2:]), a.dtype, sharding=sharded
-        )
-
-    def scalar_or_vec(x):
-        x = np.asarray(x)
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated)
-
-    fn, _names = build_fused_sharded(sig, mesh, False)
-    compiled = jax.jit(fn).lower(
-        jax.tree.map(slab, job.arrays),
-        jax.tree.map(scalar_or_vec, job.keys),
-        jax.tree.map(scalar_or_vec, job.fvals),
-    ).compile()
-    text = compiled.as_text()
+    text = _compile_on_described_mesh(
+        topo, job, sig, capacity_class(-(-SMOKE_ARITY2_ROWS // 4))
+    ).as_text()
     assert "all-gather" in text or "all-reduce" in text or "all-to-all" in text
+
+
+#: cell 3 of the benchmark (`sharded4-uniform-closed`, FlyBase shape x
+#: 0.3 on 4 shards): 8,361,000 links of arity 2 dealt round-robin, and the
+#: capacities the mesh executor holds after the cell's warm-up (recorded
+#: from a CPU run of the served path at scale 0.3 on 4 virtual devices)
+CELL3_ARITY2_ROWS = 8_361_000
+CELL3_PROGRAMS = {
+    "grounded3": dict(term_caps=(16, 16, 16), join_caps=(1024, 64),
+                      exch_caps=(0, 0), index_joins=(1, -1)),
+    "shared2": dict(term_caps=(16, 16), join_caps=(1024,),
+                    exch_caps=(0,), index_joins=(1,)),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
+def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
+                                                   shape):
+    """The two mesh programs the warm-up of `sharded4-uniform-closed`
+    builds, at the cell's per-shard table size and capacities, compiled
+    for the described v5e:2x2: the gathers of the index joins and the
+    stats reductions (int32 `pmax`, `psum`) must lower."""
+    from das_tpu.parallel.fused_sharded import get_sharded_executor
+    from das_tpu.parallel.mesh import make_mesh
+    from das_tpu.parallel.sharded_db import ShardedDB
+
+    db, plans = _tiny_store_and_query(
+        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4)),
+        n_clauses=3 if shape == "grounded3" else 2,
+    )
+    job = get_sharded_executor(db)._exec_job(plans, False)
+    assert job is not None
+    want = CELL3_PROGRAMS[shape]
+    assert job.plan_sig().index_joins == want["index_joins"]
+    sig = dataclasses.replace(job.plan_sig(), **want)
+    assert sig.n_shards == 4 and not sig.use_kernels
+    per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
+    assert per_shard == 2_220_890
+    text = _compile_on_described_mesh(topo, job, sig, per_shard).as_text()
+    assert "all-gather" in text and "all-reduce" in text
 
 
 # -- the Pallas kernels: today's verdict, pinned --------------------------
