@@ -13,8 +13,10 @@ settle — `settle_pending` pays exactly one `jax.device_get` per retry
 round, which FETCH_COUNTS pins.
 
 Scope (mechanical): function bodies, nested defs included, of
-  * functions named `dispatch_many`, `dispatch_pending`, or matching
-    `*_dispatch` (execute_fused_many_dispatch, query_many_dispatch, ...);
+  * functions named `dispatch_many`, `dispatch_pending`, its per-round
+    loop `_dispatch_round` and the group hook `dispatch_group`, or
+    matching `*_dispatch` (execute_fused_many_dispatch,
+    query_many_dispatch, ...);
   * methods named `dispatch` on classes that also define `settle` — the
     _ExecJob / _ShardedExecJob dispatch/settle split; a bare function
     named `dispatch` (query/compiler.py's per-query router) legitimately
@@ -58,7 +60,8 @@ def _dispatch_functions(tree: ast.Module) -> List[Tuple[str, ast.AST]]:
             elif isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 name = child.name
                 is_dispatch = (
-                    name in ("dispatch_many", "dispatch_pending")
+                    name in ("dispatch_many", "dispatch_pending",
+                             "_dispatch_round", "dispatch_group")
                     or name.endswith("_dispatch")
                 )
                 if (

@@ -115,6 +115,7 @@ WORKER_METHODS: Dict[str, Tuple[str, ...]] = {}
 PROGRAM_SITES: Dict[str, Optional[str]] = {
     # -- instrumented: the whole-plan program builders -------------------
     "fused.build_fused": "fused",
+    "fused.build_fused_group": "fused_group",
     "fused.build_fused_tree": "fused_tree",
     "fused.build_fused_exact": "fused_exact",
     "fused.FusedExecutor._run_batch_group": "count_batch",

@@ -47,9 +47,10 @@ SPAN_NAMES = (
     #: instant: one query's future resolved — closes the trace id
     #: opened at serve.submit
     "serve.answer",
-    #: span: one job's device-program enqueue (query/fused.py _ExecJob
-    #: and _TreeExecJob dispatch halves + the sharded twins) — attrs:
-    #: route, rounds, planner est rows
+    #: span: one device-program enqueue (query/fused.py _ExecJob and
+    #: _TreeExecJob dispatch halves + the sharded twins) — attrs:
+    #: route, rounds, planner est rows; `lanes` when the program is a
+    #: group's (_ExecJob.dispatch_group: the jobs it carries)
     "exec.dispatch",
     #: span: one settle round's host transfer — a device-to-host sync
     #: (query/fused.py settle_pending_iter, DL013's one-transfer site)
@@ -142,6 +143,13 @@ COUNTER_NAMES = (
     "commit.rebuilds",
     "exec.dispatches",
     "exec.fetches",
+    #: programs the shared dispatch loop enqueued (query/fused.py
+    #: _dispatch_round: first rounds and capacity retries, one chip and
+    #: mesh) and the jobs they carried: lanes / programs is how many
+    #: same-signature queries of a coalesced group shared one program
+    #: (1.0 where every job rides alone)
+    "exec.group_programs",
+    "exec.group_lanes",
     #: queries re-run one by one because a commit overtook their
     #: dispatched round (api/atomspace.py settle_iter, `_stale()`)
     "exec.stale_reruns",
@@ -217,6 +225,7 @@ HISTOGRAM_NAMES = (
 #: daslint DL014 pins the literals both ways, like the span names.
 PROGRAM_NAMES = (
     "das_fused",
+    "das_fused_group",
     "das_fused_tree",
     "das_fused_exact",
     "das_count_batch",

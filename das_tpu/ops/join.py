@@ -18,6 +18,8 @@ host can retry on capacity overflow.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from functools import partial
 from typing import Tuple
 
@@ -55,8 +57,40 @@ def _searchsorted_method(n_queries: int, n_keys: int) -> str:
     table (e.g. a 64-row accumulated table joining into a 33M-row
     whole-table term at FlyBase scale: 'sort' pays a 33M-element sort per
     batch member, 'scan' pays 64 binary searches).  The cutover is
-    relative: scan while queries are far fewer than keys."""
-    return "sort" if n_queries > max(1024, n_keys // 16) else "scan"
+    relative: scan while queries are far fewer than keys.
+
+    Inside a lane-batched program (lane_batched) a 'sort' against a key
+    table of at most LANE_COMPARE_KEYS rows is 'compare_all' instead:
+    the batched variadic sort is 23 s of such a program's 26 s compile
+    for the chip (2,048 queries against a 16-row table, PERF.md §6 PR
+    30) while the [queries, keys] compare compiles in 0.3 s, and a
+    program first met while serving stalls every query behind it."""
+    method = "sort" if n_queries > max(1024, n_keys // 16) else "scan"
+    if (method == "sort" and n_keys <= LANE_COMPARE_KEYS
+            and getattr(_LANES, "on", False)):
+        return "compare_all"
+    return method
+
+
+#: widest key table a lane-batched 'sort' searchsorted lowers as
+#: 'compare_all' (a [queries, keys] compare a lane)
+LANE_COMPARE_KEYS = 256
+
+_LANES = threading.local()
+
+
+@contextlib.contextmanager
+def lane_batched():
+    """Held (by query/fused.py lanes_program) while the body of a
+    lane-batched program is TRACED: the static lowering choices above
+    see that every op here carries a lanes axis.  Per thread: another
+    thread's trace of a lone program is untouched."""
+    prev = getattr(_LANES, "on", False)
+    _LANES.on = True
+    try:
+        yield
+    finally:
+        _LANES.on = prev
 
 
 def _mix_columns(vals, cols: Tuple[int, ...], valid, sentinel):

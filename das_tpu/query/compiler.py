@@ -69,8 +69,8 @@ class TermPlan:
 @dataclass
 class BindingTable:
     var_names: Tuple[str, ...]
-    vals: jax.Array      # [cap, k] int32
-    valid: jax.Array     # [cap]
+    vals: Optional[jax.Array]   # [cap, k] int32; None on the serving
+    valid: Optional[jax.Array]  # [cap]     path, which reads the copies
     count: int
     host_vals: Optional[np.ndarray] = None   # prefetched host copies (one
     host_valid: Optional[np.ndarray] = None  # transfer with the stats)
@@ -403,8 +403,15 @@ def execute_fused_many_settle_iter(
         if res is None or res.reseed_needed:
             yield i, None
             continue
+        # the serving path materializes from the prefetched host copies:
+        # the device references stay unread (an answer that rode in a
+        # group program would slice its lane out on the read)
+        prefetched = res.host_vals is not None
         yield i, BindingTable(
-            res.var_names, res.vals, res.valid, res.count,
+            res.var_names,
+            None if prefetched else res.vals,
+            None if prefetched else res.valid,
+            res.count,
             host_vals=res.host_vals, host_valid=res.host_valid,
         )
     for i, done in enumerate(seen):

@@ -28,6 +28,7 @@ reference-identical.
 
 from __future__ import annotations
 
+import operator
 import os
 import time
 from dataclasses import dataclass
@@ -45,6 +46,7 @@ from das_tpu.ops.join import (
     _dedup_table_impl,
     _index_join_impl,
     _join_tables_impl,
+    lane_batched,
 )
 
 # probe index routes (static per term).  Every compiler.TermPlan pins
@@ -145,17 +147,46 @@ def plan_index_joins(sigs: Tuple[FusedTermSig, ...], start: int = 0):
     return tuple(index_joins), right_terms
 
 
-@dataclass
 class FusedResult:
-    var_names: Tuple[str, ...]
-    vals: jax.Array          # [cap, k] int32 (device)
-    valid: jax.Array         # [cap] (device)
-    count: int
-    reseed_needed: bool      # host must fall back to the staged path
-    overflow: bool           # some capacity too small; caller re-lowers
-    host_vals: Optional[np.ndarray] = None   # prefetched host copies —
-    host_valid: Optional[np.ndarray] = None  # free for materialization
-    multiway: bool = False   # answered by a k-way multiway program
+    """One conjunction's answer: the binding table's device references,
+    its prefetched host copies, and the settle verdict's flags.
+
+    An answer that rode in a group program (`_ExecJob.dispatch_group`)
+    is handed `vals` / `valid` as zero-argument callables over
+    `(group output, lane)`: the device slice is made when a reader first
+    asks for it, not at settle.  The served path materializes from
+    `host_vals` / `host_valid` and never asks."""
+
+    __slots__ = (
+        "var_names", "_vals", "_valid", "count", "reseed_needed",
+        "overflow", "host_vals", "host_valid", "multiway",
+    )
+
+    def __init__(
+        self, var_names, vals, valid, count, reseed_needed, overflow,
+        host_vals=None, host_valid=None, multiway=False,
+    ):
+        self.var_names: Tuple[str, ...] = var_names
+        self._vals = vals            # [cap, k] int32 (device)
+        self._valid = valid          # [cap] (device)
+        self.count: int = count
+        self.reseed_needed: bool = reseed_needed  # host falls back to staged
+        self.overflow: bool = overflow  # a capacity too small; re-lower
+        self.host_vals: Optional[np.ndarray] = host_vals    # prefetched —
+        self.host_valid: Optional[np.ndarray] = host_valid  # free to read
+        self.multiway: bool = multiway  # answered by a k-way program
+
+    @property
+    def vals(self) -> Optional[jax.Array]:
+        if callable(self._vals):
+            self._vals = self._vals()
+        return self._vals
+
+    @property
+    def valid(self) -> Optional[jax.Array]:
+        if callable(self._valid):
+            self._valid = self._valid()
+        return self._valid
 
 
 class _ExecJob:
@@ -234,18 +265,32 @@ class _ExecJob:
             self.planned is not None, self.multiway,
         )
 
-    def dispatch(self):
-        """Queue the program at the current capacities (async, no sync)."""
-        from das_tpu.kernels import record_dispatch
-
-        plan_sig = self.plan_sig()
-        use_k, tiled = plan_sig.use_kernels, plan_sig.tiled
+    def dispatch(self, plan_sig=None):
+        """Queue the program at the current capacities (async, no sync);
+        `plan_sig`: the signature there, where the caller has it."""
+        if plan_sig is None:
+            plan_sig = self.plan_sig()
         entry = self.ex._cache.get((plan_sig, self.count_only))
         if entry is None:
             entry = build_fused(plan_sig, self.count_only)
             self.ex._cache[(plan_sig, self.count_only)] = entry
         fn, self.names = entry
         self.rounds += 1
+        with self._enqueue_span(plan_sig), obs.annotation("exec.dispatch"):
+            return fn(self.arrays, self.keys, self.fvals)
+
+    def _enqueue_span(self, plan_sig, lanes: int = 0):
+        """Tally ONE program about to be enqueued (this job's own, or
+        the group program this job leads: `lanes` > 0) and return the
+        trace span to hold around the enqueue (ISSUE 12): host-monotonic
+        timestamps only — the dispatch half stays sync-free
+        (DL001/DL010); attrs carry the route and the planner's
+        estimated rows so settle's actuals line up against them in one
+        Perfetto lane.  Guarded: the disabled path packs no attribute
+        dict."""
+        from das_tpu.kernels import record_dispatch
+
+        use_k, tiled = plan_sig.use_kernels, plan_sig.tiled
         if plan_sig.planned:
             from das_tpu.planner import PLANNER_COUNTS
 
@@ -257,29 +302,68 @@ class _ExecJob:
                 record_dispatch("fused_kernel_tiled")
         if self.multiway:
             record_dispatch("fused_multiway")
-        # trace span + optional jax.profiler scope around the enqueue
-        # (ISSUE 12): host-monotonic timestamps only — the dispatch half
-        # stays sync-free (DL001/DL010); attrs carry the route and the
-        # planner's estimated rows so settle's actuals line up against
-        # them in one Perfetto lane.  Guarded: the disabled path packs
-        # no attribute dict.
-        sp = obs.NOOP_SPAN
-        if obs.enabled():
-            route = "fused"
-            if self.multiway:
-                route = "fused_multiway"
-            elif use_k:
-                route = "fused_kernel"
-            sp = obs.span(
-                "exec.dispatch", route=route, round=self.rounds,
-                count_only=self.count_only,
-                est_join_rows=(
-                    list(self.planned.est_join_rows)
-                    if self.planned is not None else None
-                ),
+        if not obs.enabled():
+            return obs.NOOP_SPAN
+        route = "fused"
+        if self.multiway:
+            route = "fused_multiway"
+        elif use_k:
+            route = "fused_kernel"
+        return obs.span(
+            "exec.dispatch", route=route, round=self.rounds,
+            count_only=self.count_only,
+            est_join_rows=(
+                list(self.planned.est_join_rows)
+                if self.planned is not None else None
+            ),
+            **({"lanes": lanes} if lanes else {}),
+        )
+
+    # -- group hooks: a job type that offers them can ride with its
+    # same-signature batch-mates in ONE program (_dispatch_round) -------
+
+    @staticmethod
+    def dispatch_group(jobs, plan_sig):
+        """Queue ONE program for `jobs` (2..GROUP_LANES, all at `plan_sig`
+        and one count_only): build_fused's body over their lane-stacked
+        probe keys and fixed values, the bucket arrays passed once and
+        unbatched.  Outputs carry a leading lanes axis; a lane past the
+        last job repeats it and is never read."""
+        lead = jobs[0]
+        ex, count_only = lead.ex, lead.count_only
+        keys, key_axes, fvals, fval_axes = stack_lanes(
+            [j.keys for j in jobs], [j.fvals for j in jobs], GROUP_LANES
+        )
+        cache_key = (plan_sig, count_only, GROUP_LANES, key_axes, fval_axes)
+        entry = ex._group_cache.get(cache_key)
+        if entry is None:
+            entry = build_fused_group(
+                plan_sig, count_only, key_axes, fval_axes
             )
-        with sp, obs.annotation("exec.dispatch"):
-            return fn(self.arrays, self.keys, self.fvals)
+            ex._group_cache[cache_key] = entry
+        fn, names = entry
+        for j in jobs:
+            j.names = names
+            j.rounds += 1
+        with lead._enqueue_span(plan_sig, lanes=len(jobs)), \
+                obs.annotation("exec.dispatch"):
+            return fn(lead.arrays, keys, fvals)
+
+    def lane_out(self, host_out, dev_out, lane: int):
+        """Lane `lane` of a group program's fetched block and of its
+        device outputs, in the form settle() takes: numpy VIEWS of the
+        host block, and for the device side callables that slice on
+        first use (FusedResult) — no device op per lane here."""
+        if self.count_only:
+            return host_out[lane], None
+        return (
+            tuple(h[lane] for h in host_out),
+            (
+                partial(operator.getitem, dev_out[0], lane),
+                partial(operator.getitem, dev_out[1], lane),
+                None,
+            ),
+        )
 
     def settle(self, host_out, dev_out) -> bool:
         """Consume one round's fetched stats.  True = finished (result is
@@ -357,16 +441,17 @@ class _ExecJob:
 
 class _PendingMany:
     """One dispatched-but-unsettled batch: cache-prefilled results, the
-    in-flight jobs with their cache keys, the device refs of the enqueued
-    round, and the delta version the round was dispatched against (guards
-    the settle-time cache insert against a racing commit)."""
+    enqueued round as `(members, device output)` per PROGRAM (members:
+    the `(indices, job, cache key)` entries it carries; one for a job
+    that ran alone, two or more for a group program's lanes), and the
+    delta version the round was dispatched against (guards the
+    settle-time cache insert against a racing commit)."""
 
-    __slots__ = ("results", "jobs", "outs", "version", "fetch_ms")
+    __slots__ = ("results", "programs", "version", "fetch_ms")
 
-    def __init__(self, results, jobs, outs, version):
+    def __init__(self, results, programs, version):
         self.results = results
-        self.jobs = jobs
-        self.outs = outs
+        self.programs = programs
         self.version = version
         # wall-ms of each settle round's host transfer, timed where it
         # happens (settle_pending_iter) — fetch_ms[0] IS the settle
@@ -376,11 +461,54 @@ class _PendingMany:
         self.fetch_ms: List[float] = []
 
 
+#: lanes of a served group program: a group of 2..32 jobs is padded to
+#: it, a wider one cut into programs of it.  ONE rung: every rung is one
+#: more compiled program per query shape and capacity step, and a rung
+#: first met inside a serving window is a compile inside it; 28 padded
+#: lanes cost the device 3.4-5 ms a program (PERF.md §6, PR 30), behind
+#: a host that spends 3.8 ms a query
+GROUP_LANES = 32
+
+
+def _dispatch_round(entries):
+    """Enqueue one round of `(indices, job, cache key)` entries — the
+    first of a dispatch_pending, or a settle round's capacity retries.
+    Jobs whose type offers the group hooks (`dispatch_group`,
+    `lane_out`) and that share `(plan_sig, count_only)` — same terms,
+    same capacities, same route — ride ONE program; a job alone in its
+    signature, and every job of a type without the hooks (the mesh
+    job), is enqueued by its own `dispatch()`, the program and cache
+    entry it always had.  Returns `[(members, device output)]`, one per
+    program."""
+    programs = []
+    groups: Dict[Tuple, List] = {}
+    for entry in entries:
+        job = entry[1]
+        if hasattr(job, "dispatch_group"):
+            sig = (type(job), job.plan_sig(), job.count_only)
+            groups.setdefault(sig, []).append(entry)
+        else:
+            programs.append(([entry], job.dispatch()))
+    for (_cls, plan_sig, _co), members in groups.items():
+        for at in range(0, len(members), GROUP_LANES):
+            cut = members[at : at + GROUP_LANES]
+            jobs = [job for _, job, _ in cut]
+            programs.append((cut, (
+                jobs[0].dispatch(plan_sig) if len(jobs) == 1
+                else jobs[0].dispatch_group(jobs, plan_sig)
+            )))
+    if obs.enabled():
+        obs.counter("exec.group_programs").inc(len(programs))
+        obs.counter("exec.group_lanes").inc(len(entries))
+    return programs
+
+
 def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
                      cache_only=False):
     """Phase-1 shared loop (pendant of settle_pending): resolve
     result-cache hits, dedup identical in-batch queries, prepare and
-    ENQUEUE the remaining jobs' first round — all asynchronous.
+    ENQUEUE the remaining jobs' first round — all asynchronous, one
+    program per same-signature group (_dispatch_round).
     `exec_job(plans, count_only)` returns a dispatchable job or None.
     Shared by the single-device and sharded executors so the dedup
     invariant (duplicates alias ONE shared index list, and never record
@@ -414,8 +542,7 @@ def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
             idxs = [i]
             by_key[key] = idxs
             jobs.append((idxs, job, key))
-    outs = [job.dispatch() for _, job, _ in jobs]
-    return _PendingMany(results, jobs, outs, version)
+    return _PendingMany(results, _dispatch_round(jobs), version)
 
 
 def settle_pending_iter(results_cache, pending, on_fetch=None):
@@ -423,26 +550,29 @@ def settle_pending_iter(results_cache, pending, on_fetch=None):
     `(index, result)` as each query's answer becomes FINAL — cache hits
     first (they were answered at dispatch with zero transfer), then, per
     retry round, every job whose verdict landed in that round's ONE host
-    transfer.  A query that settled in round 1 streams to its caller
-    while its batch-mates' capacity retries are still re-dispatching —
-    its first rows arrive one RTT after its own dispatch, not after the
-    whole group settles.  Settle-time cache inserts stay guarded by the
-    dispatch-time delta version (daslint DL007).  Indices the dispatch
-    phase declined (no job, no cache hit) are never yielded — drain the
-    iterator and read `pending.results` (None = declined), or use
-    settle_pending.  Shared by the single-device and sharded executors —
-    their jobs expose the same dispatch()/settle() halves, so the
-    serving pipeline's second phase is ONE implementation.  With
-    tracing on, `on_fetch(t0, seconds, fetched)` hears of every round's
-    transfer (the mesh executor records its own span there)."""
+    transfer: the outputs of every program of the round, a group
+    program's as one block whose lane i goes to its job i.  A query
+    that settled in round 1 streams to its caller while its
+    batch-mates' capacity retries are still re-dispatching (grouped
+    again by their new signatures) — its first rows arrive one RTT
+    after its own dispatch, not after the whole group settles.
+    Settle-time cache inserts stay guarded by the dispatch-time delta
+    version (daslint DL007).  Indices the dispatch phase declined (no
+    job, no cache hit) are never yielded — drain the iterator and read
+    `pending.results` (None = declined), or use settle_pending.  Shared
+    by the single-device and sharded executors — their jobs expose the
+    same dispatch()/settle() halves, so the serving pipeline's second
+    phase is ONE implementation.  With tracing on, `on_fetch(t0,
+    seconds, fetched)` hears of every round's transfer (the mesh
+    executor records its own span there)."""
     for i, hit in enumerate(pending.results):
         if hit is not None:
             yield i, hit
-    jobs, outs = pending.jobs, pending.outs
+    programs = pending.programs
     from das_tpu import fault
 
     retry = fault.fetch_retry()
-    while jobs:
+    while programs:
         t0 = time.perf_counter()
         with obs.annotation("exec.settle_fetch"):
             # the shared RetryPolicy (das_tpu/fault, ISSUE 13) replaces
@@ -455,7 +585,7 @@ def settle_pending_iter(results_cache, pending, on_fetch=None):
             def _fetch_round():
                 FETCH_COUNTS["n"] += 1
                 fault.maybe_fail("settle_fetch")
-                return jax.device_get(tuple(outs))
+                return jax.device_get(tuple(out for _, out in programs))
 
             fetched = retry.run(_fetch_round)
         fetch_s = time.perf_counter() - t0
@@ -468,22 +598,28 @@ def settle_pending_iter(results_cache, pending, on_fetch=None):
             obs.histogram("exec.settle_fetch_ms").observe(fetch_s * 1e3)
             obs.REC.record(
                 "exec.settle_fetch", "X", t0, fetch_s, 0,
-                {"jobs": len(jobs)},
+                {"jobs": sum(len(m) for m, _ in programs),
+                 "programs": len(programs)},
             )
             if on_fetch is not None:
                 on_fetch(t0, fetch_s, fetched)
         nxt = []
-        for (idxs, job, key), host, out in zip(jobs, fetched, outs):
-            if job.settle(host, out):
-                results_cache.put(key, job.result, pending.version)
-                for i in idxs:
-                    pending.results[i] = job.result
-                    yield i, job.result
-            else:
-                nxt.append((idxs, job, key))
-        jobs = nxt
-        outs = [job.dispatch() for _, job, _ in jobs]
-    pending.jobs, pending.outs = [], []
+        for (members, out), host in zip(programs, fetched):
+            alone = len(members) == 1
+            for lane, (idxs, job, key) in enumerate(members):
+                if alone:
+                    done = job.settle(host, out)
+                else:
+                    done = job.settle(*job.lane_out(host, out, lane))
+                if done:
+                    results_cache.put(key, job.result, pending.version)
+                    for i in idxs:
+                        pending.results[i] = job.result
+                        yield i, job.result
+                else:
+                    nxt.append((idxs, job, key))
+        programs = _dispatch_round(nxt) if nxt else []
+    pending.programs = []
 
 
 def settle_pending(results_cache, pending) -> List:
@@ -1028,6 +1164,26 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
     return acc_vals, acc_valid, stats_list
 
 
+def _fused_body(sig: FusedPlanSig, count_only: bool):
+    """The traced function of one plan signature and its variable
+    names: what build_fused jits for one query and build_fused_group
+    for the lanes of a group."""
+    _positives, _negatives, names, _jm, _am = fold_join_meta(sig.terms)
+
+    def fn(bucket_arrays, keys, fixed_vals):
+        acc_vals, acc_valid, stats_list = _trace_conj(
+            sig, bucket_arrays, keys, fixed_vals
+        )
+        stats = jnp.stack(stats_list)
+        if count_only:
+            # XLA dead-code-eliminates every value gather feeding only the
+            # discarded binding table — counts need keys and masks alone
+            return stats
+        return acc_vals, acc_valid, stats
+
+    return fn, names
+
+
 def build_fused(sig: FusedPlanSig, count_only: bool = False):
     """Lower one plan signature to a single jitted callable.
 
@@ -1042,25 +1198,88 @@ def build_fused(sig: FusedPlanSig, count_only: bool = False):
     The conjunction body itself lives in _trace_conj (shared with the
     whole-tree program builder).
     """
-    _positives, _negatives, names, _jm, _am = fold_join_meta(sig.terms)
-
-    def fn(bucket_arrays, keys, fixed_vals):
-        acc_vals, acc_valid, stats_list = _trace_conj(
-            sig, bucket_arrays, keys, fixed_vals
-        )
-        stats = jnp.stack(stats_list)
-        if count_only:
-            # XLA dead-code-eliminates every value gather feeding only the
-            # discarded binding table — counts need keys and masks alone
-            return stats
-        return acc_vals, acc_valid, stats
-
+    fn, names = _fused_body(sig, count_only)
     # program ledger (ISSUE 14): identity when DAS_TPU_PROFLOG is off;
     # on, the first call per shape AOT-compiles and records wall time +
     # cost/memory analysis under this signature's digest
     return obs.proflog.instrument(
         "fused", obs.proflog.sig_digest(sig, count_only),
         jax.jit(obs.named_program("das_fused", fn, count_only)),
+        model_bytes=partial(program_model_bytes, sig),
+    ), names
+
+
+def stack_or_const(rows):
+    """One lane-stacked input slot from per-member values: (stacked,
+    axis 0) when members differ, (shared value, axis None) when
+    identical — None axes let XLA compute constant terms (e.g. an
+    ungrounded probe shared by the whole batch) ONCE instead of per
+    member."""
+    stacked = np.stack(rows)
+    if (stacked == stacked[0]).all():
+        return rows[0], None
+    return stacked, 0
+
+
+def stack_lanes(key_rows, fval_rows, lanes: int):
+    """Per-member probe keys and fixed values (one tuple of per-term
+    values each) as the inputs of ONE lane-batched program: padded to
+    `lanes` by repeating the last member (jit re-traces per stacked
+    shape, so the lane count comes from a ladder; the padded lanes'
+    output rows are dropped), each term slot stacked or hoisted
+    (stack_or_const).  Returns (keys, key_axes, fvals, fval_axes)."""
+    pad = lanes - len(key_rows)
+    if pad:
+        key_rows = list(key_rows) + [key_rows[-1]] * pad
+        fval_rows = list(fval_rows) + [fval_rows[-1]] * pad
+    n_terms = len(key_rows[0])
+    keys, key_axes = zip(*(
+        stack_or_const([kr[t] for kr in key_rows]) for t in range(n_terms)
+    ))
+    fvals, fval_axes = zip(*(
+        stack_or_const([fr[t] for fr in fval_rows]) for t in range(n_terms)
+    ))
+    if all(a is None for a in key_axes + fval_axes):
+        # every lane is the same query (one lane, or duplicates): the
+        # first slot stays stacked, so the program has its lanes axis
+        keys = (np.stack([kr[0] for kr in key_rows]),) + keys[1:]
+        key_axes = (0,) + key_axes[1:]
+    return keys, key_axes, fvals, fval_axes
+
+
+def lanes_program(fn, key_axes, fval_axes):
+    """`fn(bucket_arrays, keys, fixed_vals)` over stack_lanes' inputs:
+    every output gains a leading lanes axis.  The bucket arrays are an
+    ARGUMENT, broadcast with in_axes=None, never a closure: a
+    closed-over array is a baked constant — the whole store would be
+    serialized into every compiled program (multi-GB at reference
+    scale), and a cached entry would keep reading PRE-COMMIT arrays
+    after an incremental delta merge replaced them.  Unbatched, the
+    table-sized work of a program (key splits, layout copies) is done
+    once per GROUP.  The body is traced under lane_batched, so the
+    joins pick the lowerings that compile fast with a lanes axis."""
+
+    def lane(bucket_arrays, keys, fixed_vals):
+        with lane_batched():
+            return fn(bucket_arrays, keys, fixed_vals)
+
+    return jax.vmap(lane, in_axes=(None, tuple(key_axes), tuple(fval_axes)))
+
+
+def build_fused_group(sig: FusedPlanSig, count_only, key_axes, fval_axes):
+    """build_fused's program for a GROUP of same-signature jobs
+    (_ExecJob.dispatch_group): the same _trace_conj body under
+    lanes_program.  Returns (fn, var names); fn(bucket_arrays, keys,
+    fixed_vals) -> vals [lanes, cap, k], valid [lanes, cap], stats
+    [lanes, n] (stats alone when count_only)."""
+    body, names = _fused_body(sig, count_only)
+    return obs.proflog.instrument(
+        "fused_group",
+        obs.proflog.sig_digest(sig, count_only, key_axes, fval_axes),
+        jax.jit(obs.named_program(
+            "das_fused_group", lanes_program(body, key_axes, fval_axes),
+            count_only,
+        )),
         model_bytes=partial(program_model_bytes, sig),
     ), names
 
@@ -1885,7 +2104,11 @@ class ResultCache:
             result, "reseed_needed", False
         ):
             return
-        vals = getattr(result, "vals", None)
+        # the prefetched host copy has the table's shape: measuring it
+        # leaves the device reference of a group lane unsliced
+        vals = getattr(result, "host_vals", None)
+        if vals is None:
+            vals = getattr(result, "vals", None)
         # total elements, covering both the 2-D [cap, k] single-device
         # table and the 3-D [S, cap, k] sharded layout
         if vals is not None and vals.size > self.MAX_ENTRY_ROWS:
@@ -2069,6 +2292,10 @@ class FusedExecutor:
         #: trees keyed by plan-tree digest, same version guard
         self.tree_results = ResultCache(db)
         self._batch_cache: Dict[FusedPlanSig, object] = {}
+        #: the served path's group programs (_ExecJob.dispatch_group):
+        #: (plan_sig, count_only, lanes, key axes, fixed-value axes) ->
+        #: (fn, names)
+        self._group_cache: Dict[Tuple, Tuple] = {}
         #: whole-tree fused programs (ISSUE 10): FusedTreeSig -> (fn,
         #: names).  Bounded in _TreeExecJob.dispatch (no per-site
         #: remember_caps eviction hook — tree sigs nest many term sigs)
@@ -2111,24 +2338,14 @@ class FusedExecutor:
     _same_positive_order = staticmethod(same_positive_order)
 
     @staticmethod
-    def _stack_or_const(rows):
-        """One vmap input slot from per-member values: (stacked, axis 0)
-        when members differ, (shared value, axis None) when identical —
-        None axes let XLA compute constant terms (e.g. an ungrounded probe
-        shared by the whole batch) ONCE instead of per member."""
-        first = rows[0]
-        if all(np.array_equal(r, first) for r in rows[1:]):
-            return first, None
-        return np.stack(rows), 0
-
-    @staticmethod
     def _sig_caps(ps) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         second = ps.join_caps if isinstance(ps, FusedPlanSig) else ps.chain_caps
         return (ps.term_caps, second)
 
     def _remember_caps(self, sigs, term_caps, join_caps) -> None:
         remember_caps(
-            self._caps, (self._cache, self._batch_cache), sigs,
+            self._caps,
+            (self._cache, self._batch_cache, self._group_cache), sigs,
             (term_caps, join_caps), self._sig_caps,
         )
         self._cap_store.save(sigs, (term_caps, join_caps), self._cap_salt())
@@ -2534,13 +2751,14 @@ class FusedExecutor:
         self, make_sig, cache, build, arrays,
         key_rows, fval_rows, n_terms, term_caps, caps,
     ):
-        """Shared machinery for one vmapped batch group: stack-or-hoist the
-        per-member inputs, compile/cache the (sig, axes) entry, and retry
+        """Shared machinery for one lane-batched count group: dedup the
+        members, stack-or-hoist their inputs (stack_lanes), compile/cache
+        the (sig, axes) entry (lanes_program — what the served path's
+        group programs are built from too), and retry
         with doubled capacities until no stage overflows.  Returns
         (stats or None, term_caps, caps); stats rows follow the common
         layout [count, flag, flag, *term_ranges, *stage_totals]."""
         cfg = self.db.config
-        n_members = len(key_rows)
         # dedup identical lanes: the miner's stochastic sampler redraws the
         # same grounded keys constantly — each unique row computes once and
         # fans back out below
@@ -2559,25 +2777,12 @@ class FusedExecutor:
                 uniq_keys.append(kr)
                 uniq_fvals.append(fr)
             back.append(i)
-        key_rows, fval_rows = uniq_keys, uniq_fvals
-        n_unique = len(key_rows)
-        # pad the lane count to a power of two: jit re-traces per stacked
-        # shape, so without padding every distinct member count compiles a
-        # fresh program (the miner's joint phase produced dozens) — padded
-        # lanes duplicate the last member and their stats rows are dropped
-        lanes = _pow2_at_least(n_unique, lo=1)
-        if lanes != n_unique:
-            key_rows = list(key_rows) + [key_rows[-1]] * (lanes - n_unique)
-            fval_rows = list(fval_rows) + [fval_rows[-1]] * (lanes - n_unique)
-        keys_stacked, key_axes = zip(*(
-            self._stack_or_const([kr[t] for kr in key_rows])
-            for t in range(n_terms)
-        ))
-        fvals_stacked, fval_axes = zip(*(
-            self._stack_or_const([fr[t] for fr in fval_rows])
-            for t in range(n_terms)
-        ))
-        all_const = all(a is None for a in key_axes + fval_axes)
+        # pad the lane count to a power of two: without padding every
+        # distinct member count compiles a fresh program (the miner's
+        # joint phase produced dozens)
+        keys_stacked, key_axes, fvals_stacked, fval_axes = stack_lanes(
+            uniq_keys, uniq_fvals, _pow2_at_least(len(uniq_keys), lo=1)
+        )
         from das_tpu.kernels import record_dispatch
 
         while True:
@@ -2590,24 +2795,12 @@ class FusedExecutor:
                     record_dispatch("count_kernel_tiled")
             entry = cache.get(cache_key)
             if entry is None:
-                fn = build(plan_sig)
-                # bucket arrays are an ARGUMENT (vmap-broadcast with
-                # in_axes=None), never a closure: a closed-over array is a
-                # baked constant — the whole store would be serialized into
-                # every compiled program (multi-GB at reference scale),
-                # and a cached
-                # entry would keep reading PRE-COMMIT arrays after an
-                # incremental delta merge replaced them
                 entry = obs.proflog.instrument(
                     "count_batch",
                     obs.proflog.sig_digest(plan_sig, key_axes, fval_axes),
                     jax.jit(obs.named_program(
                         "das_count_batch",
-                        fn if all_const
-                        else jax.vmap(
-                            fn,
-                            in_axes=(None, tuple(key_axes), tuple(fval_axes)),
-                        ),
+                        lanes_program(build(plan_sig), key_axes, fval_axes),
                     )),
                     model_bytes=partial(program_model_bytes, plan_sig),
                 )
@@ -2628,7 +2821,6 @@ class FusedExecutor:
                 )
 
             stats = fault.fetch_retry().run(_count_fetch)
-            stats = np.atleast_2d(stats)  # all_const programs return one row
             ranges = stats[:, 3 : 3 + n_terms]
             totals = stats[:, 3 + n_terms :]
             new_tc = tuple(
@@ -2641,9 +2833,7 @@ class FusedExecutor:
             )
             if new_tc == term_caps and new_cc == caps:
                 # fan unique-lane rows back out to the original members
-                # (all_const programs produce one row for everybody)
-                idx = np.zeros(len(back), dtype=int) if all_const else np.asarray(back)
-                return stats[idx], term_caps, caps
+                return stats[np.asarray(back)], term_caps, caps
             if max(new_tc + new_cc) > cfg.max_result_capacity:
                 return None, term_caps, caps
             term_caps, caps = new_tc, new_cc
@@ -2712,11 +2902,11 @@ class FusedExecutor:
             raise ValueError("count loop exceeds max_result_capacity")
         W = len(prepared)
         keys_stacked, key_axes = zip(*(
-            self._stack_or_const([p[2][t] for p in prepared])
+            stack_or_const([p[2][t] for p in prepared])
             for t in range(n_terms)
         ))
         fvals_stacked, fval_axes = zip(*(
-            self._stack_or_const([p[3][t] for p in prepared])
+            stack_or_const([p[3][t] for p in prepared])
             for t in range(n_terms)
         ))
         keys_elem = tuple(

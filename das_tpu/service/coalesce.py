@@ -595,7 +595,7 @@ class QueryCoalescer:
         except Exception:  # noqa: BLE001 — settle's fallback isolates
             job = None
         pending = getattr(job, "pending", None)
-        if pending is not None and getattr(pending, "jobs", None):
+        if pending is not None and getattr(pending, "programs", None):
             dispatch_ms = (time.perf_counter() - t0) * 1e3
             self._observe("dispatch_ewma_ms", dispatch_ms)
             if obs.enabled():

@@ -221,6 +221,100 @@ def test_fused_grounded3_at_smoke_shapes(compile_for_chip, grounded_job,
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+#: cell 1 of the benchmark (`mem-uniform-closed`, FlyBase shape x 0.3 on
+#: one chip): the arity-2 bucket's capacity class, and the capacities the
+#: executor holds for the cell's two shapes after its warm-up (recorded
+#: from the chip run of _archive/group_timing.py, PR 30)
+CELL1_ARITY2_CAPACITY = 8_883_562
+CELL1_PROGRAMS = {
+    "grounded3": dict(term_caps=(16, 16, 16), join_caps=(2048, 64),
+                      index_joins=(1, -1)),
+    "shared2": dict(term_caps=(16, 16), join_caps=(2048,),
+                    index_joins=(1,)),
+}
+
+
+@pytest.fixture(scope="module")
+def cell1_jobs():
+    """The executor's own jobs for cell 1's two shapes (tiny store)."""
+    from das_tpu.query.fused import get_executor
+    from das_tpu.storage.tensor_db import TensorDB
+
+    jobs = {}
+    for shape, n in (("grounded3", 3), ("shared2", 2)):
+        db, plans = _tiny_store_and_query(
+            lambda data: TensorDB(data, DasConfig()), n_clauses=n)
+        jobs[shape] = get_executor(db)._exec_job(plans, False)
+        assert jobs[shape] is not None
+    return jobs
+
+
+@pytest.mark.parametrize("count_only", [True, False],
+                         ids=["count_program", "result_program"])
+@pytest.mark.parametrize("shape", sorted(CELL1_PROGRAMS))
+def test_fused_group_at_cell1_shapes(compile_for_chip, cell1_jobs, shape,
+                                     count_only):
+    """`das_fused_group` (ISSUE 30) at the served path's lanes (its
+    ladder has the one rung), for both shapes of cell 1 at its bucket
+    size and capacities:
+    the lowered route, and a batched lowering that keeps its
+    temporaries far under ONE table (no `[lanes, table]` intermediate:
+    the bucket arrays ride unbatched)."""
+    from das_tpu.query import fused
+
+    lanes = fused.GROUP_LANES
+    assert CELL1_ARITY2_CAPACITY == capacity_class(8_361_000)
+    job = cell1_jobs[shape]
+    want = CELL1_PROGRAMS[shape]
+    assert job.plan_sig().index_joins == want["index_joins"]
+    sig = dataclasses.replace(job.plan_sig(), **want)
+    assert not sig.use_kernels
+    # the lanes' inputs as dispatch_group stacks them: every lane its
+    # own gene (the probe key of the grounded terms), the whole-type
+    # term's key hoisted
+    keys, key_axes, fvals, fval_axes = fused.stack_lanes(
+        [tuple(np.asarray(k) + (i if t != sig.index_joins.index(1) + 1 else 0)
+               for t, k in enumerate(job.keys)) for i in range(lanes)],
+        [job.fvals] * lanes, lanes,
+    )
+    assert None in key_axes and 0 in key_axes
+    fn, _names = fused.build_fused_group(sig, count_only, key_axes, fval_axes)
+
+    def stretch(a):
+        shape_ = tuple(a.shape)
+        return _shape(
+            (CELL1_ARITY2_CAPACITY, *shape_[1:]) if shape_ else shape_,
+            a.dtype)
+
+    def as_shape(x):
+        x = np.asarray(x)
+        return _shape(x.shape, x.dtype)
+
+    compiled = compile_for_chip(
+        fn, jax.tree.map(stretch, job.arrays),
+        jax.tree.map(as_shape, keys), jax.tree.map(as_shape, fvals),
+    )
+    assert "tpu_custom_call" not in compiled.as_text()
+    # das_fused alone holds 38.7 MB of temporaries there (the u32 halves
+    # of a key array); 32 lanes add 1 MB.  One lane-batched copy of a
+    # key array's half would be 4 x 35.5 MB at the lowest rung
+    table_bytes = CELL1_ARITY2_CAPACITY * 8      # one int64 key array
+    assert compiled.memory_analysis().temp_size_in_bytes < table_bytes
+    # no lane-batched sort of a join's 2,048 left rows: that sort was
+    # 23 s of the 26 s this program took to compile for the chip, inside
+    # a serving window when a capacity step built it there; under lanes
+    # the searches into the 16-row term tables are compares
+    # (ops/join.py lane_batched) and the sorts left are those tables' own
+    import re
+
+    lowered = fn.lower(job.arrays, keys, fvals).as_text()
+    sorted_rows = [int(n) for n in re.findall(
+        r"stablehlo\.sort.*?\}\) : \(tensor<%dx(\d+)xi64>" % lanes,
+        lowered, flags=re.S)]
+    assert lowered.count("stablehlo.sort") == len(sorted_rows)
+    assert all(n <= max(want["term_caps"]) for n in sorted_rows), sorted_rows
+
+
 @pytest.mark.parametrize("cap,dcap,key_dtype", [
     (SMOKE_ARITY2_CAPACITY, delta_class(10), jnp.int64),  # the smoke's
     (CELL2_ARITY2_CAPACITY, 64, jnp.int64),    # cell 2: 5 of a commit's 8

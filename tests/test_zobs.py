@@ -481,6 +481,7 @@ def test_obs_registries_pinned():
         "serve.deadline_misses", "serve.breaker_trips",
         "serve.breaker_recoveries", "fault.injected", "fault.retries",
         "exec.stale_reruns", "exec.per_query_fallbacks",
+        "exec.group_programs", "exec.group_lanes",
     }
     assert set(obs.HISTOGRAM_NAMES) >= {
         "serve.queue_ms", "serve.dispatch_ms", "serve.settle_ms",
@@ -489,8 +490,9 @@ def test_obs_registries_pinned():
     # the module names a device trace shows: a rename moves the
     # benchmark's device-time readers (benchmark/harness/readers.py)
     assert set(obs.PROGRAM_NAMES) == {
-        "das_fused", "das_fused_tree", "das_fused_exact",
-        "das_count_batch", "das_count_loop", "das_sharded",
+        "das_fused", "das_fused_group", "das_fused_tree",
+        "das_fused_exact", "das_count_batch", "das_count_loop",
+        "das_sharded",
         "das_sharded_tree", "das_merge_padded", "das_insert_rows",
         "das_merge_sharded",
     }
@@ -814,6 +816,8 @@ def test_program_names_in_lowered_modules(monkeypatch):
     ex = FusedExecutor(db)
     plans = compiler.plan_query(db, q)
     ex.execute_exact(plans)
+    # two groundings of one shape: ONE group program (ISSUE 30)
+    ex.execute_many([plans, compiler.plan_query(db, _pair_query())])
     ex.count_batch([plans, plans])
     run, _w = ex.build_count_loop([plans, plans])
     run()
@@ -824,6 +828,7 @@ def test_program_names_in_lowered_modules(monkeypatch):
     sdas.load_metta_text(COMMIT)
     want = {
         "fused": "jit_das_fused", "fused_tree": "jit_das_fused_tree",
+        "fused_group": "jit_das_fused_group",
         "fused_exact": "jit_das_fused_exact",
         "count_batch": "jit_das_count_batch",
         "count_loop": "jit_das_count_loop",
@@ -851,6 +856,45 @@ def test_program_names_in_lowered_modules(monkeypatch):
     met |= {"das_merge_padded", "das_insert_rows", "das_merge_sharded"}
     for name in obs.PROGRAM_NAMES:
         assert any(m.startswith(name) for m in met), name
+    assert "das_fused_group" in met
+
+
+def test_traced_group_ticks_one_program_five_lanes(traced):
+    """ISSUE 30's mechanism, observable: five same-shape queries of one
+    batch are ONE enqueue — one `exec.dispatch` span with `lanes=5`, one
+    program and five lanes on the counters, one settle fetch."""
+    from das_tpu.query import compiler
+    from das_tpu.query.fused import get_executor
+
+    das, db = _tensor_das(DasConfig(result_cache_size=0))
+    ex = get_executor(db)
+    concepts = ["animal", "mammal", "reptile", "plant", "dinosaur"]
+    plans = [
+        compiler.plan_query(db, And([
+            Link("Inheritance", [Variable("$1"), Variable("$2")], True),
+            Link("Inheritance", [Variable("$2"), Node("Concept", c)], True),
+        ]))
+        for c in concepts
+    ]
+    ex.execute_many(plans)      # learn the capacities: no retry below
+    want = [ex.execute(p).count for p in plans]
+    obs.reset()
+    got = ex.execute_many(plans)
+    assert [r.count for r in got] == want
+    assert obs.counter("exec.group_programs").value == 1
+    assert obs.counter("exec.group_lanes").value == 5
+    spans = [e for e in obs.events() if e[0] == "exec.dispatch"]
+    assert len(spans) == 1 and spans[0][8]["lanes"] == 5
+    fetches = [e for e in obs.events() if e[0] == "exec.settle_fetch"]
+    assert len(fetches) == 1
+    assert fetches[0][8] == {"jobs": 5, "programs": 1}
+    # a lone job's span carries no lanes attr, and counts 1 / 1
+    obs.reset()
+    ex.execute_many(plans[:1])
+    assert obs.counter("exec.group_programs").value == 1
+    assert obs.counter("exec.group_lanes").value == 1
+    (lone,) = [e for e in obs.events() if e[0] == "exec.dispatch"]
+    assert "lanes" not in lone[8]
 
 
 def test_dl014_pins_program_names(tmp_path):

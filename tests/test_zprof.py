@@ -540,6 +540,7 @@ def test_program_sites_registry_pinned():
     }
     assert instrumented == {
         "fused.build_fused": "fused",
+        "fused.build_fused_group": "fused_group",
         "fused.build_fused_tree": "fused_tree",
         "fused.build_fused_exact": "fused_exact",
         "fused.FusedExecutor._run_batch_group": "count_batch",
