@@ -107,6 +107,12 @@ FLYBASE = dict(n_genes=2_400_000, n_processes=180_000, members_per_gene=10,
                n_interactions=1_500_000, n_evaluations=435_000)
 
 
+def cpu_only_run() -> bool:
+    """The records' `interpret` flag: True off a TPU, where timings are
+    structural data and scripts/bench_diff.py gates nothing on them."""
+    return jax.devices()[0].platform != "tpu"
+
+
 def three_var_query():
     return And([
         Link("Member", [Variable("V1"), Variable("V3")], True),
@@ -362,7 +368,6 @@ def serving_throughput(dev_db, n_clients=256, per_client=4, rounds=2):
     hide, so the qps A/B and time_to_first_row_ms are structural data —
     the perf claims (served_ms_per_query under ~2 ms at 256 clients)
     are meaningful on accelerator runs."""
-    from das_tpu import kernels
     from das_tpu.query.fused import get_executor, result_cache_stats
 
     genes = dev_db.get_all_nodes("Gene", names=True)
@@ -379,8 +384,8 @@ def serving_throughput(dev_db, n_clients=256, per_client=4, rounds=2):
         "distinct_queries": len(set(idents)),
         "per_client": per_client,
         # true = CPU-only run (no wire to hide): structural data, not a
-        # perf claim — same honesty flag as the kernel A/Bs
-        "interpret": kernels.interpret_mode(),
+        # perf claim
+        "interpret": cpu_only_run(),
     }
     prev_cache = dev_db.config.result_cache_size
 
@@ -560,7 +565,7 @@ def chaos_serving(dev_db, n_clients=64, per_client=2):
     test_bench_contract.  Runs cache-off so injected settle faults
     cannot be absorbed by dict hits; `interpret: true` (CPU) makes the
     ratio structural data, not a perf claim."""
-    from das_tpu import fault, kernels
+    from das_tpu import fault
 
     genes = dev_db.get_all_nodes("Gene", names=True)
     idents = [genes[i % len(genes)] for i in range(n_clients)]
@@ -574,7 +579,7 @@ def chaos_serving(dev_db, n_clients=64, per_client=2):
         "clients": n_clients,
         "per_client": per_client,
         "fault_spec": spec,
-        "interpret": kernels.interpret_mode(),
+        "interpret": cpu_only_run(),
     }
     prev_cache = dev_db.config.result_cache_size
     dev_db.config.result_cache_size = 0
@@ -658,24 +663,21 @@ def chaos_serving(dev_db, n_clients=64, per_client=2):
 
 
 def sharded_serving(
-    sdata, tensor_db, rounds=2, n_queries=8, n_clients=256, per_client=2
+    sdata, rounds=2, n_queries=8, n_clients=256, per_client=2
 ):
     """Sharded serving parity record (ISSUE 3, raised to 256 open-loop
     clients by ISSUE 6): open-loop pipelined-vs-serial qps on the MESH
     path — ShardedDB tenants ride the coalescer's adaptive
     dispatch/settle window (parallel/fused_sharded.py
-    dispatch_many/settle_many_iter) — plus a `count_many`
-    kernel-vs-lowered A/B on the vmapped count-batch programs
-    (query/fused.py count_batch, FusedPlanSig.use_kernels).  Open-loop
+    dispatch_many/settle_many_iter).  Open-loop
     like serving_throughput: 256 client identities cycled over
     n_queries distinct genes, the whole backlog submitted up front so
     the in-flight window can fill; the result cache is disabled for
-    BOTH A/Bs so every arm pays real device work.
+    both arms so each pays real device work.
 
-    `interpret: true` marks a CPU-only run, where BOTH A/Bs are
-    structural/correctness data, not perf claims: the kernel arm runs by
-    direct discharge, and the qps A/B measures an in-process mesh with
-    no transport — pipelining's win comes from hiding the settle
+    `interpret: true` marks a CPU-only run, where the A/B is
+    structural/correctness data, not a perf claim: the qps A/B measures
+    an in-process mesh with no transport — pipelining's win comes from hiding the settle
     round trip behind device execution, so with an
     in-RAM settle the two arms read parity-within-noise.  The structural
     guarantees (pipelined+speculative==serial program counts, the
@@ -684,7 +686,6 @@ def sharded_serving(
     accelerator runs."""
     import statistics
 
-    from das_tpu import kernels
     from das_tpu.parallel.sharded_db import ShardedDB
 
     sdb = ShardedDB(sdata, DasConfig())
@@ -696,9 +697,7 @@ def sharded_serving(
         "clients": n_clients,
         "distinct_queries": len(set(idents)),
         "per_client": per_client,
-        # true = the kernel arm ran by direct discharge (CPU-only run):
-        # the count A/B is then a correctness/telemetry datum, not perf
-        "interpret": kernels.interpret_mode(),
+        "interpret": cpu_only_run(),
     }
 
     prev_cache = sdb.config.result_cache_size
@@ -743,210 +742,6 @@ def sharded_serving(
     out["open_loop_p99_ms"] = round(pcts["p99"] or 0.0, 3)
     out["latency_buckets"] = piped_hist.nonzero_buckets()
 
-    # --- count_many kernel-vs-lowered A/B (vmapped count-batch groups) ---
-    from das_tpu.query.fused import get_executor
-
-    ex = get_executor(tensor_db)
-    queries = [grounded_query(g) for g in genes]
-    prev_mode = tensor_db.config.use_pallas_kernels
-    prev_tcache = tensor_db.config.result_cache_size
-    env_prev = os.environ.pop("DAS_TPU_PALLAS", None)  # A/B needs both routes
-    tensor_db.config.result_cache_size = 0  # time the device, not the cache
-    try:
-        counts = {}
-        for label, mode in (("lowered", "off"), ("kernel", "on")):
-            tensor_db.config.use_pallas_kernels = mode
-            plans_list = [compiler.plan_query(tensor_db, q) for q in queries]
-            before = kernels.DISPATCH_COUNTS["count_kernel"]
-            ex.count_batch(plans_list)  # warm compile + caps
-            times = []
-            for _ in range(rounds + 1):
-                t0 = time.perf_counter()
-                counts[label] = ex.count_batch(plans_list)
-                times.append(time.perf_counter() - t0)
-            out[f"count_{label}_ms"] = round(statistics.median(times) * 1e3, 3)
-            if label == "kernel":
-                # honesty flag: did the group program actually route
-                # through the kernels, or did the size guard decline?
-                out["count_kernel_engaged"] = (
-                    kernels.DISPATCH_COUNTS["count_kernel"] > before
-                )
-        out["count_parity"] = counts["kernel"] == counts["lowered"]
-    finally:
-        tensor_db.config.use_pallas_kernels = prev_mode
-        tensor_db.config.result_cache_size = prev_tcache
-        if env_prev is not None:
-            os.environ["DAS_TPU_PALLAS"] = env_prev
-    return out
-
-
-def kernel_ab(dev_db, rounds=5):
-    """Kernel-vs-lowered A/B on the headline 3-var count query: same
-    store, same query, both routes — the executor caches kernel and
-    lowered executables side by side (FusedPlanSig.use_kernels), so each
-    side times its own compiled program.  Off-TPU the kernels run in
-    interpret mode (flagged `interpret: true`): the record is then a
-    correctness/telemetry datum, not a perf claim — the perf target is
-    the TPU Mosaic compile."""
-    from das_tpu import kernels
-
-    q = three_var_query()
-    out = {"interpret": kernels.interpret_mode()}
-    prev = dev_db.config.use_pallas_kernels
-    # DAS_TPU_PALLAS beats the config in kernels.enabled(); it must not
-    # beat the A/B, which needs BOTH routes — lift it for the measurement
-    env_prev = os.environ.pop("DAS_TPU_PALLAS", None)
-    try:
-        for label, mode in (("lowered", "off"), ("kernel", "on")):
-            dev_db.config.use_pallas_kernels = mode
-            compiler.count_matches(dev_db, q)  # warm compile + caps
-            before = (
-                kernels.DISPATCH_COUNTS["fused_kernel"]
-                + kernels.DISPATCH_COUNTS["kernel"]
-            )
-            times = []
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                compiler.count_matches(dev_db, q)
-                times.append(time.perf_counter() - t0)
-            out[f"{label}_ms"] = round(statistics.median(times) * 1e3, 3)
-            if label == "kernel":
-                # honesty flag: did a kernel actually dispatch (fused
-                # kernel program OR staged-path kernel calls), or did the
-                # size guard fall back to the lowered ops throughout?
-                out["kernel_engaged"] = (
-                    kernels.DISPATCH_COUNTS["fused_kernel"]
-                    + kernels.DISPATCH_COUNTS["kernel"]
-                ) > before
-        from das_tpu.core.config import DasConfig as _Cfg
-
-        out["route"] = kernels.route_label(_Cfg(use_pallas_kernels="on"))
-    finally:
-        dev_db.config.use_pallas_kernels = prev
-        if env_prev is not None:
-            os.environ["DAS_TPU_PALLAS"] = env_prev
-    return out
-
-
-def tiled_kernel_ab(rounds=3):
-    """Grid-chunked kernel A/B at FlyBase-shape scale (ISSUE 4): a
-    SYNTHETIC >2^18-row term — a posting table past the old
-    single-block row bound (KERNEL_MAX_ROWS, 2^18) whose probe window
-    and join output the bytes planner (kernels/budget.py) grid-chunks —
-    timed kernel-route vs the lowered op chains on identical inputs.
-
-    The table is synthetic numpy (no KB build: the point is the kernel
-    shapes, not ingest).  `tiled_route` records the planner verdicts;
-    the A/B asserts NO SILENT FALLBACK — after the kernel arms,
-    DISPATCH_COUNTS must show zero lowered launches and a kernel_tiled
-    launch, else the run aborts into the error field rather than
-    reporting a kernel time that secretly measured the lowered ops.
-    Off-TPU (`interpret: true`) both arms are correctness/telemetry
-    data, not perf claims — the perf target is the TPU Mosaic compile."""
-    import statistics
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from das_tpu import kernels
-    from das_tpu.kernels import budget as kbudget
-    from das_tpu.ops import posting
-    from das_tpu.ops.join import _build_term_table_impl, _join_tables_impl
-
-    rng = np.random.default_rng(2024)
-    n = 1 << 19                      # 524288 rows: 2x the old bound
-    probe_cap = 1 << 19
-    # one fat key owns >2^18 rows — the whole-table-term probe shape
-    fat = np.zeros(n, np.int64)
-    fat[(1 << 18) + (1 << 16):] = np.arange(n - (1 << 18) - (1 << 16)) + 1
-    keys = jnp.asarray(np.sort(fat))
-    perm = jnp.asarray(rng.permutation(n).astype(np.int32))
-    targets = jnp.asarray(rng.integers(0, 1 << 20, (n, 2)).astype(np.int32))
-    key = np.int64(0)
-
-    L = R = 2048
-    join_cap = 1 << 19
-    lv = jnp.asarray(rng.integers(0, 8, (L, 2)).astype(np.int32))
-    rv = jnp.asarray(rng.integers(0, 8, (R, 2)).astype(np.int32))
-    lm = jnp.asarray(np.ones(L, bool))
-    rm = jnp.asarray(np.ones(R, bool))
-    jargs = (lv, lm, rv, rm, ((0, 0),), (1,), join_cap)
-
-    probe_plan = kbudget.probe_plan(n, n, 2, 2, probe_cap)
-    join_plan = kbudget.join_plan(L, 2, R, 2, 1, 3, join_cap)
-    out = {
-        "interpret": kernels.interpret_mode(),
-        "rows": n,
-        "probe_cap": probe_cap,
-        "join_cap": join_cap,
-        "route": probe_plan.route,
-        "tiled_route": {
-            "probe": probe_plan.route, "join": join_plan.route,
-            "chunk_rows": probe_plan.chunk_rows,
-        },
-    }
-
-    @jax.jit
-    def lowered_probe(keys, perm, targets, key):
-        local, valid, cnt = posting.range_probe(keys, perm, key, probe_cap)
-        vals, mask = _build_term_table_impl(targets, local, valid, (0, 1), ())
-        return vals, mask, cnt
-
-    lowered_join = jax.jit(
-        lambda *a: _join_tables_impl(*a, ((0, 0),), (1,), join_cap)
-    )
-
-    def timed(fn, *a):
-        best = []
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            r = fn(*a)
-            jax.block_until_ready(r)
-            best.append(time.perf_counter() - t0)
-        return r, statistics.median(best) * 1e3
-
-    pw, out["probe_lowered_ms"] = timed(lowered_probe, keys, perm, targets, key)
-    jw, out["join_lowered_ms"] = timed(lowered_join, lv, lm, rv, rm)
-
-    env_prev = os.environ.pop("DAS_TPU_PALLAS", None)
-    try:
-        kernels.reset_dispatch_counts()
-        pk, out["probe_kernel_ms"] = timed(
-            lambda: kernels.probe_term_table(
-                keys, perm, targets, key, np.zeros(0, np.int32), probe_cap,
-                var_cols=(0, 1), eq_pairs=(), extra_fixed=(),
-            )
-        )
-        jk, out["join_kernel_ms"] = timed(lambda: kernels.join_tables(*jargs))
-        c = kernels.DISPATCH_COUNTS
-        # no-silent-fallback: both eligible shapes must have launched
-        # kernels (at least one grid-chunked) and ZERO lowered ops
-        out["no_lowered_fallback"] = (
-            c["lowered"] == 0 and c["kernel"] >= 2 and c["kernel_tiled"] >= 1
-        )
-        assert out["no_lowered_fallback"], f"silent lowered fallback: {c}"
-    finally:
-        if env_prev is not None:
-            os.environ["DAS_TPU_PALLAS"] = env_prev
-    out["parity"] = bool(
-        all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(pk, pw)
-        )
-        and all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(jk, jw)
-        )
-    )
-    out["tiled_vs_lowered_ms"] = [
-        round(out["probe_kernel_ms"] + out["join_kernel_ms"], 3),
-        round(out["probe_lowered_ms"] + out["join_lowered_ms"], 3),
-    ]
-    for k in (
-        "probe_kernel_ms", "probe_lowered_ms",
-        "join_kernel_ms", "join_lowered_ms",
-    ):
-        out[k] = round(out[k], 3)
     return out
 
 
@@ -965,7 +760,7 @@ def planner_ab(rounds=3):
     that IS the planner's win), warm per-query ms (best-of-rounds),
     compiled fused program counts, retry_rounds_avoided =
     greedy_programs - planner_programs, and answer parity."""
-    from das_tpu import kernels
+    from das_tpu.ops import counters
     from das_tpu import planner as planner_mod
     from das_tpu.api.atomspace import DistributedAtomSpace
     from das_tpu.query import fused as fused_mod
@@ -1002,14 +797,13 @@ def planner_ab(rounds=3):
     env_prev = os.environ.pop("DAS_TPU_XLA_CACHE", None)
     os.environ["DAS_TPU_XLA_CACHE"] = "0"
     # DAS_TPU_PLANNER beats the config in planner.enabled(); an exported
-    # value must not collapse both arms onto one path (the kernel A/B
-    # lifts DAS_TPU_PALLAS for the same reason)
+    # value must not collapse both arms onto one path
     planner_env_prev = os.environ.pop("DAS_TPU_PLANNER", None)
     try:
         for label, mode in (("planner", "on"), ("greedy", "off")):
             db = TensorDB(data, DasConfig(use_planner=mode))
             das = DistributedAtomSpace(database_name=f"pab_{label}", db=db)
-            kernels.reset_dispatch_counts()
+            counters.reset_dispatch_counts()
             planner_mod.reset_planner_counts()
             t0 = time.perf_counter()
             # parity compares ASSIGNMENT SETS, not formatted strings —
@@ -1023,7 +817,7 @@ def planner_ab(rounds=3):
             out[f"{label}_first_contact_ms"] = round(
                 (time.perf_counter() - t0) * 1e3, 3
             )
-            out[f"{label}_programs"] = kernels.DISPATCH_COUNTS["fused"]
+            out[f"{label}_programs"] = counters.DISPATCH_COUNTS["fused"]
             best = float("inf")
             for _ in range(rounds):
                 t0 = time.perf_counter()
@@ -1051,100 +845,6 @@ def planner_ab(rounds=3):
     return out
 
 
-def multiway_ab(rounds=3):
-    """Worst-case-optimal multiway join A/B (ISSUE 9): planner-routed
-    k-way intersection vs the binary-join chain on the SKEW-HEAVY hub
-    fan-out star (three Member clauses sharing the process variable at
-    skew 1.1 — the chain's second intermediate rides the independence
-    model, which errs low exactly on skew, so its capacity seed pays a
-    retry tier; the multiway route's ONE output buffer seeds from the
-    exact k-way degree product) plus the 3-var analytic triangle (a
-    2-clause star prefix + binary tail — parity coverage for the mixed
-    program).
-
-    Each arm gets a FRESH TensorDB (fresh executor caches), the CapStore
-    is disabled, DAS_TPU_STAR=0 keeps the star count on the executors
-    whose capacities are the thing under test, and DAS_TPU_MULTIWAY is
-    lifted so the config decides the arm.  In-bench assertions: star
-    counts AND analytic assignment sets identical across arms
-    (bit-parity), and the multiway arm must actually dispatch a
-    fused_multiway program (no silent chain fallback).  Reported:
-    first-contact wall time, warm per-query ms, compiled fused program
-    counts, chain_retry_rounds_avoided = chain_programs -
-    multiway_programs, and the planner's route/est-vs-actual."""
-    from das_tpu import kernels
-    from das_tpu import planner as planner_mod
-    from das_tpu.api.atomspace import DistributedAtomSpace
-
-    data, _, _ = build_bio_atomspace(
-        n_genes=120, n_processes=40, members_per_gene=3,
-        n_interactions=300, seed=17, skew=1.1,
-    )
-    star = And([
-        Link("Member", [Variable("V1"), Variable("V3")], True),
-        Link("Member", [Variable("V2"), Variable("V3")], True),
-        Link("Member", [Variable("V4"), Variable("V3")], True),
-    ])
-    analytic = three_var_query()
-
-    out = {"skew": 1.1, "interpret": kernels.interpret_mode()}
-    counts = {}
-    answers = {}
-    saved_env = {}
-    for name in ("DAS_TPU_XLA_CACHE", "DAS_TPU_MULTIWAY", "DAS_TPU_STAR"):
-        saved_env[name] = os.environ.pop(name, None)
-    os.environ["DAS_TPU_XLA_CACHE"] = "0"
-    os.environ["DAS_TPU_STAR"] = "0"
-    try:
-        for label, mode in (("multiway", "auto"), ("chain", "off")):
-            db = TensorDB(data, DasConfig(use_multiway=mode))
-            das = DistributedAtomSpace(database_name=f"mab_{label}", db=db)
-            kernels.reset_dispatch_counts()
-            planner_mod.reset_planner_counts()
-            t0 = time.perf_counter()
-            counts[label] = compiler.count_matches(db, star)
-            answers[label] = frozenset(
-                das.query_answer(analytic)[1].assignments
-            )
-            out[f"{label}_first_contact_ms"] = round(
-                (time.perf_counter() - t0) * 1e3, 3
-            )
-            out[f"{label}_programs"] = kernels.DISPATCH_COUNTS["fused"]
-            if label == "multiway":
-                # no-silent-fallback: the k-way route must have RUN
-                assert kernels.DISPATCH_COUNTS["fused_multiway"] >= 1, (
-                    f"multiway arm never dispatched: "
-                    f"{kernels.DISPATCH_COUNTS}"
-                )
-                out["multiway_stats"] = planner_mod.snapshot()
-                out["multiway_route"] = planner_mod.explain(db, star)[
-                    "route"
-                ]
-            best = float("inf")
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                compiler.count_matches(db, star)
-                das.query(analytic)
-                best = min(best, time.perf_counter() - t0)
-            out[f"{label}_ms"] = round(best * 1e3 / 2, 3)
-            del das, db
-    finally:
-        del os.environ["DAS_TPU_XLA_CACHE"]
-        del os.environ["DAS_TPU_STAR"]
-        for name, prev in saved_env.items():
-            if prev is not None:
-                os.environ[name] = prev
-    out["chain_retry_rounds_avoided"] = (
-        out["chain_programs"] - out["multiway_programs"]
-    )
-    out["parity"] = (
-        counts["multiway"] == counts["chain"]
-        and answers["multiway"] == answers["chain"]
-    )
-    assert out["parity"], "multiway answers diverged from the chain"
-    return out
-
-
 def tree_fused_ab(rounds=3):
     """Whole-tree fused execution A/B (ISSUE 10): one planner-costed
     program for an N-branch Or vs the tree executor's per-site
@@ -1166,7 +866,7 @@ def tree_fused_ab(rounds=3):
     Reported: first-contact wall time, warm per-query ms, device
     program counts, tree_programs_avoided = tree_programs -
     fused_programs, and the planner's whole-tree route."""
-    from das_tpu import kernels
+    from das_tpu.ops import counters
     from das_tpu import planner as planner_mod
     from das_tpu.api.atomspace import DistributedAtomSpace
 
@@ -1195,7 +895,7 @@ def tree_fused_ab(rounds=3):
         # tree_programs_avoided arithmetic reads off these
         "branches": [len(q.terms) for q in queries],
         "queries": len(queries),
-        "interpret": kernels.interpret_mode(),
+        "interpret": cpu_only_run(),
     }
     answers = {}
     saved_env = {}
@@ -1208,7 +908,7 @@ def tree_fused_ab(rounds=3):
                 use_tree_fusion=mode, result_cache_size=0,
             ))
             das = DistributedAtomSpace(database_name=f"tfab_{label}", db=db)
-            kernels.reset_dispatch_counts()
+            counters.reset_dispatch_counts()
             t0 = time.perf_counter()
             answers[label] = [
                 frozenset(das.query_answer(q)[1].assignments)
@@ -1218,14 +918,14 @@ def tree_fused_ab(rounds=3):
                 (time.perf_counter() - t0) * 1e3, 3
             )
             out[f"{label}_programs"] = (
-                kernels.DISPATCH_COUNTS["fused_tree"]
-                + kernels.DISPATCH_COUNTS["fused"]
+                counters.DISPATCH_COUNTS["fused_tree"]
+                + counters.DISPATCH_COUNTS["fused"]
             )
             if label == "fused":
                 # no-silent-fallback: the whole-tree route must have RUN
-                assert kernels.DISPATCH_COUNTS["fused_tree"] >= 1, (
+                assert counters.DISPATCH_COUNTS["fused_tree"] >= 1, (
                     f"fused-tree arm never dispatched: "
-                    f"{kernels.DISPATCH_COUNTS}"
+                    f"{counters.DISPATCH_COUNTS}"
                 )
                 out["tree_fused_route"] = planner_mod.explain(
                     db, queries[0]
@@ -1251,30 +951,6 @@ def tree_fused_ab(rounds=3):
     return out
 
 
-def staged_dispatch_counts(db):
-    """Dispatched-ops count for ONE staged 3-var query, kernel vs lowered
-    route (the dispatch-count regression test pins the same numbers:
-    tests/test_zkernels.py)."""
-    from das_tpu import kernels
-
-    plans = compiler.plan_query(db, three_var_query())
-    out = {}
-    prev = db.config.use_pallas_kernels
-    env_prev = os.environ.pop("DAS_TPU_PALLAS", None)  # same lift as kernel_ab
-    try:
-        for label, mode in (("lowered", "off"), ("kernel", "on")):
-            db.config.use_pallas_kernels = mode
-            kernels.reset_dispatch_counts()
-            compiler.execute_plan(db, plans)
-            c = kernels.DISPATCH_COUNTS
-            out[label] = c["kernel"] + c["lowered"]
-    finally:
-        db.config.use_pallas_kernels = prev
-        if env_prev is not None:
-            os.environ["DAS_TPU_PALLAS"] = env_prev
-    return out
-
-
 def durability_section(dev_db, n_commits=3):
     """dasdur record (ISSUE 15): `restore_s` — verified snapshot + WAL
     replay + warm bundle vs a full rebuild from bare records (finalize
@@ -1293,7 +969,7 @@ def durability_section(dev_db, n_commits=3):
     import shutil
     import tempfile
 
-    from das_tpu import fault, kernels
+    from das_tpu import fault
     from das_tpu.api.atomspace import DistributedAtomSpace
     from das_tpu.core.config import DasConfig
     from das_tpu.core.exceptions import InjectedFault
@@ -1301,7 +977,7 @@ def durability_section(dev_db, n_commits=3):
     from das_tpu.storage.tensor_db import TensorDB
 
     root = tempfile.mkdtemp(prefix="das_bench_dur_")
-    out = {"interpret": kernels.interpret_mode(), "commits": n_commits}
+    out = {"interpret": cpu_only_run(), "commits": n_commits}
     das = DistributedAtomSpace(database_name="bench_dur", db=dev_db)
     genes = dev_db.get_all_nodes("Gene", names=True)[:4]
     queries = [grounded_query(g) for g in genes]
@@ -1824,33 +1500,10 @@ def main():
     except Exception as e:
         print(f"[bench] chaos serving failed: {e!r}", file=sys.stderr)
         chs = {"error": repr(e)[:200]}
-    # Pallas kernel A/B (VERDICT r05 depth item): fused 3-var count via
-    # the kernel route vs the lowered op chain, plus the staged pipeline's
-    # dispatched-ops count both ways (on the small KB — the count is
-    # shape-independent)
-    try:
-        ab = _with_programs(kernel_ab, dev_db)
-    except Exception as e:
-        print(f"[bench] kernel A/B failed: {e!r}", file=sys.stderr)
-        ab = {"error": repr(e)[:200]}
-    try:
-        ab["staged_dispatches"] = staged_dispatch_counts(sdev_db)
-    except Exception as e:
-        print(f"[bench] staged dispatch count failed: {e!r}", file=sys.stderr)
-        ab["staged_dispatches"] = {"error": repr(e)[:200]}
-    # grid-chunked kernel A/B at a >2^18-row synthetic term (ISSUE 4):
-    # the shapes the old single-block row bound kicked to the lowered
-    # ops; includes the no-silent-fallback dispatch assertion
-    try:
-        tiled_ab = _with_programs(tiled_kernel_ab)
-    except Exception as e:
-        print(f"[bench] tiled kernel A/B failed: {e!r}", file=sys.stderr)
-        tiled_ab = {"error": repr(e)[:200]}
     # sharded serving parity (ISSUE 3): mesh-path pipelined-vs-serial qps
-    # A/B plus the count_many kernel A/B, on the small KB (the mesh
-    # partition and the vmapped count groups are cheap at that scale)
+    # A/B on the small KB (the mesh partition is cheap at that scale)
     try:
-        shs = _with_programs(sharded_serving, sdata, sdev_db)
+        shs = _with_programs(sharded_serving, sdata)
     except Exception as e:
         print(f"[bench] sharded serving failed: {e!r}", file=sys.stderr)
         shs = {"error": repr(e)[:200]}
@@ -1862,14 +1515,6 @@ def main():
     except Exception as e:
         print(f"[bench] planner A/B failed: {e!r}", file=sys.stderr)
         pab = {"error": repr(e)[:200]}
-    # multiway join A/B (ISSUE 9): planner-routed k-way intersection vs
-    # the binary chain on the skew-heavy hub fan-out star — programs,
-    # retry tiers avoided, warm ms, bit-parity
-    try:
-        mab = _with_programs(multiway_ab)
-    except Exception as e:
-        print(f"[bench] multiway A/B failed: {e!r}", file=sys.stderr)
-        mab = {"error": repr(e)[:200]}
     # whole-tree fused execution A/B (ISSUE 10): one program per
     # N-branch Or vs the tree executor's per-site composites — program
     # counts, time-to-answer, bit-parity asserted in-bench
@@ -1974,30 +1619,13 @@ def main():
             # flag} — every failure typed, answers chaos-parity clean
             "chaos": chs,
             # sharded serving parity (ISSUE 3): mesh-path open-loop qps
-            # A/B {serial_qps, pipelined_qps, inflight_peak, n_shards} +
-            # count_many kernel A/B {count_lowered_ms, count_kernel_ms,
-            # count_kernel_engaged, count_parity}
+            # A/B {serial_qps, pipelined_qps, inflight_peak, n_shards}
             "sharded_serving": shs,
-            # kernel-vs-lowered A/B: {lowered_ms, kernel_ms, interpret,
-            # route, staged_dispatches: {lowered, kernel}}.  interpret=
-            # true means the kernels ran through the Pallas interpreter
-            # (CPU-only run) — recorded, not a perf claim
-            "kernel_ab": ab,
-            # grid-chunked A/B at a >2^18-row synthetic term:
-            # {tiled_route, probe/join kernel-vs-lowered ms,
-            #  tiled_vs_lowered_ms, parity, no_lowered_fallback,
-            #  interpret honesty flag} (ISSUE 4)
-            "tiled_kernel_ab": tiled_ab,
             # cost-based planner A/B (ISSUE 8): {planner_ms, greedy_ms,
             # planner/greedy first-contact ms + program counts,
             # retry_rounds_avoided, planner_route, parity,
             # planner_stats (est-vs-actual telemetry)}
             "planner_ab": pab,
-            # multiway join A/B (ISSUE 9): {multiway_ms, chain_ms,
-            # first-contact ms + program counts per arm,
-            # chain_retry_rounds_avoided, multiway_route, parity,
-            # multiway_stats (est-vs-actual), interpret honesty flag}
-            "multiway_ab": mab,
             # whole-tree fused execution A/B (ISSUE 10): {fused_ms,
             # tree_ms, first-contact ms + device program counts per arm,
             # tree_programs_avoided, tree_fused_route, parity, interpret
@@ -2012,10 +1640,8 @@ def main():
             "durability": dur,
             # program ledger snapshot (ISSUE 14): XLA compiles observed
             # across the whole run, total/cold-start compile seconds,
-            # ledger hit rate, and the per-site byte-model calibration
-            # aggregate (budget_vs_actual) — the device-side compile
-            # story the per-section programs_compiled/compile_s fields
-            # decompose
+            # ledger hit rate — the device-side compile story the
+            # per-section programs_compiled/compile_s fields decompose
             "programs": proflog.snapshot(),
             "flybase_scale": None,
         },
@@ -2149,33 +1775,11 @@ def compact_headline(result, full_record="BENCH_FULL.json"):
                 (ex.get("serving") or {}).get("device_path_ms"),
             ],
             # sharded serving parity (ISSUE 3): mesh-path open-loop qps
-            # [pipelined(depth=2), serial(depth=1)] and the count-batch
-            # kernel A/B [kernel_ms, lowered_ms]
+            # [pipelined(depth=2), serial(depth=1)]
             "sharded_qps": [
                 (ex.get("sharded_serving") or {}).get("pipelined_qps"),
                 (ex.get("sharded_serving") or {}).get("serial_qps"),
             ],
-            "count_kernel_vs_lowered_ms": [
-                (ex.get("sharded_serving") or {}).get("count_kernel_ms"),
-                (ex.get("sharded_serving") or {}).get("count_lowered_ms"),
-            ],
-            # Pallas route record: which kernel route ran, and the A/B
-            # [kernel_ms, lowered_ms] (interpret runs flagged in the full
-            # record's kernel_ab.interpret)
-            "kernel_route": (ex.get("kernel_ab") or {}).get("route"),
-            "kernel_vs_lowered_ms": [
-                (ex.get("kernel_ab") or {}).get("kernel_ms"),
-                (ex.get("kernel_ab") or {}).get("lowered_ms"),
-            ],
-            # grid-chunked route at the >2^18-row synthetic term (ISSUE
-            # 4): the planner verdict and [kernel_ms, lowered_ms] summed
-            # over the probe+join arms (interpret flag in the full
-            # record's tiled_kernel_ab)
-            "tiled_route": (ex.get("tiled_kernel_ab") or {}).get("route"),
-            "tiled_vs_lowered_ms": (
-                (ex.get("tiled_kernel_ab") or {}).get("tiled_vs_lowered_ms")
-                or [None, None]
-            ),
             # cost-based planner A/B (ISSUE 8): the route the planner
             # chose for the hub fan-out term, warm per-query ms
             # [planner, greedy], and the capacity-retry tiers (= XLA
@@ -2189,20 +1793,6 @@ def compact_headline(result, full_record="BENCH_FULL.json"):
             ],
             "retry_rounds_avoided": (ex.get("planner_ab") or {}).get(
                 "retry_rounds_avoided"
-            ),
-            # multiway join A/B (ISSUE 9): the route the planner chose
-            # for the skew-heavy hub fan-out star, warm per-query ms
-            # [multiway, chain], and the capacity-retry tiers (= XLA
-            # compiles) the k-way intersection's exact seed eliminated
-            "multiway_route": (ex.get("multiway_ab") or {}).get(
-                "multiway_route"
-            ),
-            "multiway_vs_chain_ms": [
-                (ex.get("multiway_ab") or {}).get("multiway_ms"),
-                (ex.get("multiway_ab") or {}).get("chain_ms"),
-            ],
-            "chain_retry_rounds_avoided": (ex.get("multiway_ab") or {}).get(
-                "chain_retry_rounds_avoided"
             ),
             # whole-tree fused execution A/B (ISSUE 10): the planner's
             # whole-tree route, warm per-query ms [fused, tree], and the

@@ -237,13 +237,13 @@ class CacheEvents:
 
 
 def counters_snapshot(db=None) -> dict:
-    from das_tpu import kernels
+    from das_tpu.ops.counters import DISPATCH_COUNTS
     from das_tpu.query.compiler import ROUTE_COUNTS
     from das_tpu.query.fused import FETCH_COUNTS, result_cache_stats
 
     snap = {
         "route": dict(ROUTE_COUNTS),
-        "dispatch": dict(kernels.DISPATCH_COUNTS),
+        "dispatch": dict(DISPATCH_COUNTS),
         "fetches": FETCH_COUNTS["n"],
     }
     if db is not None:
@@ -439,7 +439,6 @@ def _pick_or_not(s: Smoke):
 def phase_queries(s: Smoke) -> dict:
     """The served queries, each compared with the plain sets; then the
     route proof over the whole phase."""
-    from das_tpu import kernels
     from das_tpu.query.ast import And, Link, Node, Not, Or, Variable
 
     db = s.das.db
@@ -526,7 +525,6 @@ def phase_queries(s: Smoke) -> dict:
     emit("queries", results=results, route_delta=route,
          dispatch_delta=dispatch, result_cache_hits=cache_hits,
          per_query_dispatcher=rpc_direct,
-         route_label=kernels.route_label(db.config),
          fetches=after["fetches"] - before["fetches"])
     return {"equal": all(r["equal"] for r in results),
             "host_delta": route.get("host", 0), "n_queries": len(results)}
@@ -638,7 +636,6 @@ def run_phases(scale: float, seed: int, chips: int) -> dict:
     """All phases of one run; returns the summary the gate decides on.
     Any failed check raises."""
     import das_tpu
-    from das_tpu import kernels
 
     events = CacheEvents()
     s = Smoke(scale, seed, chips)
@@ -655,8 +652,6 @@ def run_phases(scale: float, seed: int, chips: int) -> dict:
         emit("counters", route_counts=snap["route"],
              dispatch_counts=snap["dispatch"], fetch_counts=snap["fetches"],
              result_cache=snap["result_cache"],
-             route_label=kernels.route_label(s.das.db.config),
-             use_pallas_kernels=s.das.db.config.use_pallas_kernels,
              compile_cache_dir=das_tpu.compile_cache_dir(),
              compile_cache_hits=events.hits,
              compile_cache_misses=events.misses,

@@ -1,13 +1,12 @@
 """daslint — AST invariant analyzer for the das_tpu contracts.
 
-Four PRs of perf work (fused kernels, dispatch/settle pipelining,
-sharded parity, grid-chunked tiling) piled up invariants that existed
-only by convention and reviewer memory: dispatch paths must be
-transfer-free, every field routing a kernel must live in the plan
-signature, every DAS_TPU_* env read must be declared, counter keys must
-be registered and test-pinned, the VMEM byte models must track the
-buffers the kernel bodies allocate, and the coalescer's worker-thread
-state must honor its locks.  Query-on-tensor-runtime systems live or
+Four PRs of perf work (fused programs, dispatch/settle pipelining,
+sharded parity) piled up invariants that existed only by convention
+and reviewer memory: dispatch paths must be transfer-free, every field
+that changes a traced program must live in the plan signature, every
+DAS_TPU_* env read must be declared, counter keys must be registered
+and test-pinned, and the coalescer's worker-thread state must honor
+its locks.  Query-on-tensor-runtime systems live or
 die on exactly these silent-recompile / cache-poisoning hazards (a
 plan/signature mismatch surfaces as a wrong answer, not a crash), so
 this package checks them mechanically, on every run of `ops/lint.sh`
@@ -29,20 +28,18 @@ Rules (one module each under rules/; contracts in ARCHITECTURE.md §11):
   DL002 plan-sig completeness   routing fields live in the frozen sig
   DL003 env registry            DAS_TPU_* reads <-> ENV_REGISTRY
   DL004 counter discipline      DISPATCH/ROUTE keys <-> ops/counters.py
-  DL005 budget-model drift      kernel-body refs <-> budget.KERNEL_BUFFERS
   DL006 lock discipline         coalescer mutations <-> LOCK_DISCIPLINE
   DL007 cache-insert guard      delta_version captured before dispatch
   DL008 planner vocabularies    routes/counter keys <-> ops/counters.py
   DL009 collective discipline   collectives <-> COLLECTIVE_SITES
   DL010 transitive host sync    DL001 through the whole call graph
-  DL011 Mosaic readiness        ref/control-flow/dtype/lane contracts
   DL012 retrace hygiene         jit closures derive from *Sig/constants
   DL013 fetch-site registry     jax.device_get <-> FETCH_SITES + tally
   DL014 obs name discipline     span/metric names <-> obs/registry.py
   DL015 fault-site registry     maybe_fail <-> FAULT_SITES, ban in
-                                kernels/ and dispatch halves
-  DL016 program-site registry   jax.jit/pallas_call <-> PROGRAM_SITES
-                                + the instrument/record_launch tally
+                                dispatch halves
+  DL016 program-site registry   jax.jit <-> PROGRAM_SITES + the
+                                instrument tally
   DL017 durability discipline   persist writes via atomic helpers,
                                 fsync-before-rename, PERSIST_SITES
 

@@ -4,10 +4,8 @@ stdlib-`ast` (never importing what it checks).
 The v1 rules were per-file syntactic scans; the contracts they enforce
 are not.  DL001's "no host sync in a dispatch half" is trivially
 escaped by one helper-function hop — the exact silent-serialization
-failure the async pipeline cannot afford — and the Mosaic-readiness
-checks (DL011) need to follow a kernel body into the shared helpers
-that actually touch its refs.  This module gives every rule the same
-three layers:
+failure the async pipeline cannot afford.  This module gives every
+rule the same three layers:
 
   * **module symbol tables** (`ModuleTable`, cached on the SourceFile
     so the (path, mtime, size) file cache amortizes them): top-level
@@ -81,7 +79,7 @@ class ModuleTable:
         self.methods: Dict[str, Dict[str, ast.AST]] = {}
         #: class name -> base expression names (unresolved)
         self.bases: Dict[str, List[ast.expr]] = {}
-        #: local name -> dotted import target ("das_tpu.kernels.budget",
+        #: local name -> dotted import target ("das_tpu.ops.counters",
         #: "das_tpu.query.fused._TreeExecJob", ...)
         self.imports: Dict[str, str] = {}
         for node in sf.tree.body:

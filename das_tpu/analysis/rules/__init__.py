@@ -5,13 +5,11 @@ from das_tpu.analysis.rules import (  # noqa: F401
     dl002_plan_sig,
     dl003_env_registry,
     dl004_counters,
-    dl005_budget_model,
     dl006_locks,
     dl007_cache_guard,
     dl008_planner_routes,
     dl009_collectives,
     dl010_transitive_sync,
-    dl011_mosaic,
     dl012_retrace,
     dl013_fetch_sites,
     dl014_obs_registry,

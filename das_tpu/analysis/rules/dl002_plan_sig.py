@@ -4,9 +4,8 @@ Contract (PR 1..4): a compiled executable is cached under its plan
 signature (FusedPlanSig / ShardedPlanSig / FusedExactSig), so EVERY
 property that changes what the builder traces must be a field of that
 frozen dataclass — and every field must participate in __eq__/__hash__.
-The `tiled` / `vmem_budget` omissions caught by hand in PR 4 are the
-canonical failure: routing consulted a value the signature didn't
-carry, two different programs collided under one cache key, and the
+An omission caught by hand in PR 4 is the canonical failure: the
+builder consulted a value the signature didn't carry, two different programs collided under one cache key, and the
 wrong executable replayed silently (wrong layout, or at sharded scale
 wrong answers — cache poisoning, not a crash).
 
@@ -21,7 +20,7 @@ ride along — they nest inside the plan sigs' hash):
      class (`def build_fused(sig: FusedPlanSig, ...)` — the
      routing/executable-build consumers), including `getattr(sig, "x"
      [, default])`, must be a declared field, property, or method —
-     the static catch for the next `tiled`-style omission;
+     the static catch for the next such omission;
   4. constructor calls must not exceed the field count positionally nor
      pass unknown keywords.
 
@@ -180,7 +179,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
                 "DL002", sig.posix, lineno,
                 f"{sig.name}.{fname} opts out of hash/compare — a "
                 "routing field excluded from the cache key is exactly "
-                "the tiled/vmem_budget class of bug",
+                "the cache-poisoning bug the rule exists for",
             )
     # 3: attribute reads through annotated consumer params
     for sf in ctx.modules():

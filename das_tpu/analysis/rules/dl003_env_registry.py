@@ -21,8 +21,8 @@ code <-> registry:
 Registry shape (parsed statically, never imported):
 
     ENV_REGISTRY = {
-        "DAS_TPU_PALLAS": ("use_pallas_kernels", "kernel routing ..."),
-        "DAS_TPU_VMEM_BUDGET": (None, "bytes planner budget ..."),
+        "DAS_TPU_PLANNER": ("use_planner", "cost-based planner ..."),
+        "DAS_TPU_PLANNER_DP_MAX": (None, "DP clause ceiling ..."),
     }
     ENV_DECLARED_EXTERNAL = ("DAS_TPU_TEST_PLATFORM",)
 """

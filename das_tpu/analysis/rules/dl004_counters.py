@@ -17,7 +17,7 @@ them; this rule pins the literals:
   * every declared key must appear (quoted) in at least one test file —
     an unpinned counter is telemetry nobody would notice breaking
     (tests/test_zlint.py's registry pin covers the long tail; hot keys
-    are pinned by the kernel/pipeline/sharded suites);
+    are pinned by the pipeline/sharded suites);
   * a literal dict assigned to DISPATCH_COUNTS/ROUTE_COUNTS must have
     exactly the declared keys (the real dicts are comprehensions over
     the registry, so this leg guards fixtures and future forks).

@@ -20,9 +20,7 @@ telemetry — and both vocabularies are closed, declared sets:
 Scope: the route-literal leg applies to planner modules — a file whose
 path contains "planner", or that references the planner markers
 (PLANNER_COUNTS / PLANNER_KEYS / PlannedProgram).  Executor-side route
-locals stay DL004's jurisdiction (they subscript ROUTE_COUNTS), and the
-kernels' budget-route locals ("single"/"tiled"/"lowered") never collide
-because those are assigned from `budget.ROUTE_*` names, not literals.
+locals stay DL004's jurisdiction (they subscript ROUTE_COUNTS).
 Dynamic subscripts resolve like DL004: a local assigned only string
 constants that later subscripts PLANNER_COUNTS pins those constants.
 """

@@ -3,20 +3,12 @@
 Contract (ISSUE 10 / ROADMAP "named candidate rules"): XLA collectives
 (`all_gather` / `all_to_all` / `psum` / `pmax` / `pmin` / `ppermute` /
 `psum_scatter`) are the mesh programs' ONLY cross-shard channel, and
-where they may appear is a closed, declared set:
-
-  * NEVER inside das_tpu/kernels/ — kernel bodies are SHARD-LOCAL by
-    design (parallel/fused_sharded.py routes them inside shard_map, one
-    shard's slab per invocation; ARCHITECTURE §9).  A collective inside
-    a kernel body either fails to lower (Pallas), deadlocks (one shard
-    takes a different trace path), or silently changes semantics
-    between the interpret/discharge/Mosaic lowerings — the worst bug
-    class on real hardware, invisible on the single-device CPU suite;
-  * everywhere else, only inside the scopes declared in
-    `COLLECTIVE_SITES` (parallel/mesh.py) — the lowered mesh helpers
-    (gather/exchange/reduction) whose collective use IS their purpose.
-    Concentrating the call sites keeps every cross-shard byte visible
-    in one reviewable list (the ICI traffic model of ARCHITECTURE §8).
+where they may appear is a closed, declared set: only inside the
+scopes declared in `COLLECTIVE_SITES` (parallel/mesh.py) — the mesh
+helpers (gather/exchange/reduction) whose collective use IS their
+purpose.  Concentrating the call sites keeps every cross-shard byte
+visible in one reviewable list (the ICI traffic model of ARCHITECTURE
+§8).
 
 Attribution: a call is charged to its OUTERMOST enclosing scope —
 leading class names plus the first function name, qualified by the
@@ -71,10 +63,6 @@ def _is_collective_call(node: ast.Call) -> Optional[str]:
     return None
 
 
-def _in_kernels(sf) -> bool:
-    return "kernels" in sf.path.parts
-
-
 def _collective_sites(sf) -> Iterable[Tuple[int, str, str]]:
     """(line, collective name, outermost qualified scope) per call."""
 
@@ -112,19 +100,8 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
     used_scopes: Set[str] = set()
     any_calls = False
     for sf in ctx.modules():
-        kernels_file = _in_kernels(sf)
         for line, name, scope in _collective_sites(sf):
             any_calls = True
-            if kernels_file:
-                yield Finding(
-                    "DL009", sf.posix, line,
-                    f"collective `{name}` inside a shard-local kernel "
-                    "body (das_tpu/kernels/) — kernel bodies run per "
-                    "shard under shard_map; a collective here deadlocks "
-                    "or silently diverges between the interpret/"
-                    "discharge/Mosaic lowerings",
-                )
-                continue
             if registry is None:
                 yield Finding(
                     "DL009", sf.posix, line,

@@ -1,7 +1,7 @@
 """DL012 — retrace hygiene at program-construction sites.
 
 Contract (ISSUE 11): every compiled-program construction site —
-`jax.jit(...)`, `pl.pallas_call(...)`, `shard_map(...)` (calls and
+`jax.jit(...)`, `shard_map(...)` (calls and
 decorators) — keys its executable cache on the STATIC inputs of the
 traced callable: its closure and static arguments.  The codebase's
 idiom is the frozen-`*Sig` builder (`build_fused(sig: FusedPlanSig)`)
@@ -18,9 +18,8 @@ where review can see the keying, not prove a dataflow theorem):
   * **keying discipline** — an inner construction site must be one of:
     a module-level decorator/assignment (statics are explicit), inside
     a builder (a function with a `*Sig`-annotated parameter, or named
-    `build_*`/`make_*` — the declared factory idiom), inside
-    das_tpu/kernels/ (launch helpers whose statics thread from jitted
-    wrappers), or its result must visibly flow to a cache (`X[key] =
+    `build_*`/`make_*` — the declared factory idiom), or its result
+    must visibly flow to a cache (`X[key] =
     fn`), a `return`, or a call in the same function.  A constructed
     program that does none of those has no reviewable cache key;
   * **per-request taint** — a parameter of the enclosing function
@@ -43,7 +42,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from das_tpu.analysis.core import AnalysisContext, Finding, attr_chain, register
 
-_CONSTRUCTORS = frozenset(("jit", "pallas_call", "shard_map"))
+_CONSTRUCTORS = frozenset(("jit", "shard_map"))
 
 _MUTABLE_ANNOTATIONS = frozenset((
     "dict", "list", "set", "Dict", "List", "Set", "DefaultDict",
@@ -215,8 +214,6 @@ def _enclosing_chains(tree: ast.Module):
 def _keyed_ok(site: ast.Call, chain: List[ast.AST], sf) -> bool:
     if not chain:
         return True  # module-level: statics are explicit in the def
-    if "kernels" in sf.path.parts:
-        return True
     if any(_is_builder(fn) for fn in chain):
         return True
     inner = chain[-1]
@@ -257,7 +254,7 @@ def _keyed_ok(site: ast.Call, chain: List[ast.AST], sf) -> bool:
 def _decorated_ok(fn_def: ast.AST, chain: List[ast.AST], sf) -> bool:
     if not chain:
         return True
-    if "kernels" in sf.path.parts or any(_is_builder(f) for f in chain):
+    if any(_is_builder(f) for f in chain):
         return True
     # a nested jitted def that the enclosing function actually calls
     inner = chain[-1]
@@ -272,7 +269,7 @@ def _decorated_ok(fn_def: ast.AST, chain: List[ast.AST], sf) -> bool:
     return False
 
 
-@register("DL012", "retrace hygiene at jit/pallas_call/shard_map sites")
+@register("DL012", "retrace hygiene at jit/shard_map sites")
 def check(ctx: AnalysisContext) -> Iterable[Finding]:
     for sf in ctx.modules():
         for site, ctor, kind, chain in _enclosing_chains(sf.tree):

@@ -6,11 +6,9 @@ registry.  An injection seam added without declaring it never gets
 swept (the schedule can't name it); a declared seam whose `maybe_fail`
 call was refactored away keeps promising coverage that no longer
 exists.  And an injection call in the WRONG place is worse than none:
-inside `das_tpu/kernels/` it would land in traced/Mosaic code (the
-bodies DL011 certifies must stay exactly as reviewed), and inside a
-dispatch half it would put host work — a potential raise, a latency
-sleep — on the paths DL001/DL010 prove transfer-free and purely
-asynchronous.
+inside a dispatch half it would put host work — a potential raise, a
+latency sleep — on the paths DL001/DL010 prove transfer-free and
+purely asynchronous.
 
 The DL013 FETCH_SITES idiom, applied to injection.  `FAULT_SITES`
 (das_tpu/fault/__init__.py) declares the closed set of seam NAMES;
@@ -22,10 +20,9 @@ pinned against it.  Three legs:
   * a declared site with no `maybe_fail` call is a stale entry
     (full-set runs only — a --changed-only subset may not include the
     caller);
-  * ANY `maybe_fail` call — declared or not — inside a module under
-    `das_tpu/kernels/` or inside a DL001 dispatch-half function fails:
-    injection belongs at host-side recovery seams, never in traced
-    code or the async dispatch path.
+  * ANY `maybe_fail` call — declared or not — inside a DL001
+    dispatch-half function fails: injection belongs at host-side
+    recovery seams, never in the async dispatch path.
 
 Attribution is syntactic (bare name or attribute, the DL004 idiom):
 naming a function `maybe_fail` and passing it a string opts into this
@@ -80,10 +77,6 @@ def _inject_calls(tree: ast.AST) -> Iterable[Tuple[int, str]]:
             yield node.lineno, lit
 
 
-def _in_kernels(sf) -> bool:
-    return "kernels" in sf.path.parts[:-1]
-
-
 @register("DL015", "fault-injection sites vs FAULT_SITES registry")
 def check(ctx: AnalysisContext) -> Iterable[Finding]:
     registry = _find_registry(ctx)
@@ -92,15 +85,6 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
         calls: List[Tuple[int, str]] = list(_inject_calls(sf.tree))
         if not calls:
             continue
-        if _in_kernels(sf):
-            for line, _lit in calls:
-                yield Finding(
-                    "DL015", sf.posix, line,
-                    "fault injection (maybe_fail) inside das_tpu/kernels/ "
-                    "— kernel bodies are traced/Mosaic code (DL011) and "
-                    "must stay exactly as reviewed; inject at the "
-                    "host-side seam that CALLS the kernel instead",
-                )
         # the dispatch-half ban: reuse DL001's root discovery so the two
         # rules cannot disagree about what "a dispatch half" is
         dispatch_spans = [

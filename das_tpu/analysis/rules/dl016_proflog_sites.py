@@ -3,7 +3,7 @@
 
 Contract: the program ledger's coverage claim — "every device program
 the serving path compiles is compile/cost/memory-observable" — is only
-as good as the registry.  A new `jax.jit(...)` / `pl.pallas_call(...)`
+as good as the registry.  A new `jax.jit(...)`
 entry point added without a registry decision is a program whose
 compile time, FLOPs and HBM footprint silently go dark (exactly the
 blind spot ISSUE 14 closes); an instrumented scope whose
@@ -14,20 +14,20 @@ The DL013 FETCH_SITES idiom, applied to program construction.
 `PROGRAM_SITES` (das_tpu/obs/proflog.py) is a dict mapping every scope
 that constructs a device program — attributed to its OUTERMOST
 enclosing function, module-qualified like DL013 ("fused.build_fused",
-"common.run_kernel") — to its ledger site label, or None for a
-DECLARED-EXEMPT scope (per-op staged programs, kernel wrappers that
-trace inside instrumented programs, ingest-time builders).  Five legs:
+"fused.build_fused_tree") — to its ledger site label, or None for a
+DECLARED-EXEMPT scope (per-op staged programs, ingest-time builders).
+Five legs:
 
-  * a jit/pallas reference in an UNdeclared scope fails lint — every
+  * a jit reference in an UNdeclared scope fails lint — every
     program-construction site stays a reviewed decision in one list;
   * a declared scope with a non-None label must contain a ledger hook
-    call (`instrument(...)` / `record_launch(...)`) passing EXACTLY
+    call (`instrument(...)`) passing EXACTLY
     that label literal — an instrumented site cannot silently drop its
     ledger coverage;
-  * every `instrument("<label>")` / `record_launch("<label>")` literal
+  * every `instrument("<label>")` literal
     anywhere must be a declared label — a typo'd site records into a
     lane nobody aggregates (the DL004/DL014 failure mode);
-  * a declared scope with NO jit/pallas reference is a stale entry
+  * a declared scope with NO jit reference is a stale entry
     (full-set runs only — a --changed-only subset may not include the
     module);
   * where the analyzed set declares PROGRAM_NAMES (obs/registry.py), a
@@ -36,7 +36,7 @@ trace inside instrumented programs, ingest-time builders).  Five legs:
     name the device trace shows stay one vocabulary (DL014 pins the
     name literals against PROGRAM_NAMES in both directions).
 
-Attribution counts ANY AST reference to `jax.jit` or `pl.pallas_call`
+Attribution counts ANY AST reference to `jax.jit`
 (call, decorator, `partial(jax.jit, ...)` argument) — the construction
 primitive reaching a scope at all is what makes it a program site.
 """
@@ -58,13 +58,12 @@ from das_tpu.analysis.core import (
 )
 
 #: the program-construction primitives this registry closes over —
-#: dotted references and the bare names a `from jax import jit` /
-#: `from jax.experimental.pallas import pallas_call` import binds
-_PROGRAM_CHAINS = frozenset(("jax.jit", "pl.pallas_call"))
-_PROGRAM_NAMES = frozenset(("pallas_call", "jit"))
+#: dotted references and the bare name a `from jax import jit` binds
+_PROGRAM_CHAINS = frozenset(("jax.jit",))
+_PROGRAM_NAMES = frozenset(("jit",))
 
 #: ledger hook call names whose first string argument is a site label
-_HOOK_CALLS = frozenset(("instrument", "record_launch"))
+_HOOK_CALLS = frozenset(("instrument",))
 
 
 def _find_registry(ctx: AnalysisContext):
@@ -184,7 +183,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
             any_ref = True
             yield Finding(
                 "DL016", sf.posix, line,
-                "program construction (jax.jit / pallas_call) outside "
+                "program construction (jax.jit) outside "
                 "any function — an import-time compile fires "
                 "unconditionally and has no declarable PROGRAM_SITES "
                 "scope; move it into a declared builder function",
@@ -225,7 +224,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
             if registry is None:
                 yield Finding(
                     "DL016", sf.posix, ref_lines[0],
-                    "program construction (jax.jit / pl.pallas_call) but "
+                    "program construction (jax.jit) but "
                     "no PROGRAM_SITES registry in the analyzed set "
                     "(das_tpu/obs/proflog.py declares it)",
                 )
@@ -235,7 +234,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
                 yield Finding(
                     "DL016", sf.posix, ref_lines[0],
                     f"program construction in undeclared scope `{scope}` "
-                    "— every jit/pallas entry point must be declared in "
+                    "— every jit entry point must be declared in "
                     f"PROGRAM_SITES ({registry[0].short}) as instrumented "
                     "(ledger label) or reviewed-exempt (None), or its "
                     "compile/cost/memory telemetry silently goes dark",
@@ -249,7 +248,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
                     "DL016", sf.posix, ref_lines[0],
                     f"scope `{scope}` is declared as ledger-instrumented "
                     f"(label {label!r}) but contains no "
-                    f"instrument/record_launch call passing that label — "
+                    f"instrument call passing that label — "
                     "the site's programs would compile unobserved while "
                     "the registry promises coverage",
                 )
@@ -273,7 +272,7 @@ def check(ctx: AnalysisContext) -> Iterable[Finding]:
             if scope not in used_scopes:
                 yield Finding(
                     "DL016", reg_sf.posix, line,
-                    f"PROGRAM_SITES declares `{scope}` but no jit/pallas "
+                    f"PROGRAM_SITES declares `{scope}` but no jit "
                     "construction lives there — stale entry (the builder "
                     "moved, got renamed, or stopped constructing "
                     "programs)",

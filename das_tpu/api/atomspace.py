@@ -194,11 +194,9 @@ class _QueryManyJob:
             self.pending = None
             self.stale_round = True
         if self.pending is not None and self.sharded:
-            from das_tpu import kernels as _kernels
             from das_tpu.parallel.sharded_db import ShardedTable
 
             pending, self.pending = self.pending, None
-            kernel_route = _kernels.enabled(getattr(das.db, "config", None))
 
             def sharded_answer(j, res):
                 if res is None:
@@ -231,8 +229,6 @@ class _QueryManyJob:
                     query_compiler.ROUTE_COUNTS["staged"] += 1
                 else:
                     query_compiler.ROUTE_COUNTS["sharded"] += 1
-                    if kernel_route:
-                        query_compiler.ROUTE_COUNTS["sharded_kernel"] += 1
                 return out_s
 
             settled = self._stream_settled(

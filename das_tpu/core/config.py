@@ -28,12 +28,6 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
     "DAS_TPU_CHECKPOINT": (
         "checkpoint_path",
         "checkpoint dir auto-loaded by a bare DistributedAtomSpace()"),
-    "DAS_TPU_PALLAS": (
-        "use_pallas_kernels",
-        "kernel routing: auto (the lowered XLA route on every platform "
-        "until a kernel passes the Mosaic compile) / on (real pallas_call "
-        "on a TPU, raising the compiler's error; discharge off-TPU) / off "
-        "(das_tpu/kernels/__init__.py enabled())"),
     "DAS_TPU_PLANNER": (
         "use_planner",
         "cost-based query planner: auto (on) / on / off "
@@ -43,11 +37,6 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
         "clause ceiling for the planner's exact DP join-order search; "
         "larger conjunctions order greedily (das_tpu/planner/search.py; "
         "default 8)"),
-    "DAS_TPU_MULTIWAY": (
-        "use_multiway",
-        "k-way multiway join kernel routing: auto (cost-based, star "
-        "prefixes of >=3 clauses) / on (every eligible prefix) / off "
-        "(das_tpu/planner/search.py multiway_mode())"),
     "DAS_TPU_TREE_FUSION": (
         "use_tree_fusion",
         "whole-tree fused execution of Or/negation plan trees: auto "
@@ -105,14 +94,6 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
         "snapshot_keep",
         "completed snapshot generations retained after each new "
         "snapshot (storage/durable.py prune_generations; default 2)"),
-    "DAS_TPU_VMEM_BUDGET": (
-        None,
-        "kernel VMEM byte budget for the bytes planner "
-        "(kernels/budget.py; default 8 MiB = half-core VMEM)"),
-    "DAS_TPU_PALLAS_INTERPRET": (
-        None,
-        "=1 forces the true Pallas interpreter off-TPU instead of the "
-        "direct ref-discharge (kernels/common.py; ~2-5 s compile/site)"),
     "DAS_TPU_XLA_CACHE": (
         None,
         "=0 disables the persistent XLA compile cache and the CapStore "
@@ -151,9 +132,9 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
               "layer (das_tpu/obs; default off = no-allocation no-op)"),
     "DAS_TPU_PROFLOG": (
         None, "=1/on enables the program ledger — per-signature XLA "
-              "compile wall time, cost/memory analysis, byte-model "
-              "calibration (das_tpu/obs/proflog.py; default off = "
-              "identity fast path, programs run exactly un-instrumented)"),
+              "compile wall time, cost/memory analysis "
+              "(das_tpu/obs/proflog.py; default off = identity fast "
+              "path, programs run exactly un-instrumented)"),
     "DAS_TPU_TRACE_RING": (
         None, "span ring-buffer capacity of the trace recorder "
               "(das_tpu/obs/recorder.py; default 65536, oldest drop)"),
@@ -213,15 +194,6 @@ class DasConfig:
     # incremental commits: total delta atoms held as an LSM overlay before
     # the store is fully re-finalized (storage/tensor_db.py refresh)
     delta_merge_threshold: int = 1 << 16
-    # Pallas fused probe→gather→join kernels (das_tpu/kernels/):
-    # "auto" = the lowered op chains on every platform (Mosaic refuses
-    # the kernels today — tests/test_tpu_compile.py pins the verdicts);
-    # "on" forces them (on a TPU the real pallas_call, which raises the
-    # compiler's error; off-TPU interpret mode — answer-identical, used
-    # by the differential suite and the bench A/B); "off" forces the
-    # lowered op chains.
-    # Env DAS_TPU_PALLAS overrides (see das_tpu/kernels/__init__.py).
-    use_pallas_kernels: str = "auto"
     # cost-based whole-plan query planner (das_tpu/planner/): cardinality
     # estimates from the wildcard-index degree statistics pick join
     # order, expected route, and the initial capacity of every
@@ -232,17 +204,6 @@ class DasConfig:
     # "off" restores the legacy heuristics (the bench A/B flips this).
     # Env DAS_TPU_PLANNER overrides (see das_tpu/planner/__init__.py).
     use_planner: str = "auto"
-    # worst-case-optimal k-way multiway join kernel (das_tpu/kernels/
-    # multiway.py): when the planner finds a star prefix — consecutive
-    # clauses all sharing exactly ONE variable — it can ground them in
-    # one leapfrog-intersection pass instead of a binary-join chain
-    # with materialized intermediates.  "auto" = cost-based (prefixes
-    # of >=3 clauses whose modeled bytes beat the chain); "on" routes
-    # every eligible prefix (>=2 clauses — what the differential tests
-    # force); "off" restores the pure binary chain.  Routed by the
-    # planner only (use_planner off disables it too).  Env
-    # DAS_TPU_MULTIWAY overrides (see das_tpu/planner/search.py).
-    use_multiway: str = "auto"
     # whole-tree fused execution (ISSUE 10): an Or/negation plan tree
     # whose every node is an ordered conjunction over one shared
     # variable universe compiles to ONE planner-costed program — every
@@ -342,15 +303,9 @@ class DasConfig:
         snapshot_keep = os.environ.get("DAS_TPU_SNAPSHOT_KEEP")
         if snapshot_keep:
             cfg.snapshot_keep = int(snapshot_keep)
-        pallas = os.environ.get("DAS_TPU_PALLAS")
-        if pallas:
-            cfg.use_pallas_kernels = pallas
         planner = os.environ.get("DAS_TPU_PLANNER")
         if planner:
             cfg.use_planner = planner
-        multiway = os.environ.get("DAS_TPU_MULTIWAY")
-        if multiway:
-            cfg.use_multiway = multiway
         tree_fusion = os.environ.get("DAS_TPU_TREE_FUSION")
         if tree_fusion:
             cfg.use_tree_fusion = tree_fusion

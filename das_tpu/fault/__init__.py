@@ -32,8 +32,8 @@ stays consistent (storage/delta.py stage-then-swap).
 
 daslint rule DL015 pins `FAULT_SITES` both ways (an undeclared
 `maybe_fail` site fires; a stale entry fails full runs) and bans
-injection calls from `das_tpu/kernels/` and the dispatch halves — the
-traced/async code paths must stay exactly as reviewed (DL001/DL010).
+injection calls from the dispatch halves — the async code paths must
+stay exactly as reviewed (DL001/DL010).
 
 Spec string (`DAS_TPU_FAULT`, or `fault.configure(spec)`):
 semicolon-separated `key=value` pairs —
@@ -68,9 +68,9 @@ from das_tpu.core.exceptions import DasError, InjectedFault
 #: DL015, the COLLECTIVE_SITES/FETCH_SITES idiom applied to fault
 #: injection).  Every entry names a recovery path the chaos suite
 #: exercises; adding a seam means adding it here, under review, with
-#: its degradation story.  Injection is banned from das_tpu/kernels/
-#: and the dispatch halves — those stay bit-identical to the reviewed
-#: fault-free code (DL001/DL010).
+#: its degradation story.  Injection is banned from the dispatch
+#: halves — those stay bit-identical to the reviewed fault-free code
+#: (DL001/DL010).
 FAULT_SITES = (
     #: coalescer submit path (service/coalesce.py submit) — the caller
     #: sees the typed error on its future, like any per-query failure

@@ -1,8 +1,7 @@
 """dasprof — the program ledger (ISSUE 14 tentpole).
 
 Every PR since BENCH_r05 has been held on CPU A/Bs: the engine compiles
-whole-plan programs, prices their VMEM by hand (kernels/budget.py), and
-records nothing about what XLA actually did — compile wall time, FLOPs,
+whole-plan programs and records nothing about what XLA actually did — compile wall time, FLOPs,
 bytes accessed, HBM footprint are all dark.  This module closes the
 device side of the observability story (dastrace, ARCHITECTURE §13,
 closed the host side): a bounded per-signature **program ledger** that
@@ -33,34 +32,17 @@ inside its own jit; `jax.eval_shape` probes it) delegate straight to
 the jitted fn — a program nested inside another program is priced by
 its parent's ledger entry.
 
-Pallas launches (`kernels/common.py run_kernel / run_grid_kernel`) are
-not separately AOT-compilable — they trace INSIDE a caller's program —
-so they record a lighter `record_launch` note instead: launch counts
-and per-launch trace wall time per (body, shape) key, kind "pallas" or
-"discharge".  Trace wall is host tracing cost, NOT XLA compile time,
-and the ledger keeps the two in separate columns.
-
-Two consumers close standing ROADMAP loops:
-
-  * **byte-model calibration** — builders pass a `model_bytes` callback
-    (kernels/budget.py's combined per-stage footprint, the number the
-    single/tiled/lowered route gate is decided on); the ledger divides
-    it by the XLA `memory_analysis` actual (temp + output bytes) into
-    `budget_vs_actual_ratio` per program shape — the planner's
-    est-vs-actual idiom applied to memory.  On CPU the "actual" is XLA's
-    host heap, so the CPU ratio is a sanity signal only; the
-    calibration contract is for TPU runs (ARCHITECTURE §15).
-  * **cold-start accounting** — a jax monitoring listener classifies
-    each compile as fresh or served by the persistent XLA cache
-    (`das_tpu.enable_compile_cache`); `snapshot()["cold_start_s"]` sums the wall
-    time of the FRESH compiles only — the time-to-first-answer compile
-    cost a warm replica (ROADMAP replica-fleet item) would not pay.
+Cold-start accounting: a jax monitoring listener classifies each
+compile as fresh or served by the persistent XLA cache
+(`das_tpu.enable_compile_cache`); `snapshot()["cold_start_s"]` sums the
+wall time of the FRESH compiles only — the time-to-first-answer compile
+cost a warm replica (ROADMAP replica-fleet item) would not pay.
 
 `PROGRAM_SITES` below is the closed registry of every scope in das_tpu/
-that constructs a device program (`jax.jit` / `pl.pallas_call`), mapping
+that constructs a device program (`jax.jit`), mapping
 each to its ledger site label or None for declared-exempt scopes.
 daslint rule DL016 pins it both ways against the actual program
-construction sites — a new jit/pallas call in an undeclared scope fails
+construction sites — a new jit call in an undeclared scope fails
 lint, an instrumented scope without its ledger hook fails lint, and a
 stale entry fails full runs (the DL013 FETCH_SITES idiom).
 
@@ -76,7 +58,7 @@ import hashlib
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from das_tpu.obs.recorder import TRUTHY
 
@@ -94,7 +76,6 @@ LOCK_DISCIPLINE = {
     "ProgramLedger.calls": "_lock",
     "ProgramLedger.hits": "_lock",
     "ProgramLedger.errors": "_lock",
-    "ProgramLedger.launches": "_lock",
     "ProgramLedger._listener_on": "_lock",
     "_InstrumentedProgram._compiled": "_lock",
 }
@@ -103,13 +84,11 @@ WORKER_METHODS: Dict[str, Tuple[str, ...]] = {}
 
 #: THE closed registry of program-construction scopes (daslint DL016,
 #: the DL013 FETCH_SITES idiom): every scope in das_tpu/ whose AST
-#: references `jax.jit` or `pl.pallas_call`, attributed to its
+#: references `jax.jit`, attributed to its
 #: OUTERMOST enclosing function ("module.func" / "module.Class.meth").
 #: Value = the ledger site label the scope must pass to
-#: `instrument(...)` / `record_launch(...)`, or None for
-#: declared-exempt scopes — programs that either trace INSIDE an
-#: instrumented program (the kernel impl wrappers), are per-op staged
-#: programs already counted by DISPATCH_COUNTS, or are cold index/
+#: `instrument(...)`, or None for declared-exempt scopes — per-op
+#: staged programs already counted by DISPATCH_COUNTS, or cold index/
 #: bootstrap programs outside the serving path.  An entry here is a
 #: reviewed decision; a jit call in an UNdeclared scope fails lint.
 PROGRAM_SITES: Dict[str, Optional[str]] = {
@@ -122,9 +101,6 @@ PROGRAM_SITES: Dict[str, Optional[str]] = {
     "fused.FusedExecutor.build_count_loop": "count_loop",
     "fused_sharded._ShardedExecJob.dispatch": "sharded",
     "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
-    # -- instrumented: the Pallas launch points (trace-wall notes) -------
-    "common.run_kernel": "kernel",
-    "common.run_grid_kernel": "kernel_grid",
     # -- declared-exempt: staged-path per-op programs (ops/posting.py,
     #    ops/join.py — one generic op each, counted by DISPATCH_COUNTS
     #    "lowered"; the staged pipeline is the retry/fallback tier, not
@@ -139,12 +115,6 @@ PROGRAM_SITES: Dict[str, Optional[str]] = {
     "join._anti_join_jit": None,
     "join._build_term_table_jit": None,
     "join._dedup_table_jit": None,
-    # -- declared-exempt: kernel single-dispatch wrappers (their bodies
-    #    trace INSIDE callers' programs on the fused route; standalone
-    #    staged launches are counted by DISPATCH_COUNTS "kernel") -------
-    "probe.probe_term_table_jit": None,
-    "join.join_tables_jit": None,
-    "join.anti_join_jit": None,
     # -- declared-exempt: star-count degree fold programs (count-only
     #    fast path, host-side fold by default — query/starcount.py) -----
     "starcount._deg_vector": None,
@@ -191,7 +161,6 @@ class ProgramLedger:
         self.calls = 0
         self.hits = 0
         self.errors = 0
-        self.launches = 0
         # reentrant: record_* hold it while _entry takes it again (the
         # lexical with-block is what DL006 pins)
         self._lock = threading.RLock()
@@ -215,7 +184,6 @@ class ProgramLedger:
             self.calls = 0
             self.hits = 0
             self.errors = 0
-            self.launches = 0
 
     # -- persistent-XLA-cache hit classification -------------------------
 
@@ -261,7 +229,7 @@ class ProgramLedger:
 
     # -- recording --------------------------------------------------------
 
-    def _entry(self, site: str, digest: str, kind: str) -> Dict[str, Any]:
+    def _entry(self, site: str, digest: str) -> Dict[str, Any]:
         with self._lock:
             key = (site, digest)
             e = self.entries.get(key)
@@ -273,7 +241,6 @@ class ProgramLedger:
             e = {
                 "site": site,
                 "digest": digest,
-                "kind": kind,
                 "compiles": 0,
                 "compile_s": 0.0,
                 "first_compile_s": None,
@@ -284,12 +251,8 @@ class ProgramLedger:
                 "out_bytes": None,
                 "temp_bytes": None,
                 "peak_bytes": None,
-                "modeled_bytes": None,
-                "budget_vs_actual_ratio": None,
                 "calls": 0,
                 "hits": 0,
-                "launches": 0,
-                "trace_s": 0.0,
                 "error": None,
             }
             self.entries[key] = e
@@ -300,10 +263,9 @@ class ProgramLedger:
         cost: Optional[Dict[str, float]],
         mem: Optional[Any],
         persistent_hit: bool,
-        modeled_bytes: Optional[int],
     ) -> None:
         with self._lock:
-            e = self._entry(site, digest, "jit")
+            e = self._entry(site, digest)
             e["compiles"] += 1
             e["compile_s"] += wall_s
             if e["first_compile_s"] is None:
@@ -325,16 +287,6 @@ class ProgramLedger:
                     # (+ aliased) — arguments are the caller's resident
                     # store, not this program's allocation
                     e["peak_bytes"] = out + tmp + ali
-            if modeled_bytes:
-                e["modeled_bytes"] = int(modeled_bytes)
-                actual = e["peak_bytes"]
-                if actual:
-                    # the planner's est-vs-actual idiom applied to
-                    # memory: modeled combined kernel footprint over the
-                    # XLA-reported allocation (§15 calibration contract)
-                    e["budget_vs_actual_ratio"] = round(
-                        int(modeled_bytes) / actual, 4
-                    )
             self.compiles += 1
             self.compile_s += wall_s
             if persistent_hit:
@@ -357,27 +309,18 @@ class ProgramLedger:
 
     def record_error(self, site: str, digest: str, err: BaseException) -> None:
         with self._lock:
-            e = self._entry(site, digest, "jit")
+            e = self._entry(site, digest)
             e["error"] = repr(err)[:200]
             self.errors += 1
 
     def record_call(self, site: str, digest: str, hit: bool) -> None:
         with self._lock:
-            e = self._entry(site, digest, "jit")
+            e = self._entry(site, digest)
             e["calls"] += 1
             self.calls += 1
             if hit:
                 e["hits"] += 1
                 self.hits += 1
-
-    def record_launch(
-        self, site: str, digest: str, kind: str, wall_s: float
-    ) -> None:
-        with self._lock:
-            e = self._entry(site, digest, kind)
-            e["launches"] += 1
-            e["trace_s"] += wall_s
-            self.launches += 1
 
     # -- readout ----------------------------------------------------------
 
@@ -396,14 +339,9 @@ class ProgramLedger:
 
     def snapshot(self) -> Dict[str, Any]:
         """The coalescer_stats()["programs"] surface: compiles, total
-        compile seconds, ledger hit rate, the cold-start decomposition,
-        and the per-site budget-vs-actual calibration aggregate."""
+        compile seconds, ledger hit rate and the cold-start
+        decomposition."""
         with self._lock:
-            ratios: Dict[str, List[float]] = {}
-            for e in self.entries.values():
-                r = e["budget_vs_actual_ratio"]
-                if r is not None:
-                    ratios.setdefault(e["site"], []).append(r)
             return {
                 "enabled": self.enabled,
                 "compiles": self.compiles,
@@ -415,12 +353,7 @@ class ProgramLedger:
                 "cold_start_s": round(self.cold_start_s, 4),
                 "persistent_cache_hits": self.persistent_cache_hits,
                 "errors": self.errors,
-                "launches": self.launches,
                 "entries": len(self.entries),
-                "budget_vs_actual": {
-                    site: round(sum(rs) / len(rs), 4)
-                    for site, rs in sorted(ratios.items())
-                },
             }
 
 
@@ -471,15 +404,12 @@ class _InstrumentedProgram:
     compiled executable.  Never raises on ledger business: every
     failure path delegates to the plain jitted fn."""
 
-    __slots__ = ("site", "digest", "fn", "model_bytes", "_compiled",
-                 "_lock")
+    __slots__ = ("site", "digest", "fn", "_compiled", "_lock")
 
-    def __init__(self, site: str, digest: str, fn,
-                 model_bytes: Optional[Callable] = None):
+    def __init__(self, site: str, digest: str, fn):
         self.site = site
         self.digest = digest
         self.fn = fn
-        self.model_bytes = model_bytes
         self._compiled: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()
 
@@ -525,15 +455,8 @@ class _InstrumentedProgram:
             mem = compiled.memory_analysis()
         except Exception:
             pass
-        modeled = None
-        if self.model_bytes is not None:
-            try:
-                modeled = self.model_bytes(*args)
-            except Exception:
-                modeled = None
         led.record_compile(
-            self.site, self.digest, wall, cost, mem, persistent_hit,
-            modeled,
+            self.site, self.digest, wall, cost, mem, persistent_hit
         )
         return compiled
 
@@ -575,8 +498,7 @@ class _InstrumentedProgram:
             return self.fn(*args)
 
 
-def instrument(site: str, digest: str, fn,
-               model_bytes: Optional[Callable] = None):
+def instrument(site: str, digest: str, fn):
     """Route one freshly-jitted program through the ledger.
 
     DISABLED (the default): returns `fn` unchanged — `instrument(s, d,
@@ -587,29 +509,4 @@ def instrument(site: str, digest: str, fn,
     the literal at the call site)."""
     if not LEDGER.enabled:
         return fn
-    return _InstrumentedProgram(site, digest, fn, model_bytes)
-
-
-def launch_mark() -> float:
-    """perf_counter origin for a record_launch note; 0.0 when the
-    ledger is off so the disabled path pays one attribute read and no
-    clock call."""
-    if not LEDGER.enabled:
-        return 0.0
-    return time.perf_counter()
-
-
-def record_launch(site: str, body, out_shapes, t0: float,
-                  pallas: bool) -> None:
-    """Note one Pallas kernel launch (kernels/common.py): per-(body,
-    shape) launch counts and trace wall time — kind "pallas" for a real
-    pallas_call, "discharge" for the off-TPU direct-discharge path.
-    Trace wall is host tracing cost, kept apart from compile_s.  No-op
-    (one attribute read) when the ledger is off."""
-    if not LEDGER.enabled or not t0:
-        return
-    wall = time.perf_counter() - t0
-    digest = sig_digest(getattr(body, "__name__", repr(body)), out_shapes)
-    LEDGER.record_launch(
-        site, digest, "pallas" if pallas else "discharge", wall
-    )
+    return _InstrumentedProgram(site, digest, fn)

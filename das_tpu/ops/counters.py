@@ -5,63 +5,65 @@ scattered literals across seven modules — a typo'd key would count into
 a fresh dict slot while the pinned key stayed zero, and the dispatch-
 count regression pins only catch that for paths someone thought to pin.
 These tuples are now the ONE declared set: the dicts are built from
-them (`das_tpu/kernels/__init__.py`, `das_tpu/query/compiler.py`), the
-analyzer (das_tpu/analysis, rule DL004) pins every counting literal
+them (DISPATCH_COUNTS below, `das_tpu/query/compiler.py` ROUTE_COUNTS),
+the analyzer (das_tpu/analysis, rule DL004) pins every counting literal
 against them in both directions, and tests/test_zlint.py pins the
 tuples themselves so a key rename cannot slip through unreviewed.
 
-This module imports nothing — both counter owners (and the analyzer's
-fixtures) can depend on it without cycles.
+This module imports nothing at load — every counter owner and user
+(ops/, query/, parallel/, the analyzer's fixtures) can depend on it
+without cycles.
 """
 
-#: host-side launches of compiled device programs, by path — the dict
-#: lives in das_tpu/kernels/__init__.py (see its docstring for what each
-#: key means); counting sites: kernels/__init__.py (staged per-stage
-#: wrappers), ops/posting.py + ops/join.py ("lowered"), query/fused.py
-#: (fused + count-batch), parallel/fused_sharded.py (mesh).
+#: host-side launches of compiled device programs, by path.  "lowered" =
+#: one generic jitted op of the staged pipeline (ops/posting.py,
+#: ops/join.py wrappers), "fused" = one whole-plan single-dispatch
+#: program, alone or a group's (query/fused.py), "fused_tree" = ONE
+#: whole-tree program for an Or/negation plan tree (query/fused.py
+#: _TreeExecJob.dispatch), "sharded" / "sharded_tree_fused" = their
+#: shard_map mesh twins (parallel/fused_sharded.py), "count" = one
+#: vmapped count-batch group program (query/fused.py count_batch).
+#: The dispatch-count regression tests pin the per-query totals so a
+#: refactor can't silently re-fragment the pipeline.
 DISPATCH_KEYS = (
     "lowered",
-    "kernel",
-    "kernel_tiled",
     "fused",
-    "fused_kernel",
-    "fused_kernel_tiled",
-    #: the fused program contained a k-way MULTIWAY intersection step
-    #: (kernels/multiway.py) instead of a binary-join chain prefix —
-    #: counted per dispatch in query/fused.py _ExecJob.dispatch; the
-    #: sharded twin in parallel/fused_sharded.py _ShardedExecJob
-    "fused_multiway",
-    #: ONE whole-tree fused program answered an Or/negation plan tree —
-    #: every conjunction site plus the in-program union/anti settles in
-    #: a single dispatch where the tree executor pays one program per
-    #: site (query/fused.py _TreeExecJob.dispatch); the mesh twin is
-    #: sharded_tree_fused (parallel/fused_sharded.py _ShardedTreeExecJob)
     "fused_tree",
     "sharded",
-    "sharded_kernel",
-    "sharded_kernel_tiled",
-    "sharded_multiway",
     "sharded_tree_fused",
     "count",
-    "count_kernel",
-    "count_kernel_tiled",
 )
+
+#: the counts themselves, built from the registry so dict and registry
+#: cannot drift
+DISPATCH_COUNTS = {k: 0 for k in DISPATCH_KEYS}
+
+
+def record_dispatch(kind: str, n: int = 1) -> None:
+    DISPATCH_COUNTS[kind] = DISPATCH_COUNTS.get(kind, 0) + n
+    from das_tpu import obs
+
+    if obs.enabled():
+        # the obs metric layer's one aggregate dispatch tick — every
+        # device-program enqueue funnels through here, so the Prometheus
+        # surface gets a total without a counter per DISPATCH_KEYS route
+        obs.counter("exec.dispatches").inc(n)
+
+
+def reset_dispatch_counts() -> None:
+    for k in DISPATCH_COUNTS:
+        DISPATCH_COUNTS[k] = 0
+
 
 #: per-query answer routes — the dict lives in query/compiler.py;
 #: counting sites: query/compiler.py (the per-query router),
-#: api/atomspace.py (batched settle), query/fused.py (count-batch
-#: cache hits), mining/miner.py (star lanes).  The cost-based planner
+#: api/atomspace.py (batched settle), query/fused.py (tree-job settle),
+#: mining/miner.py (star lanes).  The cost-based planner
 #: (das_tpu/planner) PREDICTS one of these per plan — daslint rule
 #: DL008 pins every planner route literal against this tuple, so a
 #: planner emitting a route no counter tracks fails lint.
 ROUTE_KEYS = (
     "fused",
-    "fused_kernel",
-    #: planner routed the conjunction's star prefix through the k-way
-    #: multiway kernel (das_tpu/planner/search.py emits it; counted at
-    #: job settle in query/fused.py — cache hits skip it, exactly like
-    #: the dispatch counters)
-    "fused_multiway",
     #: the whole Or/negation plan tree settled as ONE fused program
     #: (in-program union + anti; counted at tree-job settle in
     #: query/fused.py — a fused-tree answer also counts "tree", its
@@ -69,13 +71,8 @@ ROUTE_KEYS = (
     "fused_tree",
     "sharded_tree_fused",
     "staged",
-    "staged_kernel",
-    "anti_kernel",
     "tree",
     "sharded",
-    "sharded_kernel",
-    "sharded_multiway",
-    "count_kernel",
     "host",
     "star",
 )

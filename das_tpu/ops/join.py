@@ -129,7 +129,7 @@ def join_tables(
     Returns (out_vals[capacity, kL+len(right_extra)], out_valid, total).
     With no shared columns this degenerates to the cross product.
     """
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _join_tables_jit(
@@ -150,7 +150,7 @@ def anti_join(left_vals, left_valid, right_vals, right_valid, pairs: Tuple[Tuple
     a false exclusion needs a full 64-bit collision (~2^-64 per pair) —
     documented engineering tolerance of the compiled path; the host
     algebra path is collision-free."""
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _anti_join_jit(left_vals, left_valid, right_vals, right_valid, pairs)
@@ -179,7 +179,7 @@ def build_term_table(targets, local, mask, var_cols: Tuple[int, ...], eq_pairs: 
     """Project probed candidate links into a binding table: one column per
     variable (first occurrence position); `eq_pairs` enforces same-variable
     repeated positions."""
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _build_term_table_jit(targets, local, mask, var_cols, eq_pairs)
@@ -329,7 +329,7 @@ def _dedup_table_jit(vals, valid):
 def dedup_table(vals, valid):
     """Invalidate duplicate rows (exact: lexicographic sort over all
     columns, neighbor comparison).  Returns (vals_sorted, keep, count)."""
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _dedup_table_jit(vals, valid)

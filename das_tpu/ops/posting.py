@@ -40,7 +40,7 @@ def range_probe(sorted_keys, perm, probe_key, capacity: int):
 
     Returns (local[capacity] int32, valid[capacity] bool, count int32).
     """
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _range_probe_jit(sorted_keys, perm, probe_key, capacity)
@@ -56,7 +56,7 @@ def _full_scan_jit(size, capacity: int):
 def full_scan(size, capacity: int):
     """All bucket rows as a padded candidate vector (type-and-targets all
     wildcard probes)."""
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _full_scan_jit(size, capacity)
@@ -76,7 +76,7 @@ def verify_positions(targets, type_id, local, valid, probe_type, fixed: Tuple[Tu
     """Positional wildcard-pattern verification: keep candidates whose
     type matches `probe_type` (pass -1 to skip) and whose target columns
     equal each (position, row) pair in `fixed`."""
-    from das_tpu.kernels import record_dispatch
+    from das_tpu.ops.counters import record_dispatch
 
     record_dispatch("lowered")
     return _verify_positions_jit(targets, type_id, local, valid, probe_type, fixed)

@@ -30,7 +30,6 @@ equivalent of the reference's hub-key skew problem)."""
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -40,6 +39,7 @@ from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from das_tpu import obs
+from das_tpu.ops.counters import record_dispatch
 from das_tpu.ops.join import (
     _SENTINEL_L,
     _SENTINEL_R,
@@ -65,16 +65,13 @@ from das_tpu.query.fused import (
     dispatch_pending,
     estimate_plan_rows,
     fold_join_meta,
-    multiway_meta,
     order_plans,
     remember_caps,
     prepare_tree_job,
-    program_model_bytes,
     run_tree_job,
     same_positive_order,
     settle_pending,
     settle_pending_iter,
-    tree_model_bytes,
 )
 from das_tpu.ops.join import _dedup_table_impl
 
@@ -95,29 +92,10 @@ class ShardedPlanSig:
     #: own slab's (type<<32|target) posting index at this position.  The
     #: whole-type right side never materializes; one collective per join.
     index_joins: Tuple[int, ...] = ()
-    #: route the shard-LOCAL probe and join bodies through the Pallas
-    #: fused kernels (das_tpu/kernels/) inside the shard_map program;
-    #: collectives (all_gather / all_to_all / psum) stay lowered.  Part of
-    #: the signature so kernel and lowered executables cache side by side.
-    use_kernels: bool = False
-    #: the bytes planner picked the GRID-CHUNKED layout for at least one
-    #: shard-local stage (kernels/budget.py; see FusedPlanSig.tiled)
-    tiled: bool = False
-    #: budget.vmem_budget() snapshot at dispatch (0 when kernels are
-    #: off) — cache-key honesty across budget changes (FusedPlanSig)
-    vmem_budget: int = 0
     #: the cost-based planner ordered this plan and seeded its per-shard
     #: capacities — cache-key honesty for the planner A/B
     #: (FusedPlanSig.planned)
     planned: bool = False
-    #: leading positives fused into ONE shard-local k-way multiway
-    #: intersection step (kernels/multiway.py): the tail clauses'
-    #: term tables broadcast-gather (S×cap each) and every shard
-    #: intersects against its LOCAL clause-0 slab — union over shards
-    #: is the full join.  Changes the traced program and the
-    #: join_caps/exch_caps/index_joins layout (FusedPlanSig.multiway),
-    #: so it is part of the cache key.
-    multiway: int = 0
 
 
 @dataclass
@@ -129,7 +107,6 @@ class ShardedFusedResult:
     reseed_needed: bool
     host_vals: Optional[np.ndarray] = None   # prefetched host copies (one
     host_valid: Optional[np.ndarray] = None  # transfer with the stats)
-    multiway: bool = False   # answered by a k-way multiway mesh program
 
 
 class _Moved:
@@ -258,28 +235,17 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
     several sites in one mesh executable.  Every collective goes
     through a declared helper (parallel/mesh.py COLLECTIVE_SITES,
     daslint DL009) that names its scope for the device trace and adds
-    its bytes to `moved`; none lives in a shard-local kernel body."""
+    its bytes to `moved`."""
     S = sig.n_shards
     positives, _negatives, names, join_meta, anti_meta = fold_join_meta(sig.terms)
-    mw = sig.multiway
-    start = mw if mw else 1
     index_joins = sig.index_joins or tuple(
-        [-1] * max(0, len(positives) - start)
+        [-1] * max(0, len(positives) - 1)
     )
     index_right = {
-        positives[start + t]: t for t, p in enumerate(index_joins) if p >= 0
+        positives[1 + n]: n for n, p in enumerate(index_joins) if p >= 0
     }
-    if mw:
-        mw_meta, mw_vcol0 = multiway_meta(join_meta, mw)
-    use_k = sig.use_kernels
-    if use_k or mw:
-        from das_tpu import kernels as _kernels
 
-        # no separate lowered chain for the multiway step (query/fused.py
-        # _trace_conj): discharge off-TPU, the real pallas_call on a TPU
-        _interp = _kernels.interpret_mode()
-
-    # blocks arrive with a leading [1, ...] slab dim; the probe kernel
+    # blocks arrive with a leading [1, ...] slab dim; the probe body
     # itself is the single-device one (query/fused.py _probe) — probes
     # are slab-local, zero communication
     tables = {}
@@ -299,8 +265,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
             term_ranges.append(jnp.int32(0))
             continue
         vals, mask, rng = _probe(
-            t, arrays, keys[i], fixed_vals[i], sig.term_caps[i],
-            use_kernels=use_k,
+            t, arrays, keys[i], fixed_vals[i], sig.term_caps[i]
         )
         tables[i] = (vals, mask)
         pos_count[i] = _global_count(mask, moved)
@@ -317,36 +282,11 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
         reseed = jnp.bool_(False)
     join_totals = []
     exch_stats = []
-    if mw:
-        # shard-local k-way step: broadcast every tail's term table
-        # once (S×cap rows, validity packed — one collective per
-        # tail, the broadcast-right idiom) and intersect against
-        # the LOCAL clause-0 slab; each output row has exactly one
-        # clause-0 source row living on exactly one shard, so the
-        # union over shards is the full join and the output stays
-        # row-sharded by clause-0 locality.
-        mw_tails = []
-        for i in positives[1:mw]:
-            tv, tm = tables[i]
-            mw_tails.append(_gather_packed(tv, tm, moved))
-        acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
-            acc_vals, acc_valid, mw_tails, mw_vcol0, mw_meta,
-            sig.join_caps[0], interpret=_interp,
-        )
-        # partial totals are per-shard: the reference's reseed rule
-        # asks about GLOBAL intermediate emptiness, the capacity
-        # retry about the worst shard's output
-        g_totals = _global_sum(mw_totals, moved)
-        join_totals.append(_worst_shard(mw_totals[mw - 2], moved))
-        exch_stats.append(jnp.int32(0))
-        for t in range(max(0, min(mw - 1, len(positives) - 2))):
-            reseed = reseed | (g_totals[t] == 0)
-    for t_step, i in enumerate(positives[start:]):
-        n = start - 1 + t_step     # absolute join position
+    for n, i in enumerate(positives[1:]):
         pairs, extra = join_meta[n]
-        jc = sig.join_caps[(1 if mw else 0) + t_step]
-        q = sig.exch_caps[(1 if mw else 0) + t_step]
-        if index_joins[t_step] >= 0:
+        jc = sig.join_caps[n]
+        q = sig.exch_caps[n]
+        if index_joins[n] >= 0:
             # broadcast the SMALL left once; every shard probes its own
             # slab's posting index — union over shards is the full join
             # (each link lives in exactly one slab)
@@ -354,33 +294,21 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
             ks, perm, targets, _tid = (
                 a[0] for a in bucket_arrays[i]
             )
-            if use_k:
-                acc_vals, acc_valid, total = _kernels.index_join_impl(
-                    lv_full, lm_full, ks, perm, targets, keys[i],
-                    pairs, sig.terms[i].var_cols, extra,
-                    jc, interpret=_interp,
-                )
-            else:
-                acc_vals, acc_valid, total = _index_join_impl(
-                    lv_full, lm_full, ks, perm, targets, keys[i],
-                    pairs, sig.terms[i].var_cols, extra, jc,
-                )
+            acc_vals, acc_valid, total = _index_join_impl(
+                lv_full, lm_full, ks, perm, targets, keys[i],
+                pairs, sig.terms[i].var_cols, extra, jc,
+            )
             exch_stats.append(jnp.int32(0))
             join_totals.append(_worst_shard(total, moved))
             if n < len(positives) - 2:
                 reseed = reseed | (_global_count(acc_valid, moved) == 0)
             continue
         rv, rm = tables[i]
-        join_impl = (
-            partial(_kernels.join_tables_impl, interpret=_interp)
-            if use_k
-            else _join_tables_impl
-        )
         if q == 0:
             # broadcast-right: ONE tiled all_gather of the small side
             # (validity packed as an extra column)
             rv_full, rm_full = _gather_packed(rv, rm, moved)
-            acc_vals, acc_valid, total = join_impl(
+            acc_vals, acc_valid, total = _join_tables_impl(
                 acc_vals, acc_valid, rv_full, rm_full,
                 pairs, extra, jc,
             )
@@ -395,7 +323,7 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
             rv2, rm2, r_occ = _repartition(
                 rv, rm, rcols, _SENTINEL_R, S, q, moved
             )
-            acc_vals, acc_valid, total = join_impl(
+            acc_vals, acc_valid, total = _join_tables_impl(
                 lv2, lm2, rv2, rm2, pairs, extra, jc
             )
             exch_stats.append(
@@ -408,15 +336,9 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
     for i, pairs in anti_meta:
         rv, rm = tables[i]
         rv_full, rm_full = _gather_packed(rv, rm, moved)
-        if use_k:
-            acc_valid = _kernels.anti_join_impl(
-                acc_vals, acc_valid, rv_full, rm_full, pairs,
-                interpret=_interp,
-            )
-        else:
-            acc_valid = _anti_join_impl(
-                acc_vals, acc_valid, rv_full, rm_full, pairs
-            )
+        acc_valid = _anti_join_impl(
+            acc_vals, acc_valid, rv_full, rm_full, pairs
+        )
 
     count = _global_count(acc_valid, moved)
     reseed = reseed & ~any_pos_empty
@@ -474,9 +396,9 @@ def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False,
 class ShardedTreeSig:
     """Shape-static description of ONE whole-tree fused MESH program
     (ISSUE 10) — the sharded twin of query/fused.py FusedTreeSig.
-    Nested ShardedPlanSigs carry per-site per-shard capacities,
-    collective choices and kernel routing, so cache-key honesty is
-    inherited (daslint DL002)."""
+    Nested ShardedPlanSigs carry per-site per-shard capacities and
+    collective choices, so cache-key honesty is inherited (daslint
+    DL002)."""
 
     sites: Tuple[ShardedPlanSig, ...]
     neg: Optional[ShardedPlanSig] = None
@@ -666,9 +588,6 @@ class ShardedFusedExecutor:
             _planner.plan_conjunction(self.db, plans, n_shards=self.n_shards)
             if _planner.enabled(self.db.config) else None
         )
-        # k-way multiway prefix (query/fused.py _exec_job mirror):
-        # join_caps[0]/exch_caps[0] then belong to the multiway step
-        mw = planned.multiway if planned is not None else 0
         if planned is not None:
             ordered = [plans[i] for i in planned.order]
         else:
@@ -690,13 +609,9 @@ class ShardedFusedExecutor:
         ests = [self._estimate(p) for p in plans]
         term_caps = tuple(self._shard_cap(e) for e in ests)
         index_joins, index_right, arrays, term_caps = apply_index_joins(
-            self.db.tables.buckets, sigs, arrays, term_caps,
-            start_join=max(0, mw - 1),
+            self.db.tables.buckets, sigs, arrays, term_caps
         )
-        positives = [p for p in plans if not p.negated]
-        n_joins = (
-            (len(positives) - mw + 1) if mw else max(0, len(positives) - 1)
-        )
+        n_joins = max(0, sum(1 for p in plans if not p.negated) - 1)
         grounded = [
             e for p, e in zip(plans, ests)
             if p.fixed and p.ctype is None and not p.negated
@@ -718,33 +633,24 @@ class ShardedFusedExecutor:
             join_caps = planned.join_cap_seeds  # per-shard costed seeds
         else:
             join_caps = tuple([jcap0] * n_joins)
-        # static per-STEP collective choice: the multiway step (when
-        # routed) broadcasts its tail tables (slot 0); index-joinable
-        # right sides broadcast the LEFT instead (one collective,
-        # nothing materialized); otherwise broadcast the right when its
-        # whole table fits the budget, else hash-partition
+        # static per-join collective choice: index-joinable right
+        # sides broadcast the LEFT (one collective, nothing
+        # materialized); otherwise broadcast the right when its whole
+        # table fits the budget, else hash-partition
         pos_sig_idx = [i for i, s in enumerate(sigs) if not s.negated]
-        exch_caps = [0] if mw else []
-        # the step's index-join slot aligns with index_joins[t] (tail
-        # joins only); ij_of maps a step slot back to it for the
-        # learned-caps merge below
-        ij_of = ([-1] if mw else []) + list(index_joins)
+        exch_caps = []
         for t in range(len(index_joins)):
             if index_joins[t] >= 0:
                 exch_caps.append(0)
                 continue
-            right_cap = term_caps[
-                pos_sig_idx[(mw if mw else 1) + t]
-            ]
+            right_cap = term_caps[pos_sig_idx[1 + t]]
             if right_cap * self.n_shards <= self.broadcast_limit:
                 exch_caps.append(0)
             else:
                 exch_caps.append(_pow2_at_least(2 * max(jcap0 // self.n_shards, 16)))
         exch_caps = tuple(exch_caps)
         learned = self._caps.get(sigs)
-        # length guard (query/fused.py _learned_caps rationale): caps
-        # learned on the binary-chain route must not zip-truncate into
-        # the multiway route's per-step layout, or vice versa
+        # length guard (query/fused.py _learned_caps rationale)
         if learned is not None and (
             len(learned[0]) != len(term_caps)
             or len(learned[1]) != len(join_caps)
@@ -759,12 +665,10 @@ class ShardedFusedExecutor:
             join_caps = tuple(max(a, b) for a, b in zip(join_caps, learned[1]))
             exch_caps = tuple(
                 (0 if b == 0 or n_ij >= 0 else max(a, b))
-                for (a, b), n_ij in zip(zip(exch_caps, learned[2]), ij_of)
+                for (a, b), n_ij in zip(zip(exch_caps, learned[2]), index_joins)
             )
         if max(term_caps + join_caps, default=0) > cfg.max_result_capacity:
             return None
-        from das_tpu import kernels
-
         # counted only once the job exists (query/fused.py _exec_job):
         # declines run the staged mesh fallback under legacy accounting
         if planned is not None:
@@ -773,9 +677,7 @@ class ShardedFusedExecutor:
             _planner.PLANNER_COUNTS["greedy"] += 1
         return _ShardedExecJob(
             self, count_only, same_order, sigs, arrays, keys, fvals,
-            term_caps, join_caps, exch_caps, index_joins,
-            use_kernels=kernels.enabled(cfg), planned=planned,
-            multiway=mw,
+            term_caps, join_caps, exch_caps, index_joins, planned=planned,
         )
 
     def execute(
@@ -877,15 +779,14 @@ class _ShardedExecJob:
 
     __slots__ = (
         "ex", "count_only", "same_order", "sigs", "arrays", "keys", "fvals",
-        "term_caps", "join_caps", "exch_caps", "index_joins", "use_kernels",
+        "term_caps", "join_caps", "exch_caps", "index_joins",
         "names", "result", "planned", "rounds", "last_ranges",
-        "last_join_rows", "multiway", "count_route",
+        "last_join_rows",
     )
 
     def __init__(
         self, ex, count_only, same_order, sigs, arrays, keys, fvals,
-        term_caps, join_caps, exch_caps, index_joins, use_kernels=False,
-        planned=None, multiway=0,
+        term_caps, join_caps, exch_caps, index_joins, planned=None,
     ):
         self.ex = ex
         self.count_only = count_only
@@ -898,66 +799,29 @@ class _ShardedExecJob:
         self.join_caps = join_caps
         self.exch_caps = exch_caps
         self.index_joins = index_joins
-        self.use_kernels = use_kernels
         self.names = None
         self.result: Optional[ShardedFusedResult] = None
         #: PlannedProgram that ordered/seeded this job (query/fused.py
         #: _ExecJob mirror); settle feeds estimates to planner telemetry
         self.planned = planned
-        #: leading positives fused into one shard-local k-way step
-        self.multiway = multiway
         self.rounds = 0
         self.last_ranges = None
         self.last_join_rows = None
-        #: False for SITE jobs inside a whole-tree program — the tree
-        #: job owns the per-answer route count (query/fused.py _ExecJob)
-        self.count_route = True
 
     def plan_sig(self) -> ShardedPlanSig:
-        """The sharded plan signature at the CURRENT capacities.  Kernel
-        eligibility re-derives per round through the BYTES planner
-        (query/fused.py kernel_program_plan): the per-shard slab shapes
-        plus the COMBINED in-kernel footprint of every stage — the
-        gathered right side of a broadcast join is S×cap rows next to the
-        local accumulator, a hash-partitioned join holds S×q on both
-        sides, an index join gathers the small left to S×cap — decide
-        single-block / grid-chunked / lowered; a capacity retry that
-        overflows the budget re-plans tiled before falling back.
-        Shared by dispatch() and the whole-tree mesh job
+        """The sharded plan signature at the CURRENT capacities.  Shared
+        by dispatch() and the whole-tree mesh job
         (_ShardedTreeExecJob)."""
-        from das_tpu.kernels import budget
-        from das_tpu.query.fused import kernel_program_plan
-
-        ex = self.ex
-        route = budget.ROUTE_LOWERED
-        if self.use_kernels:
-            # per-shard slab sizes: bucket arrays are [S, m(, a)]-shaped
-            route = kernel_program_plan(
-                self.sigs,
-                tuple(
-                    (a[0].shape[1], a[2].shape[1]) for a in self.arrays
-                ),
-                self.term_caps, self.join_caps, self.index_joins,
-                n_shards=ex.n_shards, exch_caps=self.exch_caps,
-                multiway=self.multiway,
-            )
-        use_k = route != budget.ROUTE_LOWERED
-        tiled = route == budget.ROUTE_TILED
         return ShardedPlanSig(
             self.sigs, self.term_caps, self.join_caps, self.exch_caps,
-            ex.n_shards, self.index_joins, use_k, tiled,
-            budget.vmem_budget() if use_k else 0,
-            self.planned is not None, self.multiway,
+            self.ex.n_shards, self.index_joins, self.planned is not None,
         )
 
     def dispatch(self):
         """Queue the shard_map program at the current capacities
         (async, no sync)."""
-        from das_tpu.kernels import record_dispatch
-
         ex = self.ex
         plan_sig = self.plan_sig()
-        use_k, tiled = plan_sig.use_kernels, plan_sig.tiled
         entry = ex._cache.get((plan_sig, self.count_only))
         if entry is None:
             moved = _Moved(ex.n_shards)
@@ -976,7 +840,6 @@ class _ShardedExecJob:
                     jax.jit(obs.named_program(
                         "das_sharded", fn, self.count_only
                     )),
-                    model_bytes=partial(program_model_bytes, plan_sig),
                 ), moved),
                 out_names,
             )
@@ -988,26 +851,15 @@ class _ShardedExecJob:
 
             PLANNER_COUNTS["programs"] += 1
         record_dispatch("sharded")
-        if use_k:
-            record_dispatch("sharded_kernel")
-            if tiled:
-                record_dispatch("sharded_kernel_tiled")
-        if self.multiway:
-            record_dispatch("sharded_multiway")
         # mesh twin of _ExecJob.dispatch's trace span: same vocabulary,
         # same sync-free discipline (DL001/DL010), sharded route names
         sp = obs.NOOP_SPAN
         if obs.enabled():
-            route = "sharded"
-            if self.multiway:
-                route = "sharded_multiway"
-            elif use_k:
-                route = "sharded_kernel"
             if self.rounds > 1:
                 # a shard overflowed a capacity: the program again
                 obs.counter("mesh.retries").inc()
             sp = obs.span(
-                "exec.dispatch", route=route, round=self.rounds,
+                "exec.dispatch", route="sharded", round=self.rounds,
                 count_only=self.count_only,
                 est_join_rows=(
                     list(self.planned.est_join_rows)
@@ -1091,14 +943,7 @@ class _ShardedExecJob:
             ),
             host_vals=host_vals,
             host_valid=host_valid,
-            multiway=bool(self.multiway),
         )
-        if self.multiway and self.count_route:
-            # per-ANSWER route telemetry (query/fused.py settle mirror;
-            # tree site jobs stay silent — count_route False)
-            from das_tpu.query.compiler import ROUTE_COUNTS
-
-            ROUTE_COUNTS["sharded_multiway"] += 1
         return True
 
 
@@ -1127,7 +972,6 @@ class _ShardedTreeExecJob(_TreeExecJob):
         return _MeshProgram(obs.proflog.instrument(
             "sharded_tree", obs.proflog.sig_digest(tree_sig, False),
             jax.jit(obs.named_program("das_sharded_tree", fn)),
-            model_bytes=partial(tree_model_bytes, tree_sig),
         ), moved), out_names
 
     def _blk_len(self, j) -> int:
@@ -1148,8 +992,6 @@ class _ShardedTreeExecJob(_TreeExecJob):
 
     def dispatch(self):
         """Queue the whole-tree shard_map program (async, no sync)."""
-        from das_tpu.kernels import record_dispatch
-
         record_dispatch("sharded_tree_fused")
         sp = obs.NOOP_SPAN
         if obs.enabled():

@@ -22,11 +22,8 @@ SHARD_AXIS = "shards"
 #: shard_map collective discipline): every XLA collective call
 #: (all_gather / all_to_all / psum / pmax / pmin / ppermute /
 #: psum_scatter) in das_tpu/ must live inside one of these
-#: "module.qualname" scopes — lowered mesh helpers whose collective use
-#: is the point — and NEVER inside das_tpu/kernels/ (shard-local kernel
-#: bodies run under shard_map per shard; a collective there would
-#: deadlock or silently change semantics depending on lowering).  The
-#: rule pins both directions: an undeclared collective call fails lint,
+#: "module.qualname" scopes — mesh helpers whose collective use is the
+#: point.  The rule pins both directions: an undeclared collective call fails lint,
 #: and so does a declared scope that no longer contains one.
 COLLECTIVE_SITES = (
     "fused_sharded._repartition",

@@ -2,9 +2,8 @@
 
 Turns a conjunction into a COSTED whole-plan program before anything is
 dispatched: join order from a Selinger-style DP over the wildcard-index
-degree statistics (search.py / stats.py), per-step pricing against the
-kernel byte models (cost.py / kernels/budget.py), and an estimated
-initial capacity per intermediate — replacing the greedy smallest-first
+degree statistics (search.py / stats.py), per-step pricing in bytes
+moved (cost.py), and an estimated initial capacity per intermediate — replacing the greedy smallest-first
 `order_plans` and the blind `initial_result_capacity` seed, so most
 queries settle in retry round 0 (every avoided retry tier is a fresh
 XLA compile saved).
@@ -55,7 +54,7 @@ def reset_planner_counts() -> None:
 def enabled(config=None) -> bool:
     """Resolve planner routing.  Env DAS_TPU_PLANNER beats the config so
     a deployment (or the bench A/B) can flip the path without code
-    changes — the DAS_TPU_PALLAS idiom."""
+    changes."""
     mode = os.environ.get("DAS_TPU_PLANNER")
     if mode is None and config is not None:
         mode = getattr(config, "use_planner", "auto")
@@ -200,10 +199,6 @@ def _explain_plans(db, plans, execute: bool, sharded: bool,
             est_term_rows=list(planned.est_term_rows),
             est_join_rows=list(planned.est_join_rows),
             join_cap_seeds=list(planned.join_cap_seeds),
-            # leading positives fused into one k-way intersection step
-            # (0 = binary chain); est_join_rows/join_cap_seeds then
-            # lead with the multiway step's output figures
-            multiway=planned.multiway,
         )
     if not execute:
         return out

@@ -38,13 +38,6 @@ from das_tpu.query.fused import reference_order_authoritative
 #: greedy-by-estimated-output tail orders the conjunction
 DEFAULT_DP_MAX = 8
 
-#: "auto" multiway routing needs at least this many fused clauses — a
-#: 2-clause "star" is just the binary join with no intermediate to
-#: delete, so auto keeps the chain (and its index-join option); "on"
-#: routes any eligible prefix >= 2 (what the differential tests force)
-MULTIWAY_AUTO_MIN_K = 3
-
-
 def dp_max() -> int:
     raw = os.environ.get("DAS_TPU_PLANNER_DP_MAX")
     if not raw:
@@ -55,29 +48,6 @@ def dp_max() -> int:
         return DEFAULT_DP_MAX
 
 
-def multiway_mode(config=None) -> str:
-    """Resolve k-way multiway kernel routing: "auto" (cost-based),
-    "on" (every eligible star prefix), "off".  Env DAS_TPU_MULTIWAY
-    beats the config — the DAS_TPU_PALLAS idiom, so the bench A/B can
-    flip arms without code changes."""
-    mode = os.environ.get("DAS_TPU_MULTIWAY")
-    if mode is None and config is not None:
-        mode = getattr(config, "use_multiway", "auto")
-    mode = str("auto" if mode is None else mode).lower()
-    if mode in ("on", "1", "true"):
-        return "on"
-    if mode in ("off", "0", "false"):
-        return "off"
-    from das_tpu import kernels
-
-    if not kernels.interpret_mode() and not kernels.enabled(config):
-        # on a TPU the multiway step is a real pallas_call (it has no
-        # lowered chain, and never discharges there): while the kernel
-        # route is off, auto keeps the binary chain
-        return "off"
-    return "auto"
-
-
 @dataclass(frozen=True)
 class PlannedProgram:
     """One costed whole-plan decision, fixed BEFORE anything dispatches.
@@ -85,12 +55,10 @@ class PlannedProgram:
     order          — permutation into the caller's plan list (positives
                      in chosen join order, then negatives)
     est_term_rows  — exact per-term candidate rows, in `order`
-    est_join_rows  — estimated output rows per STEP: with multiway the
-                     first entry is the k-way output, then one entry
-                     per tail binary join; pure chains have one entry
-                     per join (the executors' stats report the same
-                     layout, so est-vs-actual compares like with like)
-    join_cap_seeds — initial capacity per step buffer (margin + pow2),
+    est_join_rows  — estimated output rows per join (the executors'
+                     stats report the same layout, so est-vs-actual
+                     compares like with like)
+    join_cap_seeds — initial capacity per join buffer (margin + pow2),
                      replacing the blind initial_result_capacity seed;
                      same layout as est_join_rows
     route          — the answer route this plan expects to take; always
@@ -98,10 +66,6 @@ class PlannedProgram:
                      DL008 pins this)
     method         — "dp" / "greedy_tail" / "ref_order" (PLANNER_KEYS)
     cost           — the model's bytes-moved figure for the whole chain
-    multiway       — number of LEADING positives fused into one k-way
-                     intersection step (kernels/multiway.py); 0 = pure
-                     binary chain.  The first `multiway` terms of
-                     `order` form a star on one shared variable.
     """
 
     order: Tuple[int, ...]
@@ -111,7 +75,6 @@ class PlannedProgram:
     route: str
     method: str
     cost: float
-    multiway: int = 0
 
 
 def _shares_var(a, b) -> bool:
@@ -174,13 +137,11 @@ def _join_step(est, acc, right, right_plan):
 
 
 def _chain_estimates(est, terms: List, order: Tuple[int, ...]):
-    """(est_join_rows, join_cap_seeds, cost, step_costs) of one
-    left-deep order.  est_join_rows are the CAPACITY-relevant per-join
-    rows — the number the executors' overflow stats report (candidate
-    counts for index joins, match counts for materialized joins) — so
-    est-vs-actual telemetry compares like with like.  `step_costs` is
-    the per-join breakdown (term costs excluded) the multiway router
-    compares its one intersection step against."""
+    """(est_join_rows, join_cap_seeds, cost) of one left-deep order.
+    est_join_rows are the CAPACITY-relevant per-join rows — the number
+    the executors' overflow stats report (candidate counts for index
+    joins, match counts for materialized joins) — so est-vs-actual
+    telemetry compares like with like."""
     rels = [est.term_estimate(terms[i]) for i in order]
     acc = rels[0]
     widths = [len(terms[i].var_names) for i in order]
@@ -189,7 +150,6 @@ def _chain_estimates(est, terms: List, order: Tuple[int, ...]):
     join_rows: List[int] = []
     max_cap = _max_capacity(est.db)
     caps: List[int] = []
-    step_costs: List[float] = []
     for n in range(1, len(order)):
         right = rels[n]
         out, cap_rows, n_pairs, exact = _join_step(
@@ -199,28 +159,24 @@ def _chain_estimates(est, terms: List, order: Tuple[int, ...]):
             1 for v in terms[order[n]].var_names if v not in acc.dv
         )
         total += pcost.term_cost(int(right.rows), widths[n])
-        step = pcost.join_step_cost(
+        total += pcost.join_step_cost(
             acc.rows, width, right.rows, widths[n],
             n_pairs, cap_rows, out_width, max_cap,
         )
-        total += step
-        step_costs.append(step)
         join_rows.append(int(cap_rows))
         caps.append(pcost.cap_for(cap_rows, max_cap, exact=exact))
         acc = out
         width = out_width
-    return tuple(join_rows), tuple(caps), total, step_costs
+    return tuple(join_rows), tuple(caps), total
 
 
-def _multiway_prefix(terms: List, order: Tuple[int, ...]):
+def _star_prefix(terms: List, order: Tuple[int, ...]):
     """(m, v): the longest prefix of the ordered positives forming a
     STAR on one shared variable — every clause after the first shares
     EXACTLY {v} with the variables accumulated so far (its remaining
-    variables are fresh).  That is the shape the k-way kernel grounds
-    in one pass: tail rows pair freely within a v group, so the slot
-    layout is a pure mixed-radix product and no cross-tail
-    verification beyond v is needed.  m == 0 when even the first join
-    is not a single-variable step."""
+    variables are fresh), so each prefix intermediate is a k-way star
+    join whose exact size `stats.star_rows` computes.  m == 0 when
+    even the first join is not a single-variable step."""
     if len(order) < 2:
         return 0, None
     seen = set(terms[order[0]].var_names)
@@ -245,31 +201,28 @@ def _max_capacity(db) -> int:
 
 
 def _star_chain_seeds(est, terms, order, join_rows, caps, max_cap):
-    """Chain-route seed reuse of the EXACT k-way statistic (ISSUE 10
-    satellite / ROADMAP multiway remainder): when the chain is chosen
-    over the multiway kernel — mode off, auto declined the cost race,
-    or the prefix infeasible — its DEEPER star-prefix intermediates
-    still ride the independence model, which errs low exactly on skew
-    (the guaranteed retry tier the multiway route exists to delete).
-    But the intermediate after folding prefix clauses 0..t+1 IS the
-    (t+2)-way star join, whose exact size `stats.multiway_rows` already
-    computes: reuse it for the capacity seed, margin-free, so the chain
-    settles in round 0 on the same skew shapes.
+    """Seeds of a star prefix from the EXACT k-way statistic: the
+    chain's DEEPER star-prefix intermediates would otherwise ride the
+    independence model, which errs low exactly on skew (a guaranteed
+    retry tier).  But the intermediate after folding prefix clauses
+    0..t+1 IS the (t+2)-way star join, whose exact size
+    `stats.star_rows` computes: use it for the capacity seed,
+    margin-free, so the chain settles in round 0 on skew shapes.
 
     The statistic covers INDEX-JOIN steps too: a star step shares
     exactly ONE variable, so the posting-index candidate count — Σ over
     accumulator rows of the right term's degree at the probed position
     — telescopes to Σ_v Π_j deg_j(v) over the intersected supports,
-    which is multiway_rows verbatim (no remaining shared columns exist
-    to verify candidates away).  The capacity model and the match count
-    coincide on stars, so the seed is exact on both routes."""
-    m, v = _multiway_prefix(terms, order)
+    which is star_rows verbatim (no remaining shared columns exist to
+    verify candidates away).  The capacity model and the match count
+    coincide on stars, so the seed is exact for index joins too."""
+    m, v = _star_prefix(terms, order)
     if m < 3:
         return join_rows, caps  # the first join is already exact (dot)
     join_rows, caps = list(join_rows), list(caps)
     for t in range(1, m - 1):
         prefix = [terms[order[j]] for j in range(t + 2)]
-        rows, exact = est.multiway_rows(prefix, v)
+        rows, exact = est.star_rows(prefix, v)
         if exact:
             join_rows[t] = int(rows)
             caps[t] = pcost.cap_for(rows, max_cap, exact=True)
@@ -382,60 +335,12 @@ def plan_conjunction(db, plans, *, n_shards: int = 1) -> Optional[PlannedProgram
         order_pos = _greedy_order(est, positives)
         method = "greedy_tail"
 
-    join_rows, caps, total, step_costs = _chain_estimates(
-        est, positives, order_pos
+    join_rows, caps, total = _chain_estimates(est, positives, order_pos)
+    # the deeper intermediates of a star prefix seed from the exact
+    # k-way statistic instead of the independence model
+    join_rows, caps = _star_chain_seeds(
+        est, positives, order_pos, join_rows, caps, _max_capacity(db)
     )
-
-    # -- multiway routing: fuse a star prefix into one k-way step ------
-    # (kernels/multiway.py).  The chain's independence model can only
-    # seed the FIRST intermediate exactly (pairwise degree dots); the
-    # k-way step's ONE output buffer seeds from the exact intersection
-    # product (stats.multiway_rows), so the skew shapes whose deeper
-    # intermediates under-seed and pay retry tiers settle in round 0.
-    mw = 0
-    config = getattr(db, "config", None)
-    mode = multiway_mode(config)
-    max_cap = _max_capacity(db)
-    if mode != "off" and len(positives) >= 2:
-        m, v = _multiway_prefix(positives, order_pos)
-        if m >= 2:
-            prefix = [positives[order_pos[j]] for j in range(m)]
-            # every prefix clause materializes as a term table: a clause
-            # whose candidate set exceeds the capacity ceiling would
-            # make the executor decline the whole job — keep the chain
-            # (whose index-join route never materializes it) instead
-            feasible = all(
-                pcost.pow2_at_least(est.rows(p)) <= max_cap
-                for p in prefix
-            )
-            if feasible:
-                mw_rows, mw_exact = est.multiway_rows(prefix, v)
-                width0 = len(prefix[0].var_names)
-                out_width = len(
-                    set().union(*(set(p.var_names) for p in prefix))
-                )
-                mw_cost = pcost.multiway_step_cost(
-                    est.rows(prefix[0]), width0,
-                    [(est.rows(p), len(p.var_names)) for p in prefix[1:]],
-                    mw_rows, out_width, max_cap,
-                )
-                if mode == "on" or (
-                    m >= MULTIWAY_AUTO_MIN_K
-                    and mw_cost < sum(step_costs[: m - 1])
-                ):
-                    mw = m
-                    mw_cap = pcost.cap_for(mw_rows, max_cap, exact=mw_exact)
-                    total = total - sum(step_costs[: m - 1]) + mw_cost
-                    join_rows = (int(mw_rows),) + join_rows[m - 1:]
-                    caps = (mw_cap,) + caps[m - 1:]
-
-    if mw == 0 and len(positives) >= 3:
-        # chain route chosen (or forced) over multiway: the deeper
-        # star-prefix intermediates reuse the exact k-way statistic
-        # instead of the independence model (see _star_chain_seeds)
-        join_rows, caps = _star_chain_seeds(
-            est, positives, order_pos, join_rows, caps, max_cap
-        )
 
     if n_shards > 1:
         caps = tuple(
@@ -446,32 +351,14 @@ def plan_conjunction(db, plans, *, n_shards: int = 1) -> Optional[PlannedProgram
     term_rows = tuple(
         est.rows(plans[i]) for i in order
     )
-    from das_tpu import kernels
-
-    kernel = kernels.enabled(config)
-    if n_shards > 1:
-        if mw:
-            route = "sharded_multiway"
-        elif kernel:
-            route = "sharded_kernel"
-        else:
-            route = "sharded"
-    else:
-        if mw:
-            route = "fused_multiway"
-        elif kernel:
-            route = "fused_kernel"
-        else:
-            route = "fused"
     return PlannedProgram(
         order=order,
         est_term_rows=term_rows,
         est_join_rows=join_rows,
         join_cap_seeds=caps,
-        route=route,
+        route="sharded" if n_shards > 1 else "fused",
         method=method,
         cost=float(total),
-        multiway=mw,
     )
 
 

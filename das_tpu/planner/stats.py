@@ -209,10 +209,10 @@ class CardinalityEstimator:
         self._rows[key] = out
         return out
 
-    def multiway_rows(self, plans, var: str) -> Tuple[float, bool]:
+    def star_rows(self, plans, var: str) -> Tuple[float, bool]:
         """(rows, exact) of the k-way STAR join of base terms on ONE
-        shared variable — the multiway kernel's output capacity model
-        (kernels/multiway.py): Σ_v Π_j deg_j(v) over the INTERSECTION
+        shared variable, the size of a chain's star-prefix intermediate
+        (search.py _star_chain_seeds): Σ_v Π_j deg_j(v) over the INTERSECTION
         of the per-clause supports.  Exact whenever every clause has a
         support extraction — the k-way generalization of
         `exact_join_rows`, realizing the min-degree intersection bound
@@ -260,7 +260,7 @@ class CardinalityEstimator:
     ) -> Tuple[float, bool]:
         """(rows, exact) of the join restricted to ONE shared variable
         — the CAPACITY model of an INDEX JOIN (query/fused.py
-        plan_index_joins): the kernel probes the posting index at the
+        plan_index_joins): the join probes the posting index at the
         first shared variable's position and materializes every
         candidate BEFORE the remaining shared columns verify, so the
         buffer (and the overflow stats the retry ladder reads) scale

@@ -89,11 +89,8 @@ class UnknownAtom(NotCompilable):
 #: How queries were executed, for benchmark reporting and tests.  "fused" =
 #: single-dispatch jitted program, "staged" = per-stage device kernels,
 #: "tree" = generalized device tree executor, "host" = Python algebra
-#: fallback (incremented by the API dispatcher, not here); "*_kernel" =
-#: the subset whose probes/joins traced through the Pallas kernels
-#: (das_tpu/kernels/ — shard-local bodies for "sharded_kernel", vmapped
-#: count-batch groups for "count_kernel", the staged negation membership
-#: filter for "anti_kernel").  Keys are DECLARED in ops/counters.py —
+#: fallback (incremented by the API dispatcher, not here).  Keys are
+#: DECLARED in ops/counters.py —
 #: the one registry daslint rule DL004 pins every counting literal
 #: against — and the dict is built from it so the two cannot drift.
 ROUTE_COUNTS = {k: 0 for k in ROUTE_KEYS}
@@ -220,23 +217,7 @@ def plan_query(
     return plans
 
 
-#: _run_term_kernel verdict: the probe outgrew the kernel size bound
-#: mid-retry — the caller must answer on the lowered path instead
-_KERNEL_DECLINED = object()
-
-
 def _run_term(db: TensorDB, plan: TermPlan) -> Optional[BindingTable]:
-    from das_tpu import kernels
-
-    bucket = db.dev.buckets.get(plan.arity)
-    if kernels.enabled(db.config) and bucket is not None:
-        # eligibility (single-block / grid-chunked / lowered) is the
-        # bytes planner's per-round call inside _run_term_kernel — no
-        # row-count pre-gate here: a FlyBase-scale bucket with a small
-        # probe window is exactly the shape the tiled route serves
-        table = _run_term_kernel(db, plan)
-        if table is not _KERNEL_DECLINED:
-            return table
     if plan.ctype is not None:
         padded = db.probe_ctype_padded(plan.arity, plan.ctype)
     else:
@@ -248,45 +229,6 @@ def _run_term(db: TensorDB, plan: TermPlan) -> Optional[BindingTable]:
     vals, mask = build_term_table(
         bucket.targets, local, mask, plan.var_cols, plan.eq_pairs
     )
-    vals, keep, count = dedup_table(vals, mask)
-    n = int(count)
-    if n == 0:
-        return None
-    return BindingTable(plan.var_names, vals, keep, n)
-
-
-def _run_term_kernel(db: TensorDB, plan: TermPlan) -> Optional[BindingTable]:
-    """Staged term probe through the fused Pallas kernel: the probe →
-    gather → verify → term-table chain is ONE dispatch instead of three
-    (range_probe, verify_positions, build_term_table), with the same
-    capacity-overflow retry contract as probe_ordered_padded."""
-    from das_tpu import kernels
-    from das_tpu.query.fused import get_executor
-    from das_tpu.storage.tensor_db import _next_capacity
-
-    m = get_executor(db)._term_args(plan)
-    if m is None:
-        return None
-    sig, arrays, key, fvals = m
-    bucket = db.dev.buckets[plan.arity]
-    cap = min(db.config.initial_result_capacity, max(bucket.size, 16))
-    while True:
-        if not kernels.budget.probe_plan(
-            arrays[0].shape[0], arrays[2].shape[0], arrays[2].shape[1],
-            len(sig.var_cols), cap,
-        ).kernel:
-            # a retry can double the capacity past the byte budget (cap
-            # ends < 2*range, so up to 2x the bucket size) — same
-            # per-round re-derivation as the fused dispatch()
-            return _KERNEL_DECLINED
-        vals, mask, rng = kernels.probe_term_table(
-            arrays[0], arrays[1], arrays[2], key, fvals, cap,
-            var_cols=sig.var_cols, eq_pairs=sig.eq_pairs,
-            extra_fixed=sig.extra_fixed,
-        )
-        if int(rng) <= cap:
-            break
-        cap = _next_capacity(int(rng), cap, db.config.max_result_capacity)
     vals, keep, count = dedup_table(vals, mask)
     n = int(count)
     if n == 0:
@@ -306,21 +248,9 @@ def _join(db: TensorDB, left: BindingTable, right: BindingTable) -> BindingTable
     out_names = left.var_names + tuple(
         v for v in right.var_names if v not in left.var_names
     )
-    from das_tpu import kernels
-
-    use_kernel = kernels.enabled(db.config)
     cap = max(64, min(left.count * right.count, db.config.initial_result_capacity))
     while True:
-        join_op = (
-            kernels.join_tables
-            if use_kernel and kernels.budget.join_plan(
-                left.vals.shape[0], left.vals.shape[1],
-                right.vals.shape[0], right.vals.shape[1],
-                len(shared), left.vals.shape[1] + len(extra), cap,
-            ).kernel
-            else join_tables
-        )
-        vals, valid, total = join_op(
+        vals, valid, total = join_tables(
             left.vals, left.valid, right.vals, right.valid,
             tuple(shared), extra, cap,
         )
@@ -511,9 +441,6 @@ def execute_plan(db: TensorDB, plans: List[TermPlan]) -> Optional[BindingTable]:
             accumulated = _join(db, accumulated, table)
     if accumulated is None:
         return None
-    from das_tpu import kernels
-
-    use_kernel = kernels.enabled(db.config)
     valid = accumulated.valid
     for tabu in tabu_tables:
         if not set(tabu.var_names) <= set(accumulated.var_names):
@@ -522,18 +449,9 @@ def execute_plan(db: TensorDB, plans: List[TermPlan]) -> Optional[BindingTable]:
             (accumulated.var_names.index(v), tabu.var_names.index(v))
             for v in tabu.var_names
         )
-        if use_kernel and kernels.budget.anti_join_plan(
-            accumulated.vals.shape[0], accumulated.vals.shape[1],
-            tabu.vals.shape[0], tabu.vals.shape[1],
-        ).kernel:
-            valid = kernels.anti_join(
-                accumulated.vals, valid, tabu.vals, tabu.valid, pairs
-            )
-            ROUTE_COUNTS["anti_kernel"] += 1
-        else:
-            valid = anti_join(
-                accumulated.vals, valid, tabu.vals, tabu.valid, pairs
-            )
+        valid = anti_join(
+            accumulated.vals, valid, tabu.vals, tabu.valid, pairs
+        )
     count = int(valid.sum())
     return BindingTable(accumulated.var_names, accumulated.vals, valid, count)
 
@@ -576,19 +494,12 @@ def query_on_device(db: TensorDB, query: LogicalExpression, answer: PatternMatch
     generalized tree executor (query/tree.py)."""
     plans = plan_query(db, query)
     if plans is not None:
-        from das_tpu import kernels
-
-        kernel_route = kernels.enabled(db.config)
         table = _execute_fused(db, plans)
         if table is None:
             table = execute_plan(db, plans)
             ROUTE_COUNTS["staged"] += 1
-            if kernel_route:
-                ROUTE_COUNTS["staged_kernel"] += 1
         else:
             ROUTE_COUNTS["fused"] += 1
-            if kernel_route:
-                ROUTE_COUNTS["fused_kernel"] += 1
         return materialize(db, table, answer)
     from das_tpu.query.tree import query_tree
 
@@ -621,10 +532,6 @@ def dispatch(db, query: LogicalExpression, answer: PatternMatchingAnswer, host=N
             matched = db.query_sharded(query, answer)
             if matched is not None:
                 ROUTE_COUNTS["sharded"] += 1
-                from das_tpu import kernels
-
-                if kernels.enabled(getattr(db, "config", None)):
-                    ROUTE_COUNTS["sharded_kernel"] += 1
         elif isinstance(db, TensorDB):
             matched = query_on_device(db, query, answer)
     except CapacityOverflowError as exc:

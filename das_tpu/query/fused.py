@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from das_tpu import obs
+from das_tpu.ops.counters import record_dispatch
 from das_tpu.ops.join import (
     _anti_join_impl,
     _build_term_table_impl,
@@ -83,52 +84,23 @@ class FusedPlanSig:
     #: whole-table terms would otherwise force 33M-row buffers and
     #: minutes-long compiles)
     index_joins: Tuple[int, ...] = ()
-    #: route term probes and joins through the Pallas fused kernels
-    #: (das_tpu/kernels/) instead of the lowered op chains.  Part of the
-    #: signature so kernel and lowered executables cache side by side
-    #: (the bench A/B flips DasConfig.use_pallas_kernels per call).
-    use_kernels: bool = False
-    #: the bytes planner's program verdict was GRID-CHUNKED for at least
-    #: one stage (kernels/budget.py).  The traced bodies re-derive their
-    #: own layout from the same byte model at trace time — this flag is
-    #: the cache-key/telemetry mirror (kernel_tiled route counters)
-    tiled: bool = False
-    #: budget.vmem_budget() snapshot at dispatch (0 when kernels are
-    #: off).  Part of the cache key because the traced LAYOUT — which
-    #: stages tile and at what chunk_rows — is a function of the budget
-    #: beyond the single tiled bit: a budget change must compile a fresh
-    #: executable, not replay one whose chunks the old budget sized
-    vmem_budget: int = 0
     #: the cost-based planner (das_tpu/planner) ordered this plan and
     #: seeded its capacities.  Part of the signature for cache-key
-    #: honesty (the vmem_budget rationale): the planner A/B flips
-    #: DasConfig.use_planner per arm, and when both arms happen to pick
-    #: the same order/caps the arms must still compile-and-count their
-    #: own executables instead of silently replaying each other's
+    #: honesty: the planner A/B flips DasConfig.use_planner per arm, and
+    #: when both arms pick the same order/caps they must still
+    #: compile-and-count their own executables, not replay each other's
     planned: bool = False
-    #: leading positives fused into ONE k-way multiway intersection
-    #: step (kernels/multiway.py) instead of a binary-join chain prefix
-    #: (0 = pure chain).  Changes the traced program AND the meaning of
-    #: join_caps/index_joins (join_caps[0] is then the multiway output
-    #: buffer; index_joins cover only the tail binary joins), so it
-    #: must be part of the cache key (DL002's tiled lesson).
-    multiway: int = 0
 
 
-def plan_index_joins(sigs: Tuple[FusedTermSig, ...], start: int = 0):
+def plan_index_joins(sigs: Tuple[FusedTermSig, ...]):
     """Static per-join index-join eligibility: right side must be an
     ordered whole-type probe (ROUTE_TYPE, no extra verification, no
     repeated variables), positive, and actually share a variable.
-
-    `start` skips the first `start` joins entirely (the multiway
-    prefix's internal joins — its clauses ground through materialized
-    term tables, never the posting index): the returned tuple covers
-    joins start..P-2 and `right_terms` maps term index to the join's
-    RELATIVE position in that tuple."""
+    Returns the per-join tuple and `right_terms`, term index -> join."""
     positives, _neg, _names, join_meta, _anti = fold_join_meta(sigs)
     index_joins = []
     right_terms = {}
-    for n in range(start, max(0, len(positives) - 1)):
+    for n in range(max(0, len(positives) - 1)):
         i = positives[n + 1]
         t = sigs[i]
         pairs, _extra = join_meta[n]
@@ -141,7 +113,7 @@ def plan_index_joins(sigs: Tuple[FusedTermSig, ...], start: int = 0):
         ):
             p = t.var_cols[pairs[0][1]]
             index_joins.append(p)
-            right_terms[i] = n - start
+            right_terms[i] = n
         else:
             index_joins.append(-1)
     return tuple(index_joins), right_terms
@@ -159,12 +131,12 @@ class FusedResult:
 
     __slots__ = (
         "var_names", "_vals", "_valid", "count", "reseed_needed",
-        "overflow", "host_vals", "host_valid", "multiway",
+        "overflow", "host_vals", "host_valid",
     )
 
     def __init__(
         self, var_names, vals, valid, count, reseed_needed, overflow,
-        host_vals=None, host_valid=None, multiway=False,
+        host_vals=None, host_valid=None,
     ):
         self.var_names: Tuple[str, ...] = var_names
         self._vals = vals            # [cap, k] int32 (device)
@@ -174,7 +146,6 @@ class FusedResult:
         self.overflow: bool = overflow  # a capacity too small; re-lower
         self.host_vals: Optional[np.ndarray] = host_vals    # prefetched —
         self.host_valid: Optional[np.ndarray] = host_valid  # free to read
-        self.multiway: bool = multiway  # answered by a k-way program
 
     @property
     def vals(self) -> Optional[jax.Array]:
@@ -199,15 +170,13 @@ class _ExecJob:
 
     __slots__ = (
         "ex", "count_only", "same_order", "sigs", "arrays", "keys", "fvals",
-        "term_caps", "join_caps", "index_joins", "use_kernels", "names",
+        "term_caps", "join_caps", "index_joins", "names",
         "result", "planned", "rounds", "last_ranges", "last_join_rows",
-        "multiway", "count_route",
     )
 
     def __init__(
         self, ex, count_only, same_order, sigs, arrays, keys, fvals,
-        term_caps, join_caps, index_joins, use_kernels=False, planned=None,
-        multiway=0,
+        term_caps, join_caps, index_joins, planned=None,
     ):
         self.ex = ex
         self.count_only = count_only
@@ -219,50 +188,23 @@ class _ExecJob:
         self.term_caps = term_caps
         self.join_caps = join_caps
         self.index_joins = index_joins
-        self.use_kernels = use_kernels
         self.names = None
         self.result: Optional[FusedResult] = None
         #: the PlannedProgram that ordered/seeded this job (None =
         #: legacy heuristics); settle feeds its estimates back to the
         #: planner counters so estimator error is observable
         self.planned = planned
-        #: leading positives fused into one k-way intersection step
-        #: (planner/search.py PlannedProgram.multiway; 0 = binary chain)
-        self.multiway = multiway
         self.rounds = 0
         self.last_ranges = None      # final-round per-term exact ranges
-        self.last_join_rows = None   # final-round per-step exact totals
-        #: False when this job is a SITE inside a whole-tree program
-        #: (_TreeExecJob): the tree job owns the per-answer route
-        #: telemetry — a 3-site tree must count ONE answer, not three
-        self.count_route = True
+        self.last_join_rows = None   # final-round per-join exact totals
 
     def plan_sig(self) -> FusedPlanSig:
-        """The plan signature at the CURRENT capacities.  Kernel
-        eligibility is re-derived per round by the BYTES planner
-        (kernels/budget.py, replacing the old per-dimension fits()): a
-        capacity retry can grow the combined footprint past the VMEM
-        budget, in which case the re-dispatch picks the grid-chunked
-        layout — or, past even the tiled resident set, falls back to
-        the lowered program.  Shared by dispatch() and the whole-tree
-        job (_TreeExecJob), whose tree signature nests one of these per
-        site."""
-        from das_tpu.kernels import budget
-
-        route = budget.ROUTE_LOWERED
-        if self.use_kernels:
-            route = kernel_program_plan(
-                self.sigs,
-                tuple((a[0].shape[0], a[2].shape[0]) for a in self.arrays),
-                self.term_caps, self.join_caps, self.index_joins,
-                multiway=self.multiway,
-            )
-        use_k = route != budget.ROUTE_LOWERED
-        tiled = route == budget.ROUTE_TILED
+        """The plan signature at the CURRENT capacities.  Shared by
+        dispatch() and the whole-tree job (_TreeExecJob), whose tree
+        signature nests one of these per site."""
         return FusedPlanSig(
             self.sigs, self.term_caps, self.join_caps, self.index_joins,
-            use_k, tiled, budget.vmem_budget() if use_k else 0,
-            self.planned is not None, self.multiway,
+            self.planned is not None,
         )
 
     def dispatch(self, plan_sig=None):
@@ -288,29 +230,15 @@ class _ExecJob:
         estimated rows so settle's actuals line up against them in one
         Perfetto lane.  Guarded: the disabled path packs no attribute
         dict."""
-        from das_tpu.kernels import record_dispatch
-
-        use_k, tiled = plan_sig.use_kernels, plan_sig.tiled
         if plan_sig.planned:
             from das_tpu.planner import PLANNER_COUNTS
 
             PLANNER_COUNTS["programs"] += 1
         record_dispatch("fused")
-        if use_k:
-            record_dispatch("fused_kernel")
-            if tiled:
-                record_dispatch("fused_kernel_tiled")
-        if self.multiway:
-            record_dispatch("fused_multiway")
         if not obs.enabled():
             return obs.NOOP_SPAN
-        route = "fused"
-        if self.multiway:
-            route = "fused_multiway"
-        elif use_k:
-            route = "fused_kernel"
         return obs.span(
-            "exec.dispatch", route=route, round=self.rounds,
+            "exec.dispatch", route="fused", round=self.rounds,
             count_only=self.count_only,
             est_join_rows=(
                 list(self.planned.est_join_rows)
@@ -426,16 +354,7 @@ class _ExecJob:
             overflow=False,
             host_vals=host_vals,
             host_valid=host_valid,
-            multiway=bool(self.multiway),
         )
-        if self.multiway and self.count_route:
-            # per-ANSWER route telemetry (dispatch counts live above):
-            # settle fires once per executed job, after every retry
-            # round; tree SITE jobs stay silent (count_route False) —
-            # their tree job counts the one fused_tree answer
-            from das_tpu.query.compiler import ROUTE_COUNTS
-
-            ROUTE_COUNTS["fused_multiway"] += 1
         return True
 
 
@@ -681,26 +600,14 @@ def _pow2_at_least(n: int, lo: int = 16) -> int:
     return c
 
 
-def _probe(sig: FusedTermSig, arrays, key, fixed_vals, cap: int,
-           use_kernels: bool = False):
+def _probe(sig: FusedTermSig, arrays, key, fixed_vals, cap: int):
     """Trace one term probe + verification + term-table build.
 
     arrays = (sorted_keys, perm, targets, type_id) device arrays for the
     term's bucket/route; key is a traced scalar; fixed_vals a traced
-    int32[len(extra_fixed)] vector.  With use_kernels the whole chain
-    traces as ONE Pallas kernel (das_tpu/kernels/probe.py) instead of the
-    lowered searchsorted/gather/verify op sequence.
+    int32[len(extra_fixed)] vector.
     """
     sorted_keys, perm, targets, type_id = arrays
-    if use_kernels:
-        from das_tpu import kernels
-
-        return kernels.probe_term_table_impl(
-            sorted_keys, perm, targets, key, fixed_vals, cap,
-            var_cols=sig.var_cols, eq_pairs=sig.eq_pairs,
-            extra_fixed=sig.extra_fixed,
-            interpret=kernels.interpret_mode(),
-        )
     # named scopes: the stages an operator reads in XProf (trace-time
     # only — they label the ops, they add none)
     with jax.named_scope("probe"):
@@ -755,174 +662,6 @@ def fold_join_meta(terms: Tuple[FusedTermSig, ...]):
                 (i, tuple((names.index(v), t.var_names.index(v)) for v in t.var_names))
             )
     return positives, negatives, names, join_meta, anti_meta
-
-
-def multiway_meta(join_meta, mw: int):
-    """Static k-way step metadata for a multiway prefix of `mw` clauses:
-    (per-tail (v column, extra columns), clause-0's v column).  ONE
-    derivation shared by build_fused and build_fused_sharded — like
-    fold_join_meta, this is load-bearing for answer correctness, and the
-    star-prefix invariant (every prefix join shares exactly one
-    variable, at the same accumulated column) is enforced here for both
-    program builders."""
-    assert all(len(join_meta[j][0]) == 1 for j in range(mw - 1)), (
-        "multiway prefix joins must share exactly one variable"
-    )
-    meta = tuple(
-        (join_meta[j][0][0][1], join_meta[j][1]) for j in range(mw - 1)
-    )
-    return meta, join_meta[0][0][0][0]
-
-
-def kernel_program_plan(
-    sigs, term_shapes, term_caps, join_caps, index_joins,
-    *, n_shards: int = 1, exch_caps=None, multiway: int = 0,
-) -> str:
-    """Bytes-based kernel route for ONE fused program (single-device,
-    shard-local, or vmapped count-batch lane) — the planner call that
-    replaced the per-dimension `fits()` gate.
-
-    term_shapes[i] = (n_keys, n_rows) of term i's probe index arrays (for
-    the sharded executor: PER-SHARD slab sizes — the kernel boundary is
-    the shard).  Every stage the program will trace gets a byte plan from
-    kernels/budget.py with its COMBINED buffer footprint:
-
-      * probes — all materialized terms (negated included);
-      * joins — the left side at its accumulated capacity and the right
-        side at the size the kernel ACTUALLY holds: inside shard_map a
-        broadcast right is S×cap rows, a hash-partitioned join holds
-        S×q on both sides, and an index join gathers the small LEFT to
-        S×cap (the old per-dimension check under-accounted exactly these
-        concurrent-buffer shapes);
-      * anti joins — the final accumulator against each gathered tabu.
-
-    Returns budget.ROUTE_LOWERED / ROUTE_SINGLE / ROUTE_TILED for the
-    whole program (one over-budget stage kicks the program to the
-    lowered bodies — the all-or-nothing use_kernels contract).  Callers
-    re-derive per capacity-retry round; the kernel impls re-derive the
-    same model per stage at trace time, so verdict and traced program
-    agree."""
-    from das_tpu.kernels import budget
-
-    return budget.combine(*_kernel_stage_plans(
-        sigs, term_shapes, term_caps, join_caps, index_joins,
-        n_shards=n_shards, exch_caps=exch_caps, multiway=multiway,
-    ))
-
-
-def _kernel_stage_plans(
-    sigs, term_shapes, term_caps, join_caps, index_joins,
-    *, n_shards: int = 1, exch_caps=None, multiway: int = 0,
-):
-    """The per-stage byte plans behind kernel_program_plan — exposed so
-    the program ledger (das_tpu/obs/proflog.py) can report the SAME
-    modeled footprint the route gate decided on next to what XLA's
-    memory_analysis actually allocated (the §15 calibration contract)."""
-    from das_tpu.kernels import budget
-
-    positives, _negatives, _names, join_meta, anti_meta = fold_join_meta(sigs)
-    start = multiway if multiway else 1
-    index_joins = (
-        tuple(index_joins) if index_joins
-        else tuple([-1] * max(0, len(positives) - start))
-    )
-    index_right = {
-        positives[start + t]: t for t, p in enumerate(index_joins) if p >= 0
-    }
-    plans = []
-    for i, t in enumerate(sigs):
-        if i in index_right:
-            continue  # never materialized; budgeted at its join below
-        n_keys, n_rows = term_shapes[i]
-        plans.append(budget.probe_plan(
-            n_keys, n_rows, t.arity, len(t.var_cols), term_caps[i]
-        ))
-    width = len(sigs[positives[0]].var_cols) if positives else 0
-    left_rows = term_caps[positives[0]] if positives else 0
-    if multiway:
-        # k-way stage: the tails arrive width-padded and — inside
-        # shard_map — broadcast-gathered to S×cap rows each, all
-        # CONCURRENTLY resident next to the local accumulator and the
-        # output block (the S×cap accounting rule of the binary joins)
-        tails = [positives[j] for j in range(1, multiway)]
-        kpad = max(len(sigs[i].var_cols) for i in tails)
-        k_out = width + sum(
-            len(join_meta[j][1]) for j in range(multiway - 1)
-        )
-        plans.append(budget.multiway_plan(
-            left_rows, width,
-            tuple((n_shards * term_caps[i], kpad) for i in tails),
-            k_out, join_caps[0],
-        ))
-        width = k_out
-        left_rows = join_caps[0]
-    for t, i in enumerate(positives[start:]):
-        pairs, extra = join_meta[start - 1 + t]
-        jc = join_caps[(1 if multiway else 0) + t]
-        k_out = width + len(extra)
-        if index_joins[t] >= 0:
-            n_keys, n_rows = term_shapes[i]
-            plans.append(budget.index_join_plan(
-                n_shards * left_rows, width, n_keys, n_rows,
-                sigs[i].arity, k_out, jc,
-            ))
-        else:
-            q = exch_caps[(1 if multiway else 0) + t] if exch_caps else 0
-            if q:  # hash-partitioned: S×q rows land on the joining shard
-                l_rows, r_rows = n_shards * q, n_shards * q
-            else:  # broadcast-right: the gathered right is S×cap rows
-                l_rows, r_rows = left_rows, n_shards * term_caps[i]
-            plans.append(budget.join_plan(
-                l_rows, width, r_rows, len(sigs[i].var_cols),
-                len(pairs), k_out, jc,
-            ))
-        width = k_out
-        left_rows = jc
-    for i, _pairs in anti_meta:
-        plans.append(budget.anti_join_plan(
-            left_rows, width, n_shards * term_caps[i], len(sigs[i].var_cols)
-        ))
-    return plans
-
-
-def program_model_bytes(sig, bucket_arrays, *_rest) -> int:
-    """Modeled peak kernel footprint of ONE fused program — the largest
-    per-stage combined (resident + streamed block) byte figure the
-    budget planner gated the kernel route on (stages run sequentially,
-    so the max is the modeled live-at-once peak).  0 when the program
-    runs the lowered bodies (no kernel stages to calibrate).  Called by
-    the program ledger at AOT-compile time with the program's actual
-    call arguments, so the table shapes are exactly what the trace saw
-    (ShardedPlanSigs carry exch_caps; their bucket arrays are [S, m]
-    slabs and the per-shard axis-1 sizes are the kernel boundary)."""
-    if not getattr(sig, "use_kernels", False):
-        return 0
-    sharded = hasattr(sig, "exch_caps")
-    ax = 1 if sharded else 0
-    shapes = tuple(
-        (a[0].shape[ax], a[2].shape[ax]) for a in bucket_arrays
-    )
-    plans = _kernel_stage_plans(
-        sig.terms, shapes, sig.term_caps, sig.join_caps, sig.index_joins,
-        n_shards=getattr(sig, "n_shards", 1),
-        exch_caps=getattr(sig, "exch_caps", None),
-        multiway=getattr(sig, "multiway", 0),
-    )
-    if not plans:
-        return 0
-    return max(p.resident_bytes + p.block_bytes for p in plans)
-
-
-def tree_model_bytes(sig, *site_inputs) -> int:
-    """Whole-tree twin of program_model_bytes: the max modeled stage
-    footprint over every conjunction site of the fused tree program
-    (sites trace sequentially into one program)."""
-    ssigs = sig.sites + ((sig.neg,) if sig.neg is not None else ())
-    return max(
-        (program_model_bytes(ssig, inputs[0])
-         for ssig, inputs in zip(ssigs, site_inputs)),
-        default=0,
-    )
 
 
 def remember_caps(caps_dict, caches, sigs, new_caps, caps_of) -> None:
@@ -1011,28 +750,12 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
     tables shared by XLA CSE where branches coincide, and all sites
     settling in one transfer."""
     positives, _negatives, names, join_meta, anti_meta = fold_join_meta(sig.terms)
-    mw = sig.multiway
-    # first positive the tail binary fold starts from (the accumulator
-    # is the multiway output when mw, else the first term table)
-    start = mw if mw else 1
     index_joins = sig.index_joins or tuple(
-        [-1] * max(0, len(positives) - start)
+        [-1] * max(0, len(positives) - 1)
     )
     index_right = {
-        positives[start + t]: t for t, p in enumerate(index_joins) if p >= 0
+        positives[1 + n]: n for n, p in enumerate(index_joins) if p >= 0
     }
-    if mw:
-        mw_meta, mw_vcol0 = multiway_meta(join_meta, mw)
-    use_k = sig.use_kernels
-    if use_k or mw:
-        from das_tpu import kernels as _kernels
-
-        # the multiway step has no separate lowered chain: off-TPU its
-        # body traces by direct discharge whether or not the kernel
-        # route is on; on a TPU it is the real pallas_call like every
-        # other kernel (the planner's auto mode keeps the chain there
-        # while the kernel route is off — planner/search.py)
-        _interp = _kernels.interpret_mode()
 
     tables = {}
     term_ranges = []
@@ -1055,8 +778,7 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
             term_ranges.append(jnp.int32(0))
             continue
         vals, mask, rng = _probe(
-            t, bucket_arrays[i], keys[i], fixed_vals[i], sig.term_caps[i],
-            use_kernels=use_k,
+            t, bucket_arrays[i], keys[i], fixed_vals[i], sig.term_caps[i]
         )
         # no per-term dedup: every route pins the link type (type_id or
         # ctype), so the full target vector is a function of (fixed
@@ -1083,59 +805,25 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
         reseed = acc_valid.sum(dtype=jnp.int32) == 0
     else:
         reseed = jnp.bool_(False)
-    if mw:
-        # k-way multiway step: ALL prefix clauses ground in one
-        # leapfrog-intersection pass — no intermediate tables, one
-        # output buffer (sig.join_caps[0]).  The kernel's partial
-        # totals are the would-be binary intermediates' exact pair
-        # counts, so the reference's empty-accumulator reseed
-        # verdict is reproduced without materializing them: the
-        # t-th internal join triggers iff its absolute position is
-        # before the LAST join of the whole program (the chain's
-        # `n < len(positives) - 2` rule).
-        with jax.named_scope("join"):
-            acc_vals, acc_valid, mw_totals = _kernels.multiway_join_impl(
-                acc_vals, acc_valid,
-                [tables[i] for i in positives[1:mw]],
-                mw_vcol0, mw_meta, sig.join_caps[0],
-                interpret=_interp,
-            )
-        join_counts.append(mw_totals[mw - 2])
-        for t in range(max(0, min(mw - 1, len(positives) - 2))):
-            reseed = reseed | (mw_totals[t] == 0)
-    for t, i in enumerate(positives[start:]):
-        n = start - 1 + t          # absolute join position
+    for n, i in enumerate(positives[1:]):
         pairs, extra = join_meta[n]
-        jc = sig.join_caps[(1 if mw else 0) + t]
+        jc = sig.join_caps[n]
         # no post-join dedup: a join of duplicate-free tables is
         # duplicate-free (output row <-> (left row, right row) is a
         # bijection: shared columns agree, extras come from exactly one
         # side, and each side's rows are unique)
         with jax.named_scope("join"):
-            if index_joins[t] >= 0:
+            if index_joins[n] >= 0:
                 ks, perm, targets, _tid = bucket_arrays[i]
-                if use_k:
-                    acc_vals, acc_valid, total = _kernels.index_join_impl(
-                        acc_vals, acc_valid, ks, perm, targets, keys[i],
-                        pairs, sig.terms[i].var_cols, extra,
-                        jc, interpret=_interp,
-                    )
-                else:
-                    acc_vals, acc_valid, total = _index_join_impl(
-                        acc_vals, acc_valid, ks, perm, targets, keys[i],
-                        pairs, sig.terms[i].var_cols, extra, jc,
-                    )
+                acc_vals, acc_valid, total = _index_join_impl(
+                    acc_vals, acc_valid, ks, perm, targets, keys[i],
+                    pairs, sig.terms[i].var_cols, extra, jc,
+                )
             else:
                 rv, rm = tables[i]
-                if use_k:
-                    acc_vals, acc_valid, total = _kernels.join_tables_impl(
-                        acc_vals, acc_valid, rv, rm, pairs, extra,
-                        jc, interpret=_interp,
-                    )
-                else:
-                    acc_vals, acc_valid, total = _join_tables_impl(
-                        acc_vals, acc_valid, rv, rm, pairs, extra, jc
-                    )
+                acc_vals, acc_valid, total = _join_tables_impl(
+                    acc_vals, acc_valid, rv, rm, pairs, extra, jc
+                )
         join_counts.append(total)
         if n < len(positives) - 2:
             reseed = reseed | (acc_valid.sum(dtype=jnp.int32) == 0)
@@ -1143,14 +831,7 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
     for i, pairs in anti_meta:
         rv, rm = tables[i]
         with jax.named_scope("anti_join"):
-            if use_k:
-                acc_valid = _kernels.anti_join_impl(
-                    acc_vals, acc_valid, rv, rm, pairs, interpret=_interp
-                )
-            else:
-                acc_valid = _anti_join_impl(
-                    acc_vals, acc_valid, rv, rm, pairs
-                )
+            acc_valid = _anti_join_impl(acc_vals, acc_valid, rv, rm, pairs)
 
     count = acc_valid.sum(dtype=jnp.int32)
     reseed = reseed & ~any_pos_empty
@@ -1205,7 +886,6 @@ def build_fused(sig: FusedPlanSig, count_only: bool = False):
     return obs.proflog.instrument(
         "fused", obs.proflog.sig_digest(sig, count_only),
         jax.jit(obs.named_program("das_fused", fn, count_only)),
-        model_bytes=partial(program_model_bytes, sig),
     ), names
 
 
@@ -1280,7 +960,6 @@ def build_fused_group(sig: FusedPlanSig, count_only, key_axes, fval_axes):
             "das_fused_group", lanes_program(body, key_axes, fval_axes),
             count_only,
         )),
-        model_bytes=partial(program_model_bytes, sig),
     ), names
 
 
@@ -1307,9 +986,9 @@ class FusedTreeSig:
     """Shape-static description of ONE whole-tree fused program (ISSUE
     10): every positive Or branch as a full per-site plan signature,
     plus the joint negative conjunction for the de-Morgan difference
-    branch.  Nested FusedPlanSigs carry the per-site capacities, kernel
-    routing and planner provenance, so the tree signature inherits
-    their cache-key honesty (daslint DL002)."""
+    branch.  Nested FusedPlanSigs carry the per-site capacities and
+    planner provenance, so the tree signature inherits their cache-key
+    honesty (daslint DL002)."""
 
     sites: Tuple[FusedPlanSig, ...]
     neg: Optional[FusedPlanSig] = None
@@ -1385,7 +1064,6 @@ def build_fused_tree(sig: FusedTreeSig, count_only: bool = False):
     return obs.proflog.instrument(
         "fused_tree", obs.proflog.sig_digest(sig, count_only),
         jax.jit(obs.named_program("das_fused_tree", fn, count_only)),
-        model_bytes=partial(tree_model_bytes, sig),
     ), out_names
 
 
@@ -1465,8 +1143,6 @@ class _TreeExecJob:
     def dispatch(self):
         """Queue the whole-tree program at every site's current
         capacities (async, no sync)."""
-        from das_tpu.kernels import record_dispatch
-
         record_dispatch("fused_tree")
         sp = obs.NOOP_SPAN
         if obs.enabled():
@@ -1581,25 +1257,21 @@ def prepare_tree_job(ex, pos_sites, neg_plans, job_cls):
     """Build one whole-tree job (ISSUE 10) on executor `ex`: one
     count_only site job per positive Or branch (each rides the full
     _exec_job machinery — planner ordering and seeds, learned caps,
-    index-join routing, multiway prefixes), plus one for the joint
-    negative conjunction.  None when ANY site declines (missing bucket,
-    capacity ceiling) — the tree executor answers, bit-identical.
-    Site jobs don't count per-answer route telemetry (count_route):
-    the tree job reports the ONE fused answer.  Shared by both
+    index-join routing), plus one for the joint negative conjunction.
+    None when ANY site declines (missing bucket, capacity ceiling) —
+    the tree executor answers, bit-identical.  Shared by both
     executors — `job_cls` is their only difference."""
     site_jobs = []
     for site in pos_sites:
         j = ex._exec_job(list(site), True)
         if j is None:
             return None
-        j.count_route = False
         site_jobs.append(j)
     neg_job = None
     if neg_plans:
         neg_job = ex._exec_job(list(neg_plans), True)
         if neg_job is None:
             return None
-        neg_job.count_route = False
     return job_cls(ex, site_jobs, neg_job)
 
 
@@ -1790,8 +1462,6 @@ def build_fused_exact(sig: FusedExactSig, count_only: bool = False):
             return stats
         return final_vals, final_valid, stats
 
-    # exact variant stays off the kernel route (no byte model to
-    # calibrate) but its compiles are ledger-visible like every program
     return obs.proflog.instrument(
         "fused_exact", obs.proflog.sig_digest(sig, count_only),
         jax.jit(obs.named_program("das_fused_exact", fn, count_only)),
@@ -1802,18 +1472,15 @@ def build_fused_exact(sig: FusedExactSig, count_only: bool = False):
 INDEX_TERM_TOKEN_CAP = 16
 
 
-def apply_index_joins(buckets, sigs, arrays, term_caps, start_join: int = 0):
+def apply_index_joins(buckets, sigs, arrays, term_caps):
     """Decide per-join index-join routing and rewrite the affected terms'
     inputs: positional posting-index arrays instead of the type-sorted
     window, and a token capacity (the term is never materialized, so it
     exerts no buffer or compile-size pressure).  `buckets` maps arity to
     the executor's bucket objects (single-device DeviceBucket or sharded
     ShardedBucket — both carry key_type_pos/order_by_type_pos/targets/
-    type_id), so both executors share one routing convention.
-    `start_join` excludes the multiway prefix's internal joins
-    (plan_index_joins) — the returned index_joins cover the TAIL binary
-    joins only."""
-    index_joins, index_right = plan_index_joins(sigs, start_join)
+    type_id), so both executors share one routing convention."""
+    index_joins, index_right = plan_index_joins(sigs)
     if index_right:
         arrays = list(arrays)
         term_caps = list(term_caps)
@@ -2319,11 +1986,8 @@ class FusedExecutor:
 
     def _learned_caps(self, mem, store, sigs, shape_lens):
         """In-memory learned caps, else the cross-process store — BOTH
-        validated against the expected per-stage lengths: the same term
-        signature carries per-JOIN buffers on the binary chain but
-        per-STEP buffers on the multiway route (one output buffer for
-        the whole star prefix), so caps learned on one route must not
-        zip-truncate into the other's seed merge."""
+        validated against the expected per-stage lengths, so a stale or
+        foreign store entry cannot zip-truncate into the seed merge."""
         def _valid(caps):
             return caps is not None and len(caps) == len(shape_lens) and all(
                 len(c) == n for c, n in zip(caps, shape_lens)
@@ -2398,9 +2062,9 @@ class FusedExecutor:
     def _estimate(self, plan) -> int:
         return estimate_plan_rows(self.db, plan)
 
-    def _apply_index_joins(self, sigs, arrays, term_caps, start_join=0):
+    def _apply_index_joins(self, sigs, arrays, term_caps):
         return apply_index_joins(
-            self.db.dev.buckets, sigs, arrays, term_caps, start_join
+            self.db.dev.buckets, sigs, arrays, term_caps
         )
 
     _clamp_index_terms = staticmethod(clamp_index_terms)
@@ -2478,10 +2142,6 @@ class FusedExecutor:
             _planner.plan_conjunction(self.db, plans)
             if _planner.enabled(self.db.config) else None
         )
-        # leading positives fused into one k-way multiway step — changes
-        # the step-buffer layout below (join_caps[0] is the multiway
-        # output; index_joins cover only the tail binary joins)
-        mw = planned.multiway if planned is not None else 0
         if planned is not None:
             ordered = [plans[i] for i in planned.order]
         else:
@@ -2508,24 +2168,20 @@ class FusedExecutor:
         # clamps (and owns the overflow error policy)
         term_caps = tuple(_pow2_at_least(self._estimate(plan)) for plan in plans)
         index_joins, index_right, arrays, term_caps = self._apply_index_joins(
-            sigs, arrays, term_caps, start_join=max(0, mw - 1)
+            sigs, arrays, term_caps
         )
-        n_positive = sum(1 for s in sigs if not s.negated)
-        # one buffer per STEP: the multiway step plus the tail binary
-        # joins, or the pure chain's P-1 joins
-        n_steps = (n_positive - mw + 1) if mw else max(0, n_positive - 1)
-        if planned is not None and len(planned.join_cap_seeds) == n_steps:
+        n_joins = max(0, sum(1 for s in sigs if not s.negated) - 1)
+        if planned is not None and len(planned.join_cap_seeds) == n_joins:
             # the costed seeds: margin × estimated rows per intermediate
             # instead of one blind seed for every join — overflow retry
             # still owns estimate error, the ladder just starts on the
-            # right rung for the common case (and margin-FREE for the
-            # multiway step, whose seed is the exact k-way intersection
-            # product — no configured clamp can shrink it back under
-            # the exact row count)
+            # right rung for the common case (margin-FREE where the
+            # statistic is exact: no configured clamp can shrink a seed
+            # back under the exact row count)
             join_caps = planned.join_cap_seeds
         else:
             join_caps = tuple(
-                [self._join_cap_seed(plans, term_caps)] * n_steps
+                [self._join_cap_seed(plans, term_caps)] * n_joins
             )
         learned = self._learned_caps(
             self._caps, self._cap_store, sigs,
@@ -2541,8 +2197,6 @@ class FusedExecutor:
         # entries must not smuggle buffers past the configured maximum
         if max(term_caps + join_caps, default=0) > cfg.max_result_capacity:
             return None
-        from das_tpu import kernels
-
         # counted only once the job EXISTS: a decline above (missing
         # bucket, capacity ceiling) runs the legacy fallback, and the
         # planned/greedy decomposition must cover executor traffic the
@@ -2553,9 +2207,7 @@ class FusedExecutor:
             _planner.PLANNER_COUNTS["greedy"] += 1
         return _ExecJob(
             self, count_only, same_order, sigs, arrays, keys, fvals,
-            term_caps, join_caps, index_joins,
-            use_kernels=kernels.enabled(cfg), planned=planned,
-            multiway=mw,
+            term_caps, join_caps, index_joins, planned=planned,
         )
 
     def execute(
@@ -2783,16 +2435,10 @@ class FusedExecutor:
         keys_stacked, key_axes, fvals_stacked, fval_axes = stack_lanes(
             uniq_keys, uniq_fvals, _pow2_at_least(len(uniq_keys), lo=1)
         )
-        from das_tpu.kernels import record_dispatch
-
         while True:
             plan_sig = make_sig(term_caps, caps)
             cache_key = (plan_sig, key_axes, fval_axes)
             record_dispatch("count")
-            if getattr(plan_sig, "use_kernels", False):
-                record_dispatch("count_kernel")
-                if getattr(plan_sig, "tiled", False):
-                    record_dispatch("count_kernel_tiled")
             entry = cache.get(cache_key)
             if entry is None:
                 entry = obs.proflog.instrument(
@@ -2802,7 +2448,6 @@ class FusedExecutor:
                         "das_count_batch",
                         lanes_program(build(plan_sig), key_axes, fval_axes),
                     )),
-                    model_bytes=partial(program_model_bytes, plan_sig),
                 )
                 cache[cache_key] = entry
             # the shared RetryPolicy (das_tpu/fault, ISSUE 13) replaces
@@ -2975,7 +2620,6 @@ class FusedExecutor:
                 "count_loop",
                 obs.proflog.sig_digest(plan_sig, W, barrier),
                 looped,
-                model_bytes=partial(program_model_bytes, plan_sig),
             )
 
             def run():
@@ -3159,9 +2803,6 @@ class FusedExecutor:
                 )
 
         cfg = self.db.config
-        from das_tpu import kernels as _kernels
-
-        use_k_cfg = _kernels.enabled(cfg)
         for sigs, members in groups.items():
             term_caps = tuple(
                 _pow2_at_least(max(prepared[m][5][t] for m in members))
@@ -3194,32 +2835,10 @@ class FusedExecutor:
                 # a vmapped group multiplies every padded buffer by the
                 # lane count: whole-table terms run single-lane instead
                 continue
-            # kernel routing for the vmapped group (use_pallas_kernels):
-            # the bytes planner re-derives the route per retry round from
-            # the caps the make_sig call sees — a capacity doubling past
-            # the VMEM budget re-plans grid-chunked, and past the tiled
-            # resident set falls back to the lowered bodies, exactly like
-            # the single-query dispatch
-            group_shapes = tuple(
-                (a[0].shape[0], a[2].shape[0]) for a in group_arrays
-            )
-
-            def _group_sig(
-                tc, jc, _s=sigs, _ij=index_joins, _shapes=group_shapes
-            ):
-                route = (
-                    kernel_program_plan(_s, _shapes, tc, jc, _ij)
-                    if use_k_cfg else _kernels.budget.ROUTE_LOWERED
-                )
-                use_k = route != _kernels.budget.ROUTE_LOWERED
-                return FusedPlanSig(
-                    _s, tc, jc, _ij, use_k,
-                    route == _kernels.budget.ROUTE_TILED,
-                    _kernels.budget.vmem_budget() if use_k else 0,
-                )
-
             stats, term_caps, join_caps = self._run_batch_group(
-                _group_sig,
+                lambda tc, jc, _s=sigs, _ij=index_joins: FusedPlanSig(
+                    _s, tc, jc, _ij
+                ),
                 self._batch_cache,
                 lambda ps: build_fused(ps, count_only=True)[0],
                 group_arrays,
@@ -3230,14 +2849,6 @@ class FusedExecutor:
             if stats is None:
                 continue
             self._remember_caps(sigs, term_caps, join_caps)
-            if use_k_cfg and kernel_program_plan(
-                sigs, group_shapes, term_caps, join_caps, index_joins
-            ) != _kernels.budget.ROUTE_LOWERED:
-                # route telemetry mirrors fused_kernel: one count per query
-                # whose group program ran kernel-routed at the final caps
-                from das_tpu.query import compiler as _qc
-
-                _qc.ROUTE_COUNTS["count_kernel"] += len(members)
             n_positive = sum(1 for s in sigs if not s.negated)
             for row, m in zip(stats, members):
                 count, reseed, pos_empty = int(row[0]), bool(row[1]), bool(row[2])

@@ -610,8 +610,8 @@ def conj_sites(node: PlanNode) -> List[List]:
 
 def tree_fusion_enabled(config=None) -> bool:
     """Resolve whole-tree fusion routing.  Env DAS_TPU_TREE_FUSION beats
-    the config (the DAS_TPU_PALLAS idiom, so the bench A/B can flip arms
-    without code changes); "auto" = on — ineligible shapes fall back to
+    the config (so the bench A/B can flip arms without code changes);
+    "auto" = on — ineligible shapes fall back to
     the tree executor, answers bit-identical either way."""
     mode = os.environ.get("DAS_TPU_TREE_FUSION")
     if mode is None and config is not None:

@@ -164,8 +164,7 @@ class DasService:
         mark, the result caches' hit/miss/invalidation counters (the
         conjunctive, tree-composite and count-batch caches all fold in),
         and the process-wide route counters — incl. the sharded mesh
-        routes (`sharded`/`sharded_kernel`) now that mesh tenants ride the
-        same pipeline.  `tenants` breaks the aggregates down per tenant
+        route (`sharded`) now that mesh tenants ride the same pipeline.  `tenants` breaks the aggregates down per tenant
         name so a noisy mesh tenant is distinguishable from a quiet
         single-device one."""
         out = {

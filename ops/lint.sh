@@ -7,7 +7,7 @@
 # --changed-only (first arg): pre-commit fast path — analyze only the
 # das_tpu/*.py files changed vs HEAD (staged, unstaged, untracked),
 # plus the registry-bearing modules every cross-file rule anchors on
-# (counters, ENV_REGISTRY, KERNEL_BUFFERS, COLLECTIVE_SITES,
+# (counters, ENV_REGISTRY, COLLECTIVE_SITES,
 # FETCH_SITES, LOCK_DISCIPLINE), under --allow-partial so staleness
 # legs that need the full tree don't fire on the subset.  The full run
 # stays the authority; CI runs it.
@@ -30,7 +30,6 @@ if [ "${1:-}" = "--changed-only" ]; then
   anchors=(
     das_tpu/ops/counters.py
     das_tpu/core/config.py
-    das_tpu/kernels/budget.py
     das_tpu/parallel/mesh.py
     das_tpu/service/coalesce.py
     das_tpu/query/fused.py

@@ -6,13 +6,6 @@
 # XLA_FLAGS=--xla_force_host_platform_device_count=8).
 set -euo pipefail
 cd "$(dirname "$0")/.."
-# `ops/pytests.sh kernels` runs the Pallas kernel suite standalone — the
-# intended loop on a TPU host, where the kernels compile (Mosaic) instead
-# of interpreting; any further args pass through to pytest.
-if [[ "${1:-}" == "kernels" ]]; then
-  shift
-  exec python -m pytest tests/ -q -m kernels "$@"
-fi
 # `ops/pytests.sh pipeline` runs the serving-pipeline + result-cache
 # suite standalone (coalescer pipelining, cache invalidation pins).
 if [[ "${1:-}" == "pipeline" ]]; then
@@ -20,8 +13,8 @@ if [[ "${1:-}" == "pipeline" ]]; then
   exec python -m pytest tests/ -q -m pipeline "$@"
 fi
 # `ops/pytests.sh sharded` runs the sharded serving-parity suite
-# standalone (mesh dispatch/settle pipeline, sharded kernel routes,
-# tree-composite + count-batch cache scope).
+# standalone (mesh dispatch/settle pipeline, tree-composite +
+# count-batch cache scope); any further args pass through to pytest.
 if [[ "${1:-}" == "sharded" ]]; then
   shift
   exec python -m pytest tests/ -q -m sharded "$@"
@@ -39,14 +32,6 @@ fi
 if [[ "${1:-}" == "planner" ]]; then
   shift
   exec python -m pytest tests/ -q -m planner "$@"
-fi
-# `ops/pytests.sh multiway` runs the k-way multiway join kernel suite
-# standalone (kernel-vs-chain bit-parity incl. partial totals, the
-# planner-routed bio/sharded end-to-end arms, the zero-retry acceptance
-# pin, and the capacity-seed floor regression).
-if [[ "${1:-}" == "multiway" ]]; then
-  shift
-  exec python -m pytest tests/ -q -m multiway "$@"
 fi
 # `ops/pytests.sh treefuse` runs the whole-tree fused execution suite
 # standalone (fused-tree vs tree-executor bit-parity on the bio

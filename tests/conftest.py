@@ -36,8 +36,18 @@ def animals_db(animals_data):
 REFERENCE_PATH = "/root/reference"
 
 
-def reference_available() -> bool:
-    return os.path.isdir(os.path.join(REFERENCE_PATH, "das"))
+def reference_available(*parts) -> bool:
+    """Whether the reference checkout holds `parts` (default: its `das`
+    package).  Checkouts can be partial, so tests ask for what they run."""
+    return os.path.exists(os.path.join(REFERENCE_PATH, *(parts or ("das",))))
+
+
+def reference_path(*parts) -> str:
+    """Path of one file of the reference checkout; skips the calling
+    test (or fixture) where the checkout does not hold it."""
+    if not reference_available(*parts):
+        pytest.skip(f"reference checkout has no {'/'.join(parts)}")
+    return os.path.join(REFERENCE_PATH, *parts)
 
 
 @pytest.fixture(scope="session")

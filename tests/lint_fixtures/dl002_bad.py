@@ -14,30 +14,30 @@ class MutablePlanSig:            # not frozen: unhashable-by-value key
 class LeakyPlanSig:
     terms: Tuple[int, ...]
     term_caps: Tuple[int, ...]
-    use_kernels: bool = False
+    planned: bool = False
     # a routing input excluded from __eq__/__hash__: cache poisoning
-    vmem_budget: int = field(default=0, compare=False)
+    n_shards: int = field(default=1, compare=False)
 
 
 def build_leaky(sig: LeakyPlanSig, count_only: bool = False):
-    if sig.use_kernels and sig.tiled:    # `tiled` was never declared
-        return ("tiled", sig.terms)
-    if getattr(sig, "chunk_rows", 0):    # default hides the omission
-        return ("chunked", sig.terms)
-    return ("single", sig.term_caps)
+    if sig.planned and sig.index_joins:  # `index_joins` never declared
+        return ("index", sig.terms)
+    if getattr(sig, "exch_caps", 0):     # default hides the omission
+        return ("exchange", sig.terms)
+    return ("greedy", sig.term_caps)
 
 
 def maybe_build(sig: Optional[LeakyPlanSig]):
     # Optional wrapping must not lose the read check
-    return None if sig is None else sig.chunk_rows
+    return None if sig is None else sig.exch_caps
 
 
 def make(terms, caps):
     # constructor drift: 4 positional args for 4 fields is fine, but an
     # unknown keyword means the field was deleted out from under a caller
-    return LeakyPlanSig(terms, caps, use_kernels=True, tiled=True)
+    return LeakyPlanSig(terms, caps, planned=True, index_joins=(1,))
 
 
 def make_qualified(mod, terms, caps):
     # module-qualified construction gets the same keyword check
-    return mod.LeakyPlanSig(terms, caps, chunk=4)
+    return mod.LeakyPlanSig(terms, caps, exch=4)

@@ -8,21 +8,21 @@ from typing import Tuple
 class TightPlanSig:
     terms: Tuple[int, ...]
     term_caps: Tuple[int, ...]
-    use_kernels: bool = False
-    tiled: bool = False
-    vmem_budget: int = 0
+    index_joins: Tuple[int, ...] = ()
+    planned: bool = False
+    n_shards: int = 1
 
     def describe(self) -> str:           # methods are fine to call
         return f"{len(self.terms)} terms"
 
 
 def build_tight(sig: TightPlanSig, count_only: bool = False):
-    if sig.use_kernels and sig.tiled:
-        return ("tiled", sig.vmem_budget, sig.describe())
-    if getattr(sig, "use_kernels", False):
-        return ("kernel", sig.terms)
-    return ("single", sig.term_caps)
+    if sig.planned and sig.index_joins:
+        return ("index", sig.n_shards, sig.describe())
+    if getattr(sig, "planned", False):
+        return ("planned", sig.terms)
+    return ("greedy", sig.term_caps)
 
 
 def make(terms, caps):
-    return TightPlanSig(terms, caps, use_kernels=True, tiled=False)
+    return TightPlanSig(terms, caps, planned=True, n_shards=4)

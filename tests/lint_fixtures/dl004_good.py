@@ -3,7 +3,7 @@ dicts built from the registry.  (The test passes no tests-dir for the
 fixture runs, so the referenced-by-a-test leg is exercised on the real
 tree instead.)"""
 
-DISPATCH_KEYS = ("fixture_kernel", "fixture_tiled")
+DISPATCH_KEYS = ("fixture_fused", "fixture_sharded")
 ROUTE_KEYS = ("fixture_fused", "fixture_staged")
 
 DISPATCH_COUNTS = {k: 0 for k in DISPATCH_KEYS}
@@ -15,8 +15,8 @@ def record_dispatch(kind, n=1):
 
 
 def run(tiled, fused):
-    record_dispatch("fixture_kernel")
+    record_dispatch("fixture_fused")
     if tiled:
-        record_dispatch("fixture_tiled")
+        record_dispatch("fixture_sharded")
     route = "fixture_fused" if fused else "fixture_staged"
     ROUTE_COUNTS[route] += 1

@@ -14,8 +14,8 @@ class PlannedProgram:
         self.route = route
 
 
-def plan(kernel):
-    route = "fixture_fused" if kernel else "fixture_warp"  # undeclared
+def plan(single):
+    route = "fixture_fused" if single else "fixture_warp"  # undeclared
     PLANNER_COUNTS["fixture_planned"] += 1
     PLANNER_COUNTS["fixture_mystery"] += 1               # undeclared key
     return PlannedProgram(route="fixture_hyperspace")    # undeclared

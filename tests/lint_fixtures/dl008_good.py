@@ -13,8 +13,8 @@ class PlannedProgram:
         self.route = route
 
 
-def plan(kernel, exact):
-    route = "fixture_fused" if kernel else "fixture_sharded"
+def plan(single, exact):
+    route = "fixture_fused" if single else "fixture_sharded"
     method = "fixture_dp" if exact else "fixture_planned"
     PLANNER_COUNTS[method] += 1
     PLANNER_COUNTS["fixture_planned"] += 0  # both keys have static sites

@@ -8,7 +8,6 @@ from das_tpu.obs import proflog
 
 PROGRAM_SITES = {
     "dl016_good.build_program": "prog",
-    "dl016_good.launch_block": "blk",
     "dl016_good._tiny_op": None,
 }
 
@@ -20,15 +19,6 @@ def build_program(sig):
     return proflog.instrument(
         "prog", proflog.sig_digest(sig), jax.jit(fn)
     )
-
-
-def launch_block(body, shapes, inputs):
-    from jax.experimental import pallas as pl
-
-    t0 = proflog.launch_mark()
-    out = pl.pallas_call(body, out_shape=shapes)(*inputs)
-    proflog.record_launch("blk", body, shapes, t0, pallas=True)
-    return out
 
 
 @jax.jit

@@ -20,30 +20,6 @@ def _full_extra():
         "batched_ms_per_query": 99999.999,
         "batched_wide_ms_per_query": 99999.999,
         "served_ms_per_query": 99999.999,
-        "kernel_ab": {
-            "lowered_ms": 99999.999,
-            "kernel_ms": 99999.999,
-            "interpret": True,
-            "route": "pallas-interpret",
-            "staged_dispatches": {"lowered": 999, "kernel": 999},
-        },
-        "tiled_kernel_ab": {
-            "interpret": True,
-            "rows": 99_999_999,
-            "probe_cap": 99_999_999,
-            "join_cap": 99_999_999,
-            "route": "tiled",
-            "tiled_route": {
-                "probe": "tiled", "join": "tiled", "chunk_rows": 999_999,
-            },
-            "probe_lowered_ms": 99999.999,
-            "probe_kernel_ms": 99999.999,
-            "join_lowered_ms": 99999.999,
-            "join_kernel_ms": 99999.999,
-            "tiled_vs_lowered_ms": [99999.999, 99999.999],
-            "parity": True,
-            "no_lowered_fallback": True,
-        },
         "sharded_serving": {
             "n_shards": 999,
             "clients": 999,
@@ -64,10 +40,6 @@ def _full_extra():
             "open_loop_p95_ms": 99999.999,
             "open_loop_p99_ms": 99999.999,
             "latency_buckets": [[99999.999, 999_999]] * 12,
-            "count_lowered_ms": 99999.999,
-            "count_kernel_ms": 99999.999,
-            "count_kernel_engaged": True,
-            "count_parity": True,
         },
         "serving": {
             "clients": 999,
@@ -124,31 +96,12 @@ def _full_extra():
             "greedy_programs": 999_999,
             "planner_ms": 99999.999,
             "greedy_ms": 99999.999,
-            "planner_route": "fused_kernel",
+            "planner_route": "fused",
             "retry_rounds_avoided": 999_999,
             "parity": True,
             "planner_stats": {
                 "planned": 9_999_999, "greedy": 9_999_999,
                 "round0": 9_999_999, "retries": 9_999_999,
-                "est_rows": 9_999_999_999, "actual_rows": 9_999_999_999,
-                "actual_vs_est_ratio": 9999.9999,
-            },
-        },
-        "multiway_ab": {
-            "skew": 9.9,
-            "interpret": True,
-            "multiway_first_contact_ms": 99999.999,
-            "chain_first_contact_ms": 99999.999,
-            "multiway_programs": 999_999,
-            "chain_programs": 999_999,
-            "multiway_ms": 99999.999,
-            "chain_ms": 99999.999,
-            "multiway_route": "fused_multiway",
-            "chain_retry_rounds_avoided": 999_999,
-            "parity": True,
-            "multiway_stats": {
-                "planned": 9_999_999, "round0": 9_999_999,
-                "retries": 9_999_999,
                 "est_rows": 9_999_999_999, "actual_rows": 9_999_999_999,
                 "actual_vs_est_ratio": 9999.9999,
             },
@@ -224,13 +177,6 @@ def test_compact_headline_fits_tail_with_margin():
     parsed = json.loads(line)
     assert parsed["metric"] == result["metric"]
     assert len(parsed["extra"]["flybase"]["error"]) == 16
-    # the Pallas A/B record must survive compaction
-    assert parsed["extra"]["kernel_route"] == "pallas-interpret"
-    assert parsed["extra"]["kernel_vs_lowered_ms"] == [99999.999, 99999.999]
-    # the grid-chunked >2^18 A/B must survive compaction (ISSUE 4:
-    # planner route at the synthetic large term, summed kernel-vs-lowered)
-    assert parsed["extra"]["tiled_route"] == "tiled"
-    assert parsed["extra"]["tiled_vs_lowered_ms"] == [99999.999, 99999.999]
     # the serving pipeline + result-cache record must survive compaction
     # (ISSUE 2: pipelined-vs-serial qps, depth, hit rate, hit-vs-device ms)
     assert parsed["extra"]["serving_qps"] == [999999.9, 999999.9]
@@ -238,11 +184,8 @@ def test_compact_headline_fits_tail_with_margin():
     assert parsed["extra"]["cache_hit_rate"] == 1.0
     assert parsed["extra"]["cache_vs_device_ms"] == [99999.9999, 99999.9999]
     # the sharded serving parity record must survive compaction (ISSUE 3:
-    # mesh pipelined-vs-serial qps, count-batch kernel-vs-lowered ms)
+    # mesh pipelined-vs-serial qps)
     assert parsed["extra"]["sharded_qps"] == [999999.9, 999999.9]
-    assert parsed["extra"]["count_kernel_vs_lowered_ms"] == [
-        99999.999, 99999.999,
-    ]
     # the 256-client open-loop record must survive compaction (ISSUE 6:
     # ms/query, time-to-first-row, the adaptive window's reached depth)
     assert parsed["extra"]["open_loop_ms_per_query"] == 99999.999
@@ -255,15 +198,9 @@ def test_compact_headline_fits_tail_with_margin():
     # the cost-based planner A/B must survive compaction (ISSUE 8: the
     # planner's chosen route, warm [planner, greedy] ms, and the
     # capacity-retry compiles the costed seeds eliminated)
-    assert parsed["extra"]["planner_route"] == "fused_kernel"
+    assert parsed["extra"]["planner_route"] == "fused"
     assert parsed["extra"]["planner_vs_greedy_ms"] == [99999.999, 99999.999]
     assert parsed["extra"]["retry_rounds_avoided"] == 999_999
-    # the multiway join A/B must survive compaction (ISSUE 9: the
-    # k-way route, warm [multiway, chain] ms, and the capacity-retry
-    # compiles the exact intersection seed eliminated on the skew star)
-    assert parsed["extra"]["multiway_route"] == "fused_multiway"
-    assert parsed["extra"]["multiway_vs_chain_ms"] == [99999.999, 99999.999]
-    assert parsed["extra"]["chain_retry_rounds_avoided"] == 999_999
     # the whole-tree fused A/B must survive compaction (ISSUE 10: the
     # whole-tree route, warm [fused, tree] ms, and the per-site
     # dispatch/settle round trips the one-program route eliminated)

@@ -24,6 +24,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def rehearsal():
     """One run of `chip_smoke.main` at the rehearsal scale: (exit code,
     {phase: record}, raw stdout)."""
+    from das_tpu.query import compiler
+
+    # the smoke reports the PROCESS's route counts (its own process is
+    # fresh); under xdist this one has run other files' queries already
+    compiler.reset_route_counts()
     out = io.StringIO()
     with contextlib.redirect_stdout(out):
         rc = chip_smoke.main(["--scale", "0.002", "--seed", "0"])
@@ -64,7 +69,6 @@ def test_route_proof(rehearsal):
     assert q["result_cache_hits"] >= chip_smoke.N_GROUNDED
     # only the Or tree reaches the per-query dispatcher, by design
     assert q["per_query_dispatcher"] == ["Or"]
-    assert q["route_label"] == "off"          # auto = the lowered route
     assert phases["counters"]["route_counts"]["host"] == 0
 
 

@@ -26,6 +26,7 @@ from das_tpu.ingest.pipeline import load_knowledge_base
 from das_tpu.query.ast import Link, Node, PatternMatchingAnswer, Variable
 from das_tpu.storage.atom_table import AtomSpaceData
 from das_tpu.storage.memory_db import MemoryDB
+from tests.conftest import reference_path
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ANIMALS = f"{REPO}/data/samples/animals.metta"
@@ -40,7 +41,7 @@ def _reference_expression_cls():
     """Import the reference's pure das/expression.py WITHOUT putting
     /root/reference on sys.path (which would shadow the compat shim)."""
     spec = importlib.util.spec_from_file_location(
-        "_ref_expression", "/root/reference/das/expression.py"
+        "_ref_expression", reference_path("das", "expression.py")
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)

@@ -17,7 +17,7 @@ group program each, and their retry tiers."""
 import numpy as np
 import pytest
 
-from das_tpu import kernels
+from das_tpu.ops import counters
 from das_tpu.core.config import DasConfig
 from das_tpu.query import compiler, fused
 from das_tpu.query.ast import And, Link, Node, Variable
@@ -107,7 +107,7 @@ def test_group_answers_equal_per_query_execute(db, n):
     ex = fused.get_executor(db)
     plans = _plans(db, _mixed(n))
     want = [ex.execute(p) for p in plans]
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     pending = ex.dispatch_many(plans)
     # programs enqueued == groups: one per shape (a shape asked for once
@@ -117,11 +117,11 @@ def test_group_answers_equal_per_query_execute(db, n):
     per_shape = [list(keys.values()).count(k) for k in set(keys.values())]
     programs = sum(-(-k // fused.GROUP_LANES) for k in per_shape)
     assert len(pending.programs) == programs <= 3
-    assert kernels.DISPATCH_COUNTS["fused"] == programs
+    assert counters.DISPATCH_COUNTS["fused"] == programs
     assert sum(len(m) for m, _ in pending.programs) == len(keys)
     got = dict(ex.settle_many_iter(pending))
     assert fused.FETCH_COUNTS["n"] == fetches + 1   # ONE transfer a round
-    assert kernels.DISPATCH_COUNTS["fused"] == programs   # and no retry
+    assert counters.DISPATCH_COUNTS["fused"] == programs   # and no retry
     assert sorted(got) == list(range(n))
     for i, ref in enumerate(want):
         assert got[i].count == ref.count
@@ -204,7 +204,7 @@ def test_overflowing_lane_retries_alone():
     ex = fused.get_executor(db)
     genes = ["g1", "g2", HUB, "g3", "g4"]
     plans = _plans(db, [shared2(g) for g in genes])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     pending = ex.dispatch_many(plans)
     assert [len(m) for m, _ in pending.programs] == [5]
@@ -212,11 +212,11 @@ def test_overflowing_lane_retries_alone():
     first = [next(stream) for _ in range(4)]
     assert sorted(i for i, _ in first) == [0, 1, 3, 4]
     assert fused.FETCH_COUNTS["n"] == fetches + 1     # still round one
-    assert kernels.DISPATCH_COUNTS["fused"] == 1
+    assert counters.DISPATCH_COUNTS["fused"] == 1
     (i, hub), = list(stream)
     assert i == 2
     assert fused.FETCH_COUNTS["n"] == fetches + 2
-    assert kernels.DISPATCH_COUNTS["fused"] == 2        # the hub, alone
+    assert counters.DISPATCH_COUNTS["fused"] == 2        # the hub, alone
     assert hub.count == N_PROCS * PER_PROC > 64
     assert [r.count for _, r in first] == [2 * PER_PROC] * 4
     # the capacities it learned seed the next group: no retry
@@ -234,11 +234,11 @@ def test_two_overflowing_lanes_retry_as_a_group():
     db = _db(result_cache_size=0)
     ex = fused.get_executor(db)
     plans = _plans(db, [shared2(g) for g in ("g1", HUB, "g2", HUB2)])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     got = ex.execute_many(plans)
     assert [r.count for r in got] == [2 * PER_PROC, N_PROCS * PER_PROC] * 2
-    assert kernels.DISPATCH_COUNTS["fused"] == 2
+    assert counters.DISPATCH_COUNTS["fused"] == 2
     assert fused.FETCH_COUNTS["n"] == fetches + 2
 
 
@@ -273,9 +273,9 @@ def test_commit_between_dispatch_and_settle_leaves_no_cache_insert():
     # and with no commit in between the lanes are cached one by one
     got = ex.execute_many(plans)
     assert len(ex.results._data) == 3
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     hits = ex.execute_many(plans)
-    assert kernels.DISPATCH_COUNTS["fused"] == 0
+    assert counters.DISPATCH_COUNTS["fused"] == 0
     assert [h is g for h, g in zip(hits, got)] == [True] * 3
 
 
@@ -284,13 +284,13 @@ def test_cache_only_enqueues_nothing():
     ex = fused.get_executor(db)
     plans = _plans(db, [shared2(f"g{i}") for i in range(4)])
     ex.execute_many(plans[:2])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     pending = ex.dispatch_many(plans, cache_only=True)
     assert pending.programs == []
     got = ex.settle_many(pending)
     assert [r is not None for r in got] == [True, True, False, False]
-    assert kernels.DISPATCH_COUNTS["fused"] == 0
+    assert counters.DISPATCH_COUNTS["fused"] == 0
     assert fused.FETCH_COUNTS["n"] == fetches
 
 

@@ -9,7 +9,7 @@ normal path, against the benchmark's plain reference.
     answer is empty and keys whose answers are not;
   * an answer the STAGED mesh pipeline gave because the fused mesh
     program declined counts as `staged` (and `mesh.staged_fallbacks`),
-    not as `sharded` / `sharded_kernel`: the harness's limit
+    not as `sharded`: the harness's limit
     `route.staged_delta == 0` holds the mesh as it holds one chip;
   * what the mesh adds to the tracing (`mesh.fetch`, `mesh.dedup`,
     `mesh.collective_bytes`, `mesh.retries`, `mesh.staged_fallbacks`)
@@ -200,7 +200,7 @@ def test_a_declined_mesh_program_counts_as_staged(local, traced, monkeypatch):
     _many(das, kb, genes)
     moved = {k: compiler.ROUTE_COUNTS[k] - v for k, v in before.items()}
     assert moved["staged"] == len(genes)
-    assert moved["sharded"] == moved["sharded_kernel"] == moved["host"] == 0
+    assert moved["sharded"] == moved["host"] == 0
     assert obs.counter("mesh.staged_fallbacks").value == len(genes)
     assert obs.counter("mesh.collective_bytes").value == 0
 

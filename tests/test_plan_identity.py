@@ -105,14 +105,6 @@ def stores(tmp_path_factory):
         _write_cell_kb(tmp_path_factory.mktemp("plan_identity")))
 
 
-@pytest.fixture(autouse=True)
-def _chip_branch(monkeypatch):
-    """The parent's planner reads the platform: record what a TPU gets."""
-    from das_tpu import kernels
-
-    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
-
-
 # -- the query shapes ----------------------------------------------------
 
 
@@ -128,13 +120,10 @@ def _interacts(a, b):
     return Link("Interacts", [a, b], True)
 
 
-def _gene(db, k):
-    if k is None:
-        return None
+def _cell_gene():
     from benchmark.reference import generator
 
-    name = generator.gene_name(k)
-    return Node("Gene", name)
+    return Node("Gene", generator.gene_name(CELL_GENE))
 
 
 def _bio_genes(db, n):
@@ -142,13 +131,13 @@ def _bio_genes(db, n):
 
 
 def q_grounded3(db):
-    g = _gene(db, CELL_GENE)
+    g = _cell_gene()
     return And([_member(g, _v("V3")), _member(_v("V2"), _v("V3")),
                 _interacts(g, _v("V2"))])
 
 
 def q_shared2(db):
-    g = _gene(db, CELL_GENE)
+    g = _cell_gene()
     return And([_member(g, _v("V3")), _member(_v("V2"), _v("V3"))])
 
 
@@ -583,9 +572,6 @@ if __name__ == "__main__":  # regenerate the literal blocks
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
 
-    from das_tpu import kernels
-
-    kernels.interpret_mode = lambda: False
     get = _store_getter(_write_cell_kb(tempfile.mkdtemp(prefix="plan_id")))
     plans = {}
     for case, (store, build) in sorted(CASES.items()):

@@ -18,14 +18,16 @@ import time
 
 import pytest
 
+from tests.conftest import reference_path
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-REFERENCE_CLIENT = "/root/reference/service/client.py"
 HUMAN = "af12f10f9ae2002a1607ba0b47ba8407"
 MAMMAL = "bdfe4e7a431f73386f37c6448afe5840"
 
 
 @pytest.fixture(scope="module")
 def das_server():
+    reference_path("service", "client.py")  # skip before serving
     from das_tpu.service.server import serve
 
     server, service = serve(port=0, backend="tensor", block=False)
@@ -43,7 +45,8 @@ def _client(port, *args, timeout=120):
         COUCHBASE_SETUP_DIR="/tmp",
     )
     proc = subprocess.run(
-        [sys.executable, REFERENCE_CLIENT, "--port", str(port), *args],
+        [sys.executable, reference_path("service", "client.py"),
+         "--port", str(port), *args],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     assert proc.returncode == 0, (proc.stdout + proc.stderr)[-3000:]
@@ -113,7 +116,8 @@ def test_reference_client_full_walkthrough(das_server):
 
 def test_reference_client_invalid_key_fails(das_server):
     env_proc = subprocess.run(
-        [sys.executable, REFERENCE_CLIENT, "--port", str(das_server),
+        [sys.executable, reference_path("service", "client.py"),
+         "--port", str(das_server),
          "count", "--das-key", "nosuchkey"],
         capture_output=True, text=True, timeout=120,
         env={

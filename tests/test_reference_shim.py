@@ -25,9 +25,10 @@ import sys
 
 import pytest
 
+from tests.conftest import reference_path
+
 pytestmark = pytest.mark.full  # heavy block: excluded from `pytest -m quick`
 
-REFERENCE_SCRIPTS = "/root/reference/scripts"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -41,7 +42,7 @@ def _shim_env(**extra):
 
 def _run_reference_script(script, env, timeout=900):
     proc = subprocess.run(
-        [sys.executable, os.path.join(REFERENCE_SCRIPTS, script)],
+        [sys.executable, reference_path("scripts", script)],
         capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
@@ -188,7 +189,7 @@ def test_reference_pattern_matcher_unit_tests_pass(tmp_path):
     resolves to the shim.  A probe asserts that resolution explicitly."""
     import shutil
 
-    src = "/root/reference/das/pattern_matcher/pattern_matcher_test.py"
+    src = reference_path("das", "pattern_matcher", "pattern_matcher_test.py")
     copied = tmp_path / "pattern_matcher_test.py"
     shutil.copyfile(src, copied)
     # probe: the das package under test must be the SHIM, not the reference
@@ -247,7 +248,7 @@ def test_reference_das_integration_tests_pass(
     tensor backend."""
     import shutil
 
-    src = f"/root/reference/das/{fname}"
+    src = reference_path("das", fname)
     copied = tmp_path / fname
     shutil.copyfile(src, copied)
     (tmp_path / "conftest.py").write_text(

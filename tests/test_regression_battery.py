@@ -16,6 +16,7 @@ import pytest
 
 pytestmark = pytest.mark.full  # heavy block: excluded from `pytest -m quick`
 
+from tests.conftest import reference_path
 from tests.test_reference_shim import _shim_env, normalize_regression_output
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -33,7 +34,7 @@ def _run(cmd, env, timeout=900):
 @pytest.fixture(scope="module")
 def reference_blocks():
     out = _run(
-        [sys.executable, "/root/reference/scripts/regression.py"],
+        [sys.executable, reference_path("scripts", "regression.py")],
         _shim_env(DAS_TPU_BACKEND="memory"),
     )
     blocks = normalize_regression_output(out)

@@ -3,8 +3,9 @@
 The CPU suite cannot see what the chip's compiler refuses: r03's
 fori_loop count body died on the chip with "reduce-window ... exceeded
 scoped vmem limit" while the identical program ran everywhere else, and
-every Pallas kernel here passed its interpret-mode tests while Mosaic
-refuses all of them.  The TPU compiler is installed in the sandbox and
+every Pallas kernel the repo once had passed its interpret-mode tests
+while Mosaic refused all of them (they were deleted in PR 31; the
+verdict test is in this file's history).  The TPU compiler is installed in the sandbox and
 compiles for a chip that is described, not attached; these cases hand it
 the programs of the served path at the shapes `chip_smoke.py` runs
 (FlyBase shape x 0.1) — shapes only, nothing executes, no chip needed.
@@ -16,10 +17,6 @@ conftest.py; no child process; all such tests live in THIS one file (a
 second file could land on another xdist worker, whose fixture would then
 skip in silence); the persistent compilation cache is off around the
 compiles (an entry written for a described device cannot be read back).
-
-The Pallas cases pin TODAY'S compiler verdict per kernel.  The PR that
-makes a kernel Mosaic-clean flips its case here and `auto` in
-das_tpu/kernels/__init__.py together (ROADMAP "Mosaic-clean kernels").
 """
 
 import dataclasses
@@ -215,7 +212,7 @@ def test_fused_grounded3_at_smoke_shapes(compile_for_chip, grounded_job,
         grounded_job.plan_sig(),
         term_caps=SMOKE_TERM_CAPS, join_caps=SMOKE_JOIN_CAPS,
     )
-    assert not sig.use_kernels and len(sig.terms) == 3
+    assert len(sig.terms) == 3
     fn, _names = build_fused(sig, count_only)
     compiled = compile_for_chip(fn, *_smoke_shapes(grounded_job))
     assert "tpu_custom_call" not in compiled.as_text()
@@ -268,7 +265,6 @@ def test_fused_group_at_cell1_shapes(compile_for_chip, cell1_jobs, shape,
     want = CELL1_PROGRAMS[shape]
     assert job.plan_sig().index_joins == want["index_joins"]
     sig = dataclasses.replace(job.plan_sig(), **want)
-    assert not sig.use_kernels
     # the lanes' inputs as dispatch_group stacks them: every lane its
     # own gene (the probe key of the grounded terms), the whole-type
     # term's key hoisted
@@ -365,7 +361,7 @@ def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
     job = get_sharded_executor(db)._exec_job(plans, False)
     assert job is not None
     sig = job.plan_sig()
-    assert sig.n_shards == 4 and not sig.use_kernels
+    assert sig.n_shards == 4
     text = _compile_on_described_mesh(
         topo, job, sig, capacity_class(-(-SMOKE_ARITY2_ROWS // 4))
     ).as_text()
@@ -405,92 +401,8 @@ def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
     want = CELL3_PROGRAMS[shape]
     assert job.plan_sig().index_joins == want["index_joins"]
     sig = dataclasses.replace(job.plan_sig(), **want)
-    assert sig.n_shards == 4 and not sig.use_kernels
+    assert sig.n_shards == 4
     per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
     assert per_shard == 2_220_890
     text = _compile_on_described_mesh(topo, job, sig, per_shard).as_text()
     assert "all-gather" in text and "all-reduce" in text
-
-
-# -- the Pallas kernels: today's verdict, pinned --------------------------
-
-
-def _probe(interpret):
-    from das_tpu import kernels
-
-    def f(keys, perm, targets, key, fixed):
-        return kernels.probe_term_table_impl(
-            keys, perm, targets, key, fixed, 4096,
-            var_cols=(1,), eq_pairs=(), extra_fixed=(), interpret=interpret,
-        )
-
-    n = 65_536
-    return f, (_shape((n,), jnp.int64), _shape((n,), jnp.int32),
-               _shape((n, 2), jnp.int32), _shape((), jnp.int64),
-               _shape((0,), jnp.int32))
-
-
-def _join(interpret):
-    from das_tpu import kernels
-
-    def f(lv, lm, rv, rm):
-        return kernels.join_tables_impl(
-            lv, lm, rv, rm, ((0, 0),), (1,), 8192, interpret=interpret
-        )
-
-    return f, (*_table(4096, 2), *_table(4096, 2))
-
-
-def _anti_join(interpret):
-    from das_tpu import kernels
-
-    def f(lv, lm, rv, rm):
-        return kernels.anti_join_impl(
-            lv, lm, rv, rm, ((0, 0),), interpret=interpret
-        )
-
-    return f, (*_table(4096, 2), *_table(4096, 2))
-
-
-def _multiway(interpret):
-    from das_tpu import kernels
-
-    def f(lv, lm, t1v, t1m, t2v, t2m):
-        return kernels.multiway_join_impl(
-            lv, lm, [(t1v, t1m), (t2v, t2m)], 1,
-            ((0, (1,)), (0, (1,))), 8192, interpret=interpret,
-        )
-
-    return f, (*_table(4096, 2), *_table(4096, 2), *_table(4096, 2))
-
-
-#: kernel -> (builder, exception Mosaic raises today, message fragment);
-#: None = the kernel compiles.  JAX 0.9.0, v5e.
-PALLAS_VERDICTS = {
-    "probe": (_probe, RecursionError, "recursion"),
-    "join": (_join, NotImplementedError, "64-bit types are not supported"),
-    "anti_join": (_anti_join, NotImplementedError,
-                  "64-bit types are not supported"),
-    "multiway": (_multiway, NotImplementedError,
-                 "64-bit types are not supported"),
-}
-
-
-@pytest.mark.parametrize("kernel", sorted(PALLAS_VERDICTS))
-def test_pallas_kernel_verdict(compile_for_chip, kernel):
-    """`use_pallas_kernels="on"` on a TPU issues this real pallas_call
-    (interpret=False) and raises what Mosaic raises; `auto` therefore
-    takes the lowered route (kernels.enabled)."""
-    from das_tpu import kernels
-
-    build, exc, fragment = PALLAS_VERDICTS[kernel]
-    fn, shapes = build(False)
-    if exc is None:
-        assert "tpu_custom_call" in compile_for_chip(fn, *shapes).as_text()
-    else:
-        with pytest.raises(exc, match=f"(?i){fragment}"):
-            compile_for_chip(fn, *shapes)
-    # the routing consequence, pinned with the verdicts: while any
-    # kernel is refused, auto resolves to the lowered route
-    assert not kernels.enabled(DasConfig(use_pallas_kernels="auto"))
-    assert kernels.enabled(DasConfig(use_pallas_kernels="on"))

@@ -33,7 +33,8 @@ from pathlib import Path
 
 import pytest
 
-from das_tpu import fault, kernels
+from das_tpu import fault
+from das_tpu.ops import counters
 from das_tpu.analysis import run_analysis
 from das_tpu.api.atomspace import DistributedAtomSpace
 from das_tpu.core.config import DasConfig
@@ -415,11 +416,10 @@ def test_warm_bundle_applies_at_matching_version(tmp_path):
     # planner stats arrived without running anything
     assert rest._rows == est._rows and rest._distinct == est._distinct
     # count-cache entries answer with zero device work
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     plans = [compiler.plan_query(restored, q) for q in queries]
     assert rex.count_batch(plans) == n_counts
-    assert kernels.DISPATCH_COUNTS["count"] == 0
-    assert kernels.DISPATCH_COUNTS["count_kernel"] == 0
+    assert counters.DISPATCH_COUNTS["count"] == 0
     das2 = DistributedAtomSpace(database_name="zdur_warm_r", db=restored)
     assert _answers(das2, queries) == baseline
 
@@ -444,19 +444,19 @@ def test_warm_restore_zero_capacity_retries(tmp_path):
              True),
         Link("Member", [Variable("G"), Variable("P2")], True),
     ])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     answer = das.query(q)  # learns the capacity the greedy seed missed
-    cold_programs = kernels.DISPATCH_COUNTS["fused"]
-    assert cold_programs >= 2, kernels.DISPATCH_COUNTS
+    cold_programs = counters.DISPATCH_COUNTS["fused"]
+    assert cold_programs >= 2, counters.DISPATCH_COUNTS
     durable.write_snapshot(db, root)
 
     restored = TensorDB.restore(root, DasConfig(use_planner="off"))
     das2 = DistributedAtomSpace(database_name="zdur_caps_r", db=restored)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     assert das2.query(q) == answer
-    assert kernels.DISPATCH_COUNTS["fused"] == 1, (
+    assert counters.DISPATCH_COUNTS["fused"] == 1, (
         "restored replica was expected to settle in round 0 on the "
-        f"bundled caps; dispatches={kernels.DISPATCH_COUNTS}"
+        f"bundled caps; dispatches={counters.DISPATCH_COUNTS}"
     )
 
     # control: a cold replica from the same records (no bundle) still
@@ -465,9 +465,9 @@ def test_warm_restore_zero_capacity_retries(tmp_path):
         durable.list_generations(root)[-1][1], _verified=True
     ), DasConfig(use_planner="off"))
     das3 = DistributedAtomSpace(database_name="zdur_caps_c", db=cold)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     assert das3.query(q) == answer
-    assert kernels.DISPATCH_COUNTS["fused"] >= 2
+    assert counters.DISPATCH_COUNTS["fused"] >= 2
 
 
 # -- round trip + disabled-path identity ---------------------------------

@@ -165,7 +165,7 @@ def test_spans_nest_and_order(traced):
                 "delta_version", "speculative", "traces"):
         assert key in disp[8], disp[8]
     # executor attributes: route + planner estimates
-    assert e[8]["route"] in ("fused", "fused_kernel", "fused_multiway")
+    assert e[8]["route"] == "fused"
     assert "est_join_rows" in e[8]
 
 

@@ -25,7 +25,7 @@ from concurrent.futures import Future
 
 import pytest
 
-from das_tpu import kernels
+from das_tpu.ops import counters
 from das_tpu.api.atomspace import DistributedAtomSpace
 from das_tpu.core.config import DasConfig
 from das_tpu.models.animals import animals_metta
@@ -74,14 +74,13 @@ def test_cache_hit_issues_zero_device_programs():
     ex = fused.get_executor(db)
     assert ex.results.stats["misses"] >= 1
 
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = das.query_many([q, q])
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches, "cache hit paid a host fetch"
-    assert kernels.DISPATCH_COUNTS["fused"] == 0, kernels.DISPATCH_COUNTS
-    assert kernels.DISPATCH_COUNTS["kernel"] == 0
-    assert kernels.DISPATCH_COUNTS["lowered"] == 0
+    assert counters.DISPATCH_COUNTS["fused"] == 0, counters.DISPATCH_COUNTS
+    assert counters.DISPATCH_COUNTS["lowered"] == 0
 
 
 def test_cache_disabled_by_zero_size():
@@ -90,27 +89,27 @@ def test_cache_disabled_by_zero_size():
     das.query_many([q, q])
     ex = fused.get_executor(db)
     assert ex.results.stats["hits"] == 0
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     das.query_many([q])
-    assert kernels.DISPATCH_COUNTS["fused"] >= 1
+    assert counters.DISPATCH_COUNTS["fused"] >= 1
 
 
 def test_single_execute_stays_uncached_by_default():
-    """test_zkernels' dispatch-count pins rely on bare execute() timing
+    """The dispatch-count pins rely on bare execute() timing
     the device — the cache must be opt-in there."""
     das, db = _tensor_das()
     plans = compiler.plan_query(db, _pair_query())
     ex = fused.get_executor(db)
     assert ex.execute(plans, count_only=True) is not None
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     assert ex.execute(plans, count_only=True) is not None
-    assert kernels.DISPATCH_COUNTS["fused"] == 1
+    assert counters.DISPATCH_COUNTS["fused"] == 1
 
     # ... and the opt-in flag caches: second call is dispatch-free
     assert ex.execute(plans, count_only=True, use_cache=True) is not None
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     assert ex.execute(plans, count_only=True, use_cache=True) is not None
-    assert kernels.DISPATCH_COUNTS["fused"] == 0
+    assert counters.DISPATCH_COUNTS["fused"] == 0
 
 
 def test_cache_invalidation_across_commit_tensor():
@@ -192,14 +191,14 @@ def test_pipelined_matches_serial_answers_and_program_count():
     # batches of ONE: a same-shape batch of two is one group program
     # (ISSUE 30), and how a backlog splits into batches is timing
     serial = QueryCoalescer(max_batch=1, pipeline_depth=1)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     serial_answers = _drive(serial, tenant, [grounded(c) for c in concepts])
-    serial_programs = kernels.DISPATCH_COUNTS["fused"]
+    serial_programs = counters.DISPATCH_COUNTS["fused"]
 
     piped = QueryCoalescer(max_batch=1, pipeline_depth=2)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     piped_answers = _drive(piped, tenant, [grounded(c) for c in concepts])
-    piped_programs = kernels.DISPATCH_COUNTS["fused"]
+    piped_programs = counters.DISPATCH_COUNTS["fused"]
 
     assert piped_answers == serial_answers
     assert serial_programs == len(concepts)  # cache really was off
@@ -410,16 +409,16 @@ def test_speculative_pipeline_matches_serial_program_count():
     das.query_many([grounded(c) for c in concepts])  # warm compile + caps
 
     serial = QueryCoalescer(max_batch=1, pipeline_depth=1)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     serial_answers = _drive(serial, tenant, [grounded(c) for c in concepts])
-    serial_programs = kernels.DISPATCH_COUNTS["fused"]
+    serial_programs = counters.DISPATCH_COUNTS["fused"]
 
     # pre-queue the whole backlog so the depth-3 window actually fills
     # (submissions racing the worker could otherwise keep it starved)
     spec = QueryCoalescer(
         max_batch=1, pipeline_depth=3, pipeline_depth_max=6
     )
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     futs = []
     for c in concepts:
         f = Future()
@@ -427,7 +426,7 @@ def test_speculative_pipeline_matches_serial_program_count():
         futs.append(f)
     spec._ensure_worker()
     spec_answers = [f.result(timeout=60) for f in futs]
-    spec_programs = kernels.DISPATCH_COUNTS["fused"]
+    spec_programs = counters.DISPATCH_COUNTS["fused"]
 
     assert spec_answers == serial_answers
     assert serial_programs == len(concepts)  # cache really was off

@@ -27,10 +27,11 @@ import dataclasses
 
 import pytest
 
-from das_tpu import kernels, planner
+from das_tpu import planner
 from das_tpu.api.atomspace import DistributedAtomSpace
 from das_tpu.core.config import DasConfig
 from das_tpu.models.bio import build_bio_atomspace
+from das_tpu.ops import counters
 from das_tpu.planner.stats import estimator_for
 from das_tpu.query import compiler, fused
 from das_tpu.query.ast import And, Link, Node, Not, Or, Variable
@@ -202,22 +203,22 @@ def test_costed_capacity_settles_round0_greedy_retries(monkeypatch):
         data, DasConfig(use_planner="off"), monkeypatch
     )
     q = _fanout_query(db_off)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     off_answer = das_off.query(q)
-    greedy_programs = kernels.DISPATCH_COUNTS["fused"]
+    greedy_programs = counters.DISPATCH_COUNTS["fused"]
     assert greedy_programs >= 2, (
         "greedy was expected to pay a capacity retry on this shape; "
-        f"dispatches={kernels.DISPATCH_COUNTS}"
+        f"dispatches={counters.DISPATCH_COUNTS}"
     )
 
     das_on, db_on = _tensor_das(
         data, DasConfig(use_planner="on"), monkeypatch
     )
     planner.reset_planner_counts()
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     on_answer = das_on.query(q)
-    planner_programs = kernels.DISPATCH_COUNTS["fused"]
-    assert planner_programs == 1, kernels.DISPATCH_COUNTS
+    planner_programs = counters.DISPATCH_COUNTS["fused"]
+    assert planner_programs == 1, counters.DISPATCH_COUNTS
     assert planner_programs < greedy_programs  # the acceptance criterion
     assert planner.PLANNER_COUNTS["round0"] >= 1
     assert planner.PLANNER_COUNTS["retries"] == 0
@@ -254,9 +255,9 @@ def test_shrunk_capacity_config_no_guaranteed_retry(monkeypatch):
         "the configured clamp must not force a seed below the exact "
         f"grounded rows: seed={seed} rows={grounded_rows}"
     )
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     das.query(q)
-    assert kernels.DISPATCH_COUNTS["fused"] == 1, kernels.DISPATCH_COUNTS
+    assert counters.DISPATCH_COUNTS["fused"] == 1, counters.DISPATCH_COUNTS
 
 
 # -- estimator invalidation on commit ------------------------------------
@@ -336,7 +337,7 @@ def test_explain_estimates_vs_actuals(monkeypatch):
     q = _fanout_query(db)
     out = das.explain(q, execute=True)
     assert out["planned"] is True
-    assert out["route"] in ("fused", "fused_kernel")
+    assert out["route"] == "fused"
     assert out["method"] in ("ref_order", "dp", "greedy_tail")
     assert len(out["order"]) == 2
     assert len(out["est_join_rows"]) == 1
@@ -366,7 +367,7 @@ def test_explain_tree_reports_sites(monkeypatch):
     assert out["anti_after_union"] is False
     assert len(out["est_site_rows"]) == 2
     for s in out["sites"]:
-        assert s["route"] in ("fused", "fused_kernel")
+        assert s["route"] == "fused"
         if s["planned"]:
             assert "est_term_rows" in s
     # with fusion off the per-site tree rendering survives unchanged
@@ -496,3 +497,131 @@ def test_dp_max_env_clamps_search(monkeypatch):
     assert search.dp_max() == search.DEFAULT_DP_MAX
     monkeypatch.delenv("DAS_TPU_PLANNER_DP_MAX")
     assert search.dp_max() == search.DEFAULT_DP_MAX
+
+
+# -- star prefixes: the chain's deeper seeds are the exact k-way figure --
+
+
+def _star3():
+    return And([
+        Link("Member", [Variable("V1"), Variable("V3")], True),
+        Link("Member", [Variable("V2"), Variable("V3")], True),
+        Link("Member", [Variable("V4"), Variable("V3")], True),
+    ])
+
+
+def _skew_kb():
+    """120 genes x 3 memberships over 40 processes at skew 1.1: hub
+    processes own degrees far above the median.  The chain's FIRST
+    intermediate seeds exactly (pairwise degree dot), but under the
+    independence model its SECOND would not — Σ deg³ concentrates on
+    the hubs far past est × CAP_MARGIN, a guaranteed retry tier."""
+    data, _g, _p = build_bio_atomspace(
+        n_genes=120, n_processes=40, members_per_gene=3,
+        n_interactions=0, seed=17, skew=1.1,
+    )
+    return data
+
+
+def _star_env(monkeypatch):
+    monkeypatch.setenv("DAS_TPU_XLA_CACHE", "0")
+    monkeypatch.delenv("DAS_TPU_PLANNER", raising=False)
+
+
+def test_chain_star_seeds_settle_round0(monkeypatch):
+    """The deeper star-prefix intermediates of a chain seed from the
+    exact `stats.star_rows` k-way statistic instead of the independence
+    model: the skew-heavy star settles in ONE program, where the blind
+    legacy seeds (planner off) pay a retry tier."""
+    _star_env(monkeypatch)
+    # off the closed-form star counter: the executors' capacities (the
+    # thing under test) only engage on the fused count path
+    monkeypatch.setenv("DAS_TPU_STAR", "0")
+    data = _skew_kb()
+    q = _star3()
+
+    db_blind = TensorDB(data, DasConfig(use_planner="off"))
+    counters.reset_dispatch_counts()
+    n_blind = compiler.count_matches(db_blind, q)
+    assert counters.DISPATCH_COUNTS["fused"] >= 2, (
+        "the blind seeds were expected to pay a capacity-retry tier on "
+        f"this skew shape; dispatches={counters.DISPATCH_COUNTS}"
+    )
+
+    db = TensorDB(data, DasConfig())
+    plans = compiler.plan_query(db, q)
+    exact_rows, exact = estimator_for(db).star_rows(plans, "V3")
+    assert exact
+    planned = planner.plan_conjunction(db, plans)
+    assert planned is not None and planned.route == "fused"
+    # the DEEPER seed (second intermediate) bounds the exact k-way
+    # figure — the independence model sat far under it on this skew
+    assert planned.join_cap_seeds[1] >= exact_rows
+    assert planned.est_join_rows[1] == int(exact_rows)
+
+    planner.reset_planner_counts()
+    counters.reset_dispatch_counts()
+    assert compiler.count_matches(db, q) == n_blind
+    assert counters.DISPATCH_COUNTS["fused"] == 1, counters.DISPATCH_COUNTS
+    assert planner.PLANNER_COUNTS["round0"] >= 1
+    assert planner.PLANNER_COUNTS["retries"] == 0
+    assert planner.snapshot()["actual_vs_est_ratio"] == 1.0
+
+
+def test_shrunk_capacity_cannot_clamp_star_seed(monkeypatch):
+    """An operator-shrunk initial_result_capacity must not clamp a star
+    prefix's seeds below the exact k-way intersection bound
+    (stats.star_rows) — that would be a GUARANTEED retry round, the
+    bug class the PR-8 `_join_cap_seed` fix closed for binary joins."""
+    _star_env(monkeypatch)
+    data, _g, _p = _bio_data(
+        n_genes=50, n_processes=10, members_per_gene=3, n_interactions=0,
+        seed=5,
+    )
+    cfg = DasConfig(initial_result_capacity=64)
+    db = TensorDB(data, cfg)
+    das = DistributedAtomSpace(database_name="zstar_seed", db=db)
+    q = _star3()
+    plans = compiler.plan_query(db, q)
+    exact_rows, exact = estimator_for(db).star_rows(plans, "V3")
+    assert exact and exact_rows > cfg.initial_result_capacity  # bug setup
+    planned = planner.plan_conjunction(db, plans)
+    assert planned is not None
+    assert planned.join_cap_seeds[-1] >= exact_rows, (
+        "the configured clamp must not force the star seed under the "
+        f"exact bound: seed={planned.join_cap_seeds[-1]} rows={exact_rows}"
+    )
+    counters.reset_dispatch_counts()
+    das.query(q)
+    assert counters.DISPATCH_COUNTS["fused"] == 1, counters.DISPATCH_COUNTS
+
+
+def test_star_rows_exact_vs_brute_force(monkeypatch):
+    """stats.star_rows == the brute-force Σ_v Π_j deg_j(v) over the
+    support intersection, memoized on the second call."""
+    from collections import Counter
+
+    import numpy as np
+
+    from das_tpu.storage.atom_table import host_segments
+
+    _star_env(monkeypatch)
+    data, _g, _p = _bio_data(
+        n_genes=40, n_processes=12, members_per_gene=3, n_interactions=0,
+        seed=9,
+    )
+    db = TensorDB(data, DasConfig())
+    plans = compiler.plan_query(db, _star3())
+    est = estimator_for(db)
+    rows, exact = est.star_rows(plans, "V3")
+    assert exact
+    deg = Counter()
+    p0 = plans[0]
+    vcol = p0.var_cols[p0.var_names.index("V3")]
+    for b in host_segments(db, p0.arity):
+        lo = int(np.searchsorted(b.key_type, np.int32(p0.type_id), "left"))
+        hi = int(np.searchsorted(b.key_type, np.int32(p0.type_id), "right"))
+        for r in np.asarray(b.order_by_type[lo:hi]):
+            deg[int(b.targets[r, vcol])] += 1
+    assert int(rows) == sum(d ** 3 for d in deg.values())
+    assert est.star_rows(plans, "V3") == (rows, True)

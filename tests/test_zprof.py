@@ -17,10 +17,6 @@ prof`):
   * the acceptance pin: the bio 3-var query under the coalescer yields
     a ledger entry with compile wall time + cost/memory analysis, and
     `explain(compile=True)` renders it by digest;
-  * byte-model calibration sanity on the interpreter: a kernel-routed
-    program records modeled_bytes > 0 and a finite positive
-    budget_vs_actual_ratio (the CPU ratio is a sanity signal — the
-    calibration CONTRACT is for TPU runs, ARCHITECTURE §15);
   * cold-start accounting: a persistent-XLA-cache-served compile is
     classified as a hit and excluded from cold_start_s;
   * scripts/bench_diff.py: the committed trajectory passes its own
@@ -115,7 +111,7 @@ def test_disabled_workload_records_nothing():
     snap = proflog.snapshot()
     assert snap["enabled"] is False
     assert snap["compiles"] == 0 and snap["entries"] == 0
-    assert snap["launches"] == 0 and snap["calls"] == 0
+    assert snap["calls"] == 0
 
 
 # -- ledger lifecycle ------------------------------------------------------
@@ -194,43 +190,6 @@ def test_count_batch_site_records(ledger):
     assert rows and rows[0]["compiles"] >= 1
 
 
-def test_kernel_launch_notes(ledger, monkeypatch):
-    monkeypatch.setenv("DAS_TPU_PALLAS", "on")
-    das, _db = _tensor_das()
-    ok, ans = das.query_answer(_inherit_query())
-    assert ok and ans.assignments
-    rows = proflog.rows(site="kernel")
-    assert rows, "kernel-routed program must note its launches"
-    assert all(r["kind"] in ("pallas", "discharge") for r in rows)
-    assert sum(r["launches"] for r in rows) >= 1
-    assert proflog.snapshot()["launches"] >= 1
-    # trace wall is kept APART from compile seconds (honesty: tracing
-    # is host cost, not XLA compile)
-    assert all(r["compile_s"] == 0.0 for r in rows)
-
-
-# -- byte-model calibration ------------------------------------------------
-
-
-def test_budget_vs_actual_ratio_sanity(ledger, monkeypatch):
-    """Interpreter-sanity pin for the §15 calibration contract: a
-    kernel-routed program records the modeled combined footprint the
-    route gate used and a finite positive ratio against the XLA
-    allocation."""
-    monkeypatch.setenv("DAS_TPU_PALLAS", "on")
-    das, _db = _tensor_das()
-    ok, _ans = das.query_answer(_inherit_query())
-    assert ok
-    (row,) = proflog.rows(site="fused")
-    assert row["modeled_bytes"] and row["modeled_bytes"] > 0
-    ratio = row["budget_vs_actual_ratio"]
-    assert ratio is not None and 0 < ratio < 1e6
-    snap = proflog.snapshot()
-    assert snap["budget_vs_actual"].get("fused") == pytest.approx(
-        ratio, rel=1e-6
-    )
-
-
 # -- acceptance: bio 3-var under the coalescer + explain(compile=True) -----
 
 
@@ -265,7 +224,7 @@ def test_bio_three_var_coalescer_and_explain_compile(ledger):
     assert comp["rows"][0]["digest"] == comp["digest"]
     for col in ("site", "compiles", "compile_s", "flops",
                 "bytes_accessed", "arg_bytes", "out_bytes", "temp_bytes",
-                "peak_bytes", "budget_vs_actual_ratio"):
+                "peak_bytes"):
         assert col in comp["rows"][0]
     # compile=True implies execute: the actual block rides along
     assert out["actual"]["count"] is not None
@@ -342,8 +301,7 @@ def test_programs_in_service_stats_and_prometheus(ledger):
     stats = svc.coalescer_stats()
     progs = stats["programs"]
     for key in ("enabled", "compiles", "compile_s", "hit_rate",
-                "cold_start_s", "persistent_cache_hits",
-                "budget_vs_actual"):
+                "cold_start_s", "persistent_cache_hits"):
         assert key in progs
     text = svc.metrics_text()
     assert "das_tpu_obs_programs_compiles" in text
@@ -494,7 +452,6 @@ def test_dl016_catches_deleted_hook_on_real_builder(tmp_path):
         "    return obs.proflog.instrument(\n"
         '        "fused", obs.proflog.sig_digest(sig, count_only),\n'
         '        jax.jit(obs.named_program("das_fused", fn, count_only)),\n'
-        "        model_bytes=partial(program_model_bytes, sig),\n"
         "    ), names"
     )
     assert src.count(needle) == 1, "fused.py build_fused layout changed"
@@ -547,6 +504,4 @@ def test_program_sites_registry_pinned():
         "fused.FusedExecutor.build_count_loop": "count_loop",
         "fused_sharded._ShardedExecJob.dispatch": "sharded",
         "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
-        "common.run_kernel": "kernel",
-        "common.run_grid_kernel": "kernel_grid",
     }

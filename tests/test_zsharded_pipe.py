@@ -8,27 +8,21 @@ Pins, in one place (markers `sharded` + `pipeline`, standalone via
     program counts and identical answers on a ShardedDB tenant;
   * a repeated mesh query through the serving path is a pure host dict
     lookup — zero shard_map programs, zero host fetches;
-  * the sharded kernel route (ShardedPlanSig.use_kernels) produces
-    BIT-IDENTICAL binding tables vs the lowered shard-local bodies, with
-    pinned dispatch counts (sharded=1 program per query, sharded_kernel
-    counting the kernel-routed subset);
   * the widened ResultCache scope: tree-composite entries (query/tree.py)
     and count-batch entries (query/fused.py count_batch) hit at zero
     device dispatches and invalidate exactly on commit — on TensorDB and
     (tree path) on ShardedDB.
 
 Compile-budget note (ROADMAP tier-1): every query here reuses a handful
-of fixed plan shapes on the small animals KB — no per-test interpret-mode
-compiles (off-TPU the kernel route runs by direct discharge).
+of fixed plan shapes on the small animals KB.
 """
 
 import threading
 from concurrent.futures import Future
 
-import numpy as np
 import pytest
 
-from das_tpu import kernels
+from das_tpu.ops import counters
 from das_tpu.api.atomspace import DistributedAtomSpace, QueryOutputFormat
 from das_tpu.core.config import DasConfig
 from das_tpu.models.animals import animals_metta
@@ -120,14 +114,14 @@ def test_mesh_pipelined_matches_serial_answers_and_program_count(env):
         das.query_many(queries)  # warm compile + caps
 
         serial = QueryCoalescer(max_batch=2, pipeline_depth=1)
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         serial_answers = _drive(serial, tenant, queries)
-        serial_programs = kernels.DISPATCH_COUNTS["sharded"]
+        serial_programs = counters.DISPATCH_COUNTS["sharded"]
 
         piped = QueryCoalescer(max_batch=2, pipeline_depth=2)
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         piped_answers = _drive(piped, tenant, queries)
-        piped_programs = kernels.DISPATCH_COUNTS["sharded"]
+        piped_programs = counters.DISPATCH_COUNTS["sharded"]
     finally:
         db.config.result_cache_size = prev
 
@@ -163,12 +157,12 @@ def test_mesh_query_many_cache_hit_zero_programs(env):
     das, db = env
     q = _pair_query()
     first = das.query_many([q, q])  # one program: in-batch dedup aliases
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = das.query_many([q, q])
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches, "mesh cache hit paid a fetch"
-    assert kernels.DISPATCH_COUNTS["sharded"] == 0, kernels.DISPATCH_COUNTS
+    assert counters.DISPATCH_COUNTS["sharded"] == 0, counters.DISPATCH_COUNTS
 
 
 def test_mesh_commit_invalidates_serving_cache():
@@ -181,67 +175,6 @@ def test_mesh_commit_invalidates_serving_cache():
     after = das.query_many([q])
     assert after != before
     assert after == [das.query(q)]  # post-commit ground truth
-
-
-# -- sharded kernel route -------------------------------------------------
-
-
-def test_sharded_kernel_route_bit_identical_with_pinned_dispatches(env):
-    """Fixed fuzz shape-combos (grounded pair, ungrounded chain, negation)
-    through the SAME executor: the kernel-routed shard_map program must
-    return bit-identical binding tables and counts vs the lowered one,
-    each answered in exactly ONE sharded program."""
-    from das_tpu.parallel.fused_sharded import get_sharded_executor
-
-    das, db = env
-    ex = get_sharded_executor(db)
-    combos = [_pair_query(), _pair_query("animal"), _chain_query(), _neg_query()]
-    prev = db.config.use_pallas_kernels
-    try:
-        for qi, q in enumerate(combos):
-            plans = compiler.plan_query(db, q)
-            assert plans is not None
-
-            db.config.use_pallas_kernels = "off"
-            ex.execute(plans)  # warm caps so the pinned runs are 1 dispatch
-            kernels.reset_dispatch_counts()
-            low = ex.execute(plans)
-            assert kernels.DISPATCH_COUNTS["sharded"] == 1, (qi, kernels.DISPATCH_COUNTS)
-            assert kernels.DISPATCH_COUNTS["sharded_kernel"] == 0
-
-            db.config.use_pallas_kernels = "on"
-            kernels.reset_dispatch_counts()
-            ker = ex.execute(plans)
-            assert kernels.DISPATCH_COUNTS["sharded"] == 1, (qi, kernels.DISPATCH_COUNTS)
-            assert kernels.DISPATCH_COUNTS["sharded_kernel"] == 1
-
-            assert ker.count == low.count, qi
-            assert ker.var_names == low.var_names, qi
-            assert np.array_equal(np.asarray(ker.valid), np.asarray(low.valid)), qi
-            assert np.array_equal(np.asarray(ker.vals), np.asarray(low.vals)), qi
-    finally:
-        db.config.use_pallas_kernels = prev
-
-
-def test_sharded_kernel_route_counts_in_dispatch(env):
-    """ROUTE_COUNTS gains the sharded_kernel route: a mesh query answered
-    with the kernel route enabled counts under both sharded and
-    sharded_kernel (the fused/fused_kernel convention)."""
-    das, db = env
-    prev = db.config.use_pallas_kernels
-    try:
-        db.config.use_pallas_kernels = "on"
-        compiler.reset_route_counts()
-        das.query(_pair_query("reptile"))
-        assert compiler.ROUTE_COUNTS["sharded"] == 1
-        assert compiler.ROUTE_COUNTS["sharded_kernel"] == 1
-        db.config.use_pallas_kernels = "off"
-        compiler.reset_route_counts()
-        das.query(_pair_query("plant"))
-        assert compiler.ROUTE_COUNTS["sharded"] == 1
-        assert compiler.ROUTE_COUNTS["sharded_kernel"] == 0
-    finally:
-        db.config.use_pallas_kernels = prev
 
 
 # -- widened result-cache scope: tree composites --------------------------
@@ -260,12 +193,12 @@ def test_tree_composite_cache_hit_zero_dispatch_tensor():
     ex = fused.get_executor(db)
     assert ex.tree_results.stats["misses"] >= 1
 
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = das.query(q)
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches, "tree hit paid a host fetch"
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0, kernels.DISPATCH_COUNTS
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0, counters.DISPATCH_COUNTS
     assert ex.tree_results.stats["hits"] >= 1
 
     # commit invalidation: platypus→mammal lands in the Or's answer set
@@ -284,12 +217,12 @@ def test_tree_composite_cache_sharded_unordered(env):
     q = Link("Similarity", [Variable("$1"), Node("Concept", "human")], False)
     first = das.query(q)
     ex = db.tables._fused_executor
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = das.query(q)
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0, kernels.DISPATCH_COUNTS
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0, counters.DISPATCH_COUNTS
     assert ex.tree_results.stats["hits"] >= 1
 
 
@@ -305,40 +238,18 @@ def test_count_batch_cache_hit_and_commit_invalidation():
     first = ex.count_batch(plans_list)
     assert all(n is not None for n in first)
 
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = ex.count_batch(plans_list)
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches, "count hit paid a device fetch"
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0, kernels.DISPATCH_COUNTS
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0, counters.DISPATCH_COUNTS
 
     das.load_metta_text(COMMIT)  # platypus→chimp→mammal: +1 pair
     after = ex.count_batch(
         [compiler.plan_query(db, _pair_query(c)) for c in ("mammal", "animal")]
     )
     assert after[0] == first[0] + 1, (first, after)
-
-
-def test_count_batch_kernel_route_parity():
-    """count_many's vmapped group programs route through the kernels
-    behind use_pallas_kernels: identical counts, count_kernel telemetry in
-    ROUTE_COUNTS and DISPATCH_COUNTS."""
-    das, db = _tensor_das(DasConfig(result_cache_size=0))
-    ex = fused.get_executor(db)
-    queries = [_pair_query(c) for c in ("mammal", "animal", "reptile")]
-    plans_of = lambda: [compiler.plan_query(db, q) for q in queries]  # noqa: E731
-
-    db.config.use_pallas_kernels = "off"
-    lowered = ex.count_batch(plans_of())
-
-    db.config.use_pallas_kernels = "on"
-    compiler.reset_route_counts()
-    kernels.reset_dispatch_counts()
-    kerneled = ex.count_batch(plans_of())
-    assert kerneled == lowered
-    assert compiler.ROUTE_COUNTS["count_kernel"] == len(queries)
-    assert kernels.DISPATCH_COUNTS["count_kernel"] >= 1
-    assert kernels.DISPATCH_COUNTS["count"] == kernels.DISPATCH_COUNTS["count_kernel"]
 
 
 def test_miner_count_many_rides_the_caches():
@@ -350,12 +261,12 @@ def test_miner_count_many_rides_the_caches():
     miner = PatternMiner(db)
     queries = [_pair_query("mammal"), _pair_query("animal")]
     first = miner.count_many(queries)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     fetches = fused.FETCH_COUNTS["n"]
     again = miner.count_many(queries)
     assert again == first
     assert fused.FETCH_COUNTS["n"] == fetches
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0, kernels.DISPATCH_COUNTS
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0, counters.DISPATCH_COUNTS
 
 
 # -- serving stats --------------------------------------------------------
@@ -376,7 +287,7 @@ def test_service_stats_surface_sharded_and_tenants(env):
         )
         assert reply["success"], reply["msg"]
     stats = service.coalescer_stats()
-    assert "sharded" in stats["routes"] and "sharded_kernel" in stats["routes"]
+    assert "sharded" in stats["routes"]
     assert stats["routes"]["sharded"] >= 1
     per = stats["tenants"]["zsp_stats"]
     assert per["items"] >= 3
@@ -404,16 +315,16 @@ def test_mesh_speculative_dispatch_keeps_program_count(env):
         das.query_many(queries)  # warm compile + caps
 
         serial = QueryCoalescer(max_batch=1, pipeline_depth=1)
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         serial_answers = _drive(serial, tenant, queries)
-        serial_programs = kernels.DISPATCH_COUNTS["sharded"]
+        serial_programs = counters.DISPATCH_COUNTS["sharded"]
 
         # pre-queue the backlog so the window actually fills past one
         # unsettled group (speculation), then drain
         spec = QueryCoalescer(
             max_batch=1, pipeline_depth=3, pipeline_depth_max=6
         )
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         futs = []
         for q in queries:
             f = Future()
@@ -421,7 +332,7 @@ def test_mesh_speculative_dispatch_keeps_program_count(env):
             futs.append(f)
         spec._ensure_worker()
         spec_answers = [f.result(timeout=120) for f in futs]
-        spec_programs = kernels.DISPATCH_COUNTS["sharded"]
+        spec_programs = counters.DISPATCH_COUNTS["sharded"]
     finally:
         db.config.result_cache_size = prev
 

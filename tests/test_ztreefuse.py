@@ -28,7 +28,7 @@ import dataclasses
 
 import pytest
 
-from das_tpu import kernels
+from das_tpu.ops import counters
 from das_tpu.api.atomspace import DistributedAtomSpace
 from das_tpu.core.config import DasConfig
 from das_tpu.models.bio import build_bio_atomspace
@@ -121,9 +121,9 @@ def test_tree_fused_bit_identical_tensor(monkeypatch):
     names = db_on.get_all_nodes("Gene", names=True)[:3]
     fused_answers = 0
     for q in _suite(names):
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         m_on, a_on = das_on.query_answer(q)
-        fused_answers += kernels.DISPATCH_COUNTS["fused_tree"]
+        fused_answers += counters.DISPATCH_COUNTS["fused_tree"]
         m_off, a_off = das_off.query_answer(q)
         assert m_on == m_off
         assert a_on.assignments == a_off.assignments, q
@@ -144,9 +144,9 @@ def test_tree_fused_bit_identical_sharded(monkeypatch):
     names = db_on.get_all_nodes("Gene", names=True)[:3]
     fused_answers = 0
     for q in _suite(names):
-        kernels.reset_dispatch_counts()
+        counters.reset_dispatch_counts()
         m_on, a_on = das_on.query_answer(q)
-        fused_answers += kernels.DISPATCH_COUNTS["sharded_tree_fused"]
+        fused_answers += counters.DISPATCH_COUNTS["sharded_tree_fused"]
         m_off, a_off = das_off.query_answer(q)
         assert m_on == m_off
         assert a_on.assignments == a_off.assignments, q
@@ -164,12 +164,12 @@ def test_three_branch_or_one_program(monkeypatch):
     )
     names = db_off.get_all_nodes("Gene", names=True)[:3]
     q = Or([_branch(g) for g in names])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     m_off, a_off = das_off.query_answer(q)
-    tree_programs = kernels.DISPATCH_COUNTS["fused"]
+    tree_programs = counters.DISPATCH_COUNTS["fused"]
     assert tree_programs >= 3, (
         "the tree executor pays one fused program per Or branch; "
-        f"dispatches={kernels.DISPATCH_COUNTS}"
+        f"dispatches={counters.DISPATCH_COUNTS}"
     )
 
     das_on, _db = _tensor_das(
@@ -178,18 +178,18 @@ def test_three_branch_or_one_program(monkeypatch):
     from das_tpu.query import compiler as qc
 
     qc.reset_route_counts()
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     m_on, a_on = das_on.query_answer(q)
-    assert kernels.DISPATCH_COUNTS["fused_tree"] == 1, (
-        kernels.DISPATCH_COUNTS
+    assert counters.DISPATCH_COUNTS["fused_tree"] == 1, (
+        counters.DISPATCH_COUNTS
     )
-    assert kernels.DISPATCH_COUNTS["fused"] == 0  # no per-site programs
+    assert counters.DISPATCH_COUNTS["fused"] == 0  # no per-site programs
     assert 1 < tree_programs  # the acceptance criterion
     assert m_on == m_off and a_on.assignments == a_off.assignments
     # per-ANSWER route telemetry: ONE fused_tree answer, and the site
-    # jobs must not leak per-site route counts (count_route=False)
+    # jobs count no route of their own
     assert qc.ROUTE_COUNTS["fused_tree"] == 1
-    assert qc.ROUTE_COUNTS["fused_multiway"] == 0
+    assert qc.ROUTE_COUNTS["fused"] == 0
 
 
 # -- fallback on shapes outside the homogeneous subset -------------------
@@ -216,9 +216,9 @@ def test_unordered_shapes_fall_back(monkeypatch, animals_data):
         Link("Similarity", [Node("Concept", "human"), Variable("V1")],
              False),
     ])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     m_on, a_on = das_on.query_answer(q)
-    assert kernels.DISPATCH_COUNTS["fused_tree"] == 0
+    assert counters.DISPATCH_COUNTS["fused_tree"] == 0
     m_off, a_off = das_off.query_answer(q)
     assert m_on == m_off
     assert a_on.assignments == a_off.assignments
@@ -239,9 +239,9 @@ def test_heterogeneous_universe_falls_back(monkeypatch):
         _branch(names[0]),  # binds {V2, V3}
         Link("Interacts", [Node("Gene", names[1]), Variable("V5")], True),
     ])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     m_on, a_on = das_on.query_answer(q)
-    assert kernels.DISPATCH_COUNTS["fused_tree"] == 0
+    assert counters.DISPATCH_COUNTS["fused_tree"] == 0
     m_off, a_off = das_off.query_answer(q)
     assert m_on == m_off
     assert a_on.assignments == a_off.assignments
@@ -262,10 +262,10 @@ def test_sharded_tree_fallback_mode_gates_fusion(monkeypatch):
     # a negated Or dodges the per-branch decomposition: in "host" mode
     # it must reach the host algebra with zero mesh tree programs
     q = Or([_branch(names[0]), Not(_branch(names[1]))])
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     m, a = das.query_answer(q)
-    assert kernels.DISPATCH_COUNTS["sharded_tree_fused"] == 0, (
-        kernels.DISPATCH_COUNTS
+    assert counters.DISPATCH_COUNTS["sharded_tree_fused"] == 0, (
+        counters.DISPATCH_COUNTS
     )
     das_mesh, _db2 = _sharded_das(
         data, DasConfig(use_tree_fusion="on"), monkeypatch, "ztfs_mesh"
@@ -285,9 +285,9 @@ def test_tree_fused_cache_hit_and_commit_invalidation(monkeypatch):
     names = db.get_all_nodes("Gene", names=True)[:3]
     q = Or([_branch(names[0]), Not(_branch(names[1]))])
     _m1, a1 = das.query_answer(q)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     _m2, a2 = das.query_answer(q)
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0, (
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0, (
         "a fused-tree cache hit must issue ZERO device programs"
     )
     assert a2.assignments == a1.assignments
@@ -301,9 +301,9 @@ def test_tree_fused_cache_hit_and_commit_invalidation(monkeypatch):
         + f'(: "{procs[0]}" BiologicalProcess)\n'
         + f'(Member "GENE:ZTF" "{procs[0]}")\n'
     )
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     _m3, a3 = das.query_answer(q)
-    assert kernels.DISPATCH_COUNTS["fused_tree"] >= 1, (
+    assert counters.DISPATCH_COUNTS["fused_tree"] >= 1, (
         "a commit must invalidate the fused-tree entry"
     )
     # parity against the tree executor on the post-commit store
@@ -360,9 +360,9 @@ def test_sharded_tree_fused_cache_hit(monkeypatch):
     names = db.get_all_nodes("Gene", names=True)[:3]
     q = Or([_branch(g) for g in names])
     das.query_answer(q)
-    kernels.reset_dispatch_counts()
+    counters.reset_dispatch_counts()
     das.query_answer(q)
-    assert sum(kernels.DISPATCH_COUNTS.values()) == 0
+    assert sum(counters.DISPATCH_COUNTS.values()) == 0
 
 
 # -- sig-field distinctness (cache-key honesty, DL002) -------------------
