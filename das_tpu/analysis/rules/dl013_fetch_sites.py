@@ -14,7 +14,7 @@ The DL009 COLLECTIVE_SITES idiom, applied to transfers:
 `FETCH_SITES` (query/fused.py, next to FETCH_COUNTS) declares the
 closed set of scopes allowed to call `jax.device_get`; calls attribute
 to their OUTERMOST enclosing function qualified by module
-("fused.settle_pending_iter", "sharded_db.ShardedDB.materialize" —
+("fused.settle_pending_iter", "fused.FusedExecutor.execute" —
 `__init__` modules take their package name, so planner/__init__.py is
 "planner").  Three legs:
 

@@ -67,12 +67,17 @@ class _QueryManyJob:
 
     __slots__ = ("das", "queries", "output_format", "plans_lists", "idxs",
                  "pending", "db_ref", "version", "sharded", "settle_rtt_ms",
-                 "cache_only", "stale_round")
+                 "cache_only", "stale_round", "is_rerun")
 
-    def __init__(self, das, queries, output_format, cache_only=False):
+    def __init__(self, das, queries, output_format, cache_only=False,
+                 is_rerun=False):
         self.das = das
         self.queries = queries
         self.output_format = output_format
+        # this job IS the second go of a round a commit overtook
+        # (settle_iter): should a commit overtake it too, what is left
+        # re-runs one by one and not as a third round
+        self.is_rerun = is_rerun
         # degraded-mode serving (ISSUE 13, the coalescer's open circuit
         # breaker): answer from the delta-versioned result cache ONLY —
         # no device dispatch, no staged fallback, no per-query re-run;
@@ -80,8 +85,8 @@ class _QueryManyJob:
         # BreakerOpenError instead
         self.cache_only = cache_only
         # a commit overtook the dispatched round (dropped whole at
-        # settle, or broken mid-stream): its unanswered queries re-run
-        # one by one, counted as `exec.stale_reruns`
+        # settle, or broken mid-stream): its unanswered queries re-run,
+        # counted as `exec.stale_reruns`
         self.stale_round = False
         self.plans_lists: List = []
         self.idxs: List[int] = []
@@ -141,8 +146,8 @@ class _QueryManyJob:
         YIELD — streaming paces settle to the CONSUMER, so a commit
         landing between yields invalidates every not-yet-materialized
         entry (already-yielded answers were consistent when delivered):
-        abandon the round, the per-query loop in settle_iter re-runs
-        the rest on the post-commit store; (3) materialize/format via
+        abandon the round, settle_iter's tail re-runs the rest on
+        the post-commit store; (3) materialize/format via
         `answer_fn(j, result)`, a failure degrading that entry (and
         only it) to the per-query dispatcher.  Yields
         `(query index, formatted answer)`."""
@@ -177,14 +182,16 @@ class _QueryManyJob:
         form.  The dispatch-time delta_version guard is re-checked per
         yield, not just once up front: streaming paces settle to the
         CONSUMER, so a commit can land between yields — when it does,
-        the not-yet-materialized remainder re-runs per query on the
-        post-commit store."""
+        the not-yet-materialized remainder re-runs on the post-commit
+        store, as one new round (a lone query, and what a second
+        commit leaves of that round, through the per-query
+        dispatcher)."""
         das = self.das
         done = [False] * len(self.queries)
         if self.pending is not None and self._stale():
             # a commit raced in between dispatch and settle: drop the
             # dispatched round wholesale (its row ids and plans belong to
-            # the pre-commit store) and re-run everything per query on
+            # the pre-commit store) and re-run everything on
             # the post-commit store — correctness over the saved
             # transfer.  This is the guard that keeps SPECULATIVE
             # dispatch (a group dispatched before earlier settles
@@ -276,9 +283,29 @@ class _QueryManyJob:
             for i, out_s in settled:
                 done[i] = True
                 yield i, out_s
-        for i, q in enumerate(self.queries):
-            if done[i]:
-                continue
+        rest = [i for i in range(len(self.queries)) if not done[i]]
+        if (self.stale_round and len(rest) > 1 and not self.is_rerun
+                and not self.cache_only):
+            # what the commit left unanswered goes again as ONE round on
+            # the post-commit store — one plan pass, one program a
+            # signature, one fetch — where the per-query dispatcher
+            # below pays a lone program and a blocking fetch per query.
+            # (The answer path of PR 32 made this visible: with a
+            # group's last large answers out in a millisecond, a commit
+            # paced by reads lands just after the NEXT dispatch and
+            # finds a whole round stale.)
+            if obs.enabled():
+                planned = set(self.idxs)
+                obs.counter("exec.stale_reruns").inc(
+                    sum(1 for i in rest if i in planned))
+            again = _QueryManyJob(
+                das, [self.queries[i] for i in rest], self.output_format,
+                is_rerun=True)
+            for j, out_s in again.settle_iter():
+                yield rest[j], out_s
+            return
+        for i in rest:
+            q = self.queries[i]
             if self.cache_only:
                 # degraded-mode contract: cache hits streamed above,
                 # everything else is rejected RETRYABLE — fresh device
@@ -687,7 +714,7 @@ class DistributedAtomSpace:
         tracing off: the bare call."""
         if not obs.enabled():
             return self._format_answer(matched, answer, output_format)
-        with obs.span("exec.format", rows=len(answer.assignments)) as sp:
+        with obs.span("exec.format", rows=answer.row_count()) as sp:
             out = self._format_answer(matched, answer, output_format)
             sp.set(bytes=len(out))
         return out
@@ -701,7 +728,15 @@ class DistributedAtomSpace:
             if answer.negation:
                 tag_not = "NOT "
             if output_format == QueryOutputFormat.HANDLE:
-                mapping = str(answer.assignments)
+                # the block goes out as text while nobody has turned
+                # it into objects (query/ast.py AnswerBlock)
+                block = answer.block
+                if block is not None:
+                    mapping = block.handle_text()
+                    if obs.enabled():
+                        obs.counter("exec.answers_block").inc()
+                else:
+                    mapping = str(answer.assignments)
             elif output_format == QueryOutputFormat.ATOM_INFO:
                 mapping = str(
                     [self._render_assignment(a, deep=False) for a in answer.assignments]

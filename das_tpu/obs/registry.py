@@ -55,10 +55,11 @@ SPAN_NAMES = (
     #: span: one settle round's host transfer — a device-to-host sync
     #: (query/fused.py settle_pending_iter, DL013's one-transfer site)
     "exec.settle_fetch",
-    #: span: binding table -> frozen assignments (query/compiler.py)
+    #: span: binding table -> the answer's block of distinct valid rows
+    #: (query/compiler.py materialize) — attrs: rows, prefetched
     "exec.materialize",
-    #: span: assignments -> the answer string of one query
-    #: (api/atomspace.py _formatted) — attrs: rows, bytes
+    #: span: the block (or the assignments) -> the answer string of one
+    #: query (api/atomspace.py _formatted) — attrs: rows, bytes
     "exec.format",
     #: instants: delta-versioned result/tree/count cache traffic
     #: (query/fused.py ResultCache)
@@ -150,8 +151,18 @@ COUNTER_NAMES = (
     #: (1.0 where every job rides alone)
     "exec.group_programs",
     "exec.group_lanes",
-    #: queries re-run one by one because a commit overtook their
-    #: dispatched round (api/atomspace.py settle_iter, `_stale()`)
+    #: how an answer left the executor: its HANDLE text printed from
+    #: the block of distinct rows (api/atomspace.py _format_answer), or
+    #: its block turned into frozen assignment objects because a
+    #: consumer touched `answer.assignments` (query/ast.py
+    #: PatternMatchingAnswer) — on the served HANDLE path the second
+    #: stays 0
+    "exec.answers_block",
+    "exec.answers_objects",
+    #: queries re-run because a commit overtook their dispatched round
+    #: (api/atomspace.py settle_iter, `_stale()`): two or more go again
+    #: as ONE round, a lone one (and what a second commit leaves of
+    #: the re-run round) through the per-query dispatcher
     "exec.stale_reruns",
     #: every query the coalesced path hands to the per-query
     #: dispatcher `das.query`, whatever the cause: settle_iter's last

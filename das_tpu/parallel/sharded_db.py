@@ -42,7 +42,6 @@ from das_tpu.core.exceptions import CapacityOverflowError
 from das_tpu.ops.join import _anti_join_impl, _join_tables_impl, _build_term_table_impl
 from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh
 from das_tpu.query import compiler as qc
-from das_tpu.query.assignment import OrderedAssignment
 from das_tpu.query.ast import LogicalExpression, PatternMatchingAnswer
 from das_tpu.storage.atom_table import AtomSpaceData, Finalized
 from das_tpu.storage.delta import (
@@ -349,6 +348,18 @@ class ShardedTable:
     host_valid: Optional[np.ndarray] = None  # fused settle's one transfer)
 
 
+def _shard_rows(vals, valid) -> np.ndarray:
+    """The mesh's part of `exec.materialize`: the `[S, cap, k]` stack of
+    per-shard rows flattened, its valid rows each once."""
+    with obs.span("mesh.dedup") as sp:
+        vals = np.asarray(vals)
+        vals = vals.reshape(-1, vals.shape[-1])
+        rows = vals[np.asarray(valid).reshape(-1)]
+        distinct = qc.distinct_rows(rows)
+        sp.set(rows=len(rows), distinct=len(distinct))
+    return distinct
+
+
 def _probe_kernel(key_sorted, perm, targets, type_id, probe_key, fixed, cap, var_cols, eq_pairs):
     """Shard-local probe + term-table build.  Runs inside shard_map: blocks
     arrive as [1, m(, a)] slabs; outputs carry the same leading block dim."""
@@ -615,39 +626,12 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         return accumulated
 
     def materialize(self, table: Optional[ShardedTable], answer: PatternMatchingAnswer) -> bool:
-        """The stacked per-shard rows of a mesh answer -> frozen
-        assignments: the same span as one chip's (`exec.materialize`,
-        query/compiler.py), with the mesh's own step inside it — the
-        valid rows of all shards, each once (`mesh.dedup`: two Or
-        branches may ground one answer on two shards)."""
-        if table is None or table.count == 0:
-            return False
-        with obs.span("exec.materialize", rows=table.count,
-                      prefetched=table.host_vals is not None):
-            if table.host_vals is not None:
-                vals, valid = table.host_vals, table.host_valid
-            else:
-                # one transfer for both arrays (each fetch is a host sync)
-                from das_tpu.query.fused import FETCH_COUNTS
-
-                FETCH_COUNTS["n"] += 1
-                vals, valid = jax.device_get((table.vals, table.valid))
-            with obs.span("mesh.dedup") as sp:
-                vals = np.asarray(vals).reshape(-1, len(table.var_names))
-                rows = vals[np.asarray(valid).reshape(-1)]
-                distinct = np.unique(rows, axis=0)
-                sp.set(rows=len(rows), distinct=len(distinct))
-            hexes = self.fin.hex_of_row
-            for row in distinct:
-                a = OrderedAssignment()
-                ok = True
-                for name, val in zip(table.var_names, row):
-                    if not a.assign(name, hexes[int(val)]):
-                        ok = False
-                        break
-                if ok and a.freeze():
-                    answer.assignments.add(a)
-        return bool(answer.assignments)
+        """The stacked per-shard rows of a mesh answer into the answer's
+        block: one chip's `exec.materialize` (query/compiler.py), with
+        the mesh's own step inside it — the valid rows of all shards,
+        each once (`mesh.dedup`: two Or branches may ground one answer
+        on two shards)."""
+        return qc.materialize(self, table, answer, valid_rows=_shard_rows)
 
     def _run_conjunctive(self, plans: List[qc.TermPlan]) -> Optional[ShardedTable]:
         """One conjunctive plan on the mesh: the fused single-dispatch

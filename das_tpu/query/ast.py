@@ -22,6 +22,9 @@ from __future__ import annotations
 from functools import cmp_to_key
 from typing import List, Optional, Set
 
+import numpy as np
+
+from das_tpu import obs
 from das_tpu.core.schema import WILDCARD
 from das_tpu.query.assignment import (
     Assignment,
@@ -30,10 +33,123 @@ from das_tpu.query.assignment import (
 )
 
 
+class AnswerBlock:
+    """The distinct valid rows of a settled binding table: an integer
+    block `rows` [n, k] of global atom rows, one column per name of
+    `var_names` (distinct: a join unifies a repeated variable), and the
+    registry (`Finalized.hex_of_row`) that turns a
+    row id into its handle.  An answer leaves the worker as this block:
+    `handle_text` prints it without a Python object per binding, and
+    `assignments` builds the frozen objects for a consumer that asks
+    for objects."""
+
+    __slots__ = ("rows", "var_names", "hexes")
+
+    def __init__(self, rows: np.ndarray, var_names, hexes):
+        self.rows = rows
+        self.var_names = tuple(var_names)
+        self.hexes = hexes
+
+    def __len__(self) -> int:
+        return self.rows.shape[0]
+
+    def assignments(self) -> Set[Assignment]:
+        out: Set[Assignment] = set()
+        hexes = self.hexes
+        for row in self.rows:
+            a = OrderedAssignment()
+            ok = True
+            for name, val in zip(self.var_names, row):
+                if not a.assign(name, hexes[int(val)]):
+                    ok = False
+                    break
+            if ok and a.freeze():
+                out.add(a)
+        return out
+
+    def handle_text(self) -> str:
+        """What `str()` of the set of assignments prints, from the
+        block: per row `repr` of its mapping (names in `var_names`
+        order, single-quoted handles), `, ` between rows, one pair of
+        braces around.  A registry with a bulk read (storage/columnar.py
+        LazyHexRows.hex_block) fills a fixed-width byte template for
+        all rows at once; any other (a plain list) is read row by row."""
+        names = [f"{name!r}: '" for name in self.var_names]
+        bulk = getattr(self.hexes, "hex_block", None)
+        if bulk is None:
+            hexes = self.hexes
+            return "{" + ", ".join(
+                "{" + ", ".join(
+                    f"{name}{hexes[int(val)]}'"
+                    for name, val in zip(names, row)
+                ) + "}"
+                for row in self.rows
+            ) + "}"
+        n, k = self.rows.shape
+        digits = bulk(self.rows.reshape(-1)).reshape(n, k, 32)
+        # one row of text: {'$1': '<32>', '$2': '<32>'}, then ", "
+        pieces = [("{" if j == 0 else "', ") + name
+                  for j, name in enumerate(names)]
+        template = "".join(p + " " * 32 for p in pieces) + "'}, "
+        line = np.frombuffer(template.encode(), dtype=np.uint8)
+        text = np.tile(line, (n, 1))
+        at = 0
+        for j, piece in enumerate(pieces):
+            at += len(piece.encode())
+            text[:, at:at + 32] = digits[:, j]
+            at += 32
+        return "{" + text.tobytes()[:-2].decode() + "}"
+
+
 class PatternMatchingAnswer:
+    """A set of frozen assignments plus a negation flag.  The compiled
+    paths hand in a settled table as an `AnswerBlock` (`add_block`);
+    the set of objects is built from it on the first touch of
+    `assignments`, and never when the block goes out as HANDLE text
+    (api/atomspace.py _format_answer)."""
+
     def __init__(self):
-        self.assignments: Set[Assignment] = set()
+        self._assignments: Set[Assignment] = set()
+        #: the answer's rows while nobody has turned them into objects
+        self.block: Optional[AnswerBlock] = None
         self.negation: bool = False
+
+    @property
+    def assignments(self) -> Set[Assignment]:
+        if self.block is not None:
+            self._to_objects(self.block)
+        return self._assignments
+
+    @assignments.setter
+    def assignments(self, value: Set[Assignment]) -> None:
+        self.block = None
+        self._assignments = value
+
+    def _to_objects(self, *blocks: AnswerBlock) -> None:
+        self.block = None
+        for block in blocks:
+            self._assignments |= block.assignments()
+        if obs.enabled():
+            obs.counter("exec.answers_objects").inc()
+
+    def add_block(self, block: AnswerBlock) -> None:
+        """Union `block`'s rows into the answer.  The first block of an
+        empty answer is kept as it is; a second one (the mesh's Or
+        branches add into one answer) meets it as objects."""
+        if not len(block):
+            return
+        if self.block is None and not self._assignments:
+            self.block = block
+        elif self.block is not None:
+            self._to_objects(self.block, block)
+        else:
+            self._to_objects(block)
+
+    def row_count(self) -> int:
+        """`len(assignments)` without building them."""
+        if self.block is not None:
+            return len(self.block)
+        return len(self._assignments)
 
     def __repr__(self):
         s = "NOT\n" if self.negation else ""

@@ -587,7 +587,6 @@ FETCH_SITES = (
     "compiler.materialize",
     "tree.materialize_tables",
     "tree._tree_entry",
-    "sharded_db.ShardedDB.materialize",
     #: sharded execute()'s settle fetch (mesh twin of execute)
     "fused_sharded.ShardedFusedExecutor.execute",
 )

@@ -541,6 +541,17 @@ def attach_columnar(data: AtomSpaceData, core: ColumnarCore) -> AtomSpaceData:
 # ---------------------------------------------------------------------------
 
 
+#: byte -> its two lower-case hex digits (what `bytes.hex()` prints),
+#: each pair held as one uint16 so a digest is one `take`
+_HEX_OF_BYTE = np.frombuffer(
+    bytes(range(256)).hex().encode(), dtype=np.uint16)
+
+
+def _hex_digits(digests: np.ndarray) -> np.ndarray:
+    """uint8 [m, 16] digests -> uint8 [m, 32] ASCII hex digits."""
+    return _HEX_OF_BYTE.take(digests).view(np.uint8).reshape(-1, 32)
+
+
 class LazyHexRows:
     """`Finalized.hex_of_row` served from an [N, 16] digest array, with a
     plain-list tail for delta-appended atoms."""
@@ -563,6 +574,23 @@ class LazyHexRows:
 
     def append(self, hex_digest: str) -> None:
         self._tail.append(hex_digest)
+
+    def hex_block(self, rows: np.ndarray) -> np.ndarray:
+        """The bulk read: the hex digits of every row of `rows` in one
+        pass over the digest array — uint8 [len(rows), 32], the ASCII of
+        what `self[i]` gives row by row (query/ast.py AnswerBlock prints
+        an answer from it).  Rows past the base come from the tail."""
+        rows = np.asarray(rows, dtype=np.int64)
+        n = self._base.shape[0]
+        in_tail = rows >= n if self._tail else None
+        if in_tail is None or not in_tail.any():
+            return _hex_digits(self._base.take(rows, axis=0))
+        out = np.empty((rows.shape[0], 32), dtype=np.uint8)
+        text = "".join(self._tail[i - n] for i in rows[in_tail].tolist())
+        out[in_tail] = np.frombuffer(
+            text.encode("ascii"), dtype=np.uint8).reshape(-1, 32)
+        out[~in_tail] = _hex_digits(self._base.take(rows[~in_tail], axis=0))
+        return out
 
     def __iter__(self) -> Iterator[str]:
         for i in range(self._base.shape[0]):

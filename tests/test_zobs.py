@@ -38,6 +38,7 @@ from das_tpu.core.config import DasConfig
 from das_tpu.models.animals import animals_metta
 from das_tpu.obs.metrics import Histogram
 from das_tpu.query.ast import And, Link, Node, Variable
+from das_tpu.query.fused import FETCH_COUNTS
 from das_tpu.service.coalesce import QueryCoalescer
 from das_tpu.service.server import _Tenant
 from das_tpu.storage.atom_table import load_metta_text
@@ -689,12 +690,65 @@ def test_failed_stage_records_no_commit_swap(traced):
     assert db.delta_version == before
 
 
+def _mammal_queries():
+    """Three distinct queries, so a round holds three jobs: who inherits
+    from mammal / reptile / animal through one step."""
+    return [And([
+        Link("Inheritance", [Variable("$1"), Variable("$2")], True),
+        Link("Inheritance", [Variable("$2"), Node("Concept", c)], True),
+    ]) for c in ("mammal", "reptile", "animal")]
+
+
+def test_an_overtaken_round_goes_again_as_one_round(traced):
+    """A commit overtakes a round of three: its queries are answered on
+    the post-commit store by ONE new round (one `serve.plan`, programs
+    by signature, one fetch), none by the per-query dispatcher."""
+    das, _db = _tensor_das()
+    queries = _mammal_queries()
+    before = das.query(queries[0])
+    job = das.query_many_dispatch(queries)
+    das.load_metta_text(COMMIT)
+    expected = [das.query(q) for q in queries]
+    assert before == "" and expected[0] != ""    # the commit's row is in
+    obs.reset()
+    fetches = FETCH_COUNTS["n"]
+    assert job.settle() == expected
+    assert job.stale_round
+    assert obs.counter("exec.stale_reruns").value == 3
+    assert obs.counter("exec.per_query_fallbacks").value == 0
+    assert FETCH_COUNTS["n"] - fetches == 1
+    assert sum(1 for e in obs.events() if e[0] == "serve.plan") == 1
+
+
+def test_a_commit_that_overtakes_the_second_round_too_ends_one_by_one(traced):
+    """The re-run round is itself overtaken mid-stream: what it left goes
+    through the per-query dispatcher (no third round), every query is
+    answered once, on the last store."""
+    das, _db = _tensor_das()
+    queries = _mammal_queries()
+    job = das.query_many_dispatch(queries)
+    das.load_metta_text(COMMIT)
+    obs.reset()
+    it = job.settle_iter()
+    first = next(it)                         # the second round's first answer
+    das.load_metta_text('(: "echidna" Concept)\n'
+                        '(Inheritance "echidna" "chimp")')
+    rest = dict(it)
+    assert len(rest) == 2 and first[0] not in rest
+    for i, got in rest.items():
+        assert got == das.query(queries[i])
+    # three for the first round, two more for the second's remainder
+    assert obs.counter("exec.stale_reruns").value == 5
+    assert obs.counter("exec.per_query_fallbacks").value == 2
+
+
 def test_stale_reruns_count_the_rerun_queries(traced):
     """The commit race of test_zpipeline under speculation: two groups
     dispatched, a commit overtakes both, every one of their three
-    queries is re-run through the per-query dispatcher — counted once
-    each; a mid-stream commit counts only the queries not yet
-    answered."""
+    queries is re-run — counted once each: the two of the first group
+    as ONE new round (PR 32), the lone one of the second through the
+    per-query dispatcher; a mid-stream commit counts only the queries
+    not yet answered."""
     das, _db = _tensor_das()
     q = _pair_query()
     job1 = das.query_many_dispatch([q, q])
@@ -705,11 +759,11 @@ def test_stale_reruns_count_the_rerun_queries(traced):
     assert job1.settle() == [expected, expected]
     assert job2.settle() == [expected]
     assert obs.counter("exec.stale_reruns").value == 3
-    assert obs.counter("exec.per_query_fallbacks").value == 3
+    assert obs.counter("exec.per_query_fallbacks").value == 1
     # an undisturbed round re-runs nothing
     assert das.query_many_dispatch([q, q]).settle() == [expected] * 2
     assert obs.counter("exec.stale_reruns").value == 3
-    assert obs.counter("exec.per_query_fallbacks").value == 3
+    assert obs.counter("exec.per_query_fallbacks").value == 1
     # mid-stream: the first answer was delivered before the commit
     job = das.query_many_dispatch([q, q])
     it = job.settle_iter()
@@ -718,7 +772,7 @@ def test_stale_reruns_count_the_rerun_queries(traced):
                         '(Inheritance "echidna" "chimp")')
     assert len(dict(it)) == 1
     assert obs.counter("exec.stale_reruns").value == 4
-    assert obs.counter("exec.per_query_fallbacks").value == 4
+    assert obs.counter("exec.per_query_fallbacks").value == 2
     # every answer path above recorded its exec.format span
     assert sum(1 for e in obs.events() if e[0] == "exec.format") >= 8
 
