@@ -197,6 +197,17 @@ COUNTER_NAMES = (
     #: answers the STAGED mesh pipeline gave because the fused mesh
     #: program declined — twin of ROUTE_COUNTS["staged"] on the mesh
     "mesh.staged_fallbacks",
+    #: whole-table supports (query/starcount.py _table_sparse: the
+    #: run-length pass over a link type's slice of the sorted key that
+    #: the planner's exact join sizes and a table ⊙ table fold read)
+    #: BUILT (each is one `planner.stats` span of what="table_sparse")
+    #: and SERVED from the kept entry.  A read-only server shows the
+    #: first at the count of its joined (type, position) pairs and
+    #: never moving; a commit that swaps an arity's segments costs one
+    #: more per joined table of that arity.  Hit share = hits / (hits +
+    #: extractions)
+    "planner.table_extractions",
+    "planner.table_hits",
 )
 
 #: fixed log-bucket latency histograms (obs/metrics.py HISTOGRAMS) —

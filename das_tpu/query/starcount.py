@@ -51,7 +51,10 @@ the live DeviceBucket identity, so an incremental commit naturally
 invalidates): sparse probe supports per (arity, type, fixed) and
 whole-table run-length supports per (arity, type, position).  A handful
 of terms recur across the miner's hundreds of joints, so everything
-amortizes.
+amortizes.  Both editions keep the two classes apart: probe supports
+are many and cheap (a FIFO bounded by count), whole-table supports are
+few and dear (the host edition keeps one per joined (type, position)
+for as long as the table's segments live: `_table_sparse`).
 
 Routing: `plan_star` recognizes the shape (ordered terms only, no
 negation, no eq_pairs, no templates); everything else falls through to
@@ -288,17 +291,39 @@ GROUP = 12
 
 
 def _host_cache(db) -> Dict:
+    """The FIFO of GROUNDED supports (`_host_sparse_deg`)."""
     cache = getattr(db, "_star_host_cache", None)
     if cache is None:
         cache = db._star_host_cache = {}
     return cache
 
 
+def _table_cache(db) -> Dict:
+    """The kept WHOLE-TABLE supports (`_table_sparse`): one entry per
+    (arity, type_id, position) some query joined on, outside the FIFO
+    of grounded supports."""
+    cache = getattr(db, "_star_table_cache", None)
+    if cache is None:
+        cache = db._star_table_cache = {}
+    return cache
+
+
+def _same_segments(kept, segments) -> bool:
+    """THE validity rule of both host classes: an entry is served only
+    while the store's segments are the objects it was read from (a
+    commit swaps or extends the segment list of the arities it
+    touches)."""
+    return len(kept) == len(segments) and all(
+        a is b for a, b in zip(kept, segments)
+    )
+
+
 def _evict_oldest(cache, pred, keep: int) -> None:
     """FIFO-evict entries matching ``pred`` down to ``keep`` (dict
     preserves insertion order, so the front of the iteration is the
-    oldest).  A miner cycling >256 distinct terms keeps its working set
-    instead of rebuilding the whole key class from scratch."""
+    oldest).  A miner cycling >256 distinct grounded terms keeps its
+    working set instead of rebuilding the whole key class from scratch
+    (its whole-table supports are not in this FIFO: `_table_cache`)."""
     matching = [k for k in cache if pred(k)]
     for k in matching[: max(0, len(matching) - keep)]:
         del cache[k]
@@ -319,11 +344,7 @@ def _host_sparse_deg(db, spec):
     cache = _host_cache(db)
     key = ("sparse", arity, type_id, v0_pos, fixed)
     hit = cache.get(key)
-    if (
-        hit is not None
-        and len(hit[0]) == len(segments)
-        and all(a is b for a, b in zip(hit[0], segments))
-    ):
+    if hit is not None and _same_segments(hit[0], segments):
         return hit[1]
     chunks = []
     for b in segments:
@@ -342,7 +363,7 @@ def _host_sparse_deg(db, spec):
         e = np.empty(0, dtype=np.int64)
         ent = ((e, e), 0)
     if len(cache) > 256:
-        _evict_oldest(cache, lambda k: k[0] in ("sparse", "tsparse"), 192)
+        _evict_oldest(cache, lambda k: k[0] == "sparse", 192)
     cache.pop(key, None)  # refresh -> FIFO back
     cache[key] = (tuple(segments), ent)
     return ent
@@ -410,25 +431,34 @@ def _table_sparse(db, spec):
     total) of a WHOLE-TABLE term, extracted by run-length over the
     CONTIGUOUS (type<<32|target) sorted-key slice — the slice is already
     sorted, so uniques are np.diff boundaries: one linear pass, no
-    bincount, no [atom_count] vector.  Cached like the probe supports."""
+    bincount, no [atom_count] vector.
+
+    KEPT, not cached like the probe supports: the pass is dear (148 ms
+    over the 7.2 M-row `Member` slice of the FlyBase store at scale 0.3:
+    PERF.md §6, PR 35) and its class is bounded by the schema, one
+    entry per joined (type, position), so the entry lives as long as
+    the arity's segments are the objects it was read from and is
+    replaced in place when they are not.  The grounded supports' FIFO
+    (`_host_sparse_deg`: one insert per distinct grounded term, so per
+    query under distinct keys) never sees it."""
     arity, type_id, v0_pos, _ = spec
     from das_tpu.storage.atom_table import host_segments
 
     segments = host_segments(db, arity)
     if not segments:
         return None
-    cache = _host_cache(db)
-    key = ("tsparse", arity, type_id, v0_pos)
+    cache = _table_cache(db)
+    key = (arity, type_id, v0_pos)
     hit = cache.get(key)
-    if (
-        hit is not None
-        and len(hit[0]) == len(segments)
-        and all(a is b for a, b in zip(hit[0], segments))
-    ):
+    if hit is not None and _same_segments(hit[0], segments):
+        if obs.enabled():
+            obs.counter("planner.table_hits").inc()
         return hit[1]
-    # an uncached whole-table extraction (the planner's
-    # exact_join_rows reads it; a commit swaps the segment list, so
-    # every commit pays it again)
+    # a whole-table extraction: the first read of a table (the
+    # planner's exact_join_rows, a table ⊙ table fold) and the first
+    # after each commit that swapped its arity's segment list
+    if obs.enabled():
+        obs.counter("planner.table_extractions").inc()
     with obs.span("planner.stats", what="table_sparse") as sp:
         base = np.int64(type_id) << 32
         parts = []  # (idx, cnt) per segment
@@ -460,9 +490,13 @@ def _table_sparse(db, spec):
             ent = ((sv[starts], cnt), int(cnt.sum()))
         sp.set(version=getattr(db, "delta_version", None),
                rows=int(ent[1]))
-    if len(cache) > 256:
-        _evict_oldest(cache, lambda k: k[0] in ("sparse", "tsparse"), 192)
-    cache.pop(key, None)  # refresh -> FIFO back
+    # the arity's other entries read from segments that are gone would
+    # each pin a whole pre-commit bucket until their own next read
+    for k in [
+        k for k, (kept, _e) in cache.items()
+        if k[0] == arity and not _same_segments(kept, segments)
+    ]:
+        del cache[k]
     cache[key] = (tuple(segments), ent)
     return ent
 

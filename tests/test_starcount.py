@@ -425,3 +425,139 @@ def test_evict_oldest_is_fifo_and_partial():
     # the SURVIVORS are the newest 192, in original order
     assert sparse_left == [("sparse", i) for i in range(108, 300)]
     assert cache[("dense", 0)] == "keep"
+
+
+# -- the two host classes: kept whole-table supports, the grounded FIFO ----
+
+
+@pytest.fixture
+def table_counters():
+    """`() -> (planner.table_extractions, planner.table_hits)`, live
+    (the counters move only under tracing, like every obs counter)."""
+    from das_tpu import obs
+
+    was = obs.enabled()
+    obs.configure(enabled=True)
+    yield lambda: (
+        obs.counter("planner.table_extractions").value,
+        obs.counter("planner.table_hits").value,
+    )
+    obs.configure(enabled=was)
+
+
+def _small_store():
+    data, _, _ = build_bio_atomspace(
+        n_genes=40, n_processes=6, members_per_gene=3,
+        n_interactions=50, n_evaluations=0, seed=3,
+    )
+    return TensorDB(data, DasConfig())
+
+
+def _grounded_specs(db, n):
+    """`n` DISTINCT grounded Member specs (fixed position 0 at atom row
+    r; most supports are empty, every one is an entry of the FIFO)."""
+    tid = db._type_id("Member")
+    return [(2, tid, 1, ((0, r),)) for r in range(n)]
+
+
+def _by_handle(db, ent):
+    """A support as {atom handle: multiplicity} + its total: what two
+    stores with different row numberings (a cold rebuild renumbers the
+    rows a commit appended) can be compared on."""
+    (idx, cnt), total = ent
+    hexes = db.fin.hex_of_row
+    return {hexes[int(r)]: int(c) for r, c in zip(idx, cnt)}, total
+
+
+def test_table_support_outlives_the_grounded_fifo(table_counters):
+    """A whole-table support is not in the FIFO of grounded supports:
+    600 distinct grounded supports later (six evictions of that FIFO;
+    it used to hold the table's entry too and swept it out within
+    three, so a table read between them was extracted every ~195
+    inserts) the SAME entry object is served, nothing extracted again."""
+    db = _small_store()
+    spec = (2, db._type_id("Member"), 0, ())
+    first = starcount._table_sparse(db, spec)
+    assert first[1] == 120  # 40 genes x 3 memberships
+    built, served = table_counters()
+    for g in _grounded_specs(db, 600):
+        starcount._host_sparse_deg(db, g)
+    assert starcount._table_sparse(db, spec) is first
+    assert table_counters() == (built, served + 1)
+    assert list(starcount._table_cache(db)) == [spec[:3]]
+    # and the FIFO holds grounded supports only
+    assert {k[0] for k in starcount._host_cache(db)} == {"sparse"}
+
+
+def test_grounded_fifo_keeps_its_bounds():
+    """256 -> 192, counting only its own entries: 257 grounded supports
+    all stay; the next insert keeps the newest 192 of them, whatever
+    the whole-table class holds."""
+    db = _small_store()
+    tid = db._type_id("Member")
+    for pos in (0, 1):
+        starcount._table_sparse(db, (2, tid, pos, ()))
+    specs = _grounded_specs(db, 258)
+    for g in specs[:257]:
+        starcount._host_sparse_deg(db, g)
+    assert len(starcount._host_cache(db)) == 257
+    starcount._host_sparse_deg(db, specs[257])
+    assert list(starcount._host_cache(db)) == [
+        ("sparse",) + g for g in specs[65:258]
+    ]
+    assert len(starcount._table_cache(db)) == 2
+
+
+def test_commit_replaces_a_table_support_in_place(table_counters):
+    """Segment identity is the one validity rule: a commit that extends
+    the arity's segment list costs exactly ONE extraction per joined
+    table, equal to a cold store's, and the class holds one entry for
+    the key; an arity whose segment objects survived keeps its entry."""
+    from das_tpu.storage.atom_table import load_metta_text
+
+    text = "\n".join(
+        ["(: Concept Type)", "(: List Type)", "(: Pair Type)"]
+        + [f'(: "c{i}" Concept)' for i in range(6)]
+        + [f'(List "c{i}")' for i in range(6)]
+        + [f'(Pair "c{i}" "c{(i + 1) % 6}")' for i in range(6)]
+    )
+    db = TensorDB(load_metta_text(text), DasConfig())
+    pair = (2, db._type_id("Pair"), 0, ())
+    lst = (1, db._type_id("List"), 0, ())
+    pair_before = starcount._table_sparse(db, pair)
+    list_before = starcount._table_sparse(db, lst)
+    built, served = table_counters()
+    # new node + arity-2 link ONLY: the arity-1 segments survive
+    load_metta_text('(: "c_new" Concept)\n(Pair "c_new" "c0")', db.data)
+    db.refresh()
+    pair_after = starcount._table_sparse(db, pair)
+    assert starcount._table_sparse(db, pair) is pair_after
+    assert starcount._table_sparse(db, lst) is list_before
+    assert table_counters() == (built + 1, served + 2)
+    assert pair_after is not pair_before
+    assert pair_after[1] == pair_before[1] + 1
+    cold = TensorDB(db.data, DasConfig())
+    assert _by_handle(db, pair_after) == _by_handle(
+        cold, starcount._table_sparse(cold, pair)
+    )
+    assert sorted(starcount._table_cache(db)) == sorted([pair[:3], lst[:3]])
+    kept_segments = starcount._table_cache(db)[pair[:3]][0]
+    assert len(kept_segments) == 2  # base + the commit's overlay
+
+
+def test_commit_drops_the_arity_s_overtaken_siblings():
+    """A support read from segments that are gone pins them: the next
+    extraction at that arity drops such siblings (they could only miss)
+    and leaves another arity's live entry alone."""
+    from das_tpu.storage.atom_table import load_metta_text
+
+    db = _small_store()
+    member, interacts = db._type_id("Member"), db._type_id("Interacts")
+    for tid in (member, interacts):
+        starcount._table_sparse(db, (2, tid, 0, ()))
+    load_metta_text(
+        '(: "GENE:NEW" Gene)\n(Interacts "GENE:NEW" "GENE:NEW")', db.data
+    )
+    db.refresh()
+    starcount._table_sparse(db, (2, member, 1, ()))
+    assert list(starcount._table_cache(db)) == [(2, member, 1)]

@@ -483,6 +483,7 @@ def test_obs_registries_pinned():
         "serve.breaker_recoveries", "fault.injected", "fault.retries",
         "exec.stale_reruns", "exec.per_query_fallbacks",
         "exec.group_programs", "exec.group_lanes",
+        "planner.table_extractions", "planner.table_hits",
     }
     assert set(obs.HISTOGRAM_NAMES) >= {
         "serve.queue_ms", "serve.dispatch_ms", "serve.settle_ms",

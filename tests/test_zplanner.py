@@ -625,3 +625,54 @@ def test_star_rows_exact_vs_brute_force(monkeypatch):
             deg[int(b.targets[r, vcol])] += 1
     assert int(rows) == sum(d ** 3 for d in deg.values())
     assert est.star_rows(plans, "V3") == (rows, True)
+
+
+def test_exact_join_rows_reads_each_table_once(monkeypatch):
+    """400 distinct grounded terms joined against the whole-table side
+    of their link type (the serving shapes: one new grounded support a
+    query, enough to turn the FIFO of grounded supports over twice):
+    every value equals a COLD store's, and whole-table supports were
+    extracted once per distinct (type, position), two in all."""
+    from das_tpu import obs
+    from das_tpu.query import starcount
+
+    data, _g, _p = _bio_data(
+        n_genes=400, n_processes=12, members_per_gene=3, n_interactions=300,
+        seed=13,
+    )
+    db = TensorDB(data, DasConfig())
+    cold = TensorDB(data, DasConfig())
+    genes = _gene_names(db, 400)
+    assert len(set(genes)) == 400
+
+    def shape(gene, link):
+        return And([
+            Link(link, [Node("Gene", gene), Variable("V3")], True),
+            Link(link, [Variable("V2"), Variable("V3")], True),
+        ])
+
+    was = obs.enabled()
+    obs.configure(enabled=True)
+    try:
+        built = obs.counter("planner.table_extractions")
+        served = obs.counter("planner.table_hits")
+        built0, served0 = built.value, served.value
+        values = []
+        for i, gene in enumerate(genes):
+            q = shape(gene, ("Member", "Interacts")[i % 2])
+            pa, pb = compiler.plan_query(db, q)
+            values.append(estimator_for(db).exact_join_rows(pa, pb, "V3"))
+        assert (built.value - built0, served.value - served0) == (2, 398)
+        assert len(starcount._table_cache(db)) == 2
+        assert len(starcount._host_cache(db)) <= 257
+    finally:
+        obs.configure(enabled=was)
+    assert sum(v > 0 for v in values) >= 200
+    for i, gene in enumerate(genes):
+        # a cold store: no estimator, no support of either class
+        for kept in ("_planner_estimator", "_star_table_cache",
+                     "_star_host_cache"):
+            cold.__dict__.pop(kept, None)
+        q = shape(gene, ("Member", "Interacts")[i % 2])
+        pa, pb = compiler.plan_query(cold, q)
+        assert values[i] == estimator_for(cold).exact_join_rows(pa, pb, "V3")

@@ -356,3 +356,47 @@ def test_mesh_streaming_settle_yields_incrementally(env):
         seen.append((i, answer))
     assert len(seen) == len(queries)
     assert [a for _, a in sorted(seen)] == expected
+
+
+# -- the planner's kept whole-table supports on the mesh store -------------
+
+
+def test_mesh_table_support_kept_until_a_commit_swaps_its_segments():
+    """query/starcount.py `_table_sparse` reaches a ShardedDB through
+    `host_segments` like a TensorDB: the support outlives 600 distinct
+    grounded supports (the FIFO it used to share), and a commit costs
+    one extraction, equal to a cold mesh store's, replacing the entry."""
+    from das_tpu import obs
+    from das_tpu.query import starcount
+
+    das, db = _sharded_das()
+    tid = db._type_id("Inheritance")
+    spec = (2, tid, 0, ())
+
+    def by_handle(store, ent):
+        (idx, cnt), total = ent
+        hexes = store.fin.hex_of_row
+        return {hexes[int(r)]: int(c) for r, c in zip(idx, cnt)}, total
+
+    was = obs.enabled()
+    obs.configure(enabled=True)
+    try:
+        built = obs.counter("planner.table_extractions")
+        first = starcount._table_sparse(db, spec)
+        built0 = built.value
+        for r in range(600):
+            starcount._host_sparse_deg(db, (2, tid, 1, ((0, r),)))
+        assert starcount._table_sparse(db, spec) is first
+        assert built.value == built0
+        das.load_metta_text(COMMIT)
+        after = starcount._table_sparse(db, spec)
+        assert starcount._table_sparse(db, spec) is after
+        assert built.value == built0 + 1
+    finally:
+        obs.configure(enabled=was)
+    assert after[1] == first[1] + 1
+    cold = type(db)(db.data, DasConfig())  # built whole: no overlay
+    assert by_handle(db, after) == by_handle(
+        cold, starcount._table_sparse(cold, spec)
+    )
+    assert list(starcount._table_cache(db)) == [spec[:3]]
