@@ -220,8 +220,9 @@ class Writer:
                 query=run.dsl(shape, g))
             rows = (plain.canonical_answer(reply["msg"])
                     if reply["success"] else None)
-            want = run.kb.canonical_rows(run.kb.rows(
-                run.cell.queries[shape]["reference_rule"], g))
+            rule = run.cell.rules[shape]
+            want = run.kb.canonical_rows(rule.rows(run.kb, g),
+                                         columns=rule.COLUMNS)
             if rows != want:
                 self.readback_misses += 1
         if readback:
@@ -287,7 +288,10 @@ class Run:
 
     def build_store(self) -> None:
         t = time.monotonic()
-        self.store = generator.Store(self.scale, self.seed)
+        # the store's profile is the configuration's: its `shape` block
+        # (counts at full scale, skew), scaled by its `scale`
+        self.store = generator.Store(self.scale, self.seed,
+                                     self.cell.config["shape"])
         self.kb_path = os.path.join(self.workdir, "kb.metta")
         lines = generator.write_canonical(self.store, self.kb_path)
         gen_s = time.monotonic() - t
@@ -298,7 +302,8 @@ class Run:
         self.perm = traffic_mod.key_permutation(self.seed, self.store.n_genes)
         self.reference_s += time.monotonic() - t
         log("store", scale=self.scale, seed=self.seed,
-            params=self.store.params, expression_lines=lines,
+            params=self.store.params, skew=self.store.skew,
+            expression_lines=lines,
             file_mb=round(os.path.getsize(self.kb_path) / 2 ** 20, 1),
             generate_s=gen_s, reference_build_s=self.reference_s)
 
@@ -553,46 +558,67 @@ class Run:
     def verify(self, records: list) -> dict:
         t = time.monotonic()
         out = verify_records(
-            records, self.kb, self.cell.queries,
+            records, self.kb, self.cell.rules,
             self.writer.acked if self.writer else [],
             self.writer.issued if self.writer else [])
         self.verify_s = time.monotonic() - t
         return out
 
 
-def verify_records(records: list, kb, queries: dict, acked: list,
+def verify_records(records: list, kb, rules: dict, acked: list,
                    issued: list) -> dict:
-    """Every answer of the window against the plain reference.
+    """Every answer of the window against the plain reference: `rules`
+    is shape -> its loaded rule (`spec.Cell.rules`).
 
     An answer must be the exact set of ONE committed state between the
     last commit acknowledged before the query was sent and the last one
     issued before its answer was received: never a row of a commit that
     had not begun, never a state missing an acknowledged commit, never
     a mixture of two states.  `acked` / `issued` are the commits' times
-    in commit order; commit numbers start at 1."""
+    in commit order; commit numbers start at 1.  A rule whose KEY is
+    None answers for the whole store: its rows are worked out once (by
+    now every commit is applied, and the stamps say what each cut holds)
+    and its canonical text once per cut."""
     wrong, failed, nonempty, raced = [], [], 0, 0
+    whole_rows, whole_states = {}, {}    # whole-store rules: by shape;
+    #                                      by (shape, cut)
+
+    def rows_of(rec):
+        rule = rules[rec["shape"]]
+        if rule.KEY is not None:
+            return rule.rows(kb, rec["key"])
+        if rec["shape"] not in whole_rows:
+            whole_rows[rec["shape"]] = rule.rows(kb, None)
+        return whole_rows[rec["shape"]]
+
+    def state_at(rec, rows, v):
+        """(row count, digest) of the answer at commit number v."""
+        rule = rules[rec["shape"]]
+        at = (rec["shape"], v)
+        if at in whole_states:
+            return whole_states[at]
+        want = kb.canonical_rows(rows, v, rule.COLUMNS)
+        state = (len(want), plain.digest(want))
+        if rule.KEY is None:
+            whole_states[at] = state
+        return state
+
     for rec in records:
         if not rec["ok"]:
             failed.append(rec)
             continue
-        rows = kb.rows(queries[rec["shape"]]["reference_rule"], rec["key"])
+        rows = rows_of(rec)
         v_lo = bisect.bisect_right(acked, rec["sent"])
         v_hi = bisect.bisect_right(issued, rec["recv"])
         cuts = {v_lo} | {s for s in rows.values() if v_lo < s <= v_hi}
         if len(cuts) > 1:
             raced += 1
-        ok = False
-        for v in sorted(cuts):
-            want = kb.canonical_rows(rows, v)
-            if len(want) == rec["n"] and plain.digest(want) == rec["d"]:
-                ok = True
-                break
         if rec["n"]:
             nonempty += 1
-        if not ok:
+        if not any(state_at(rec, rows, v) == (rec["n"], rec["d"])
+                   for v in sorted(cuts)):
             wrong.append({k: rec[k] for k in ("c", "i", "shape", "key", "n")}
-                         | {"want_n_at_send": len(
-                             kb.canonical_rows(rows, v_lo))})
+                         | {"want_n_at_send": state_at(rec, rows, v_lo)[0]})
     return {"wrong": wrong, "failed": failed, "nonempty": nonempty,
             "raced_a_commit": raced}
 
@@ -736,6 +762,8 @@ def report(run: Run, cell: Cell, device: dict, win: dict, records: list):
     late = [(r["sent"] - r["due"]) * 1e3 for r in records]
     log("window", seconds=run.seconds, requests=len(records),
         latency_samples=len(lat_ms), highest_supported_tail=tail,
+        latency_ms_at={f"p{q}": stats.percentile(lat_ms, q / 100.0)
+                       for q in (50, 75, 90, 93, 95, 97, 99)} if lat_ms else None,
         nonempty_answers_compared=checked["nonempty"],
         answers_that_raced_a_commit=checked["raced_a_commit"],
         failed_requests=len(failed), failed_examples=failed[:3],
@@ -784,8 +812,8 @@ def report(run: Run, cell: Cell, device: dict, win: dict, records: list):
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     missing = [n for n in names if n not in values]
     if missing and not run.trace:
-        log("compare", name="end_to_end_metrics_missing", value=missing,
-            limit=[], ok=False)
+        compare("end_to_end_metrics_missing", missing, [], False)
+        log("compare", **compared[-1])
         correct = False
     result = {
         "correct": correct, "attempted": n_attempted, "failed": n_failed,
@@ -795,6 +823,10 @@ def report(run: Run, cell: Cell, device: dict, win: dict, records: list):
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # last in the line: every number compared beside its limit
+    result["compared"] = {item["name"]: {"value": item["value"],
+                                         "limit": item["limit"]}
+                          for item in compared}
     return result
 
 
@@ -807,8 +839,8 @@ def traced_metrics(run, cell, win, records, good, lat_ms, commit_ms):
     # time.monotonic (the window's clock)
     origin = obs.REC._t_origin - run.perf_minus_mono
     spans = [{"name": n, "phase": ph, "t": t + origin, "dur": d, "trace": tr,
-              "group": g, "attrs": a}
-             for (n, ph, t, d, tr, g, _lane, _th, a) in obs.events()]
+              "group": g, "thread": th, "attrs": a}
+             for (n, ph, t, d, tr, g, _lane, th, a) in obs.events()]
     hist = {name: {"p50": h.percentile(0.5), "p95": h.percentile(0.95),
                    "count": h.total}
             for name, h in obs_metrics.HISTOGRAMS.items()}
@@ -821,6 +853,8 @@ def traced_metrics(run, cell, win, records, good, lat_ms, commit_ms):
         "t0": win["t0"], "t_end": win["t_end"], "seconds": run.seconds,
         "slice_t0": run.slice_t0, "slice_t1": run.slice_t1,
         "latency_ms": lat_ms, "commit_ms": commit_ms,
+        "loop": cell.traffic["loop"],
+        "lateness_ms": [(r["sent"] - r["due"]) * 1e3 for r in records],
         "requests": len(records), "answered": len(good),
         "commits": len(commit_ms), "histograms": hist,
         "rows_by_shape_in_slice": {
@@ -851,8 +885,13 @@ def traced_metrics(run, cell, win, records, good, lat_ms, commit_ms):
         window["trace_window_ns"] = [w_lo, w_hi]
         dev_times = {"busy_s": devtrace.busy_seconds(trace, w_lo, w_hi),
                      "window_s": (w_hi - w_lo) / 1e9}
+        # a gap is named by what the coalescer's ONE worker thread (the
+        # thread that records `serve.drain`) was doing: every gRPC thread
+        # has a `wire.query` open all the time, which names nothing
+        workers = {s["thread"] for s in spans
+                   if s["name"] == devtrace.WORKER_SPAN}
         host = [[s["name"], s["t"] + run.perf_minus_mono, s["dur"]]
-                for s in spans if s["phase"] == "X"]
+                for s in spans if s["phase"] == "X" and s["thread"] in workers]
         breakdown = {
             "device_ops": devtrace.seconds_by_name(trace),
             "idle_gaps": devtrace.attribute_gaps(

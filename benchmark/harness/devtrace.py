@@ -12,6 +12,7 @@ start of the trace; results are seconds.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -195,23 +196,58 @@ def sync_offset_ns(trace: dict):
     return None
 
 
-def attribute_gaps(gaps: list, host_spans: list, offset_ns) -> list:
-    """Name each gap by what the host was doing: of the host spans
-    [name, start_s, duration_s] (perf_counter seconds) the one that
-    covers most of the gap.  [[name, seconds], ..], gaps with the same
-    name summed, longest first."""
+#: the span only the coalescer's worker thread records (one per drain)
+WORKER_SPAN = "serve.drain"
+NO_SPAN = "worker: no span open (waiting for requests)"
+
+
+def innermost_segments(spans: list) -> list:
+    """Spans [name, start_s, duration_s] of ONE thread (they nest) ->
+    sorted disjoint [start_s, end_s, name] segments, each named by the
+    innermost span open in it: a span's own time, without its children's."""
+    out, stack = [], []        # stack of [name, end]
+
+    def emit(a, b):
+        if stack and b > a:
+            out.append([a, b, stack[-1][0]])
+
+    at = None
+    for name, start, dur in sorted(spans, key=lambda s: (s[1], -s[2])):
+        while stack and stack[-1][1] <= start:
+            emit(at, stack[-1][1])
+            at = stack.pop()[1]
+        emit(at, start)
+        stack.append([name, start + dur])
+        at = start
+    while stack:
+        emit(at, stack[-1][1])
+        at = stack.pop()[1]
+    return out
+
+
+def attribute_gaps(gaps: list, worker_spans: list, offset_ns) -> list:
+    """Name each gap by what the host's worker thread was doing: of its
+    spans [name, start_s, duration_s] (perf_counter seconds) the
+    INNERMOST one open over most of the gap, `NO_SPAN` where none was.
+    [[name, seconds], ..], gaps with the same name summed, longest
+    first."""
+    if offset_ns is None:
+        total = sum(dur for _start, dur in gaps) / 1e9
+        return [["host: clocks not aligned", total]] if gaps else []
+    segments = innermost_segments(worker_spans)
+    starts = [seg[0] for seg in segments]
     acc = {}
     for start, dur in gaps:
-        label = "host: no span open"
-        if offset_ns is not None:
-            a = (start + offset_ns) / 1e9
-            b = a + dur / 1e9
-            best = 0.0
-            for name, s0, sd in host_spans:
-                cover = min(b, s0 + sd) - max(a, s0)
-                if cover > best:
-                    best, label = cover, name
-        else:
-            label = "host: clocks not aligned"
+        a = (start + offset_ns) / 1e9
+        b = a + dur / 1e9
+        cover = {}
+        i = max(0, bisect.bisect_right(starts, a) - 1)
+        while i < len(segments) and segments[i][0] < b:
+            s0, s1, name = segments[i]
+            if min(b, s1) > max(a, s0):
+                cover[name] = cover.get(name, 0.0) + min(b, s1) - max(a, s0)
+            i += 1
+        cover[NO_SPAN] = (b - a) - sum(cover.values())
+        label = max(cover, key=cover.get)
         acc[label] = acc.get(label, 0.0) + dur / 1e9
     return [[k, v] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])]

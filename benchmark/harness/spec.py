@@ -1,9 +1,10 @@
 """Where the harness finds what a cell names.
 
 Everything that belongs to one configuration, one traffic mix, one
-query shape or one per-layer metric is a file of its own, found by the
-name `BENCHMARK.json` gives it; a name with no file behind it is an
-error, never a default.
+query shape, one shape's plain reference or one per-layer metric is a
+file of its own, found by the name `BENCHMARK.json` (or the file it
+names) gives it; a name with no file behind it is an error, never a
+default.
 """
 
 from __future__ import annotations
@@ -35,6 +36,40 @@ def _read_json(path: str):
 
 def load_benchmark(root: str = ROOT) -> dict:
     return _read_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def _load_module(path: str, what: str, needs: tuple):
+    """The Python file `path` as a module of its own; `what` names it in
+    the error when the file, or one of the names it must state, is not
+    there."""
+    rel = os.path.relpath(path, ROOT)
+    if not os.path.isfile(path):
+        raise SpecError(f"{what}: no such file {rel}")
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"\W", "_", "bench_" + rel), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    for name in needs:
+        if not hasattr(module, name):
+            raise SpecError(f"{rel} states no {name}")
+    return module
+
+
+def load_rule(name: str, rules_dir: str = None):
+    """A query shape's plain reference: `<rules_dir>/<name>.py`, which
+    states COLUMNS ((variable, node type), .. in answer order), KEY
+    ("gene": the shape's dsl carries {key}; None: it is asked of the
+    whole store) and rows(kb, key) -> {tuple of ids: stamp} over
+    `reference.plain.PlainKB`'s accessors."""
+    if not isinstance(name, str) or not NAME.match(name):
+        raise SpecError(f"reference rule {name!r} is not a name")
+    rules_dir = rules_dir or os.path.join(BENCH_DIR, "reference", "rules")
+    rule = _load_module(os.path.join(rules_dir, name + ".py"),
+                        f"reference rule {name!r}", ("COLUMNS", "KEY", "rows"))
+    if not rule.COLUMNS or rule.KEY not in ("gene", None):
+        raise SpecError(f"reference rule {name!r}: COLUMNS {rule.COLUMNS!r}, "
+                        f"KEY {rule.KEY!r}")
+    return rule
 
 
 def problems(bench: dict) -> list:
@@ -127,21 +162,23 @@ class Cell:
                 bench_dir, "queries", q["shape"] + ".json"))
             for q in self.traffic["queries"]
         }
+        #: shape -> its plain reference, found by the name the shape's
+        #: file gives (a `reference_rule` with no file is refused here)
+        self.rules = {}
+        for shape, q in self.queries.items():
+            rule = load_rule(q.get("reference_rule"), os.path.join(
+                bench_dir, "reference", "rules"))
+            if (rule.KEY is not None) != ("{key}" in q["dsl"]):
+                raise SpecError(
+                    f"query shape {shape!r}: rule KEY {rule.KEY!r} against "
+                    f"dsl {q['dsl']!r}")
+            self.rules[shape] = rule
         self.end_to_end = metrics_of(self.bench, "end_to_end", name)
         self.per_layer = metrics_of(self.bench, "per_layer", name)
 
     def layer_reader(self, metric_name: str):
         """`read(spans, counters, trace, window)` of one per-layer
         metric, from the file that carries its name."""
-        path = os.path.join(self.bench_dir, "layer_metrics",
-                            metric_name + ".py")
-        if not os.path.isfile(path):
-            raise SpecError(f"per-layer metric {metric_name!r} has no reader "
-                            f"at {os.path.relpath(path, self.root)}")
-        spec = importlib.util.spec_from_file_location(
-            "layer_metric_" + re.sub(r"\W", "_", metric_name), path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        if not callable(getattr(module, "read", None)):
-            raise SpecError(f"{os.path.relpath(path, self.root)} has no read()")
-        return module.read
+        return _load_module(
+            os.path.join(self.bench_dir, "layer_metrics", metric_name + ".py"),
+            f"per-layer metric {metric_name!r} has no reader", ("read",)).read

@@ -1,12 +1,18 @@
-"""The benchmark's own store generator: the FlyBase shape, in bulk.
+"""The benchmark's own store generator: a FlyBase-shaped store, in bulk.
 
 Same shape as `das_tpu/models/bio.py write_bio_canonical` (the store
 `chip_smoke.py` ran on the chip in PR 22): `n_genes` Gene nodes,
 `n_processes` BiologicalProcess nodes, `members_per_gene` DISTINCT
-uniform memberships per gene, `n_interactions` uniform gene pairs
-stored in both orientations, `n_evaluations`
-`Evaluation(Predicate, List(gene, process))` links.  One departure: the
-pairs are DISTINCT, so every seed gives the same link counts (see Store).
+memberships per gene, `n_interactions` gene pairs stored in both
+orientations, `n_evaluations` `Evaluation(Predicate, List(gene,
+process))` links.  The counts and the `skew` of the draws are a
+PROFILE: the `shape` block of the cell's configuration file, scaled by
+its `scale`.  `skew` 0 draws every index uniformly; `skew` > 0 sends a
+uniform u through u^(1+skew) onto LOW indices, a power-law
+participation profile with hub genes and processes (what bio.py's
+`_skew_idx` documents, written again here: the reference shares no code
+with the program).  One departure: the pairs are DISTINCT, so every
+seed gives the same link counts (see Store).
 Node names, link types and the canonical one-expression-per-line file
 format are the program's input contract; the draws are numpy's, made in
 bulk, so a run's store costs seconds of set-up, not a Python loop over
@@ -19,24 +25,38 @@ import hashlib
 
 import numpy as np
 
-#: the reference-scale shape (bench.py FLYBASE; SimplePatternMiner.ipynb
-#: cell 0 of the reference repository: 2,584,508 nodes / 27,871,440 links)
+#: the reference-scale profile (bench.py FLYBASE; SimplePatternMiner.ipynb
+#: cell 0 of the reference repository: 2,584,508 nodes / 27,871,440 links).
+#: No run reads it: a run's profile is its configuration's `shape`.  It
+#: is the default of callers that give none: tests/ (tier 1), which build
+#: `Store(scale, seed)` and which a benchmark PR may not edit.
 FLYBASE = dict(
     n_genes=2_400_000, n_processes=180_000, members_per_gene=10,
     n_interactions=1_500_000, n_evaluations=435_000,
+    link_types=["Member", "Interacts", "Evaluation", "List"], skew=0,
 )
 
 TYPE_NAMES = ("Gene", "BiologicalProcess", "Member", "Interacts",
               "Predicate", "Evaluation", "List")
+LINK_TYPES = ("Member", "Interacts", "Evaluation", "List")
 PREDICATE = "Predicate:has_name"
+#: what `scale` multiplies; members_per_gene (a row width) is kept
+COUNTS = ("n_genes", "n_processes", "n_interactions", "n_evaluations")
 
 
-def kb_params(scale: float) -> dict:
-    """FLYBASE x scale: every count scaled, members_per_gene kept."""
-    p = {
-        k: (v if k == "members_per_gene" else max(1, int(v * scale)))
-        for k, v in FLYBASE.items()
-    }
+def kb_params(scale: float, shape: dict) -> dict:
+    """`shape` x scale: every count scaled, members_per_gene kept."""
+    lacking = [k for k in COUNTS + ("members_per_gene", "skew", "link_types")
+               if k not in shape]
+    if lacking:
+        raise ValueError(f"the store's shape lacks {lacking}")
+    if sorted(shape["link_types"]) != sorted(LINK_TYPES):
+        raise ValueError(f"the generator writes {LINK_TYPES}, the shape "
+                         f"asks for {shape['link_types']}")
+    if not shape["skew"] >= 0:
+        raise ValueError(f"skew {shape['skew']!r}")
+    p = {k: max(1, int(shape[k] * scale)) for k in COUNTS}
+    p["members_per_gene"] = int(shape["members_per_gene"])
     p["n_processes"] = max(p["n_processes"], 2 * p["members_per_gene"])
     return p
 
@@ -53,6 +73,10 @@ def proc_name(i: int) -> str:
     return PROC_FORMAT.format(i)
 
 
+#: node type -> the name of its i-th node
+NODE_NAMES = {"Gene": gene_name, "BiologicalProcess": proc_name}
+
+
 def handle(node_type: str, name: str) -> str:
     """Node handle as the reference DAS defines it: md5("<type> <name>")."""
     return hashlib.md5(f"{node_type} {name}".encode()).hexdigest()
@@ -66,22 +90,23 @@ class Store:
     evaluations  int32 [n_evaluations, 2]           distinct (gene, process)
     """
 
-    def __init__(self, scale: float, seed: int):
-        self.params = kb_params(scale)
+    def __init__(self, scale: float, seed: int, shape: dict = None):
+        shape = FLYBASE if shape is None else shape
+        self.params = kb_params(scale, shape)
         self.scale, self.seed = scale, seed
+        self.skew = skew = float(shape["skew"])
         p = self.params
         rng = np.random.default_rng([int(seed), 0x0DA5])
         n_g, n_p, k = p["n_genes"], p["n_processes"], p["members_per_gene"]
-        members = rng.integers(0, n_p, size=(n_g, k), dtype=np.int32)
+        members = _indices(rng, n_p, (n_g, k), skew)
         while True:
             # a row with a repeated process is redrawn whole: k distinct
-            # uniform draws, as random.sample gives
+            # draws, as random.sample gives
             srt = np.sort(members, axis=1)
             bad = np.nonzero((srt[:, 1:] == srt[:, :-1]).any(axis=1))[0]
             if not len(bad):
                 break
-            members[bad] = rng.integers(0, n_p, size=(len(bad), k),
-                                        dtype=np.int32)
+            members[bad] = _indices(rng, n_p, (len(bad), k), skew)
         self.members = members
         # EXACT counts, whatever the seed: the program pads its tables
         # to n + n/16 rows (storage/delta.py capacity_class), so a store
@@ -90,9 +115,9 @@ class Store:
         # in the compile cache.  So: exactly n_interactions distinct
         # unordered pairs, exactly n_evaluations distinct (gene, process).
         self.interactions = _distinct_pairs(
-            rng, p["n_interactions"], n_g, n_g, unordered=True)
+            rng, p["n_interactions"], n_g, n_g, unordered=True, skew=skew)
         self.evaluations = _distinct_pairs(
-            rng, p["n_evaluations"], n_g, n_p, unordered=False)
+            rng, p["n_evaluations"], n_g, n_p, unordered=False, skew=skew)
 
     @property
     def n_genes(self) -> int:
@@ -114,15 +139,28 @@ class Store:
                           + 2 * len(self.evaluations))
 
 
-def _distinct_pairs(rng, n: int, n_a: int, n_b: int, unordered: bool):
-    """int32 [n, 2]: n distinct pairs, uniform, in draw order.  With
-    `unordered`, (a, b) and (b, a) are one pair and a != b."""
+def _indices(rng, n: int, size, skew: float) -> np.ndarray:
+    """int32 draws from 0..n-1.  skew 0: uniform, ONE `rng.integers`
+    call (the draws every store has had since PR 25); skew > 0: a
+    uniform u through u^(1+skew), so index i is drawn with probability
+    ((i+1)/n)^(1/(1+skew)) - (i/n)^(1/(1+skew)): mass on low indices."""
+    if skew <= 0:
+        return rng.integers(0, n, size=size, dtype=np.int32)
+    return np.minimum(n - 1, (n * rng.random(size) ** (1.0 + skew))
+                      .astype(np.int32))
+
+
+def _distinct_pairs(rng, n: int, n_a: int, n_b: int, unordered: bool,
+                    skew: float = 0.0):
+    """int32 [n, 2]: n distinct pairs in draw order, each end drawn by
+    `_indices`.  With `unordered`, (a, b) and (b, a) are one pair and
+    a != b."""
     if n > (n_a * n_b) // 4:
         raise ValueError("too many distinct pairs asked of too few nodes")
     out = np.empty((0, 2), dtype=np.int32)
     while len(out) < n:
-        more = np.stack([rng.integers(0, n_a, size=n, dtype=np.int32),
-                         rng.integers(0, n_b, size=n, dtype=np.int32)], axis=1)
+        more = np.stack([_indices(rng, n_a, n, skew),
+                         _indices(rng, n_b, n, skew)], axis=1)
         out = np.concatenate([out, more])
         if unordered:
             out = out[out[:, 0] != out[:, 1]]

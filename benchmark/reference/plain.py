@@ -4,8 +4,9 @@ Built from `generator.Store`'s arrays (never from the file, never with
 any das_tpu import): adjacency as numpy CSR for the base store, plus the
 links the run's commits add, each stamped with the number of the commit
 that added it.  Commits only add links, so the state after commit `v`
-is "every link with stamp <= v", and `answer(shape, gene, v)` is exact
-for any v.  One rule per query shape; a shape's JSON file names its rule.
+is "every link with stamp <= v", and a rule's rows, cut at v, are exact
+for any v.  One rule file per query shape (`rules/<rule>.py`, over the
+accessors below); a shape's JSON file names its rule.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ import re
 
 import numpy as np
 
-from benchmark.reference.generator import Store, gene_name, handle, proc_name
+from benchmark.reference.generator import NODE_NAMES, Store, handle
 
 
 def _csr(src: np.ndarray, dst: np.ndarray, n: int):
@@ -42,8 +43,8 @@ class PlainKB:
         self.added_genes = {}     # process -> {gene: v}
         self.added_out = {}       # gene -> {gene: v}
         self.n_added = 0
-        self._gh = {}
-        self._ph = {}
+        self._handles = {}    # node type -> _Handles
+        self._rules = {}      # rule name -> loaded rule (see `rows`)
 
     # -- state at commit number v (None = everything so far) --------------
 
@@ -91,52 +92,58 @@ class PlainKB:
         nodes, links = self.store.counts()
         return nodes, links + self.n_added
 
-    # -- answers: {(gene $2, process $3): stamp} ---------------------------
+    # -- answers -----------------------------------------------------------
+    # A query shape's rule is a file of its own (`rules/<rule>.py`,
+    # `harness.spec.load_rule`): rows(kb, key) -> {tuple of ids: stamp}.
 
-    def grounded3(self, g: int) -> dict:
-        """And(Member(g,$3), Member($2,$3), Interacts(g,$2))"""
-        mine = self.procs_of(g)
-        rows = {}
-        for x, s_int in self.out_of(g).items():
-            for p, s_x in self.procs_of(x).items():
-                if p in mine:
-                    rows[(x, p)] = max(s_int, s_x, mine[p])
-        return rows
+    def rows(self, rule: str, key) -> dict:
+        """The named rule's rows, carrying its COLUMNS.  Kept for
+        tests/test_mesh_cell.py, which asks by name and which a benchmark
+        PR may not edit; the harness goes through the loaded rule."""
+        loaded = self._rules.get(rule)
+        if loaded is None:
+            from benchmark.harness.spec import load_rule
 
-    def shared2(self, g: int) -> dict:
-        """And(Member(g,$3), Member($2,$3))"""
-        rows = {}
-        for p, s_p in self.procs_of(g).items():
-            for x, s_x in self.genes_of(p).items():
-                rows[(x, p)] = max(s_p, s_x)
-        return rows
+            loaded = self._rules[rule] = load_rule(rule)
+        out = _NamedRows(loaded.rows(self, key))
+        out.columns = loaded.COLUMNS
+        return out
 
-    RULES = ("grounded3", "shared2")
-
-    def rows(self, rule: str, g: int) -> dict:
-        if rule not in self.RULES:
-            raise KeyError(f"the plain reference has no rule {rule!r}")
-        return getattr(self, rule)(g)
-
-    def gene_handle(self, i: int) -> str:
-        h = self._gh.get(i)
-        if h is None:
-            h = self._gh[i] = handle("Gene", gene_name(i))
-        return h
-
-    def proc_handle(self, i: int) -> str:
-        h = self._ph.get(i)
-        if h is None:
-            h = self._ph[i] = handle("BiologicalProcess", proc_name(i))
-        return h
-
-    def canonical_rows(self, rows, v=None) -> list:
+    def canonical_rows(self, rows, v=None, columns=None) -> list:
         """The rows present at commit number v, in the canonical text
-        form `canonical_answer` gives a served answer."""
-        return sorted(
-            f"$2={self.gene_handle(x)},$3={self.proc_handle(p)}"
-            for (x, p), stamp in rows.items() if v is None or stamp <= v
-        )
+        form `canonical_answer` gives a served answer: a row's bindings
+        sorted by variable.  `columns` is the rule's COLUMNS."""
+        columns = rows.columns if columns is None else columns
+        order = sorted(range(len(columns)), key=lambda i: columns[i][0])
+        text = ",".join(f"{columns[i][0]}=%s" for i in order)
+        kept = [ids for ids, stamp in rows.items() if v is None or stamp <= v]
+        # column by column: each a plain list comprehension over a table
+        # that works a handle out the first time it is asked for
+        handles = []
+        for i in order:
+            table = self._handles.get(columns[i][1])
+            if table is None:
+                table = self._handles[columns[i][1]] = _Handles(columns[i][1])
+            handles.append([table[ids[i]] for ids in kept])
+        return sorted(map(text.__mod__, zip(*handles)))
+
+
+class _Handles(dict):
+    """Node id -> handle of one node type, worked out when first asked."""
+
+    def __init__(self, node_type: str):
+        super().__init__()
+        self.node_type, self.name = node_type, NODE_NAMES[node_type]
+
+    def __missing__(self, i: int) -> str:
+        h = self[i] = handle(self.node_type, self.name(i))
+        return h
+
+
+class _NamedRows(dict):
+    """What `PlainKB.rows` returns: the rows with the rule's COLUMNS."""
+
+    columns = None
 
 
 # -- a served answer, brought to the same canonical form -------------------

@@ -6,7 +6,8 @@
 Earlier lines of stdout are JSON logs (store, warm-up, every number
 compared beside its limit, counters); the LAST line is the result:
 `correct`, `attempted`, `failed`, `metrics`, `device` (+ `breakdown`
-when traced).  Without a TPU, or with fewer chips than the cell asks
+when traced) and, last, `compared`: each number compared with its limit,
+which are also the last lines of stderr.  Without a TPU, or with fewer chips than the cell asks
 for, it prints no result and exits non-zero.
 
     --rehearse SCALE   run every phase at SCALE on whatever platform JAX
@@ -56,6 +57,10 @@ def main(argv=None) -> int:
         return 2
     if result is None:
         return code
+    # the last lines on standard error: each number compared, its limit
+    for name, item in result["compared"].items():
+        print(f"compared {name}: {item['value']} (limit {item['limit']})",
+              file=sys.stderr)
     if args.rehearse is not None:
         print(json.dumps(result), file=sys.stderr)
         print("benchmark: rehearsal done; refusing to print a result "

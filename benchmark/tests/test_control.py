@@ -6,7 +6,8 @@ chip skipped:
 
   approximate_answers  every answer of more than 100 rows loses one row
                        where it is produced ("every answer is the exact
-                       set") — both cells
+                       set") — every cell (the mesh cell's control is
+                       tests/test_mesh_cell.py's)
   drop_commits         commits are acknowledged and never applied ("an
                        acknowledged commit is visible to the next query")
                        — the cell with writes
@@ -31,6 +32,7 @@ import pytest  # noqa: E402
 
 CASES = [
     ("mem-uniform-closed", "approximate_answers"),
+    ("mem-zipf-open", "approximate_answers"),
     ("wal-mixed95-closed", "approximate_answers"),
     ("wal-mixed95-closed", "drop_commits"),
 ]

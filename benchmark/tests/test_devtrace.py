@@ -51,16 +51,60 @@ def test_recorded_trace_gaps_and_attribution(trace):
         (hi - lo) / 1e9, rel=1e-9)
     offset = devtrace.sync_offset_ns(trace)
     assert offset == 5_000_000_500.0 - 500.0
-    # a host span that covers the longest gap names it; the rest is unnamed
+    # a worker span that covers the longest gap names it; the rest is unnamed
     g0, d0 = gaps[0]
     spans = [["serve.settle", (g0 + offset) / 1e9 - 1e-4, d0 / 1e9 + 2e-4]]
     named = dict(devtrace.attribute_gaps(all_gaps, spans, offset))
     assert named["serve.settle"] >= d0 / 1e9
-    assert "host: no span open" in named
+    assert devtrace.NO_SPAN in named
     assert sum(named.values()) == pytest.approx(
         sum(d for _, d in all_gaps) / 1e9)
     unaligned = dict(devtrace.attribute_gaps(all_gaps, spans, None))
     assert list(unaligned) == ["host: clocks not aligned"]
+
+
+def test_a_gap_is_named_by_the_innermost_span_of_the_worker_thread():
+    """The worker's spans nest (drain > dispatch > plan); a gap takes the
+    name of the span whose OWN time covers most of it, never of the
+    outer one that merely contains it."""
+    worker = [
+        ["serve.drain", 10.000, 0.100],
+        ["serve.dispatch", 10.010, 0.050],
+        ["serve.plan", 10.020, 0.010],
+        ["exec.dispatch", 10.035, 0.020],
+        ["serve.settle", 10.070, 0.025],
+        ["serve.drain", 10.200, 0.010],
+    ]
+    segs = devtrace.innermost_segments(worker)
+    assert [(round(a, 3), round(b, 3), n) for a, b, n in segs] == [
+        (10.0, 10.01, "serve.drain"), (10.01, 10.02, "serve.dispatch"),
+        (10.02, 10.03, "serve.plan"), (10.03, 10.035, "serve.dispatch"),
+        (10.035, 10.055, "exec.dispatch"), (10.055, 10.06, "serve.dispatch"),
+        (10.06, 10.07, "serve.drain"), (10.07, 10.095, "serve.settle"),
+        (10.095, 10.1, "serve.drain"), (10.2, 10.21, "serve.drain")]
+    offset = 0.0                       # trace ns == perf_counter ns
+
+    def gap(a, b):
+        return [a * 1e9, (b - a) * 1e9]
+
+    gaps = [gap(10.021, 10.029),       # inside serve.plan
+            gap(10.036, 10.054),       # inside exec.dispatch
+            gap(10.056, 10.072),       # dispatch 4, drain 10, settle 2 ms
+            gap(10.110, 10.190),       # between two drains: nothing open
+            gap(10.195, 10.204)]       # 5 ms of nothing, 4 ms of a drain
+    named = dict(devtrace.attribute_gaps(gaps, worker, offset))
+    assert named == {
+        "serve.plan": pytest.approx(0.008),
+        "exec.dispatch": pytest.approx(0.018),
+        "serve.drain": pytest.approx(0.016),
+        devtrace.NO_SPAN: pytest.approx(0.089)}
+    # the parent's rule (the span that covers most of the gap) would
+    # have read serve.drain for the first three: the outer span wins
+    assert "serve.dispatch" not in named
+    # no worker thread found (a tree without serve.drain): all unnamed
+    assert dict(devtrace.attribute_gaps(gaps, [], offset)) == {
+        devtrace.NO_SPAN: pytest.approx(0.131)}
+    assert devtrace.attribute_gaps([], worker, offset) == []
 
 
 def test_a_trace_without_device_ops_reads_nothing():

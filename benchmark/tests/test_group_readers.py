@@ -10,7 +10,7 @@ NAME = "exec.lanes_per_program"
 
 
 @pytest.mark.parametrize("cell", ["mem-uniform-closed", "wal-mixed95-closed",
-                                  "sharded4-uniform-closed"])
+                                  "sharded4-uniform-closed", "mem-zipf-open"])
 def test_exec_lanes_per_program(cell):
     read = Cell(cell).layer_reader(NAME)
     w = {"answered": 5230}
@@ -31,7 +31,6 @@ def test_exec_lanes_per_program(cell):
 def test_it_is_listed_where_its_end_to_end_metric_is_reported():
     bench = load_benchmark()
     (metric,) = [m for m in bench["per_layer"] if m["name"] == NAME]
-    assert bench["per_layer"][-1] is metric            # appended, last
     assert metric["layer"] == "executor"
     for cell in metric["workloads"]:
         reported = {m["name"] for m in metrics_of(bench, "end_to_end", cell)}

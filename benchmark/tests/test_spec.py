@@ -30,7 +30,10 @@ def test_every_cell_finds_its_files_and_readers(bench):
         for m in cell.per_layer:
             assert callable(cell.layer_reader(m["name"]))
         for shape, q in cell.queries.items():
-            assert "{key}" in q["dsl"] and q["reference_rule"]
+            rule = cell.rules[shape]
+            assert rule.COLUMNS and callable(rule.rows)
+            # a keyed rule's dsl carries the key; a whole-store rule's not
+            assert ("{key}" in q["dsl"]) == (rule.KEY is not None)
 
 
 def test_files_are_named_from_name_characters(bench):

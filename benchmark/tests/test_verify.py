@@ -7,10 +7,12 @@ and never mixes two states)."""
 import pytest
 
 from benchmark.harness.cell import verify_records
+from benchmark.harness.spec import Cell
 from benchmark.reference import generator, plain
 
-QUERIES = {"grounded3": {"reference_rule": "grounded3"},
-           "shared2": {"reference_rule": "shared2"}}
+#: shape -> its loaded rule file, as a cell holds them
+RULES = Cell("mem-uniform-closed").rules
+COLUMNS = RULES["grounded3"].COLUMNS
 
 
 @pytest.fixture()
@@ -18,8 +20,12 @@ def kb():
     return plain.PlainKB(generator.Store(0.002, 11))
 
 
+def grounded3(kb, g):
+    return RULES["grounded3"].rows(kb, g)
+
+
 def gene_with_rows(kb):
-    return next(g for g in range(kb.store.n_genes) if kb.grounded3(g))
+    return next(g for g in range(kb.store.n_genes) if grounded3(kb, g))
 
 
 def commit(kb, g, v, n=2):
@@ -38,7 +44,8 @@ def commit(kb, g, v, n=2):
 
 
 def record(kb, shape, g, v, sent, recv, drop=0, extra=None):
-    rows = kb.canonical_rows(kb.rows(shape, g), v)
+    rule = RULES[shape]
+    rows = kb.canonical_rows(rule.rows(kb, g), v, rule.COLUMNS)
     rows = rows[drop:] + ([extra] if extra else [])
     return {"c": 0, "i": 0, "shape": shape, "key": g, "ok": True,
             "sent": sent, "recv": recv, "n": len(rows),
@@ -47,10 +54,11 @@ def record(kb, shape, g, v, sent, recv, drop=0, extra=None):
 
 def test_reference_states_grow_with_commits(kb):
     g = gene_with_rows(kb)
-    base = kb.canonical_rows(kb.grounded3(g), 0)
+    base = kb.canonical_rows(grounded3(kb, g), 0, COLUMNS)
     commit(kb, g, 1)
     commit(kb, g, 2)
-    at = [kb.canonical_rows(kb.grounded3(g), v) for v in (0, 1, 2, None)]
+    at = [kb.canonical_rows(grounded3(kb, g), v, COLUMNS)
+          for v in (0, 1, 2, None)]
     assert at[0] == base and at[3] == at[2]
     assert set(at[0]) < set(at[1]) < set(at[2])
     assert len(at[1]) >= len(base) + 2 and len(at[2]) >= len(at[1]) + 2
@@ -71,7 +79,7 @@ def test_answers_between_send_and_receive_are_accepted(kb):
         record(kb, "grounded3", g, 2, 19.0, 30.0),    # saw commit 2
         record(kb, "shared2", g, 2, 25.0, 26.0),
     ]
-    out = verify_records(cases, kb, QUERIES, acked, issued)
+    out = verify_records(cases, kb, RULES, acked, issued)
     assert out["wrong"] == [] and out["raced_a_commit"] == 3
 
 
@@ -88,8 +96,8 @@ def test_wrong_answers_are_caught(kb, case):
     elif case == "from_the_future":    # commit 2 not yet issued, seen
         rec = record(kb, "grounded3", g, 2, 11.0, 12.0)
     elif case == "mixed_states":       # half of commit 1's rows
-        full = kb.canonical_rows(kb.grounded3(g), 1)
-        base = kb.canonical_rows(kb.grounded3(g), 0)
+        full = kb.canonical_rows(grounded3(kb, g), 1, COLUMNS)
+        base = kb.canonical_rows(grounded3(kb, g), 0, COLUMNS)
         new = [r for r in full if r not in base]
         rows = sorted(base + new[:1])
         rec = {"c": 0, "i": 0, "shape": "grounded3", "key": g, "ok": True,
@@ -100,14 +108,14 @@ def test_wrong_answers_are_caught(kb, case):
     else:
         rec = record(kb, "shared2", g, 2, 25.0, 26.0,
                      extra="$2=" + "f" * 32 + ",$3=" + "e" * 32)
-    out = verify_records([rec], kb, QUERIES, acked, issued)
+    out = verify_records([rec], kb, RULES, acked, issued)
     assert len(out["wrong"]) == 1
 
 
 def test_a_failed_request_is_failed_not_wrong(kb):
     rec = {"c": 0, "i": 0, "shape": "grounded3", "key": 1, "ok": False,
            "sent": 1.0, "recv": 2.0, "err": "DAS-RETRY kind=saturated"}
-    out = verify_records([rec], kb, QUERIES, [], [])
+    out = verify_records([rec], kb, RULES, [], [])
     assert out["wrong"] == [] and len(out["failed"]) == 1
 
 
