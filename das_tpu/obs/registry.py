@@ -52,6 +52,13 @@ SPAN_NAMES = (
     #: route, rounds, planner est rows; `lanes` when the program is a
     #: group's (_ExecJob.dispatch_group: the jobs it carries)
     "exec.dispatch",
+    #: span: the build of ONE batch's jobs (query/fused.py
+    #: FusedExecutor._build_jobs, inside serve.dispatch, before the
+    #: batch's exec.dispatch spans): per query SHAPE a kept template,
+    #: per query the planner's fold on the batch's statistics — attrs:
+    #: queries (cache-missing, de-duplicated), shapes, templates_built.
+    #: One per batch, never one per query
+    "exec.build",
     #: span: one settle round's host transfer — a device-to-host sync
     #: (query/fused.py settle_pending_iter, DL013's one-transfer site)
     "exec.settle_fetch",
@@ -151,6 +158,13 @@ COUNTER_NAMES = (
     #: (1.0 where every job rides alone)
     "exec.group_programs",
     "exec.group_lanes",
+    #: the job builder's per-shape templates (query/fused.py
+    #: _JobTemplate): BUILT (a shape's first query of a delta_version)
+    #: and jobs filled from a kept one.  Hit share = hits / (hits +
+    #: builds): ~1 on a read-only store, lower by one build per served
+    #: shape and commit where commits land
+    "exec.template_builds",
+    "exec.template_hits",
     #: how an answer left the executor: its HANDLE text printed from
     #: the block of distinct rows (api/atomspace.py _format_answer), or
     #: its block turned into frozen assignment objects because a

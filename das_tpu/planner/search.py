@@ -298,22 +298,15 @@ def _greedy_order(est, terms: List) -> Tuple[int, ...]:
     return tuple(order)
 
 
-def plan_conjunction(db, plans, *, n_shards: int = 1) -> Optional[PlannedProgram]:
-    """Turn a conjunction into a costed whole-plan program, or None when
-    the planner declines (no estimator surface, disconnected positives)
-    — the caller falls back to the legacy heuristics, answer-identical.
-
-    `n_shards > 1` scales the capacity seeds to PER-SHARD buffers (the
-    sharded executor's join_caps unit), with the same 2x skew headroom
-    its probe capacities use.
-
-    Pure planning — no counters here: explain() calls this too, and the
-    planned/method telemetry must decompose EXECUTOR traffic only (the
-    hooks count via planner.record_planned)."""
+def conjunction_rule(plans):
+    """What of a plan the SHAPE of the conjunction decides, whatever
+    its grounded values: `(pos_idx, neg_idx, method)`, or None when the
+    planner declines (no positive term, disconnected positives).
+    `method` is "ref_order" where the reference-order rule fixes the
+    order, else None: the order is then searched per query from its
+    own counts.  The executor's job builder keeps it per shape
+    (query/fused.py _JobTemplate)."""
     if not plans or not isinstance(plans, (list, tuple)):
-        return None
-    est = estimator_for(db)
-    if est is None:
         return None
     pos_idx = [i for i, p in enumerate(plans) if not p.negated]
     neg_idx = [i for i, p in enumerate(plans) if p.negated]
@@ -322,12 +315,41 @@ def plan_conjunction(db, plans, *, n_shards: int = 1) -> Optional[PlannedProgram
     positives = [plans[i] for i in pos_idx]
     if not _connected(positives):
         return None  # cross products: legacy ordering owns the rare case
-
     # reference-order authority rule — ONE shared predicate with
     # order_plans (see module docstring)
-    if reference_order_authoritative(positives):
+    method = "ref_order" if reference_order_authoritative(positives) else None
+    return pos_idx, neg_idx, method
+
+
+def plan_conjunction(
+    db, plans, *, n_shards: int = 1, est=None, rule=None,
+) -> Optional[PlannedProgram]:
+    """Turn a conjunction into a costed whole-plan program, or None when
+    the planner declines (no estimator surface, disconnected positives)
+    — the caller falls back to the legacy heuristics, answer-identical.
+
+    `n_shards > 1` scales the capacity seeds to PER-SHARD buffers (the
+    sharded executor's join_caps unit), with the same 2x skew headroom
+    its probe capacities use.  `est`: the estimator to read (default:
+    the backend's live one; the job builder passes its batch's
+    `BatchEstimator`), `rule`: `conjunction_rule(plans)` where the
+    caller kept it.
+
+    Pure planning — no counters here: explain() calls this too, and the
+    planned/method telemetry must decompose EXECUTOR traffic only (the
+    hooks count via planner.record_planned)."""
+    if est is None:
+        est = estimator_for(db)
+        if est is None:
+            return None
+    if rule is None:
+        rule = conjunction_rule(plans)
+        if rule is None:
+            return None
+    pos_idx, neg_idx, method = rule
+    positives = [plans[i] for i in pos_idx]
+    if method is not None:
         order_pos: Tuple[int, ...] = tuple(range(len(positives)))
-        method = "ref_order"
     elif len(positives) <= dp_max():
         order_pos = _dp_order(est, positives)
         method = "dp"

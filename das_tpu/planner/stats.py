@@ -161,7 +161,7 @@ class CardinalityEstimator:
         segment-identity-validated so commits invalidate naturally.
         None when the shape has no support extraction (templates,
         repeated variables)."""
-        if plan.ctype is not None or plan.type_id is None or plan.eq_pairs:
+        if not self._has_support(plan):
             return None
         from das_tpu.query import starcount
 
@@ -170,6 +170,13 @@ class CardinalityEstimator:
         if plan.fixed:
             return starcount._host_sparse_deg(self.db, spec)
         return starcount._table_sparse(self.db, spec)
+
+    @staticmethod
+    def _has_support(plan) -> bool:
+        return (
+            plan.ctype is None and plan.type_id is not None
+            and not plan.eq_pairs
+        )
 
     def exact_join_rows(self, pa, pb, var: str) -> Optional[int]:
         """EXACT output rows of a leaf ⋈ leaf join on ONE shared
@@ -194,20 +201,23 @@ class CardinalityEstimator:
         pos_b = pb.var_cols[pb.var_names.index(var)]
         key = ("dot", self._plan_key(pa), pos_a, self._plan_key(pb), pos_b)
         hit = self._rows.get(key)
-        if hit is not None:
-            return hit if hit >= 0 else None
+        if hit is None:
+            out = self._dot(pa, pb, var)
+            hit = self._rows[key] = -1 if out is None else out
+        return hit if hit >= 0 else None
+
+    def _dot(self, pa, pb, var: str) -> Optional[int]:
+        """`exact_join_rows` without its memo: the sparse dot of the two
+        supports, None where a side has none."""
         ea = self._support(pa, var)
         eb = self._support(pb, var)
         if ea is None or eb is None:
-            self._rows[key] = -1
             return None
         (ia, ca), _ta = ea
         (ib, cb), _tb = eb
         if ia.size > ib.size:
             (ia, ca), (ib, cb) = (ib, cb), (ia, ca)
-        out = int((ca * _probe_degrees(ia, ib, cb)).sum())
-        self._rows[key] = out
-        return out
+        return int((ca * _probe_degrees(ia, ib, cb)).sum())
 
     def star_rows(self, plans, var: str) -> Tuple[float, bool]:
         """(rows, exact) of the k-way STAR join of base terms on ONE
@@ -231,29 +241,36 @@ class CardinalityEstimator:
             for p in plans
         )
         hit = self._rows.get(key)
-        if hit is not None and hit >= 0:
-            return float(hit), True
         if hit is None:
-            sups = [self._support(p, var) for p in plans]
-            if all(s is not None for s in sups):
-                arrs = sorted(
-                    ((ia, ca) for (ia, ca), _t in sups),
-                    key=lambda t: t[0].size,
-                )
-                base_i, prod = arrs[0][0], arrs[0][1].astype(np.int64)
-                for ia, ca in arrs[1:]:
-                    prod = prod * _probe_degrees(base_i, ia, ca)
-                out = int(prod.sum()) if prod.size else 0
-                self._rows[key] = out
-                return float(out), True
-            self._rows[key] = -1
-        # no support for some clause (template/repeated-var shapes):
-        # fold the pairwise model — the chain's estimate, same error bar
+            out = self._star_exact(plans, var)
+            hit = self._rows[key] = -1 if out is None else out
+        if hit >= 0:
+            return float(hit), True
+        return self._star_model(plans), False
+
+    def _star_exact(self, plans, var: str) -> Optional[int]:
+        """`star_rows`' exact statistic without its memo; None where a
+        clause has no support extraction."""
+        sups = [self._support(p, var) for p in plans]
+        if any(s is None for s in sups):
+            return None
+        arrs = sorted(
+            ((ia, ca) for (ia, ca), _t in sups), key=lambda t: t[0].size
+        )
+        base_i, prod = arrs[0][0], arrs[0][1].astype(np.int64)
+        for ia, ca in arrs[1:]:
+            prod = prod * _probe_degrees(base_i, ia, ca)
+        return int(prod.sum()) if prod.size else 0
+
+    def _star_model(self, plans) -> float:
+        """No support for some clause (template / repeated-variable
+        shapes): fold the pairwise model, the chain's estimate with the
+        same error bar."""
         rels = [self.term_estimate(p) for p in plans]
         acc = rels[0]
         for r in rels[1:]:
             acc = self.join_estimate(acc, r)
-        return acc.rows, False
+        return acc.rows
 
     def pair_join_rows(
         self, left: RelEstimate, right: RelEstimate, var: str
@@ -300,6 +317,211 @@ class CardinalityEstimator:
         for v in dv:
             dv[v] = max(min(dv[v], rows), 1.0 if rows else 0.0)
         return RelEstimate(rows, dv)
+
+
+class BatchEstimator(CardinalityEstimator):
+    """The estimator over N conjunctions of ONE shape (the same terms,
+    other grounded values: query/fused.py `shape_key`), for the
+    executor's job builder: the statistics that differ between two
+    queries of a shape are read for all N at once, the first time a
+    fold asks for one, and every later fold of the batch finds its
+    number in a list.
+
+      * a grounded term's exact rows: ONE searchsorted per (term, side,
+        host segment) over the vector of probe keys;
+      * a grounded leaf x table leaves on one variable (the pairwise
+        dot, the k-way star): sum_v deg_g(v) * prod_j deg_j(v) is the
+        sum of prod_j deg_j over the ROWS of the grounded term, so the
+        N ranges are gathered once, probed into the tables' kept
+        supports (`_probe_degrees`) and summed per query: no unique,
+        no `_host_sparse_deg` entry per grounded value;
+      * what no grounded value enters (a table's rows, its distinct
+        counts, table x table) is the live estimator's, memo and all;
+      * two or more grounded leaves in one statistic: per query,
+        through `_dot` / `_star_exact`, unmemoized.
+
+    The formulas (`term_estimate`, `join_estimate`, `pair_join_rows`,
+    the planner's chain fold) are the base class's, run per query on
+    plain numbers after `at(i)`: what is batched is the statistics,
+    never a second copy of a rule.  Nothing per grounded value is
+    written to the live estimator's memo (ROADMAP D17).  Valid for the
+    one batch it was built for."""
+
+    def __init__(self, base: CardinalityEstimator, plans_lists):
+        self.db = base.db
+        self.version = base.version
+        self._base = base
+        self._distinct = base._distinct
+        self._lists = plans_lists
+        self._n = len(plans_lists)
+        self._cols: Dict[Tuple, object] = {}
+        self._i = 0
+        self._term: Dict[int, int] = {}
+
+    def at(self, i: int) -> "BatchEstimator":
+        """Answer for query `i` of the batch from here on."""
+        self._i = i
+        self._term = {id(p): t for t, p in enumerate(self._lists[i])}
+        return self
+
+    def term_of(self, plan) -> int:
+        """Index of `plan` in the current query's plan list."""
+        return self._term[id(plan)]
+
+    def _col(self, key, make, *args):
+        """The batch's column under `key`, made on first demand (None is
+        a column: "no such statistic").  `rows`, `term_estimate` and
+        `exact_join_rows`, asked several times per query, look theirs
+        up in place."""
+        col = self._cols.get(key, self)
+        if col is self:
+            col = self._cols[key] = make(*args)
+        return col
+
+    # -- the grounded values, as vectors -------------------------------------
+
+    def fixed_col(self, t: int, k: int) -> np.ndarray:
+        """int64[N]: the k-th grounded value of term `t`, per query."""
+        return self._col(("fixed", t, k), self._fixed_col, t, k)
+
+    def _fixed_col(self, t, k):
+        return np.fromiter(
+            (pl[t].fixed[k][1] for pl in self._lists), np.int64, self._n
+        )
+
+    def key_col(self, t: int) -> np.ndarray:
+        """int64[N]: term `t`'s probe key (type_id << 32 | v0), the key
+        the device program searches and estimate_plan_rows counts."""
+        return self._col(("key", t), self._key_col, t)
+
+    def _key_col(self, t):
+        return (np.int64(self._lists[0][t].type_id) << 32) | self.fixed_col(t, 0)
+
+    # -- raw statistics ------------------------------------------------------
+
+    def rows(self, plan) -> int:
+        t = self._term[id(plan)]
+        col = self._cols.get(t)
+        if col is None:
+            col = self._cols[t] = self._rows_col(t)
+        return col[self._i]
+
+    def term_estimate(self, plan) -> RelEstimate:
+        """The base formula; of a term no grounded value enters, ONE
+        estimate for the batch (a fold reads a RelEstimate, it never
+        writes one)."""
+        if plan.fixed:
+            return super().term_estimate(plan)
+        key = ("term", self._term[id(plan)])
+        rel = self._cols.get(key)
+        if rel is None:
+            rel = self._cols[key] = super().term_estimate(plan)
+        return RelEstimate(rel.rows, rel.dv, plan=plan)
+
+    def _ranges(self, t):
+        """Per host segment `(segment, lo, count)`: grounded term `t`'s
+        key range for all N queries, ONE searchsorted per side."""
+        from das_tpu.storage.atom_table import host_segments
+
+        plan = self._lists[0][t]
+        p0 = plan.fixed[0][0]
+        keys = self.key_col(t)
+        out = []
+        for b in host_segments(self.db, plan.arity):
+            sk = b.key_type_pos[p0]
+            lo = np.searchsorted(sk, keys, side="left")
+            out.append((b, lo, np.searchsorted(sk, keys, side="right") - lo))
+        return out
+
+    def _rows_col(self, t):
+        plan = self._lists[0][t]
+        if not plan.fixed:
+            return [self._base.rows(plan)] * self._n
+        ranges = self._col(("ranges", t), self._ranges, t)
+        if len(ranges) == 1:
+            return ranges[0][2].tolist()
+        return sum(cnt for _b, _lo, cnt in ranges).tolist()
+
+    def exact_join_rows(self, pa, pb, var: str) -> Optional[int]:
+        key = (self._term[id(pa)], self._term[id(pb)], var)
+        col = self._cols.get(key, self)
+        if col is self:
+            col = self._cols[key] = self._star_col(key[:2], var)
+        return None if col is None else col[self._i]
+
+    def star_rows(self, plans, var: str) -> Tuple[float, bool]:
+        ts = tuple(self._term[id(p)] for p in plans)
+        col = self._col(("mdot", ts, var), self._star_col, ts, var)
+        if col is not None:
+            return float(col[self._i]), True
+        return self._star_model(plans), False
+
+    def _star_col(self, ts, var):
+        """Per query, sum_v prod_j deg_j(v) over the terms `ts` (two:
+        the pairwise dot); None where a term has no support."""
+        first = [self._lists[0][t] for t in ts]
+        sups = {}
+        for t, p in zip(ts, first):
+            if not self._has_support(p):
+                return None
+            if not p.fixed:
+                sups[t] = self._support(p, var)
+                if sups[t] is None:
+                    return None
+        grounded = [t for t in ts if t not in sups]
+        if len(grounded) == 1:
+            return self._grounded_star(
+                grounded[0], var, [sups[t][0] for t in ts if t in sups]
+            )
+        if grounded:
+            out = [
+                self._base._star_exact([pl[t] for t in ts], var)
+                for pl in self._lists
+            ]
+            return None if any(o is None for o in out) else out
+        # tables alone: the live estimator's memo keeps it
+        if len(ts) == 2:
+            one = self._base.exact_join_rows(first[0], first[1], var)
+        else:
+            rows, exact = self._base.star_rows(first, var)
+            one = int(rows) if exact else None
+        return None if one is None else [one] * self._n
+
+    def _grounded_star(self, t, var, tables):
+        """sum over the rows of grounded term `t` of prod deg_table at
+        the row's value of `var`, for all N queries: the rows of every
+        query's key range gathered in one pass per host segment."""
+        plan = self._lists[0][t]
+        ranges = self._col(("ranges", t), self._ranges, t)
+        if not ranges:
+            return None
+        p0 = plan.fixed[0][0]
+        pos = plan.var_cols[plan.var_names.index(var)]
+        n = self._n
+        out = np.zeros(n, np.int64)
+        for b, lo, cnt in ranges:
+            total = int(cnt.sum())
+            if total == 0:
+                continue
+            qid = np.repeat(np.arange(n), cnt)
+            at = np.arange(total) + np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+            local = b.order_by_type_pos[p0][at]
+            vals = b.targets[local, pos]
+            ok = vals >= 0  # device parity: dangling rows never scatter
+            for k, (q, _v) in enumerate(plan.fixed[1:], 1):
+                ok &= b.targets[local, q] == self.fixed_col(t, k)[qid]
+            if not ok.all():
+                vals, qid = vals[ok], qid[ok]
+            vals = vals.astype(np.int64)
+            w = None
+            for ib, cb in tables:
+                deg = _probe_degrees(vals, ib, cb)
+                w = deg if w is None else w * deg
+            # the sums are whole numbers far below 2^53 (a join past
+            # max_result_capacity is declined), where float64 adds them
+            # exactly
+            out += np.bincount(qid, weights=w, minlength=n).astype(np.int64)
+        return out.tolist()
 
 
 def estimator_for(db) -> Optional[CardinalityEstimator]:

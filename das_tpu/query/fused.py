@@ -172,11 +172,13 @@ class _ExecJob:
         "ex", "count_only", "same_order", "sigs", "arrays", "keys", "fvals",
         "term_caps", "join_caps", "index_joins", "names",
         "result", "planned", "rounds", "last_ranges", "last_join_rows",
+        "_sig", "lanes", "row",
     )
 
     def __init__(
         self, ex, count_only, same_order, sigs, arrays, keys, fvals,
         term_caps, join_caps, index_joins, planned=None,
+        sig=None, lanes=None, row=0,
     ):
         self.ex = ex
         self.count_only = count_only
@@ -197,15 +199,35 @@ class _ExecJob:
         self.rounds = 0
         self.last_ranges = None      # final-round per-term exact ranges
         self.last_join_rows = None   # final-round per-join exact totals
+        #: the signature at the capacities the job was built with: ONE
+        #: object for every job of a batch that ended with equal
+        #: capacities (FusedExecutor._fill), whose `term_caps` /
+        #: `join_caps` tuples ARE this job's
+        self._sig = sig
+        #: the builder's lane columns, `(key columns, fixed-value
+        #: columns)` per term slot with the batch's same-shape queries
+        #: along axis 0, and this job's row in them: what
+        #: dispatch_group stacks from (`keys` / `fvals` are this row)
+        self.lanes = lanes
+        self.row = row
 
     def plan_sig(self) -> FusedPlanSig:
-        """The plan signature at the CURRENT capacities.  Shared by
-        dispatch() and the whole-tree job (_TreeExecJob), whose tree
-        signature nests one of these per site."""
-        return FusedPlanSig(
-            self.sigs, self.term_caps, self.join_caps, self.index_joins,
-            self.planned is not None,
-        )
+        """The plan signature at the CURRENT capacities: the builder's
+        shared object until a settle grows a capacity (settle assigns
+        new tuples, so identity tells).  Shared by dispatch() and the
+        whole-tree job (_TreeExecJob), whose tree signature nests one
+        of these per site."""
+        sig = self._sig
+        if (
+            sig is None
+            or sig.term_caps is not self.term_caps
+            or sig.join_caps is not self.join_caps
+        ):
+            sig = self._sig = FusedPlanSig(
+                self.sigs, self.term_caps, self.join_caps, self.index_joins,
+                self.planned is not None,
+            )
+        return sig
 
     def dispatch(self, plan_sig=None):
         """Queue the program at the current capacities (async, no sync);
@@ -256,12 +278,23 @@ class _ExecJob:
         and one count_only): build_fused's body over their lane-stacked
         probe keys and fixed values, the bucket arrays passed once and
         unbatched.  Outputs carry a leading lanes axis; a lane past the
-        last job repeats it and is never read."""
+        last job repeats it and is never read.  Jobs of one fill (the
+        first round of a batch) take their rows out of the builder's
+        lane columns, one fancy index per term slot; jobs that met in a
+        retry round stack their own values."""
         lead = jobs[0]
         ex, count_only = lead.ex, lead.count_only
-        keys, key_axes, fvals, fval_axes = stack_lanes(
-            [j.keys for j in jobs], [j.fvals for j in jobs], GROUP_LANES
-        )
+        lanes = lead.lanes
+        if lanes is not None and all(j.lanes is lanes for j in jobs):
+            at = [j.row for j in jobs]
+            at += at[-1:] * (GROUP_LANES - len(at))
+            keys, key_axes, fvals, fval_axes = hoist_lanes(
+                [col[at] for col in lanes[0]], [col[at] for col in lanes[1]]
+            )
+        else:
+            keys, key_axes, fvals, fval_axes = stack_lanes(
+                [j.keys for j in jobs], [j.fvals for j in jobs], GROUP_LANES
+            )
         cache_key = (plan_sig, count_only, GROUP_LANES, key_axes, fval_axes)
         entry = ex._group_cache.get(cache_key)
         if entry is None:
@@ -383,9 +416,11 @@ class _PendingMany:
 #: lanes of a served group program: a group of 2..32 jobs is padded to
 #: it, a wider one cut into programs of it.  ONE rung: every rung is one
 #: more compiled program per query shape and capacity step, and a rung
-#: first met inside a serving window is a compile inside it; 28 padded
-#: lanes cost the device 3.4-5 ms a program (PERF.md §6, PR 30), behind
-#: a host that spends 3.8 ms a query
+#: first met inside a serving window is a compile inside it.  A padded
+#: lane is device time: a 32-lane program costs the device 4-7 ms
+#: whether 2 or 32 of its lanes carry a query (PERF.md §5: 0.41 ms a
+#: query at 11 lanes a program, 1.2 ms at 2.3), which one rung accepts
+#: while the ONE host thread, not the device, sets the pace
 GROUP_LANES = 32
 
 
@@ -397,15 +432,26 @@ def _dispatch_round(entries):
     same capacities, same route — ride ONE program; a job alone in its
     signature, and every job of a type without the hooks (the mesh
     job), is enqueued by its own `dispatch()`, the program and cache
-    entry it always had.  Returns `[(members, device output)]`, one per
-    program."""
+    entry it always had.  The jobs of a batch that the builder gave
+    equal capacities hold ONE signature object, so a signature (three
+    nested dataclasses) is hashed once per group here, not once per
+    job: jobs are told apart by the identity of their signature first,
+    and only distinct objects meet in the dict that compares them.
+    Returns `[(members, device output)]`, one per program."""
     programs = []
     groups: Dict[Tuple, List] = {}
+    by_object: Dict[Tuple, List] = {}
     for entry in entries:
         job = entry[1]
         if hasattr(job, "dispatch_group"):
-            sig = (type(job), job.plan_sig(), job.count_only)
-            groups.setdefault(sig, []).append(entry)
+            plan_sig = job.plan_sig()
+            seen = (id(plan_sig), job.count_only)
+            members = by_object.get(seen)
+            if members is None:
+                members = by_object[seen] = groups.setdefault(
+                    (type(job), plan_sig, job.count_only), []
+                )
+            members.append(entry)
         else:
             programs.append(([entry], job.dispatch()))
     for (_cls, plan_sig, _co), members in groups.items():
@@ -423,18 +469,25 @@ def _dispatch_round(entries):
 
 
 def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
-                     cache_only=False):
+                     cache_only=False, build_jobs=None):
     """Phase-1 shared loop (pendant of settle_pending): resolve
-    result-cache hits, dedup identical in-batch queries, prepare and
-    ENQUEUE the remaining jobs' first round — all asynchronous, one
-    program per same-signature group (_dispatch_round).
-    `exec_job(plans, count_only)` returns a dispatchable job or None.
+    result-cache hits, dedup identical in-batch queries, build the
+    remaining queries' jobs and ENQUEUE their first round — all
+    asynchronous, one program per same-signature group
+    (_dispatch_round).  `exec_job(plans, count_only)` returns a
+    dispatchable job or None (a decline: missing bucket, capacity
+    ceiling — per query); an executor that builds the jobs of a batch
+    together passes `build_jobs(plans_lists, count_only)` -> one job
+    or None per entry (FusedExecutor._build_jobs: once per query SHAPE,
+    the rest filled per query) and the loop hands it every
+    cache-missing, de-duplicated query of the batch at once.
     Shared by the single-device and sharded executors so the dedup
-    invariant (duplicates alias ONE shared index list, and never record
-    their own cache miss) lives in exactly one place."""
+    invariant (duplicates alias ONE shared index list BEFORE the cache
+    look-up, and never record their own cache miss) lives in exactly
+    one place."""
     results: List = [None] * len(plans_lists)
     version = results_cache.version()
-    jobs = []
+    todo = []
     by_key: Dict[Tuple, List[int]] = {}
     for i, plans in enumerate(plans_lists):
         key = results_cache.key(plans, count_only)
@@ -456,11 +509,16 @@ def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
             # delta-versioned cache ONLY — a miss stays a dispatch-time
             # decline (results[i] None, no device program enqueued)
             continue
-        job = exec_job(plans, count_only)
-        if job is not None:
-            idxs = [i]
-            by_key[key] = idxs
-            jobs.append((idxs, job, key))
+        idxs = by_key[key] = [i]
+        todo.append((idxs, plans, key))
+    if build_jobs is None or not todo:
+        built = [exec_job(plans, count_only) for _, plans, _ in todo]
+    else:
+        built = build_jobs([plans for _, plans, _ in todo], count_only)
+    jobs = [
+        (idxs, job, key)
+        for (idxs, _, key), job in zip(todo, built) if job is not None
+    ]
     return _PendingMany(results, _dispatch_round(jobs), version)
 
 
@@ -597,6 +655,45 @@ def _pow2_at_least(n: int, lo: int = 16) -> int:
     while c < n:
         c *= 2
     return c
+
+
+def term_sig(plan) -> FusedTermSig:
+    """The shape-static signature of one compiler.TermPlan: its probe
+    route and everything else the traced program reads of it, no
+    grounded value."""
+    if plan.ctype is not None:
+        route, p0, extra = ROUTE_CTYPE, -1, ()
+    elif plan.type_id is not None and plan.fixed:
+        p0 = plan.fixed[0][0]
+        route, extra = ROUTE_TYPE_POS, tuple(p for p, _ in plan.fixed[1:])
+    else:
+        # plan_query guarantees type_id or ctype is set (TermPlan
+        # invariant) — an untyped plan cannot reach the fused path
+        assert plan.type_id is not None, "TermPlan without type or ctype"
+        route, p0, extra = ROUTE_TYPE, -1, ()
+    return FusedTermSig(
+        arity=plan.arity,
+        route=route,
+        p0=p0,
+        extra_fixed=extra,
+        var_cols=plan.var_cols,
+        eq_pairs=plan.eq_pairs,
+        var_names=plan.var_names,
+        negated=plan.negated,
+    )
+
+
+def route_arrays(bucket, sig: FusedTermSig):
+    """(sorted keys, permutation, targets, type ids) of the index a
+    term's route probes, off a DeviceBucket."""
+    if sig.route == ROUTE_CTYPE:
+        return (bucket.key_ctype, bucket.order_by_ctype, bucket.targets,
+                bucket.type_id)
+    if sig.route == ROUTE_TYPE_POS:
+        return (bucket.key_type_pos[sig.p0], bucket.order_by_type_pos[sig.p0],
+                bucket.targets, bucket.type_id)
+    return (bucket.key_type, bucket.order_by_type, bucket.targets,
+            bucket.type_id)
 
 
 def _probe(sig: FusedTermSig, arrays, key, fixed_vals, cap: int):
@@ -894,36 +991,48 @@ def stack_or_const(rows):
     identical — None axes let XLA compute constant terms (e.g. an
     ungrounded probe shared by the whole batch) ONCE instead of per
     member."""
-    stacked = np.stack(rows)
-    if (stacked == stacked[0]).all():
-        return rows[0], None
-    return stacked, 0
+    return hoist_lane(np.stack(rows))
+
+
+def hoist_lane(col):
+    """stack_or_const's rule on a slot already stacked (lanes along
+    axis 0)."""
+    return (col[0], None) if (col == col[0]).all() else (col, 0)
+
+
+def hoist_lanes(key_cols, fval_cols):
+    """The inputs of ONE lane-batched program from its lane columns (per
+    term slot an array with the lanes along axis 0): a slot whose lanes
+    differ stays stacked (axis 0), one whose lanes are all equal is
+    hoisted to its one value (axis None: XLA computes a constant term,
+    e.g. the ungrounded probe a whole group shares, ONCE instead of per
+    lane).  Returns (keys, key_axes, fvals, fval_axes)."""
+    keys, key_axes = zip(*map(hoist_lane, key_cols))
+    fvals, fval_axes = zip(*map(hoist_lane, fval_cols))
+    if all(a is None for a in key_axes + fval_axes):
+        # every lane is the same query (one lane, or duplicates): the
+        # first slot stays stacked, so the program has its lanes axis
+        keys = (key_cols[0],) + keys[1:]
+        key_axes = (0,) + key_axes[1:]
+    return keys, key_axes, fvals, fval_axes
 
 
 def stack_lanes(key_rows, fval_rows, lanes: int):
-    """Per-member probe keys and fixed values (one tuple of per-term
-    values each) as the inputs of ONE lane-batched program: padded to
-    `lanes` by repeating the last member (jit re-traces per stacked
-    shape, so the lane count comes from a ladder; the padded lanes'
-    output rows are dropped), each term slot stacked or hoisted
-    (stack_or_const).  Returns (keys, key_axes, fvals, fval_axes)."""
+    """hoist_lanes for callers that hold per-MEMBER values (one tuple of
+    per-term probe keys and one of fixed values each: a retry round's
+    regrouped jobs, the count batches): padded to `lanes` by repeating
+    the last member (jit re-traces per stacked shape, so the lane count
+    comes from a ladder; the padded lanes' output rows are dropped),
+    then one column per term slot."""
     pad = lanes - len(key_rows)
     if pad:
         key_rows = list(key_rows) + [key_rows[-1]] * pad
         fval_rows = list(fval_rows) + [fval_rows[-1]] * pad
     n_terms = len(key_rows[0])
-    keys, key_axes = zip(*(
-        stack_or_const([kr[t] for kr in key_rows]) for t in range(n_terms)
-    ))
-    fvals, fval_axes = zip(*(
-        stack_or_const([fr[t] for fr in fval_rows]) for t in range(n_terms)
-    ))
-    if all(a is None for a in key_axes + fval_axes):
-        # every lane is the same query (one lane, or duplicates): the
-        # first slot stays stacked, so the program has its lanes axis
-        keys = (np.stack([kr[0] for kr in key_rows]),) + keys[1:]
-        key_axes = (0,) + key_axes[1:]
-    return keys, key_axes, fvals, fval_axes
+    return hoist_lanes(
+        [np.stack([kr[t] for kr in key_rows]) for t in range(n_terms)],
+        [np.stack([fr[t] for fr in fval_rows]) for t in range(n_terms)],
+    )
 
 
 def lanes_program(fn, key_axes, fval_axes):
@@ -1480,20 +1589,27 @@ def apply_index_joins(buckets, sigs, arrays, term_caps):
     ShardedBucket — both carry key_type_pos/order_by_type_pos/targets/
     type_id), so both executors share one routing convention."""
     index_joins, index_right = plan_index_joins(sigs)
-    if index_right:
-        arrays = list(arrays)
-        term_caps = list(term_caps)
-        for i, n in index_right.items():
-            p = index_joins[n]
-            b = buckets[sigs[i].arity]
-            arrays[i] = (
-                b.key_type_pos[p], b.order_by_type_pos[p],
-                b.targets, b.type_id,
-            )
-            term_caps[i] = INDEX_TERM_TOKEN_CAP
-        arrays = tuple(arrays)
-        term_caps = tuple(term_caps)
-    return index_joins, frozenset(index_right), arrays, term_caps
+    return (
+        index_joins, frozenset(index_right),
+        index_join_arrays(buckets, sigs, arrays, index_joins, index_right),
+        clamp_index_terms(term_caps, index_right),
+    )
+
+
+def index_join_arrays(buckets, sigs, arrays, index_joins, index_right):
+    """`arrays` with every index-joined term's inputs replaced by the
+    posting index of the position its join probes (plan_index_joins'
+    `index_joins`, `right_terms`)."""
+    if not index_right:
+        return arrays
+    arrays = list(arrays)
+    for i, n in index_right.items():
+        p = index_joins[n]
+        b = buckets[sigs[i].arity]
+        arrays[i] = (
+            b.key_type_pos[p], b.order_by_type_pos[p], b.targets, b.type_id,
+        )
+    return tuple(arrays)
 
 
 def clamp_index_terms(term_caps, index_right):
@@ -1810,6 +1926,118 @@ def result_cache_stats(db) -> Dict[str, int]:
     return out
 
 
+def shape_key(plans) -> Tuple:
+    """The SHAPE of a conjunction: ResultCache.key's per-term digest
+    with every grounded value left out (of `fixed`, the positions
+    stay).  Two queries of one shape differ in grounded row ids alone:
+    they share a `_JobTemplate`.  `count_only` is not part of it:
+    nothing a template keeps depends on it."""
+    return tuple(
+        (
+            p.arity, p.type_id, p.ctype, tuple(q for q, _ in p.fixed),
+            p.var_names, p.var_cols, p.eq_pairs, p.negated,
+        )
+        for p in plans
+    )
+
+
+class _OrderedShape:
+    """What one join ORDER of a shape fixes: the term signatures in
+    that order, whether it is the reference fold (`same_order`), and
+    the index-join routing (plan_index_joins: a pure function of the
+    signatures)."""
+
+    __slots__ = ("sigs", "same_order", "index_joins", "index_right",
+                 "n_joins")
+
+    def __init__(self, sigs, order):
+        self.sigs = tuple(sigs[t] for t in order)
+        positives = [t for t in order if not sigs[t].negated]
+        # reseed semantics depend only on the POSITIVE term order
+        # (same_positive_order)
+        self.same_order = positives == sorted(positives)
+        self.index_joins, self.index_right = plan_index_joins(self.sigs)
+        self.n_joins = max(0, len(positives) - 1)
+
+
+class _JobTemplate:
+    """What the job builder keeps per query SHAPE (FusedExecutor._build):
+    everything `_exec_job` used to derive again for every query although
+    no grounded value enters it.
+
+      * `rule`: the planner's shape-level verdict
+        (planner/search.py conjunction_rule): declined, ordered by the
+        reference-order rule, or ordered per query;
+      * `sigs`: the FusedTermSig of each term, in the plan list's
+        order, and of each term its key recipe (route, type id or
+        ctype, probe position: `lane_columns`);
+      * per join order met (`ordered`): the signatures in that order,
+        `same_order`, `index_joins` / `index_right`, `n_joins`.  A
+        rule-ordered shape meets one order; a shape ordered per query
+        ("dp", "greedy_tail", the legacy greedy order) one per order
+        its queries' counts choose.
+
+    NOT kept: anything a commit replaces.  The bucket arrays are taken
+    fresh from `db.dev.buckets` per fill, the table-level statistics
+    (distinct counts, the kept whole-table supports) stay with the
+    estimator and starcount's caches under their own validity rules,
+    the learned capacities with the executor; the templates themselves
+    are dropped when `delta_version` moves."""
+
+    __slots__ = ("rule", "sigs", "consts", "orders", "_const_cols")
+
+    def __init__(self, plans):
+        from das_tpu.planner.search import conjunction_rule
+
+        self.rule = conjunction_rule(plans)
+        self.sigs = tuple(term_sig(p) for p in plans)
+        #: per term the probe key of a route no grounded value enters
+        #: (FusedExecutor._term_args' keys), None for a type-pos probe
+        self.consts = tuple(
+            np.int64(p.ctype) if sig.route == ROUTE_CTYPE
+            else np.int32(p.type_id) if sig.route == ROUTE_TYPE
+            else None
+            for p, sig in zip(plans, self.sigs)
+        )
+        self.orders: Dict[Tuple[int, ...], _OrderedShape] = {}
+        self._const_cols: Dict[int, Tuple] = {}
+
+    def ordered(self, order) -> _OrderedShape:
+        shape = self.orders.get(order)
+        if shape is None:
+            shape = self.orders[order] = _OrderedShape(self.sigs, order)
+        return shape
+
+    def lane_columns(self, stats, n: int):
+        """Per term (plan-list order) the probe keys and the verified
+        fixed values of `n` same-shape queries as columns, the queries
+        along axis 0: `(type_id << 32 | v0)` over the vector of v0, a
+        route's constant key repeated, the fixed values beyond the
+        probe as int32 [n, extras]."""
+        consts = self._const_cols.get(n)
+        if consts is None:
+            # the columns no grounded value enters, kept per width
+            consts = self._const_cols[n] = (
+                [None if c is None else np.full(n, c, c.dtype)
+                 for c in self.consts],
+                np.zeros((n, 0), np.int32),
+            )
+        key_cols = [
+            stats.key_col(t) if col is None else col
+            for t, col in enumerate(consts[0])
+        ]
+        fval_cols = [
+            np.stack(
+                [stats.fixed_col(t, k)
+                 for k in range(1, 1 + len(sig.extra_fixed))],
+                axis=1,
+            ).astype(np.int32)
+            if sig.extra_fixed else consts[1]
+            for t, sig in enumerate(self.sigs)
+        ]
+        return key_cols, fval_cols
+
+
 def get_executor(db) -> "FusedExecutor":
     """The per-database executor, cached on the device tables so a
     `refresh()` (which rebuilds them) naturally drops stale programs."""
@@ -1975,6 +2203,10 @@ class FusedExecutor:
         self._exact_caps: Dict[Tuple, Tuple[int, ...]] = {}
         self._cap_store = CapStore("greedy")
         self._exact_cap_store = CapStore("exact")
+        #: the job builder's per-shape templates (shape_key ->
+        #: _JobTemplate), valid for one delta_version (_build)
+        self._templates: Dict[Tuple, "_JobTemplate"] = {}
+        self._templates_version = None
 
     def _cap_salt(self) -> str:
         """Capacities are KB-size dependent: key the cross-process store by
@@ -2006,6 +2238,10 @@ class FusedExecutor:
         return (ps.term_caps, second)
 
     def _remember_caps(self, sigs, term_caps, join_caps) -> None:
+        if self._caps.get(sigs) == (term_caps, join_caps):
+            # known, and saved when it was learned: no CapStore key
+            # (an md5 of the signatures' repr) per settled job
+            return
         remember_caps(
             self._caps,
             (self._cache, self._batch_cache, self._group_cache), sigs,
@@ -2017,46 +2253,21 @@ class FusedExecutor:
 
     def _term_args(self, plan) -> Optional[Tuple[FusedTermSig, Tuple, object, np.ndarray]]:
         """Map a compiler.TermPlan to (sig, bucket_arrays, key, fixed_vals)."""
-        db = self.db
-        bucket = db.dev.buckets.get(plan.arity)
+        bucket = self.db.dev.buckets.get(plan.arity)
         if bucket is None or bucket.size == 0:
             return None
-        if plan.ctype is not None:
-            sig_route, p0, extra = ROUTE_CTYPE, -1, ()
-            arrays = (bucket.key_ctype, bucket.order_by_ctype, bucket.targets, bucket.type_id)
+        sig = term_sig(plan)
+        if sig.route == ROUTE_CTYPE:
             key = np.int64(plan.ctype)
-        elif plan.type_id is not None and plan.fixed:
-            p0, v0 = plan.fixed[0]
-            sig_route, extra = ROUTE_TYPE_POS, tuple(p for p, _ in plan.fixed[1:])
-            arrays = (
-                bucket.key_type_pos[p0],
-                bucket.order_by_type_pos[p0],
-                bucket.targets,
-                bucket.type_id,
-            )
-            key = (np.int64(plan.type_id) << 32) | np.int64(v0)
+        elif sig.route == ROUTE_TYPE_POS:
+            key = (np.int64(plan.type_id) << 32) | np.int64(plan.fixed[0][1])
         else:
-            # plan_query guarantees type_id or ctype is set (TermPlan
-            # invariant) — an untyped plan cannot reach the fused path
-            assert plan.type_id is not None, "TermPlan without type or ctype"
-            sig_route, p0, extra = ROUTE_TYPE, -1, ()
-            arrays = (bucket.key_type, bucket.order_by_type, bucket.targets, bucket.type_id)
             key = np.int32(plan.type_id)
         fixed_vals = np.asarray(
-            [v for _, v in plan.fixed[1:]] if sig_route == ROUTE_TYPE_POS else [],
+            [v for _, v in plan.fixed[1:]] if sig.route == ROUTE_TYPE_POS else [],
             dtype=np.int32,
         )
-        sig = FusedTermSig(
-            arity=plan.arity,
-            route=sig_route,
-            p0=p0,
-            extra_fixed=extra,
-            var_cols=plan.var_cols,
-            eq_pairs=plan.eq_pairs,
-            var_names=plan.var_names,
-            negated=plan.negated,
-        )
-        return sig, arrays, key, fixed_vals
+        return sig, route_arrays(bucket, sig), key, fixed_vals
 
     def _estimate(self, plan) -> int:
         return estimate_plan_rows(self.db, plan)
@@ -2068,7 +2279,7 @@ class FusedExecutor:
 
     _clamp_index_terms = staticmethod(clamp_index_terms)
 
-    def _join_cap_seed(self, plans, term_caps) -> int:
+    def _join_cap_seed(self, plans, term_caps, rows=None) -> int:
         """First-call join/chain capacity seed.  When the plan has grounded
         (fixed-target) positive terms, real join outputs are near those
         small candidate sets — seeding from the biggest UNGROUNDED term
@@ -2083,11 +2294,12 @@ class FusedExecutor:
         grounded term's table already holds max(grounded) exact rows —
         clamping the join capacity under that forces a guaranteed retry
         round (one wasted XLA compile per shape) that no configuration
-        can be trying to buy."""
+        can be trying to buy.  `rows`: the plans' exact candidate rows,
+        where the caller has counted them."""
         cfg = self.db.config
         grounded = [
-            self._estimate(p)
-            for p in plans
+            self._estimate(p) if rows is None else rows[k]
+            for k, p in enumerate(plans)
             if p.fixed and p.ctype is None and not p.negated
         ]
         if grounded:
@@ -2126,88 +2338,188 @@ class FusedExecutor:
 
     def _exec_job(self, plans, count_only: bool) -> Optional["_ExecJob"]:
         """Prepare one execution's state (ordering, term args, capacity
-        seeds).  None when a bucket is missing or the merged caps exceed
-        the configured ceiling — the caller falls back, as before.
+        seeds): the batch of one of `_build` (the lone execute(), a
+        tree's sites, planner.explain).  None when a bucket is missing
+        or the merged caps exceed the configured ceiling — the caller
+        falls back, as before."""
+        return self._build([plans], count_only)[0][0]
 
-        Behind DasConfig.use_planner the cost-based planner
-        (das_tpu/planner) fixes the join order and the per-intermediate
-        capacity seeds from cardinality estimates; when it declines (or
-        is off) the legacy greedy ordering and blind seeds apply —
-        answers are identical either way, only compile/retry traffic
-        differs."""
+    def _build_jobs(self, plans_lists, count_only: bool) -> List:
+        """dispatch_pending's `build_jobs`: the jobs of a batch, one or
+        None (a decline) per entry, under ONE `exec.build` span
+        (attrs: queries, shapes, templates_built) — per batch, never
+        per query: recording a span costs the thread that builds."""
+        if not obs.enabled():
+            return self._build(plans_lists, count_only)[0]
+        with obs.span("exec.build", queries=len(plans_lists)) as sp:
+            jobs, shapes, built = self._build(plans_lists, count_only)
+            sp.set(shapes=shapes, templates_built=built)
+        obs.counter("exec.template_builds").inc(built)
+        obs.counter("exec.template_hits").inc(len(plans_lists) - built)
+        return jobs
+
+    def _build(self, plans_lists, count_only: bool):
+        """THE job builder: `(jobs, shapes, templates built)`.  The
+        queries are told apart by SHAPE (shape_key); what a shape
+        decides is read once from its kept `_JobTemplate` (built on
+        first sight, dropped when `delta_version` moves, like the
+        planner's estimator), and `_fill` computes for all queries of
+        a shape at once what their grounded values decide."""
+        version = getattr(self.db, "delta_version", None)
+        if version != self._templates_version or len(self._templates) > 256:
+            self._templates.clear()
+            self._templates_version = version
+        jobs: List = [None] * len(plans_lists)
+        by_shape: Dict[Tuple, List[int]] = {}
+        for i, plans in enumerate(plans_lists):
+            by_shape.setdefault(shape_key(plans), []).append(i)
+        built = 0
+        for key, members in by_shape.items():
+            template = self._templates.get(key)
+            if template is None:
+                template = self._templates[key] = _JobTemplate(
+                    plans_lists[members[0]]
+                )
+                built += 1
+            filled = self._fill(
+                template, [plans_lists[i] for i in members], count_only
+            )
+            for i, job in zip(members, filled):
+                jobs[i] = job
+        return jobs, len(by_shape), built
+
+    def _fill(self, template, plans_lists, count_only: bool) -> List:
+        """The jobs of N same-shape queries.  Per fill: the bucket
+        arrays (FRESH from `db.dev.buckets`: a commit replaces them,
+        lanes_program's docstring), the probe-key and fixed-value
+        columns, the batch's statistics (planner/stats.py
+        BatchEstimator: one searchsorted per term, side and host
+        segment for all N) and, per join order met, the learned
+        capacities.  Per query: the planner's fold on its own numbers
+        (behind DasConfig.use_planner the cost-based planner fixes the
+        join order and the per-intermediate capacity seeds; where it
+        declines or is off the legacy greedy order and blind seeds
+        apply — answers are identical either way, only compile/retry
+        traffic differs), the merge with the learned capacities, the
+        ceiling, the planner counters.  Jobs that end with equal
+        capacities hold ONE FusedPlanSig object."""
         from das_tpu import planner as _planner
+        from das_tpu.planner.stats import BatchEstimator, estimator_for
 
-        planned = (
-            _planner.plan_conjunction(self.db, plans)
-            if _planner.enabled(self.db.config) else None
-        )
-        if planned is not None:
-            ordered = [plans[i] for i in planned.order]
-        else:
-            ordered = self._order(plans)
-        # when ordering preserved the positive fold the program IS the
-        # reference fold: its in-program reseed flag is then exact, so a
-        # zero count with no flag (final join empty) is definitively empty
-        same_order = self._same_positive_order(ordered, plans)
-        plans = ordered
-        mapped = []
-        for plan in plans:
-            m = self._term_args(plan)
-            if m is None:
-                return None
-            mapped.append(m)
-        sigs = tuple(m[0] for m in mapped)
-        arrays = tuple(m[1] for m in mapped)
-        keys = tuple(m[2] for m in mapped)
-        fvals = tuple(m[3] for m in mapped)
-
-        cfg = self.db.config
-        # exact host-side range counts => term capacities never overflow;
-        # shapes past the configured ceiling go to the staged path, which
-        # clamps (and owns the overflow error policy)
-        term_caps = tuple(_pow2_at_least(self._estimate(plan)) for plan in plans)
-        index_joins, index_right, arrays, term_caps = self._apply_index_joins(
-            sigs, arrays, term_caps
-        )
-        n_joins = max(0, sum(1 for s in sigs if not s.negated) - 1)
-        if planned is not None and len(planned.join_cap_seeds) == n_joins:
-            # the costed seeds: margin × estimated rows per intermediate
-            # instead of one blind seed for every join — overflow retry
-            # still owns estimate error, the ladder just starts on the
-            # right rung for the common case (margin-FREE where the
-            # statistic is exact: no configured clamp can shrink a seed
-            # back under the exact row count)
-            join_caps = planned.join_cap_seeds
-        else:
-            join_caps = tuple(
-                [self._join_cap_seed(plans, term_caps)] * n_joins
+        db, cfg = self.db, self.db.config
+        n = len(plans_lists)
+        buckets = db.dev.buckets
+        for sig in template.sigs:
+            bucket = buckets.get(sig.arity)
+            if bucket is None or bucket.size == 0:
+                return [None] * n  # a missing bucket: the shape declines
+        stats = BatchEstimator(estimator_for(db), plans_lists)
+        rule = template.rule if _planner.enabled(cfg) else None
+        key_cols, fval_cols = template.lane_columns(stats, n)
+        orders: Dict[Tuple, Tuple] = {}
+        jobs: List = []
+        for row, plans in enumerate(plans_lists):
+            stats.at(row)
+            # a shape whose order no rule fixes ("dp", "greedy_tail",
+            # the legacy greedy order) is ordered from THIS query's
+            # counts: per-query planning, inside the same builder
+            planned = (
+                _planner.plan_conjunction(db, plans, est=stats, rule=rule)
+                if rule is not None else None
             )
-        learned = self._learned_caps(
-            self._caps, self._cap_store, sigs,
-            (len(term_caps), len(join_caps)),
-        )
-        if learned is not None:
-            term_caps = self._clamp_index_terms(
-                tuple(max(a, b) for a, b in zip(term_caps, learned[0])),
-                index_right,
+            if planned is not None:
+                order = planned.order
+            else:
+                order = tuple(
+                    stats.term_of(p) for p in order_plans(plans, stats.rows)
+                )
+            entry = orders.get(order)
+            if entry is None:
+                shape = template.ordered(order)
+                arrays = index_join_arrays(
+                    buckets, shape.sigs,
+                    tuple(
+                        route_arrays(buckets[sig.arity], sig)
+                        for sig in shape.sigs
+                    ),
+                    shape.index_joins, shape.index_right,
+                )
+                learned = self._learned_caps(
+                    self._caps, self._cap_store, shape.sigs,
+                    (len(order), shape.n_joins),
+                )
+                lanes = (
+                    tuple(key_cols[t] for t in order),
+                    tuple(fval_cols[t] for t in order),
+                )
+                entry = orders[order] = (shape, arrays, learned, lanes, {})
+            shape, arrays, learned, lanes, by_caps = entry
+            rows = (
+                planned.est_term_rows if planned is not None
+                else [stats.rows(plans[t]) for t in order]
             )
-            join_caps = tuple(max(a, b) for a, b in zip(join_caps, learned[1]))
-        # ceiling applies to the MERGED caps: stale/foreign CapStore
-        # entries must not smuggle buffers past the configured maximum
-        if max(term_caps + join_caps, default=0) > cfg.max_result_capacity:
-            return None
-        # counted only once the job EXISTS: a decline above (missing
-        # bucket, capacity ceiling) runs the legacy fallback, and the
-        # planned/greedy decomposition must cover executor traffic the
-        # settle observation will actually complete
-        if planned is not None:
-            _planner.record_planned(planned)
-        else:
-            _planner.PLANNER_COUNTS["greedy"] += 1
-        return _ExecJob(
-            self, count_only, same_order, sigs, arrays, keys, fvals,
-            term_caps, join_caps, index_joins, planned=planned,
-        )
+            # exact host-side range counts => term capacities never
+            # overflow; an index-joined term is never materialized
+            term_caps = tuple(
+                INDEX_TERM_TOKEN_CAP if k in shape.index_right
+                else _pow2_at_least(r)
+                for k, r in enumerate(rows)
+            )
+            if (
+                planned is not None
+                and len(planned.join_cap_seeds) == shape.n_joins
+            ):
+                # the costed seeds: margin × estimated rows per
+                # intermediate instead of one blind seed for every join
+                # — overflow retry still owns estimate error, the
+                # ladder just starts on the right rung for the common
+                # case (margin-FREE where the statistic is exact: no
+                # configured clamp can shrink a seed back under the
+                # exact row count)
+                join_caps = planned.join_cap_seeds
+            else:
+                join_caps = (
+                    self._join_cap_seed(
+                        [plans[t] for t in order], term_caps, rows
+                    ),
+                ) * shape.n_joins
+            if learned is not None:
+                term_caps = self._clamp_index_terms(
+                    tuple(max(a, b) for a, b in zip(term_caps, learned[0])),
+                    shape.index_right,
+                )
+                join_caps = tuple(
+                    max(a, b) for a, b in zip(join_caps, learned[1])
+                )
+            # ceiling applies to the MERGED caps: stale/foreign CapStore
+            # entries must not smuggle buffers past the configured
+            # maximum; shapes past it go to the staged path, which
+            # clamps (and owns the overflow error policy)
+            if max(term_caps + join_caps, default=0) > cfg.max_result_capacity:
+                jobs.append(None)
+                continue
+            # counted only once the job EXISTS: a decline above (missing
+            # bucket, capacity ceiling) runs the legacy fallback, and the
+            # planned/greedy decomposition must cover executor traffic the
+            # settle observation will actually complete
+            if planned is not None:
+                _planner.record_planned(planned)
+            else:
+                _planner.PLANNER_COUNTS["greedy"] += 1
+            plan_sig = by_caps.get((term_caps, join_caps))
+            if plan_sig is None:
+                plan_sig = by_caps[(term_caps, join_caps)] = FusedPlanSig(
+                    shape.sigs, term_caps, join_caps, shape.index_joins,
+                    planned is not None,
+                )
+            jobs.append(_ExecJob(
+                self, count_only, shape.same_order, shape.sigs, arrays,
+                tuple(col[row] for col in lanes[0]),
+                tuple(col[row] for col in lanes[1]),
+                plan_sig.term_caps, plan_sig.join_caps, shape.index_joins,
+                planned=planned, sig=plan_sig, lanes=lanes, row=row,
+            ))
+        return jobs
 
     def execute(
         self, plans, count_only: bool = False, use_cache: bool = False
@@ -2272,7 +2584,7 @@ class FusedExecutor:
         answer, misses stay dispatch-time declines."""
         return dispatch_pending(
             self.results, self._exec_job, plans_lists, count_only,
-            cache_only=cache_only,
+            cache_only=cache_only, build_jobs=self._build_jobs,
         )
 
     def settle_many(self, pending) -> List[Optional[FusedResult]]:

@@ -474,6 +474,8 @@ def test_obs_registries_pinned():
         # statistics and the wire
         "commit.apply", "commit.stage", "commit.swap", "dur.wal_append",
         "exec.format", "planner.stats", "wire.query", "wire.parse",
+        # ISSUE 41: the build of a batch's jobs
+        "exec.build",
     }
     assert set(obs.COUNTER_NAMES) >= {
         "serve.submitted", "serve.answers", "serve.rejections",
@@ -484,6 +486,7 @@ def test_obs_registries_pinned():
         "exec.stale_reruns", "exec.per_query_fallbacks",
         "exec.group_programs", "exec.group_lanes",
         "planner.table_extractions", "planner.table_hits",
+        "exec.template_builds", "exec.template_hits",
     }
     assert set(obs.HISTOGRAM_NAMES) >= {
         "serve.queue_ms", "serve.dispatch_ms", "serve.settle_ms",
@@ -970,3 +973,90 @@ def test_dl014_pins_program_names(tmp_path):
     assert "das_typo" in msgs and "das_stale" in msgs
     with pytest.raises(KeyError):
         obs.named_program("das_typo", lambda: None)
+
+
+# -- the job builder's span and counters (ISSUE 41) -----------------------
+
+
+def _concept_queries(names):
+    return [And([
+        Link("Inheritance", [Variable("$1"), Variable("$2")], True),
+        Link("Inheritance", [Variable("$2"), Node("Concept", c)], True),
+    ]) for c in names]
+
+
+def _builds():
+    return [e for e in obs.events() if e[0] == "exec.build"]
+
+
+def test_exec_build_is_one_span_a_batch(traced):
+    """`exec.build`: ONE span per batch of built jobs, before the
+    batch's `exec.dispatch`; attrs queries (cache-missing,
+    de-duplicated), shapes, templates_built; `exec.template_builds` /
+    `exec.template_hits` move with it."""
+    das, _db = _tensor_das()
+    two_shapes = _concept_queries(["mammal", "reptile", "animal"]) + [And([
+        Link("Similarity", [Variable("$1"), Variable("$2")], True),
+        Link("Inheritance", [Variable("$2"), Node("Concept", "mammal")], True),
+    ])]
+    das.query_many_dispatch(two_shapes + two_shapes[:1]).settle()
+    (build,) = _builds()                     # the duplicate: not built
+    assert build[8]["queries"] == 4
+    assert build[8]["shapes"] == build[8]["templates_built"] == 2
+    assert obs.counter("exec.template_builds").value == 2
+    assert obs.counter("exec.template_hits").value == 2
+    enqueues = [e for e in obs.events() if e[0] == "exec.dispatch"]
+    assert enqueues and all(build[2] + build[3] <= e[2] for e in enqueues)
+    # a second batch of the same shapes is filled from the kept
+    # templates; a batch the cache answers whole builds nothing
+    das.query_many_dispatch(
+        _concept_queries(["human", "monkey", "chimp"])).settle()
+    assert len(_builds()) == 2 and _builds()[1][8]["templates_built"] == 0
+    assert obs.counter("exec.template_builds").value == 2
+    assert obs.counter("exec.template_hits").value == 5
+    das.query_many_dispatch(two_shapes).settle()
+    assert len(_builds()) == 2
+    # a commit drops the templates: the shape is built again
+    das.load_metta_text(COMMIT)
+    das.query_many_dispatch(_concept_queries(["mammal", "reptile"])).settle()
+    assert _builds()[-1][8]["templates_built"] == 1
+    assert obs.counter("exec.template_builds").value == 3
+
+
+def test_exec_build_sits_inside_serve_dispatch(traced):
+    """Served: every `exec.build` lies inside a `serve.dispatch` of the
+    worker thread, at most one a group."""
+    das, _db = _tensor_das()
+    _serve(das, _concept_queries(["mammal", "reptile", "animal"]))
+    outers = [e for e in obs.events() if e[0] == "serve.dispatch"]
+    builds = _builds()
+    assert 1 <= len(builds) <= len(outers)
+    for build in builds:
+        assert sum(
+            o[7] == build[7] and o[2] <= build[2]
+            and build[2] + build[3] <= o[2] + o[3] for o in outers
+        ) == 1
+
+
+def test_exec_build_disabled_path_allocates_nothing(monkeypatch):
+    """Tracing off: the builder packs no attribute dict, builds no span
+    object, reads no clock of the obs layer and moves no counter."""
+    from das_tpu.obs import recorder
+    from das_tpu.query import fused
+
+    assert not obs.enabled()
+
+    def no_span(*_a, **_k):
+        raise AssertionError("a span object was built with tracing off")
+
+    monkeypatch.setattr(recorder._Span, "__init__", no_span)
+    monkeypatch.setattr(recorder, "time", _NoClock())
+    monkeypatch.setattr(obs, "time", _NoClock())
+    das, db = _tensor_das()
+    before = {k: c.value for k, c in obs.metrics.COUNTERS.items()}
+    queries = _concept_queries(["mammal", "reptile", "animal"])
+    answers = das.query_many_dispatch(queries).settle()
+    assert answers == [das.query(q) for q in queries]
+    assert len(fused.get_executor(db)._templates) == 1
+    assert obs.events() == []
+    assert {k: c.value for k, c in obs.metrics.COUNTERS.items()} == before
