@@ -243,6 +243,9 @@ class _QueryManyJob:
                 query_compiler.execute_sharded_many_settle_iter,
                 sharded_answer,
             )
+            # the stream owns the round now: where it gives the round
+            # up (a commit between two yields), the round goes with it
+            del pending
             for i, out_s in settled:
                 done[i] = True
                 yield i, out_s
@@ -280,6 +283,7 @@ class _QueryManyJob:
                 query_compiler.execute_fused_many_settle_iter,
                 fused_answer,
             )
+            del pending             # as above: the stream owns it
             for i, out_s in settled:
                 done[i] = True
                 yield i, out_s
@@ -298,9 +302,13 @@ class _QueryManyJob:
                 planned = set(self.idxs)
                 obs.counter("exec.stale_reruns").inc(
                     sum(1 for i in rest if i in planned))
-            again = _QueryManyJob(
-                das, [self.queries[i] for i in rest], self.output_format,
-                is_rerun=True)
+            # serve.rerun names the second round's plan, build and
+            # enqueue; closed before the first yield (its fetch,
+            # verdicts and answers have their own spans)
+            with obs.span("serve.rerun", queries=len(rest), route="round"):
+                again = _QueryManyJob(
+                    das, [self.queries[i] for i in rest],
+                    self.output_format, is_rerun=True)
             for j, out_s in again.settle_iter():
                 yield rest[j], out_s
             return
@@ -318,9 +326,11 @@ class _QueryManyJob:
                 if self.stale_round and i in self.idxs:
                     obs.counter("exec.stale_reruns").inc()
             try:
-                yield i, das.query(q, self.output_format)
+                with obs.span("serve.rerun", queries=1, route="per_query"):
+                    out_s = das.query(q, self.output_format)
             except Exception as exc:  # noqa: BLE001 — per-query isolation
-                yield i, exc
+                out_s = exc
+            yield i, out_s
 
     def settle(self) -> List[Union[str, Exception]]:
         """One entry per query: the answer string, or that query's OWN

@@ -1,14 +1,16 @@
 """das_tpu.obs — structured per-query tracing + typed metrics (ISSUE 12).
 
-The serving engine's window into itself: a trace id born at coalescer
-submit threads through drain/group/plan/dispatch/settle-fetch/
-materialize-or-cache-hit to answer delivery, each stage recording a
+The serving engine's window into itself: a trace id born in the RPC
+handler (`wire.query`, PR 26; at coalescer submit only for a direct
+caller) threads through submit/drain/group/plan/dispatch/settle-fetch/
+verdict/materialize-or-cache-hit to answer delivery, each stage recording a
 host-monotonic span into a bounded ring (obs/recorder.py), while the
 metric layer (obs/metrics.py) keeps counters and fixed log-bucket
 latency histograms that answer p50/p95/p99 without sample retention.
 Exporters (obs/export.py) render the ring as Perfetto-loadable Chrome
-trace JSON (`scripts/dump_trace.py`) and the metrics as Prometheus
-text exposition (service/server.py `metrics_text`); obs/jaxprof.py
+trace JSON (`scripts/dump_trace.py`), as one thread's account of own
+wall and CPU time per span name (`worker_account`), and the metrics as
+Prometheus text exposition (service/server.py `metrics_text`); obs/jaxprof.py
 optionally wraps the dispatch/settle halves in
 `jax.profiler.TraceAnnotation` so host spans line up with the XLA
 device timeline on hardware runs.
@@ -27,9 +29,11 @@ from typing import Optional, Tuple
 
 from das_tpu.obs import metrics as metrics  # noqa: F401 — public surface
 from das_tpu.obs.export import (  # noqa: F401
+    account_text,
     chrome_trace,
     dump_chrome_trace,
     prometheus_text,
+    worker_account,
 )
 from das_tpu.obs.jaxprof import (  # noqa: F401
     annotation,
@@ -77,16 +81,12 @@ def reset() -> None:
     reset_metrics()
 
 
-def span(name: str, trace: int = 0, **attrs):
-    """Context manager recording one complete span; the shared no-op
-    when tracing is off.  `name` must be an obs/registry.py member
-    (daslint DL014)."""
-    return REC.span(name, trace, **attrs)
-
-
-def event(name: str, trace: int = 0, **attrs) -> None:
-    """One instant event; no-op when tracing is off."""
-    REC.event(name, trace, **attrs)
+#: `obs.span(name, trace=0, **attrs)` / `obs.event(...)`: the process
+#: recorder's own methods, bound once (REC is never rebound), so a call
+#: site pays one call and one packing of its attrs.  `name` must be an
+#: obs/registry.py member (daslint DL014)
+span = REC.span
+event = REC.event
 
 
 def new_trace() -> int:

@@ -1,5 +1,5 @@
-"""Trace/metric exporters: Chrome trace-event JSON (Perfetto-loadable)
-and Prometheus text exposition.
+"""Trace/metric exporters: Chrome trace-event JSON (Perfetto-loadable),
+Prometheus text exposition, and one thread's span account.
 
 Chrome trace format (the subset Perfetto ingests): one "X" complete
 event per span and one "i" instant event per point event, timestamps
@@ -14,6 +14,11 @@ Prometheus text exposition (the service/server.py hook): counters as
 `das_tpu_obs_<name>_total`, histograms in the native histogram triple
 (`_bucket{le=...}` cumulative, `_sum`, `_count`) — scrape-ready,
 derivable p50/p95/p99 via `histogram_quantile`.
+
+Span account (`worker_account`): where ONE thread's time went, per span
+name its OWN wall and CPU time (its children's taken out) — the table
+PERF.md §5 ranks the coalescer worker's bottlenecks by
+(`scripts/worker_account.py`, `scripts/dump_trace.py --account`).
 """
 
 from __future__ import annotations
@@ -80,6 +85,103 @@ def dump_chrome_trace(events: List[Tuple], path: str) -> str:
     with open(path, "w") as f:
         json.dump(chrome_trace(events), f)
     return path
+
+
+#: an account is of the thread that records this span (the coalescer's
+#: worker, one per drain); benchmark/harness/devtrace.py names the idle
+#: gaps of the ledger's `breakdown` by the same thread
+WORKER_SPAN = "serve.drain"
+
+#: numeric span attrs an account sums per span name: what a span
+#: carries for its whole group in place of an event per query
+ACCOUNT_ATTRS = ("queries", "lanes", "lock_wait_ms", "wait_ms", "resolve_ms")
+
+
+def worker_account(events: List[Tuple], t0: Optional[float] = None,
+                   t1: Optional[float] = None) -> Dict:
+    """Recorder event tuples -> the account of ONE thread, the one with
+    the most `serve.drain` spans (the coalescer's worker): per span
+    name its count, total wall seconds, OWN wall and OWN CPU seconds (a
+    span's duration / `cpu_ms` less its direct children's: the time
+    inside it with no child span open) and the sums of its
+    `ACCOUNT_ATTRS`; instants by count.  `t0` / `t1`: keep the events
+    that START in that stretch of the recorder's clock, whole (no span
+    is cut).
+
+    Own wall minus own CPU of a span that never blocks is the wait for
+    the interpreter lock.  Spans of one thread nest (nothing is open across a `yield`);
+    spans with equal intervals nest in recording order (`mesh.fetch`
+    inside `exec.settle_fetch`)."""
+    drains: Dict[str, int] = {}
+    for ev in events:
+        if ev[0] == WORKER_SPAN:
+            drains[ev[7]] = drains.get(ev[7], 0) + 1
+    thread = max(drains, key=drains.get) if drains else None
+    mine = [ev for ev in events if ev[7] == thread
+            and (t0 is None or ev[2] >= t0) and (t1 is None or ev[2] <= t1)]
+    spans: Dict[str, Dict] = {}
+    instants: Dict[str, int] = {}
+    stack: List[List] = []      # [end, row, child wall, child cpu, wall, cpu]
+
+    def close(top) -> None:
+        _end, row, child_wall, child_cpu, wall, cpu = top
+        row["own_wall_s"] += wall - child_wall
+        row["own_cpu_s"] += cpu - child_cpu
+
+    ordered = sorted(
+        (ev for ev in mine if ev[1] == "X"), key=lambda ev: (ev[2], -ev[3])
+    )
+    for name, _ph, start, dur, _tr, _g, _lane, _th, attrs in ordered:
+        while stack and stack[-1][0] <= start + 1e-9:
+            close(stack.pop())
+        row = spans.get(name)
+        if row is None:
+            row = spans[name] = {"count": 0, "wall_s": 0.0,
+                                 "own_wall_s": 0.0, "own_cpu_s": 0.0,
+                                 "attrs": {}}
+        attrs = attrs or {}
+        cpu = attrs.get("cpu_ms", 0.0) / 1e3
+        row["count"] += 1
+        row["wall_s"] += dur
+        sums = row["attrs"]
+        for key in ACCOUNT_ATTRS:
+            value = attrs.get(key)
+            if value is not None and not isinstance(value, bool):
+                sums[key] = sums.get(key, 0) + value
+        if stack:
+            stack[-1][2] += dur
+            stack[-1][3] += cpu
+        stack.append([start + dur, row, 0.0, 0.0, dur, cpu])
+    while stack:
+        close(stack.pop())
+    for ev in mine:
+        if ev[1] != "X":
+            instants[ev[0]] = instants.get(ev[0], 0) + 1
+    ranked = sorted(spans.items(), key=lambda kv: -kv[1]["own_wall_s"])
+    return {"thread": thread, "t0": t0, "t1": t1,
+            "spans": dict(ranked), "instants": instants}
+
+
+def account_text(account: Dict, per: Optional[int] = None) -> str:
+    """The account as a table, one line per span name, largest own
+    wall first; with `per` (a count of answers) a column of own
+    milliseconds per answer."""
+    head = f"{'span':<20}{'count':>8}{'wall s':>10}{'own s':>10}" \
+           f"{'own cpu s':>11}"
+    lines = [f"thread {account['thread']}",
+             head + (f"{'own ms/answer':>15}" if per else "")]
+    for name, row in account["spans"].items():
+        line = (f"{name:<20}{row['count']:>8}{row['wall_s']:>10.3f}"
+                f"{row['own_wall_s']:>10.3f}{row['own_cpu_s']:>11.3f}")
+        if per:
+            line += f"{row['own_wall_s'] * 1e3 / per:>15.4f}"
+        if row["attrs"]:
+            line += "  " + " ".join(
+                f"{k}={v:.6g}" for k, v in sorted(row["attrs"].items()))
+        lines.append(line)
+    lines.append("instants: " + " ".join(
+        f"{k}={v}" for k, v in sorted(account["instants"].items())))
+    return "\n".join(lines)
 
 
 def _prom_name(name: str) -> str:

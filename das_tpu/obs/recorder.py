@@ -1,8 +1,9 @@
 """Low-overhead structured trace recorder (ISSUE 12 tentpole).
 
 One process-wide `TraceRecorder` holds a bounded ring of span/instant
-events.  A trace id is born at coalescer submit (`new_trace`), rides
-the submit-queue tuple to the worker, and every deeper layer —
+events.  A trace id is born in the RPC handler (`wire.query`; at
+coalescer submit for a direct caller: `new_trace`), rides the
+submit-queue tuple to the worker, and every deeper layer —
 drain/group/plan/dispatch/settle-fetch/materialize-or-cache-hit down
 to answer delivery — attaches either that id or the GROUP id the
 worker publishes through a thread-local (`set_context`), so a
@@ -123,8 +124,6 @@ class _Span:
         self.name = name
         self.trace = trace
         self.attrs = attrs
-        self.t0 = 0.0
-        self._cpu0 = 0.0
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. the drained
@@ -137,11 +136,11 @@ class _Span:
         return self
 
     def __exit__(self, *_exc):
-        dur = time.perf_counter() - self.t0
-        self.attrs["cpu_ms"] = (time.thread_time() - self._cpu0) * 1e3
-        self._rec.record(
-            self.name, "X", self.t0, dur, self.trace, self.attrs,
-        )
+        t0 = self.t0
+        dur = time.perf_counter() - t0
+        attrs = self.attrs
+        attrs["cpu_ms"] = (time.thread_time() - self._cpu0) * 1e3
+        self._rec.record(self.name, "X", t0, dur, self.trace, attrs)
         return False
 
 
@@ -194,6 +193,20 @@ class TraceRecorder:
             self._next += 1
             return self._next
 
+    def _thread_ctx(self) -> list:
+        """This thread's `[thread name, lane, group]`, made at the
+        thread's first event and kept in the thread-local: what is
+        fixed per thread (its name, read once: a thread renamed later
+        keeps the name it recorded first under) or set once per group
+        (`set_context`) is not looked up again per event.  The list
+        lives and dies with its thread, so it needs no lock."""
+        tls = self._tls
+        try:
+            return tls.ctx
+        except AttributeError:
+            ctx = tls.ctx = [threading.current_thread().name, None, 0]
+            return ctx
+
     def set_context(self, lane: Optional[str] = None,
                     group: int = 0) -> None:
         """Publish the worker's current (tenant lane, group id): deeper
@@ -201,12 +214,9 @@ class TraceRecorder:
         cache events) inherit them without signature changes.  Lane maps
         to a Perfetto track; group links a device span back to the
         submit traces it served."""
-        self._tls.lane = lane
-        self._tls.group = group
-
-    def context(self) -> Tuple[Optional[str], int]:
-        tls = self._tls
-        return getattr(tls, "lane", None), getattr(tls, "group", 0)
+        ctx = self._thread_ctx()
+        ctx[1] = lane
+        ctx[2] = group
 
     # -- recording --------------------------------------------------------
 
@@ -217,22 +227,25 @@ class TraceRecorder:
         tenant's thread produced them (the proflog compile lane)."""
         if not self.enabled:
             return
-        ctx_lane, group = self.context()
-        th = threading.current_thread()
+        thread, ctx_lane, group = self._thread_ctx()
         self._ring.append((
             name, phase, t0 - self._t_origin, dur, trace, group,
-            lane if lane is not None else ctx_lane, th.name, attrs,
+            ctx_lane if lane is None else lane, thread, attrs,
         ))
 
     def span(self, name: str, trace: int = 0, **attrs):
+        """Context manager recording one complete span; the shared
+        no-op when tracing is off.  Never hold one open across a
+        `yield`: the consumer's spans would nest under it and its own
+        time would be booked to the producer (registry.py)."""
         if not self.enabled:
             return NOOP_SPAN
         return _Span(self, name, trace, attrs)
 
     def event(self, name: str, trace: int = 0, **attrs) -> None:
-        if not self.enabled:
-            return
-        self.record(name, "i", time.perf_counter(), 0.0, trace, attrs)
+        """One instant event; no-op when tracing is off."""
+        if self.enabled:
+            self.record(name, "i", time.perf_counter(), 0.0, trace, attrs)
 
     # -- readout ----------------------------------------------------------
 

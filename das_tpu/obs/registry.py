@@ -25,10 +25,15 @@ cycles.
 #: lifecycle stages (service/coalesce.py + api/atomspace.py), the
 #: executor halves (query/fused.py + parallel/fused_sharded.py), the
 #: delta-versioned caches, the commit path, and the planner's
-#: est-vs-actual observation.
+#: statistics.  What is summed over a group rides its span as an attr
+#: (`lock_wait_ms`, `resolve_ms`): an event per answered query is paid
+#: for by the ONE worker thread, so a name recorded there once a query
+#: has to have a reader (obs/export.py worker_account, a file under
+#: benchmark/layer_metrics/, scripts/dump_trace.py).
 SPAN_NAMES = (
     #: instant: one query accepted into the coalescer submit queue
-    #: (service/coalesce.py submit) — the trace id is born here
+    #: (service/coalesce.py submit); carries the trace id born at
+    #: wire.query (born here only for a direct caller)
     "serve.submit",
     #: instant: backpressure rejection at the queue bound
     "serve.reject",
@@ -42,15 +47,30 @@ SPAN_NAMES = (
     #: group width, speculative flag, effective depth, dispatch EWMA
     "serve.dispatch",
     #: span: per-group streamed settle — attrs: streamed/fallback
-    #: counts, settle rtt
+    #: counts, settle rtt, `lock_wait_ms` (summed waits for the tenant
+    #: lock), `resolve_ms` (summed wall time of delivering the group's
+    #: answers: `Future.set_result`, its callbacks, the serve.answer
+    #: instant; no event per delivery)
     "serve.settle",
+    #: span: queries going again after a commit overtook their round,
+    #: or handed to the per-query dispatcher — `route="round"`: the
+    #: construction of the re-run job (api/atomspace.py settle_iter;
+    #: its serve.plan, exec.build, exec.dispatch are children; the
+    #: re-run's fetch, verdicts and answers stream under their own
+    #: names); `route="per_query"`: one blocking `das.query`
+    #: (settle_iter's last loop, the coalescer's fall-back) — attrs:
+    #: queries, route
+    "serve.rerun",
     #: instant: one query's future resolved — closes the trace id
     #: opened at serve.submit
     "serve.answer",
     #: span: one device-program enqueue (query/fused.py _ExecJob and
     #: _TreeExecJob dispatch halves + the sharded twins) — attrs:
     #: route, rounds, planner est rows; `lanes` when the program is a
-    #: group's (_ExecJob.dispatch_group: the jobs it carries)
+    #: group's (_ExecJob.dispatch_group: the jobs it carries);
+    #: `inflight`: programs enqueued and not yet fetched as this one
+    #: is enqueued, itself not counted (query/fused.py
+    #: programs_in_flight)
     "exec.dispatch",
     #: span: the build of ONE batch's jobs (query/fused.py
     #: FusedExecutor._build_jobs, inside serve.dispatch, before the
@@ -60,8 +80,22 @@ SPAN_NAMES = (
     #: One per batch, never one per query
     "exec.build",
     #: span: one settle round's host transfer — a device-to-host sync
-    #: (query/fused.py settle_pending_iter, DL013's one-transfer site)
+    #: (query/fused.py fetch_outputs, DL013's one-transfer site) —
+    #: attrs: jobs, programs; `wait_ms`, the part of the duration in
+    #: which the worker waited for the device before it copied (with
+    #: tracing on it waits first, `jax.block_until_ready`, then
+    #: copies); `inflight`, as on exec.dispatch, the round's own
+    #: programs among them
     "exec.settle_fetch",
+    #: span: the verdict of ONE settled job (query/fused.py
+    #: settle_pending_iter, one chip and mesh): its lane taken out of
+    #: the fetched block, the stats read, the result object built or
+    #: the capacities grown, the result cache's insert — attrs: `done`
+    #: (false = a capacity retry rides the next round), `lanes` of the
+    #: program it rode in.  Closed BEFORE the answer is yielded: a span
+    #: is never open across a `yield` (the consumer's spans would nest
+    #: under it, and own time go to the wrong name)
+    "exec.verdict",
     #: span: binding table -> the answer's block of distinct valid rows
     #: (query/compiler.py materialize) — attrs: rows, prefetched
     "exec.materialize",
@@ -70,8 +104,8 @@ SPAN_NAMES = (
     "exec.format",
     #: instants: delta-versioned result/tree/count cache traffic
     #: (query/fused.py ResultCache)
+    #: (a miss is counter `cache.misses` alone)
     "cache.hit",
-    "cache.miss",
     "cache.invalidate",
     #: instants: commit-path delta_version bumps (storage/delta.py) —
     #: incremental commit vs full rebuild
@@ -86,8 +120,6 @@ SPAN_NAMES = (
     "commit.apply",
     "commit.stage",
     "commit.swap",
-    #: instant: planner est-vs-actual at job settle (das_tpu/planner)
-    "planner.observe",
     #: span: planner statistics recomputed — the estimator's rebuild
     #: after delta_version moved (planner/stats.py estimator_for) and
     #: each uncached whole-table extraction (distinct_at,
@@ -130,7 +162,8 @@ SPAN_NAMES = (
     #: span: one settle round's pull of the mesh programs' per-shard
     #: result slabs and stats to the host (parallel/fused_sharded.py
     #: settle_many_iter; the same interval as exec.settle_fetch, which
-    #: the mesh shares with one chip) — attrs: jobs, shards, bytes
+    #: the mesh shares with one chip) — attrs: jobs, shards, bytes,
+    #: wait_ms (that span's)
     "mesh.fetch",
     #: span: stacked per-shard rows -> the distinct valid rows of a mesh
     #: answer (parallel/sharded_db.py materialize; child of

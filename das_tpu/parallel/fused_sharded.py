@@ -68,6 +68,7 @@ from das_tpu.query.fused import (
     order_plans,
     remember_caps,
     prepare_tree_job,
+    programs_in_flight,
     run_tree_job,
     same_positive_order,
     settle_pending,
@@ -733,16 +734,19 @@ class ShardedFusedExecutor:
         (query/fused.py settle_pending_iter), so mesh tenants' first rows
         reach their clients one RTT after their own dispatch too.  Each
         round's transfer pulls every job's per-shard result slabs to the
-        host: span `mesh.fetch` (with tracing on)."""
+        host: span `mesh.fetch` (with tracing on; the interval, the
+        `wait_ms` and the `cpu_ms` of exec.settle_fetch)."""
         return settle_pending_iter(
             self.results, pending, on_fetch=self._record_fetch
         )
 
-    def _record_fetch(self, t0: float, seconds: float, fetched) -> None:
+    def _record_fetch(self, t0: float, seconds: float, fetched,
+                      clocks) -> None:
         obs.REC.record(
             "mesh.fetch", "X", t0, seconds, 0,
             {"jobs": len(fetched), "shards": self.n_shards,
-             "bytes": sum(a.nbytes for a in jax.tree.leaves(fetched))},
+             "bytes": sum(a.nbytes for a in jax.tree.leaves(fetched)),
+             **clocks},
         )
 
     def execute_many(
@@ -865,6 +869,7 @@ class _ShardedExecJob:
                     list(self.planned.est_join_rows)
                     if self.planned is not None else None
                 ),
+                inflight=programs_in_flight(),
             )
         with sp, obs.annotation("exec.dispatch"):
             return fn(self.arrays, self.keys, self.fvals)

@@ -99,7 +99,9 @@ def observe_settle(planned, actual_join_rows, rounds: int,
                    shards: int = 1) -> None:
     """Fold one settled planner-driven job into the telemetry: retry
     rounds actually paid and estimated-vs-actual join output rows (the
-    estimator-error signal).  Called from the executors' settle halves.
+    estimator-error signal).  Called from the executors' settle halves
+    (inside span `exec.verdict`).  Totals only: the worker pays for
+    every event it records, and nobody read one job's.
     The sharded executor's per-join actuals are WORST-SHARD totals, so
     its estimates are scaled to the even-split per-shard expectation —
     a ratio drifting past the 2x skew headroom is exactly the signal
@@ -112,18 +114,6 @@ def observe_settle(planned, actual_join_rows, rounds: int,
     act = sum(int(r) for r in actual_join_rows)
     PLANNER_COUNTS["est_rows"] += est
     PLANNER_COUNTS["actual_rows"] += act
-    from das_tpu import obs
-
-    if obs.enabled():
-        # est-vs-actual PER SETTLED JOB on the trace (ISSUE 12): the
-        # aggregate ratio above smooths exactly the per-query outliers
-        # the closeout run needs to see next to their dispatch spans
-        obs.event(
-            "planner.observe", est_rows=est, actual_rows=act,
-            per_step_est=list(planned.est_join_rows),
-            per_step_actual=[int(r) for r in actual_join_rows],
-            retry_rounds=rounds - 1,
-        )
 
 
 # re-exports: the public planner surface

@@ -30,7 +30,9 @@ from __future__ import annotations
 
 import operator
 import os
+import threading
 import time
+import weakref
 from dataclasses import dataclass
 from functools import partial
 from typing import Dict, List, Optional, Tuple
@@ -266,6 +268,7 @@ class _ExecJob:
                 list(self.planned.est_join_rows)
                 if self.planned is not None else None
             ),
+            inflight=programs_in_flight(),
             **({"lanes": lanes} if lanes else {}),
         )
 
@@ -399,12 +402,15 @@ class _PendingMany:
     delta version the round was dispatched against (guards the
     settle-time cache insert against a racing commit)."""
 
-    __slots__ = ("results", "programs", "version", "fetch_ms")
+    __slots__ = ("results", "programs", "version", "fetch_ms",
+                 "__weakref__")
 
     def __init__(self, results, programs, version):
         self.results = results
         self.programs = programs
         self.version = version
+        if obs.enabled():
+            _live_pendings().add(self)
         # wall-ms of each settle round's host transfer, timed where it
         # happens (settle_pending_iter) — fetch_ms[0] IS the settle
         # round-trip the coalescer's adaptive window sizes from; an
@@ -423,8 +429,32 @@ class _PendingMany:
 #: while the ONE host thread, not the device, sets the pace
 GROUP_LANES = 32
 
+#: the dispatched batches this thread holds (tracing on): what
+#: `programs_in_flight` sums.  Held weakly: a round that a commit
+#: overtook is dropped unfetched with its object (api/atomspace.py
+#: settle_iter) and leaves with it, no site has to say so
+_LIVE = threading.local()
 
-def _dispatch_round(entries):
+
+def _live_pendings():
+    try:
+        return _LIVE.pendings
+    except AttributeError:
+        live = _LIVE.pendings = weakref.WeakSet()
+        return live
+
+
+def programs_in_flight() -> int:
+    """Device programs this thread enqueued (_dispatch_round appends
+    each to its batch's `_PendingMany.programs` as it goes) and has not
+    fetched (settle_pending_iter empties the list): the device's queue
+    as the ONE worker thread knows it, attr `inflight` of spans
+    `exec.dispatch` and `exec.settle_fetch`.  Read under tracing only;
+    a batch dispatched before tracing came on is not seen."""
+    return sum(len(p.programs) for p in _live_pendings())
+
+
+def _dispatch_round(entries, programs):
     """Enqueue one round of `(indices, job, cache key)` entries — the
     first of a dispatch_pending, or a settle round's capacity retries.
     Jobs whose type offers the group hooks (`dispatch_group`,
@@ -437,8 +467,10 @@ def _dispatch_round(entries):
     nested dataclasses) is hashed once per group here, not once per
     job: jobs are told apart by the identity of their signature first,
     and only distinct objects meet in the dict that compares them.
-    Returns `[(members, device output)]`, one per program."""
-    programs = []
+    Appends `(members, device output)`, one per program, to `programs`
+    (the batch's `_PendingMany.programs`, empty: each is in flight
+    from the moment it is enqueued, `programs_in_flight`) and returns
+    it."""
     groups: Dict[Tuple, List] = {}
     by_object: Dict[Tuple, List] = {}
     for entry in entries:
@@ -519,7 +551,9 @@ def dispatch_pending(results_cache, exec_job, plans_lists, count_only,
         (idxs, job, key)
         for (idxs, _, key), job in zip(todo, built) if job is not None
     ]
-    return _PendingMany(results, _dispatch_round(jobs), version)
+    pending = _PendingMany(results, [], version)
+    _dispatch_round(jobs, pending.programs)
+    return pending
 
 
 def settle_pending_iter(results_cache, pending, on_fetch=None):
@@ -539,64 +573,106 @@ def settle_pending_iter(results_cache, pending, on_fetch=None):
     `pending.results` (None = declined), or use settle_pending.  Shared
     by the single-device and sharded executors — their jobs expose the
     same dispatch()/settle() halves, so the serving pipeline's second
-    phase is ONE implementation.  With tracing on, `on_fetch(t0,
-    seconds, fetched)` hears of every round's transfer (the mesh
-    executor records its own span there)."""
+    phase is ONE implementation.  With tracing on, every job's verdict
+    is one `exec.verdict` span and `on_fetch` hears of every round's
+    transfer (fetch_outputs; the mesh executor records its own span
+    there)."""
+    from das_tpu import fault
+
+    retry = fault.fetch_retry()
     for i, hit in enumerate(pending.results):
         if hit is not None:
             yield i, hit
     programs = pending.programs
-    from das_tpu import fault
-
-    retry = fault.fetch_retry()
     while programs:
-        t0 = time.perf_counter()
-        with obs.annotation("exec.settle_fetch"):
-            # the shared RetryPolicy (das_tpu/fault, ISSUE 13) replaces
-            # the old bare fetch: a transient runtime failure (or an
-            # injected settle_fetch fault) retries with deterministic
-            # backoff instead of failing the whole group, and EVERY
-            # attempt tallies FETCH_COUNTS — the fetches-per-query
-            # telemetry must count real transfers, not logical rounds
-            # (DL013's tally leg)
-            def _fetch_round():
-                FETCH_COUNTS["n"] += 1
-                fault.maybe_fail("settle_fetch")
-                return jax.device_get(tuple(out for _, out in programs))
-
-            fetched = retry.run(_fetch_round)
-        fetch_s = time.perf_counter() - t0
+        traced = obs.enabled()
+        fetched, fetch_s = fetch_outputs(
+            tuple(out for _, out in programs),
+            {"jobs": sum(len(m) for m, _ in programs),
+             "programs": len(programs)} if traced else None,
+            retry=retry, on_fetch=on_fetch,
+        )
+        pending.programs = []       # fetched: no longer in flight
         pending.fetch_ms.append(fetch_s * 1e3)
-        if obs.enabled():
-            # the wire, where it happens: one span per settle round's
-            # host transfer, one histogram sample (the RTT distribution
-            # the adaptive window must hide), one fetch counter tick
-            obs.counter("exec.fetches").inc()
-            obs.histogram("exec.settle_fetch_ms").observe(fetch_s * 1e3)
-            obs.REC.record(
-                "exec.settle_fetch", "X", t0, fetch_s, 0,
-                {"jobs": sum(len(m) for m, _ in programs),
-                 "programs": len(programs)},
-            )
-            if on_fetch is not None:
-                on_fetch(t0, fetch_s, fetched)
         nxt = []
         for (members, out), host in zip(programs, fetched):
-            alone = len(members) == 1
+            lanes = len(members)
             for lane, (idxs, job, key) in enumerate(members):
-                if alone:
-                    done = job.settle(host, out)
-                else:
-                    done = job.settle(*job.lane_out(host, out, lane))
+                # the verdict of ONE job, named (exec.verdict) and
+                # closed before the yield: a span open across a yield
+                # would take the consumer's spans for its children
+                sp = (obs.span("exec.verdict", lanes=lanes) if traced
+                      else obs.NOOP_SPAN)
+                with sp:
+                    if lanes == 1:
+                        done = job.settle(host, out)
+                    else:
+                        done = job.settle(*job.lane_out(host, out, lane))
+                    if done:
+                        results_cache.put(key, job.result, pending.version)
+                    if traced:
+                        sp.set(done=done)
                 if done:
-                    results_cache.put(key, job.result, pending.version)
                     for i in idxs:
                         pending.results[i] = job.result
                         yield i, job.result
                 else:
                     nxt.append((idxs, job, key))
-        programs = _dispatch_round(nxt) if nxt else []
-    pending.programs = []
+        programs = _dispatch_round(nxt, pending.programs) if nxt else []
+
+
+def fetch_outputs(outs, attrs=None, retry=None, on_fetch=None):
+    """The ONE host transfer of a settle round (settle_pending_iter) or
+    of a tree round (run_tree_job): `jax.device_get(outs)`, timed where
+    it happens.  Returns `(fetched, seconds)`.  Under `retry` (the
+    shared RetryPolicy, das_tpu/fault, ISSUE 13) a transient runtime
+    failure or an injected settle_fetch fault retries with
+    deterministic backoff instead of failing the whole group, and EVERY
+    attempt tallies FETCH_COUNTS: the fetches-per-query telemetry
+    counts real transfers, not logical rounds (DL013's tally leg).
+
+    With tracing on (`attrs` given: the span's own) the worker first
+    WAITS for the outputs (`jax.block_until_ready`), then copies: span
+    `exec.settle_fetch` carries `wait_ms`, the part of its duration
+    in which the device had not finished, and `inflight`
+    (`programs_in_flight`: a settle round's own programs among them).
+    `on_fetch(t0, seconds, fetched, clocks)` hears of the same
+    interval (`clocks`: its `wait_ms` and `cpu_ms`).  With tracing
+    off: the one `device_get`, which waits and copies in one call."""
+    from das_tpu import fault
+
+    traced = attrs is not None
+    wait_s = 0.0
+
+    def attempt():
+        nonlocal wait_s
+        FETCH_COUNTS["n"] += 1
+        if retry is not None:
+            fault.maybe_fail("settle_fetch")
+        if traced:
+            t_wait = time.perf_counter()
+            jax.block_until_ready(outs)
+            wait_s += time.perf_counter() - t_wait
+        return jax.device_get(outs)
+
+    cpu0 = time.thread_time() if traced else 0.0
+    t0 = time.perf_counter()
+    with obs.annotation("exec.settle_fetch"):
+        fetched = attempt() if retry is None else retry.run(attempt)
+    fetch_s = time.perf_counter() - t0
+    if traced:
+        # the wire, where it happens: one span per transfer, one
+        # histogram sample (the RTT distribution the adaptive window
+        # must hide), one fetch counter tick
+        clocks = {"wait_ms": wait_s * 1e3,
+                  "cpu_ms": (time.thread_time() - cpu0) * 1e3}
+        attrs.update(clocks, inflight=programs_in_flight())
+        obs.counter("exec.fetches").inc()
+        obs.histogram("exec.settle_fetch_ms").observe(fetch_s * 1e3)
+        obs.REC.record("exec.settle_fetch", "X", t0, fetch_s, 0, attrs)
+        if on_fetch is not None:
+            on_fetch(t0, fetch_s, fetched, clocks)
+    return fetched, fetch_s
 
 
 def settle_pending(results_cache, pending) -> List:
@@ -628,10 +704,10 @@ FETCH_COUNTS = {"n": 0}
 #: enumerable and the telemetry cannot undercount.  Adding a fetch site
 #: means adding it here, under review, with its RTT story.
 FETCH_SITES = (
-    #: the serving pipeline's ONE transfer per settle round (§10)
-    "fused.settle_pending_iter",
-    #: whole-tree retry loop — one transfer per tree round (ISSUE 10)
-    "fused.run_tree_job",
+    #: the serving pipeline's ONE transfer per settle round (§10:
+    #: settle_pending_iter) and the whole-tree retry loop's one per
+    #: tree round (ISSUE 10: run_tree_job), through one helper
+    "fused.fetch_outputs",
     #: single-query execute()'s settle fetch
     "fused.FusedExecutor.execute",
     #: reference-order exact variant's settle fetch
@@ -1346,17 +1422,9 @@ def run_tree_job(job):
     execute() idiom) — ONE implementation for both executors."""
     while True:
         out = job.dispatch()
-        FETCH_COUNTS["n"] += 1
-        if obs.enabled():
-            obs.counter("exec.fetches").inc()
-        t0 = time.perf_counter()
-        with obs.annotation("exec.settle_fetch"):
-            fetched = jax.device_get(out)
-        if obs.enabled():
-            fetch_s = time.perf_counter() - t0
-            obs.histogram("exec.settle_fetch_ms").observe(fetch_s * 1e3)
-            obs.REC.record("exec.settle_fetch", "X", t0, fetch_s, 0,
-                           {"tree": True})
+        fetched, _ = fetch_outputs(
+            out, {"tree": True} if obs.enabled() else None
+        )
         if job.settle(fetched, out):
             return job
 
@@ -1854,7 +1922,8 @@ class ResultCache:
             if hit is None:
                 self.stats["misses"] += 1
                 if obs.enabled():
-                    obs.event("cache.miss")
+                    # a counter and no instant: nothing reads one
+                    # miss, and the worker pays for every event
                     obs.counter("cache.misses").inc()
                 return None
             self._data.move_to_end(key)

@@ -679,6 +679,20 @@ class QueryCoalescer:
             )
         return True
 
+    def _deliver(self, item: Tuple, answer, clock) -> bool:
+        """`_resolve` for one entry of a settling group.  With tracing
+        on (`clock` a one-slot list) the wall seconds of the delivery
+        (the `set_result`, its callbacks, the tracing calls inside) are
+        added to `clock[0]`: `serve.settle`'s attr `resolve_ms`, the
+        lock_wait_ms idiom (no event per answer)."""
+        if clock is None:
+            return self._resolve(item[3], answer, self._mark_of(item))
+        t0 = time.perf_counter()
+        try:
+            return self._resolve(item[3], answer, self._mark_of(item))
+        finally:
+            clock[0] += time.perf_counter() - t0
+
     def _settle_group(self, entry: Tuple) -> None:
         """Phase 2: STREAM the settle — resolve each query's future as
         its answer lands (settle_iter), so early answers reach their
@@ -716,10 +730,12 @@ class QueryCoalescer:
         # instead of falling back to per-query device work
         degraded = entry[5] if len(entry) > 5 else False
         sp = obs.NOOP_SPAN
+        clock = None            # [wall s] summed over deliveries
         if obs.enabled():
             obs.set_context(lane=getattr(tenant, "name", None), group=gid)
             sp = obs.span("serve.settle", trace=gid, queries=len(group),
                           degraded=degraded)
+            clock = [0.0]
         t_settle0 = time.perf_counter()
         streamed = 0
         lock_ms = 0.0           # summed waits for the tenant lock
@@ -754,9 +770,13 @@ class QueryCoalescer:
                         fault.is_retryable(answer)
                     ):
                         retryable_errors += 1
-                    delivered_last = self._resolve(
-                        group[i][3], answer, self._mark_of(group[i])
-                    )
+                    if clock is None:
+                        delivered_last = self._resolve(
+                            group[i][3], answer, self._mark_of(group[i])
+                        )
+                    else:
+                        delivered_last = self._deliver(
+                            group[i], answer, clock)
                     if delivered_last:
                         streamed += 1
                 rtt = getattr(job, "settle_rtt_ms", None)
@@ -789,12 +809,12 @@ class QueryCoalescer:
                     # work; unresolved members reject retryable with
                     # the breaker's retry-after hint
                     self.stats["breaker_rejections"] += 1
-                    self._resolve(
-                        fut,
+                    self._deliver(
+                        item,
                         BreakerOpenError(
                             retry_after_ms=self.breaker.retry_after_ms()
                         ),
-                        self._mark_of(item),
+                        clock,
                     )
                     continue
                 try:
@@ -802,7 +822,9 @@ class QueryCoalescer:
                     try:
                         if obs.enabled():
                             obs.counter("exec.per_query_fallbacks").inc()
-                        answer = tenant.das.query(item[1], fmt)
+                        with obs.span("serve.rerun", queries=1,
+                                      route="per_query"):
+                            answer = tenant.das.query(item[1], fmt)
                     finally:
                         tenant.lock.release()
                 except Exception as exc:  # noqa: BLE001 — per-future
@@ -811,9 +833,11 @@ class QueryCoalescer:
                     fault.is_retryable(answer)
                 ):
                     retryable_errors += 1
-                if self._resolve(fut, answer, self._mark_of(item)):
+                if self._deliver(item, answer, clock):
                     fellback += 1
             sp.set(fallbacks=fellback, lock_wait_ms=lock_ms)
+            if clock is not None:
+                sp.set(resolve_ms=clock[0] * 1e3)
             # breaker verdict for this group (worker-side, ISSUE 13):
             # transport-class failures — a broken streamed settle or
             # retryable per-query errors — count against the tenant;

@@ -19,6 +19,11 @@ Two modes:
   * `--self`: no workload — dump whatever the CURRENT process recorder
     holds (importable `dump_current(path)` for embedding in services).
 
+  * `--account`: also print where the coalescer's worker thread spent
+    the traced time, per span name its own wall and CPU seconds
+    (obs/export.py worker_account; `scripts/worker_account.py` makes
+    the same table of a benchmark cell's window).
+
 Open the output at https://ui.perfetto.dev or chrome://tracing.  With
 DAS_TPU_TRACE_JAX=1 / DAS_TPU_TRACE_DIR the same run also captures a
 jax.profiler device trace to correlate against (obs/jaxprof.py).
@@ -129,6 +134,11 @@ def main(argv=None) -> int:
         "--self", action="store_true", dest="self_only",
         help="dump the current recorder ring; run no demo workload",
     )
+    ap.add_argument(
+        "--account", action="store_true",
+        help="print the worker thread's account: own wall / CPU "
+             "seconds per span name",
+    )
     ap.add_argument("--clients", type=int, default=16)
     ap.add_argument("--scale", type=float, default=0.1,
                     help="bio KB size factor (default 0.1)")
@@ -139,6 +149,12 @@ def main(argv=None) -> int:
     with open(path) as f:
         n = len(json.load(f)["traceEvents"])
     print(f"wrote {n} trace events to {path} — open in ui.perfetto.dev")
+    if args.account:
+        from das_tpu import obs
+
+        account = obs.worker_account(obs.events())
+        print(obs.account_text(
+            account, per=account["instants"].get("serve.answer")))
     return 0
 
 

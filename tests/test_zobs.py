@@ -122,8 +122,12 @@ def test_coalesced_query_full_lifecycle(traced):
     for stage in ("serve.submit", "serve.drain", "serve.group",
                   "serve.plan", "serve.dispatch", "serve.settle",
                   "serve.answer", "exec.dispatch", "exec.settle_fetch",
-                  "exec.materialize", "cache.miss"):
+                  "exec.verdict", "exec.materialize"):
         assert stage in names, f"lifecycle stage {stage} missing: {names}"
+    # a miss is a counter and no instant (PR 42): the worker pays for
+    # every event it records, and nothing read one miss
+    assert "cache.miss" not in names
+    assert obs.counter("cache.misses").value >= 1
     # every span/event name the ring holds is a declared registry member
     assert names <= set(obs.SPAN_NAMES)
 
@@ -170,14 +174,25 @@ def test_spans_nest_and_order(traced):
     assert "est_join_rows" in e[8]
 
 
-def test_planner_observe_carries_est_vs_actual(traced):
+def test_planner_observe_totals_and_no_event_per_job(traced):
+    """A planned job's settle folds est-vs-actual into PLANNER_COUNTS,
+    inside its exec.verdict span; the per-job `planner.observe` instant
+    is gone (PR 42: nothing read it), the dispatch span keeps the
+    per-step estimates."""
+    from das_tpu.planner import PLANNER_COUNTS
+
     das, _db = _tensor_das()
+    before = dict(PLANNER_COUNTS)
     _coal, _tenant, _ = _serve(das, [_pair_query()])
-    evs = [e for e in obs.events() if e[0] == "planner.observe"]
-    assert evs, "planned settle must emit planner.observe"
-    attrs = evs[0][8]
-    assert attrs["per_step_est"] and attrs["per_step_actual"]
-    assert attrs["retry_rounds"] >= 0
+    assert (PLANNER_COUNTS["round0"] + PLANNER_COUNTS["retries"]
+            > before["round0"] + before["retries"])
+    assert PLANNER_COUNTS["est_rows"] >= before["est_rows"]
+    evs = obs.events()
+    assert not [e for e in evs if e[0] == "planner.observe"]
+    verdicts = [e for e in evs if e[0] == "exec.verdict"]
+    assert verdicts and verdicts[-1][8]["done"] is True
+    disp = [e for e in evs if e[0] == "exec.dispatch"]
+    assert disp and disp[0][8]["est_join_rows"]
 
 
 # -- cache + commit events ------------------------------------------------
@@ -202,10 +217,12 @@ def test_cache_hit_and_commit_invalidation_events(traced):
     assert deltas and deltas[0][8]["version"] == db.delta_version
     assert db.delta_version > before
     # the post-commit repeat must invalidate, then miss, then dispatch
+    misses = obs.counter("cache.misses").value
     _serve(das, [q], coal=coal, tenant=tenant)
     names = [e[0] for e in obs.events()]
     assert "cache.invalidate" in names
-    assert "cache.miss" in names
+    assert obs.counter("cache.misses").value == misses + 1
+    assert "exec.dispatch" in names
 
 
 # -- histogram percentile math --------------------------------------------
@@ -467,8 +484,8 @@ def test_obs_registries_pinned():
         "serve.submit", "serve.drain", "serve.group", "serve.plan",
         "serve.dispatch", "serve.settle", "serve.answer",
         "exec.dispatch", "exec.settle_fetch", "exec.materialize",
-        "cache.hit", "cache.miss", "cache.invalidate",
-        "commit.delta", "commit.rebuild", "planner.observe",
+        "cache.hit", "cache.invalidate",
+        "commit.delta", "commit.rebuild",
         "serve.deadline", "serve.breaker", "fault.inject",
         # ISSUE 26: the commit path, the answer path, the planner's
         # statistics and the wire
@@ -476,7 +493,11 @@ def test_obs_registries_pinned():
         "exec.format", "planner.stats", "wire.query", "wire.parse",
         # ISSUE 41: the build of a batch's jobs
         "exec.build",
+        # ISSUE 42: inside the settle loop
+        "exec.verdict", "serve.rerun",
     }
+    # ISSUE 42: the instants nobody read are gone, their counters stay
+    assert not {"cache.miss", "planner.observe"} & set(obs.SPAN_NAMES)
     assert set(obs.COUNTER_NAMES) >= {
         "serve.submitted", "serve.answers", "serve.rejections",
         "cache.hits", "cache.misses", "cache.invalidations",
@@ -945,7 +966,16 @@ def test_traced_group_ticks_one_program_five_lanes(traced):
     assert len(spans) == 1 and spans[0][8]["lanes"] == 5
     fetches = [e for e in obs.events() if e[0] == "exec.settle_fetch"]
     assert len(fetches) == 1
-    assert fetches[0][8] == {"jobs": 5, "programs": 1}
+    attrs = fetches[0][8]
+    assert (attrs["jobs"], attrs["programs"]) == (5, 1)
+    # ISSUE 42: the wait for the device inside the fetch, and the
+    # device's queue as it began (this round's one program in it)
+    assert 0 <= attrs["wait_ms"] <= fetches[0][3] * 1e3
+    assert attrs["inflight"] == 1 and spans[0][8]["inflight"] == 0
+    verdicts = [e for e in obs.events() if e[0] == "exec.verdict"]
+    assert [v[8] for v in verdicts] == [
+        {"lanes": 5, "done": True, "cpu_ms": v[8]["cpu_ms"]}
+        for v in verdicts] and len(verdicts) == 5
     # a lone job's span carries no lanes attr, and counts 1 / 1
     obs.reset()
     ex.execute_many(plans[:1])
