@@ -217,8 +217,16 @@ class _QueryManyJob:
                     # fallback _run_conjunctive takes
                     table = das.db.sharded_execute(self.plans_lists[j])
                 else:
+                    # materialized from the prefetched host copies:
+                    # the device references stay unread (an answer
+                    # that rode in a group program would slice its
+                    # lane out on the read)
+                    prefetched = res.host_vals is not None
                     table = ShardedTable(
-                        res.var_names, res.vals, res.valid, res.count,
+                        res.var_names,
+                        None if prefetched else res.vals,
+                        None if prefetched else res.valid,
+                        res.count,
                         host_vals=res.host_vals,
                         host_valid=res.host_valid,
                     )

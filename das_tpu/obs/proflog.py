@@ -100,6 +100,7 @@ PROGRAM_SITES: Dict[str, Optional[str]] = {
     "fused.FusedExecutor._run_batch_group": "count_batch",
     "fused.FusedExecutor.build_count_loop": "count_loop",
     "fused_sharded._ShardedExecJob.dispatch": "sharded",
+    "fused_sharded._ShardedExecJob._build_group": "sharded_group",
     "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
     # -- declared-exempt: staged-path per-op programs (ops/posting.py,
     #    ops/join.py — one generic op each, counted by DISPATCH_COUNTS

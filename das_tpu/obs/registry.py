@@ -67,7 +67,7 @@ SPAN_NAMES = (
     #: span: one device-program enqueue (query/fused.py _ExecJob and
     #: _TreeExecJob dispatch halves + the sharded twins) — attrs:
     #: route, rounds, planner est rows; `lanes` when the program is a
-    #: group's (_ExecJob.dispatch_group: the jobs it carries);
+    #: group's (_GroupHooks.dispatch_group: the jobs it carries);
     #: `inflight`: programs enqueued and not yet fetched as this one
     #: is enqueued, itself not counted (query/fused.py
     #: programs_in_flight)
@@ -162,8 +162,8 @@ SPAN_NAMES = (
     #: span: one settle round's pull of the mesh programs' per-shard
     #: result slabs and stats to the host (parallel/fused_sharded.py
     #: settle_many_iter; the same interval as exec.settle_fetch, which
-    #: the mesh shares with one chip) — attrs: jobs, shards, bytes,
-    #: wait_ms (that span's)
+    #: the mesh shares with one chip) — attrs: shards, bytes, and that
+    #: span's own (jobs, programs, wait_ms, cpu_ms, inflight)
     "mesh.fetch",
     #: span: stacked per-shard rows -> the distinct valid rows of a mesh
     #: answer (parallel/sharded_db.py materialize; child of
@@ -300,6 +300,7 @@ PROGRAM_NAMES = (
     "das_count_batch",
     "das_count_loop",
     "das_sharded",
+    "das_sharded_group",
     "das_sharded_tree",
     "das_merge_padded",
     "das_insert_rows",

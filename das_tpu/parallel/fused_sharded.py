@@ -53,8 +53,10 @@ from das_tpu.query.fused import (
     ROUTE_CTYPE,
     ROUTE_TYPE,
     ROUTE_TYPE_POS,
+    FusedResult,
     FusedTermSig,
     ResultCache,
+    _GroupHooks,
     _pow2_at_least,
     _probe,
     _TreeExecJob,
@@ -65,10 +67,10 @@ from das_tpu.query.fused import (
     dispatch_pending,
     estimate_plan_rows,
     fold_join_meta,
+    lanes_program,
     order_plans,
     remember_caps,
     prepare_tree_job,
-    programs_in_flight,
     run_tree_job,
     same_positive_order,
     settle_pending,
@@ -99,15 +101,24 @@ class ShardedPlanSig:
     planned: bool = False
 
 
-@dataclass
-class ShardedFusedResult:
-    var_names: Tuple[str, ...]
-    vals: Optional[jax.Array]    # [S, capF, k] row-sharded
-    valid: Optional[jax.Array]
-    count: int
-    reseed_needed: bool
-    host_vals: Optional[np.ndarray] = None   # prefetched host copies (one
-    host_valid: Optional[np.ndarray] = None  # transfer with the stats)
+class ShardedFusedResult(FusedResult):
+    """One mesh conjunction's answer: query/fused.py FusedResult with
+    `vals` [S, capF, k] and `valid` [S, capF] row-sharded (a lane of a
+    group program hands them as callables, sliced on first use) and
+    `host_vals` / `host_valid` their prefetched host copies (one
+    transfer with the stats).  A mesh job never reports `overflow`: its
+    settle grows the capacity and asks for the program again."""
+
+    __slots__ = ()
+
+    def __init__(
+        self, var_names, vals, valid, count, reseed_needed,
+        host_vals=None, host_valid=None,
+    ):
+        super().__init__(
+            var_names, vals, valid, count, reseed_needed, False,
+            host_vals, host_valid,
+        )
 
 
 class _Moved:
@@ -354,6 +365,37 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
     return acc_vals, acc_valid, stats_list
 
 
+def _site_in_specs(sig: ShardedPlanSig):
+    """shard_map in_specs of ONE conjunction's (bucket_arrays, keys,
+    fixed_vals): the four index arrays of every term row-sharded, the
+    probe keys and fixed values replicated."""
+    spec = P(SHARD_AXIS)
+    return (
+        tuple(tuple(spec for _ in range(4)) for _ in sig.terms),
+        tuple(P() for _ in sig.terms),
+        tuple(P() for _ in sig.terms),
+    )
+
+
+def _sharded_body(sig: ShardedPlanSig, count_only: bool, moved: _Moved):
+    """The per-shard traced function of one sharded plan signature
+    (query/fused.py _fused_body's mesh twin): what build_fused_sharded
+    runs for one query and build_fused_sharded_group for the lanes of a
+    group.  Returns (vals [cap, k], valid [cap], stats), stats alone
+    when count_only; the collectives' bytes add to `moved`."""
+
+    def fn(bucket_arrays, keys, fixed_vals):
+        acc_vals, acc_valid, stats_list = _trace_sharded_conj(
+            sig, bucket_arrays, keys, fixed_vals, moved
+        )
+        stats = jnp.stack(stats_list)
+        if count_only:
+            return stats
+        return acc_vals, acc_valid, stats
+
+    return fn
+
+
 def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False,
                         moved: Optional[_Moved] = None):
     """Lower one sharded plan signature to a single shard_map program.
@@ -370,26 +412,62 @@ def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False,
     """
     _pos, _neg, names, _jm, _am = fold_join_meta(sig.terms)
     moved = moved if moved is not None else _Moved(sig.n_shards)
+    shard = _sharded_body(sig, count_only, moved)
 
     def body(bucket_arrays, keys, fixed_vals):
         moved.bytes = 0  # a re-trace counts the program once
-        acc_vals, acc_valid, stats_list = _trace_sharded_conj(
-            sig, bucket_arrays, keys, fixed_vals, moved
-        )
-        stats = jnp.stack(stats_list)
+        out = shard(bucket_arrays, keys, fixed_vals)
         if count_only:
-            return stats
-        return acc_vals[None], acc_valid[None], stats
+            return out
+        vals, valid, stats = out
+        return vals[None], valid[None], stats
 
     spec = P(SHARD_AXIS)
-    n_terms = len(sig.terms)
-    in_specs = (
-        tuple(tuple(spec for _ in range(4)) for _ in range(n_terms)),
-        tuple(P() for _ in range(n_terms)),
-        tuple(P() for _ in range(n_terms)),
-    )
     out_specs = P() if count_only else (spec, spec, P())
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = shard_map(
+        body, mesh=mesh, in_specs=_site_in_specs(sig), out_specs=out_specs
+    )
+    return fn, names
+
+
+def build_fused_sharded_group(sig: ShardedPlanSig, mesh, count_only,
+                              key_axes, fval_axes,
+                              moved: Optional[_Moved] = None):
+    """build_fused_sharded's program for a GROUP of same-signature mesh
+    jobs (_ShardedExecJob's group hooks): the same body under
+    query/fused.py lanes_program INSIDE the shard_map, so the bucket
+    arrays ride unbatched (the slab re-layout `a[0]`, the key splits
+    and every other table-sized op run once a PROGRAM, not once a
+    query) and every collective carries the lanes axis: one all_gather
+    moves the lanes' tables together.  Call convention:
+    fn(bucket_arrays, keys, fixed_vals) over stack_lanes' inputs;
+    outputs vals [lanes, S, cap, k] and valid [lanes, S, cap] sharded
+    on axis 1, stats [lanes, n] replicated (stats alone when
+    count_only): a lane of them is build_fused_sharded's output.
+    `moved` holds after the first call what the program's collectives
+    move: the per-lane tally (the helpers see a lane's shapes under
+    vmap) times the lanes, the padded ones included."""
+    _pos, _neg, names, _jm, _am = fold_join_meta(sig.terms)
+    moved = moved if moved is not None else _Moved(sig.n_shards)
+    lane_moved = _Moved(sig.n_shards)
+    lanes = lanes_program(
+        _sharded_body(sig, count_only, lane_moved), key_axes, fval_axes
+    )
+
+    def body(bucket_arrays, keys, fixed_vals):
+        lane_moved.bytes = 0  # a re-trace counts the program once
+        out = lanes(bucket_arrays, keys, fixed_vals)
+        stats = out if count_only else out[2]
+        moved.bytes = stats.shape[0] * lane_moved.bytes
+        if count_only:
+            return stats
+        return out[0][:, None], out[1][:, None], stats
+
+    spec = P(None, SHARD_AXIS)
+    out_specs = P() if count_only else (spec, spec, P())
+    fn = shard_map(
+        body, mesh=mesh, in_specs=_site_in_specs(sig), out_specs=out_specs
+    )
     return fn, names
 
 
@@ -481,11 +559,7 @@ def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False
 
     spec = P(SHARD_AXIS)
     in_specs = tuple(
-        (
-            tuple(tuple(spec for _ in range(4)) for _ in ssig.terms),
-            tuple(P() for _ in ssig.terms),
-            tuple(P() for _ in ssig.terms),
-        )
+        _site_in_specs(ssig)
         for ssig in sig.sites + ((sig.neg,) if sig.neg is not None else ())
     )
     out_specs = P() if count_only else (spec, spec, P())
@@ -503,6 +577,10 @@ class ShardedFusedExecutor:
         self.n_shards = int(db.mesh.devices.size)
         self.broadcast_limit = BROADCAST_LIMIT
         self._cache: Dict[Tuple, Tuple] = {}
+        #: the served path's group programs (the job's group hooks):
+        #: (plan_sig, count_only, lanes, key_axes, fval_axes) ->
+        #: (_MeshProgram, names)
+        self._group_cache: Dict[Tuple, Tuple] = {}
         self._caps: Dict[Tuple, Tuple] = {}
         #: answered-result cache, delta-version guarded (query/fused.py
         #: ResultCache).  The mesh serving path (sharded_db
@@ -734,19 +812,19 @@ class ShardedFusedExecutor:
         (query/fused.py settle_pending_iter), so mesh tenants' first rows
         reach their clients one RTT after their own dispatch too.  Each
         round's transfer pulls every job's per-shard result slabs to the
-        host: span `mesh.fetch` (with tracing on; the interval, the
-        `wait_ms` and the `cpu_ms` of exec.settle_fetch)."""
+        host: span `mesh.fetch` (with tracing on; the interval and the
+        attrs of exec.settle_fetch, a group program's block one array
+        for all its lanes)."""
         return settle_pending_iter(
             self.results, pending, on_fetch=self._record_fetch
         )
 
     def _record_fetch(self, t0: float, seconds: float, fetched,
-                      clocks) -> None:
+                      attrs) -> None:
         obs.REC.record(
             "mesh.fetch", "X", t0, seconds, 0,
-            {"jobs": len(fetched), "shards": self.n_shards,
-             "bytes": sum(a.nbytes for a in jax.tree.leaves(fetched)),
-             **clocks},
+            {**attrs, "shards": self.n_shards,
+             "bytes": sum(a.nbytes for a in jax.tree.leaves(fetched))},
         )
 
     def execute_many(
@@ -773,20 +851,25 @@ class ShardedFusedExecutor:
         return run_tree_job(job)
 
 
-class _ShardedExecJob:
+class _ShardedExecJob(_GroupHooks):
     """One mesh execute()'s mutable state, split into dispatch / settle
     halves (the query/fused.py _ExecJob idiom) so the coalescer can keep
     pipeline_depth sharded batches in flight.  Semantics are exactly the
     old synchronous execute(): same program cache, same capacity retry
     (term / join / exchange-slot), same reseed verdict, same cap
-    learning."""
+    learning.  The same-signature jobs of a batch ride ONE
+    `das_sharded_group` program (_GroupHooks)."""
 
     __slots__ = (
         "ex", "count_only", "same_order", "sigs", "arrays", "keys", "fvals",
         "term_caps", "join_caps", "exch_caps", "index_joins",
         "names", "result", "planned", "rounds", "last_ranges",
-        "last_join_rows",
+        "last_join_rows", "_sig",
     )
+
+    #: the mesh builds its jobs per query (_exec_job): no lane columns
+    #: of a batch's builder, a group stacks its jobs' own values
+    lanes = None
 
     def __init__(
         self, ex, count_only, same_order, sigs, arrays, keys, fvals,
@@ -811,21 +894,35 @@ class _ShardedExecJob:
         self.rounds = 0
         self.last_ranges = None
         self.last_join_rows = None
+        self._sig = None
 
     def plan_sig(self) -> ShardedPlanSig:
-        """The sharded plan signature at the CURRENT capacities.  Shared
-        by dispatch() and the whole-tree mesh job
+        """The sharded plan signature at the CURRENT capacities, ONE
+        object until a settle grows a capacity (settle assigns new
+        tuples, so identity tells; _dispatch_round tells jobs apart by
+        their signature object first, so it must live as long as the
+        job).  Shared by dispatch() and the whole-tree mesh job
         (_ShardedTreeExecJob)."""
-        return ShardedPlanSig(
-            self.sigs, self.term_caps, self.join_caps, self.exch_caps,
-            self.ex.n_shards, self.index_joins, self.planned is not None,
-        )
+        sig = self._sig
+        if (
+            sig is None
+            or sig.term_caps is not self.term_caps
+            or sig.join_caps is not self.join_caps
+            or sig.exch_caps is not self.exch_caps
+        ):
+            sig = self._sig = ShardedPlanSig(
+                self.sigs, self.term_caps, self.join_caps, self.exch_caps,
+                self.ex.n_shards, self.index_joins, self.planned is not None,
+            )
+        return sig
 
-    def dispatch(self):
+    def dispatch(self, plan_sig=None):
         """Queue the shard_map program at the current capacities
-        (async, no sync)."""
+        (async, no sync); `plan_sig`: the signature there, where the
+        caller has it."""
         ex = self.ex
-        plan_sig = self.plan_sig()
+        if plan_sig is None:
+            plan_sig = self.plan_sig()
         entry = ex._cache.get((plan_sig, self.count_only))
         if entry is None:
             moved = _Moved(ex.n_shards)
@@ -850,29 +947,37 @@ class _ShardedExecJob:
             ex._cache[(plan_sig, self.count_only)] = entry
         fn, self.names = entry
         self.rounds += 1
-        if plan_sig.planned:
-            from das_tpu.planner import PLANNER_COUNTS
-
-            PLANNER_COUNTS["programs"] += 1
-        record_dispatch("sharded")
-        # mesh twin of _ExecJob.dispatch's trace span: same vocabulary,
-        # same sync-free discipline (DL001/DL010), sharded route names
-        sp = obs.NOOP_SPAN
-        if obs.enabled():
-            if self.rounds > 1:
-                # a shard overflowed a capacity: the program again
-                obs.counter("mesh.retries").inc()
-            sp = obs.span(
-                "exec.dispatch", route="sharded", round=self.rounds,
-                count_only=self.count_only,
-                est_join_rows=(
-                    list(self.planned.est_join_rows)
-                    if self.planned is not None else None
-                ),
-                inflight=programs_in_flight(),
-            )
-        with sp, obs.annotation("exec.dispatch"):
+        with self._enqueue_span(plan_sig), obs.annotation("exec.dispatch"):
             return fn(self.arrays, self.keys, self.fvals)
+
+    def _enqueue_span(self, plan_sig, jobs=()):
+        """Tally ONE mesh program about to be enqueued (this job's own,
+        or the group program this job leads for `jobs`), a retry per
+        JOB that a shard's overflow sends again, and return the trace
+        span to hold around the enqueue: _ExecJob's vocabulary, the
+        sharded route's names."""
+        record_dispatch("sharded")
+        if obs.enabled():
+            again = sum(1 for j in jobs or (self,) if j.rounds > 1)
+            if again:
+                obs.counter("mesh.retries").inc(again)
+        return self._program_span(plan_sig, "sharded", jobs)
+
+    def _build_group(self, plan_sig, key_axes, fval_axes):
+        moved = _Moved(self.ex.n_shards)
+        fn, names = build_fused_sharded_group(
+            plan_sig, self.ex.mesh, self.count_only, key_axes, fval_axes,
+            moved,
+        )
+        return _MeshProgram(obs.proflog.instrument(
+            "sharded_group",
+            obs.proflog.sig_digest(
+                plan_sig, self.count_only, key_axes, fval_axes
+            ),
+            jax.jit(obs.named_program(
+                "das_sharded_group", fn, self.count_only
+            )),
+        ), moved), names
 
     def settle(self, host_out, dev_out) -> bool:
         """Consume one round's fetched stats.  True = finished (result
@@ -920,7 +1025,7 @@ class _ShardedExecJob:
             )
             return False
         remember_caps(
-            self.ex._caps, (self.ex._cache,), self.sigs,
+            self.ex._caps, (self.ex._cache, self.ex._group_cache), self.sigs,
             (self.term_caps, self.join_caps, self.exch_caps),
             lambda ps: (ps.term_caps, ps.join_caps, ps.exch_caps),
         )

@@ -162,7 +162,104 @@ class FusedResult:
         return self._valid
 
 
-class _ExecJob:
+class _GroupHooks:
+    """The group hooks: a job type that has them rides with its
+    same-signature batch-mates in ONE program (_dispatch_round) and
+    takes its lane out of the one fetched block (settle_pending_iter).
+    ONE body for the one-chip job (_ExecJob) and the mesh job
+    (parallel/fused_sharded.py _ShardedExecJob): the lanes are stacked,
+    the program cached, the jobs told and the enqueue counted here; a
+    type brings `_build_group(plan_sig, key_axes, fval_axes)` -> (fn,
+    names), its lane-batched program with the lanes as every output's
+    LEADING axis, and `_enqueue_span(plan_sig, jobs)`, its own counter
+    keys; its executor keeps a `_group_cache`."""
+
+    __slots__ = ()
+
+    @staticmethod
+    def dispatch_group(jobs, plan_sig):
+        """Queue ONE program for `jobs` (2..GROUP_LANES, all at `plan_sig`
+        and one count_only): the lone program's body over their
+        lane-stacked probe keys and fixed values, the bucket arrays
+        passed once and unbatched.  Outputs carry a leading lanes axis; a
+        lane past the last job repeats it and is never read.  Jobs of
+        one fill (the first round of a batch whose builder kept lane
+        columns) take their rows out of those, one fancy index per term
+        slot; jobs built one by one, and jobs that met in a retry
+        round, stack their own values."""
+        lead = jobs[0]
+        ex = lead.ex
+        lanes = lead.lanes
+        if lanes is not None and all(j.lanes is lanes for j in jobs):
+            at = [j.row for j in jobs]
+            at += at[-1:] * (GROUP_LANES - len(at))
+            keys, key_axes, fvals, fval_axes = hoist_lanes(
+                [col[at] for col in lanes[0]], [col[at] for col in lanes[1]]
+            )
+        else:
+            keys, key_axes, fvals, fval_axes = stack_lanes(
+                [j.keys for j in jobs], [j.fvals for j in jobs], GROUP_LANES
+            )
+        cache_key = (
+            plan_sig, lead.count_only, GROUP_LANES, key_axes, fval_axes
+        )
+        entry = ex._group_cache.get(cache_key)
+        if entry is None:
+            entry = ex._group_cache[cache_key] = lead._build_group(
+                plan_sig, key_axes, fval_axes
+            )
+        fn, names = entry
+        for j in jobs:
+            j.names = names
+            j.rounds += 1
+        with lead._enqueue_span(plan_sig, jobs), \
+                obs.annotation("exec.dispatch"):
+            return fn(lead.arrays, keys, fvals)
+
+    def lane_out(self, host_out, dev_out, lane: int):
+        """Lane `lane` of a group program's fetched block and of its
+        device outputs, in the form settle() takes: numpy VIEWS of the
+        host block, and for the device side callables that slice on
+        first use (FusedResult) — no device op per lane here."""
+        if self.count_only:
+            return host_out[lane], None
+        return (
+            tuple(h[lane] for h in host_out),
+            (
+                partial(operator.getitem, dev_out[0], lane),
+                partial(operator.getitem, dev_out[1], lane),
+                None,
+            ),
+        )
+
+    def _program_span(self, plan_sig, route: str, jobs):
+        """ONE program is about to be enqueued, this job's own or the
+        group program it leads for `jobs`: tick the planner's program
+        count and return the `exec.dispatch` span to hold around the
+        enqueue (ISSUE 12): host-monotonic timestamps only — the
+        dispatch half stays sync-free (DL001/DL010); attrs carry the
+        route and the planner's estimated rows so settle's actuals line
+        up against them in one Perfetto lane.  Guarded: the disabled
+        path packs no attribute dict."""
+        if plan_sig.planned:
+            from das_tpu.planner import PLANNER_COUNTS
+
+            PLANNER_COUNTS["programs"] += 1
+        if not obs.enabled():
+            return obs.NOOP_SPAN
+        return obs.span(
+            "exec.dispatch", route=route, round=self.rounds,
+            count_only=self.count_only,
+            est_join_rows=(
+                list(self.planned.est_join_rows)
+                if self.planned is not None else None
+            ),
+            inflight=programs_in_flight(),
+            **({"lanes": len(jobs)} if jobs else {}),
+        )
+
+
+class _ExecJob(_GroupHooks):
     """One execute()'s mutable state, split into dispatch / settle halves
     so execute_many can interleave many queries' dispatches before paying
     a single host transfer (each fetch is a host sync that waits for the
@@ -245,88 +342,16 @@ class _ExecJob:
         with self._enqueue_span(plan_sig), obs.annotation("exec.dispatch"):
             return fn(self.arrays, self.keys, self.fvals)
 
-    def _enqueue_span(self, plan_sig, lanes: int = 0):
+    def _enqueue_span(self, plan_sig, jobs=()):
         """Tally ONE program about to be enqueued (this job's own, or
-        the group program this job leads: `lanes` > 0) and return the
-        trace span to hold around the enqueue (ISSUE 12): host-monotonic
-        timestamps only — the dispatch half stays sync-free
-        (DL001/DL010); attrs carry the route and the planner's
-        estimated rows so settle's actuals line up against them in one
-        Perfetto lane.  Guarded: the disabled path packs no attribute
-        dict."""
-        if plan_sig.planned:
-            from das_tpu.planner import PLANNER_COUNTS
-
-            PLANNER_COUNTS["programs"] += 1
+        the group program this job leads for `jobs`) and return the
+        trace span to hold around the enqueue."""
         record_dispatch("fused")
-        if not obs.enabled():
-            return obs.NOOP_SPAN
-        return obs.span(
-            "exec.dispatch", route="fused", round=self.rounds,
-            count_only=self.count_only,
-            est_join_rows=(
-                list(self.planned.est_join_rows)
-                if self.planned is not None else None
-            ),
-            inflight=programs_in_flight(),
-            **({"lanes": lanes} if lanes else {}),
-        )
+        return self._program_span(plan_sig, "fused", jobs)
 
-    # -- group hooks: a job type that offers them can ride with its
-    # same-signature batch-mates in ONE program (_dispatch_round) -------
-
-    @staticmethod
-    def dispatch_group(jobs, plan_sig):
-        """Queue ONE program for `jobs` (2..GROUP_LANES, all at `plan_sig`
-        and one count_only): build_fused's body over their lane-stacked
-        probe keys and fixed values, the bucket arrays passed once and
-        unbatched.  Outputs carry a leading lanes axis; a lane past the
-        last job repeats it and is never read.  Jobs of one fill (the
-        first round of a batch) take their rows out of the builder's
-        lane columns, one fancy index per term slot; jobs that met in a
-        retry round stack their own values."""
-        lead = jobs[0]
-        ex, count_only = lead.ex, lead.count_only
-        lanes = lead.lanes
-        if lanes is not None and all(j.lanes is lanes for j in jobs):
-            at = [j.row for j in jobs]
-            at += at[-1:] * (GROUP_LANES - len(at))
-            keys, key_axes, fvals, fval_axes = hoist_lanes(
-                [col[at] for col in lanes[0]], [col[at] for col in lanes[1]]
-            )
-        else:
-            keys, key_axes, fvals, fval_axes = stack_lanes(
-                [j.keys for j in jobs], [j.fvals for j in jobs], GROUP_LANES
-            )
-        cache_key = (plan_sig, count_only, GROUP_LANES, key_axes, fval_axes)
-        entry = ex._group_cache.get(cache_key)
-        if entry is None:
-            entry = build_fused_group(
-                plan_sig, count_only, key_axes, fval_axes
-            )
-            ex._group_cache[cache_key] = entry
-        fn, names = entry
-        for j in jobs:
-            j.names = names
-            j.rounds += 1
-        with lead._enqueue_span(plan_sig, lanes=len(jobs)), \
-                obs.annotation("exec.dispatch"):
-            return fn(lead.arrays, keys, fvals)
-
-    def lane_out(self, host_out, dev_out, lane: int):
-        """Lane `lane` of a group program's fetched block and of its
-        device outputs, in the form settle() takes: numpy VIEWS of the
-        host block, and for the device side callables that slice on
-        first use (FusedResult) — no device op per lane here."""
-        if self.count_only:
-            return host_out[lane], None
-        return (
-            tuple(h[lane] for h in host_out),
-            (
-                partial(operator.getitem, dev_out[0], lane),
-                partial(operator.getitem, dev_out[1], lane),
-                None,
-            ),
+    def _build_group(self, plan_sig, key_axes, fval_axes):
+        return build_fused_group(
+            plan_sig, self.count_only, key_axes, fval_axes
         )
 
     def settle(self, host_out, dev_out) -> bool:
@@ -460,7 +485,7 @@ def _dispatch_round(entries, programs):
     Jobs whose type offers the group hooks (`dispatch_group`,
     `lane_out`) and that share `(plan_sig, count_only)` — same terms,
     same capacities, same route — ride ONE program; a job alone in its
-    signature, and every job of a type without the hooks (the mesh
+    signature, and every job of a type without the hooks (a tree
     job), is enqueued by its own `dispatch()`, the program and cache
     entry it always had.  The jobs of a batch that the builder gave
     equal capacities hold ONE signature object, so a signature (three
@@ -636,8 +661,9 @@ def fetch_outputs(outs, attrs=None, retry=None, on_fetch=None):
     `exec.settle_fetch` carries `wait_ms`, the part of its duration
     in which the device had not finished, and `inflight`
     (`programs_in_flight`: a settle round's own programs among them).
-    `on_fetch(t0, seconds, fetched, clocks)` hears of the same
-    interval (`clocks`: its `wait_ms` and `cpu_ms`).  With tracing
+    `on_fetch(t0, seconds, fetched, attrs)` hears of the same
+    interval (`attrs`: that span's own, `jobs`, `programs`, `wait_ms`,
+    `cpu_ms`, `inflight`).  With tracing
     off: the one `device_get`, which waits and copies in one call."""
     from das_tpu import fault
 
@@ -664,14 +690,14 @@ def fetch_outputs(outs, attrs=None, retry=None, on_fetch=None):
         # the wire, where it happens: one span per transfer, one
         # histogram sample (the RTT distribution the adaptive window
         # must hide), one fetch counter tick
-        clocks = {"wait_ms": wait_s * 1e3,
-                  "cpu_ms": (time.thread_time() - cpu0) * 1e3}
-        attrs.update(clocks, inflight=programs_in_flight())
+        attrs.update(wait_ms=wait_s * 1e3,
+                     cpu_ms=(time.thread_time() - cpu0) * 1e3,
+                     inflight=programs_in_flight())
         obs.counter("exec.fetches").inc()
         obs.histogram("exec.settle_fetch_ms").observe(fetch_s * 1e3)
         obs.REC.record("exec.settle_fetch", "X", t0, fetch_s, 0, attrs)
         if on_fetch is not None:
-            on_fetch(t0, fetch_s, fetched, clocks)
+            on_fetch(t0, fetch_s, fetched, attrs)
     return fetched, fetch_s
 
 

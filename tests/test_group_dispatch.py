@@ -4,7 +4,8 @@
 `(plan_sig, count_only)` and enqueue ONE `das_fused_group` program per
 same-signature group of two or more (query/fused.py _dispatch_round);
 a job alone in its signature, and every job of a type without the group
-hooks, runs the program it always ran.  Pinned here: the answers are the
+hooks, runs the program it always ran (the mesh job has them since ISSUE
+43: tests/test_mesh_group.py).  Pinned here: the answers are the
 per-query `execute` answers row for row; programs enqueued == groups and
 one FETCH_COUNTS tick a round; a lane over its capacity retries alone
 while its group-mates stream in round one; the reseed verdict, the
@@ -295,8 +296,8 @@ def test_cache_only_enqueues_nothing():
 
 
 class _Alone:
-    """A job type without the group hooks (the mesh job, a tree job):
-    `dispatch()` / `settle()` only."""
+    """A job type without the group hooks (a tree job): `dispatch()` /
+    `settle()` only."""
 
     count_only = False
 
@@ -325,17 +326,6 @@ def test_jobs_without_the_hooks_are_dispatched_alone(db):
     assert [len(m) for m, _ in pending.programs] == [1, 1, 1]
     assert len(log) == 3
     assert fused.settle_pending(cache, pending) == [2, 2, 2]
-
-
-def test_mesh_jobs_offer_no_group_hooks():
-    from das_tpu.parallel.fused_sharded import (
-        _ShardedExecJob, _ShardedTreeExecJob,
-    )
-
-    for cls in (_ShardedExecJob, _ShardedTreeExecJob, fused._TreeExecJob):
-        assert not hasattr(cls, "dispatch_group")
-    assert hasattr(fused._ExecJob, "dispatch_group")
-    assert hasattr(fused._ExecJob, "lane_out")
 
 
 def test_lane_device_refs_are_made_on_demand(db):

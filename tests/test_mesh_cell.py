@@ -231,6 +231,51 @@ def test_mesh_spans_and_counters_in_a_traced_run(local, traced):
     assert obs.counter("mesh.staged_fallbacks").value == 0
 
 
+def test_a_traced_mesh_batch_rides_group_programs(local, traced):
+    """ISSUE 43's mechanism, observable in the cell's own traffic (one
+    large and one small signature group a batch): programs <
+    jobs on the shared loop's counters, `exec.dispatch` spans of the
+    sharded route carry their `lanes`, and the round is ONE fetch."""
+    das, kb = local
+    keys = _keys(kb)
+    base = keys["shared2-rows"][1] + 10
+    genes = ([("grounded3", base + i) for i in range(6)]
+             + [("shared2", base + 6 + i) for i in range(2)])
+    before = dict(compiler.ROUTE_COUNTS)
+    _many(das, kb, genes)
+    assert compiler.ROUTE_COUNTS["sharded"] - before["sharded"] == len(genes)
+    assert compiler.ROUTE_COUNTS["staged"] == before["staged"]
+    programs = obs.counter("exec.group_programs").value
+    assert obs.counter("exec.group_lanes").value == len(genes) > programs
+    spans = {}
+    for name, _ph, _t, _dur, _tr, _g, _lane, _th, attrs in obs.events():
+        spans.setdefault(name, []).append(attrs)
+    enqueued = spans["exec.dispatch"]
+    assert len(enqueued) == programs
+    assert {a["route"] for a in enqueued} == {"sharded"}
+    assert sum(a.get("lanes", 1) for a in enqueued) == len(genes)
+    assert max(a.get("lanes", 1) for a in enqueued) > 1
+    (fetch,) = spans["mesh.fetch"]
+    assert fetch["jobs"] == len(genes)
+    assert len(spans["exec.verdict"]) == len(genes)
+    assert obs.counter("mesh.collective_bytes").value > 0
+    assert obs.counter("mesh.retries").value == 0
+
+
+def test_the_group_program_is_declared_and_read_as_a_mesh_program():
+    """`das_sharded_group` (and its `_count` variant) is a declared
+    program name, and the benchmark's mesh readers, which match
+    `das_sharded*`, take it for a mesh query program."""
+    from benchmark.harness import mesh_trace, readers
+
+    assert "das_sharded_group" in obs.PROGRAM_NAMES
+    for module in ("jit_das_sharded_group(123)",
+                   "jit_das_sharded_group_count(7)"):
+        name = readers.program_name(module)
+        assert name.startswith(mesh_trace.MESH_PROGRAMS)
+        assert readers.kind(module) == readers.QUERY
+
+
 def test_a_shard_overflow_is_a_counted_retry(local, traced):
     from das_tpu.parallel.fused_sharded import get_sharded_executor
 

@@ -141,14 +141,17 @@ def _tiny_store_and_query(make_db, n_clauses=3):
     return db, compiler.plan_query(db, query)
 
 
-def _compile_on_described_mesh(topo, job, sig, per_shard):
+def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
     """The fused shard_map program of `sig`, compiled against a Mesh
     built from the described v5e:2x2 devices, the job's row-sharded
-    bucket arrays stretched to `per_shard` rows a shard."""
+    bucket arrays stretched to `per_shard` rows a shard.  `group`:
+    `(count_only, lanes)` for the GROUP program over `lanes` lanes of
+    the job's inputs, every lane its own gene."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from das_tpu.parallel.fused_sharded import build_fused_sharded
+    from das_tpu.parallel import fused_sharded as fs
     from das_tpu.parallel.mesh import SHARD_AXIS
+    from das_tpu.query import fused
 
     mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
     sharded, replicated = NamedSharding(mesh, P(SHARD_AXIS)), NamedSharding(mesh, P())
@@ -162,11 +165,27 @@ def _compile_on_described_mesh(topo, job, sig, per_shard):
         x = np.asarray(x)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated)
 
-    fn, _names = build_fused_sharded(sig, mesh, False)
+    keys, fvals = job.keys, job.fvals
+    if group is None:
+        fn, _names = fs.build_fused_sharded(sig, mesh, False)
+    else:
+        count_only, lanes = group
+        # the lanes' inputs as dispatch_group stacks them: the grounded
+        # terms' probe keys differ a lane, the whole-type term's key is
+        # hoisted
+        hoisted = sig.index_joins.index(1) + 1
+        keys, key_axes, fvals, fval_axes = fused.stack_lanes(
+            [tuple(np.asarray(k) + (i if t != hoisted else 0)
+                   for t, k in enumerate(job.keys)) for i in range(lanes)],
+            [job.fvals] * lanes, lanes,
+        )
+        assert None in key_axes and 0 in key_axes
+        fn, _names = fs.build_fused_sharded_group(
+            sig, mesh, count_only, key_axes, fval_axes)
     return jax.jit(fn).lower(
         jax.tree.map(slab, job.arrays),
-        jax.tree.map(scalar_or_vec, job.keys),
-        jax.tree.map(scalar_or_vec, job.fvals),
+        jax.tree.map(scalar_or_vec, keys),
+        jax.tree.map(scalar_or_vec, fvals),
     ).compile()
 
 
@@ -381,13 +400,9 @@ CELL3_PROGRAMS = {
 }
 
 
-@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
-def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
-                                                   shape):
-    """The two mesh programs the warm-up of `sharded4-uniform-closed`
-    builds, at the cell's per-shard table size and capacities, compiled
-    for the described v5e:2x2: the gathers of the index joins and the
-    stats reductions (int32 `pmax`, `psum`) must lower."""
+def _cell3_job(shape):
+    """The mesh executor's own job for one of cell 3's shapes (tiny
+    store) and its signature at the cell's capacities."""
     from das_tpu.parallel.fused_sharded import get_sharded_executor
     from das_tpu.parallel.mesh import make_mesh
     from das_tpu.parallel.sharded_db import ShardedDB
@@ -404,5 +419,41 @@ def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
     assert sig.n_shards == 4
     per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
     assert per_shard == 2_220_890
+    return job, sig, per_shard
+
+
+@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
+def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
+                                                   shape):
+    """The two mesh programs the warm-up of `sharded4-uniform-closed`
+    builds for a job alone in its signature, at the cell's per-shard
+    table size and capacities, compiled for the described v5e:2x2: the
+    gathers of the index joins and the stats reductions (int32 `pmax`,
+    `psum`) must lower."""
+    job, sig, per_shard = _cell3_job(shape)
     text = _compile_on_described_mesh(topo, job, sig, per_shard).as_text()
     assert "all-gather" in text and "all-reduce" in text
+
+
+@pytest.mark.parametrize("count_only", [True, False],
+                         ids=["count_program", "result_program"])
+@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
+def test_cell3_mesh_group_programs_on_described_2x2_mesh(
+        topo, no_persistent_cache, shape, count_only):
+    """`das_sharded_group` (ISSUE 43), the program a batch's
+    same-signature mesh jobs ride, at the served path's lanes and cell
+    3's shapes: the collectives lower with the lanes axis on them, and
+    the lanes add no table-sized temporary (the bucket arrays ride
+    unbatched inside the shard_map: no `[lanes, slab]` intermediate)."""
+    from das_tpu.query import fused
+
+    job, sig, per_shard = _cell3_job(shape)
+    compiled = _compile_on_described_mesh(
+        topo, job, sig, per_shard, group=(count_only, fused.GROUP_LANES))
+    text = compiled.as_text()
+    assert "all-gather" in text and "all-reduce" in text
+    assert "tpu_custom_call" not in text
+    lone = _compile_on_described_mesh(topo, job, sig, per_shard)
+    slab_bytes = per_shard * 8                  # one int64 key array
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < lone.memory_analysis().temp_size_in_bytes + slab_bytes)

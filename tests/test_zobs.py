@@ -518,7 +518,7 @@ def test_obs_registries_pinned():
     assert set(obs.PROGRAM_NAMES) == {
         "das_fused", "das_fused_group", "das_fused_tree",
         "das_fused_exact", "das_count_batch", "das_count_loop",
-        "das_sharded",
+        "das_sharded", "das_sharded_group",
         "das_sharded_tree", "das_merge_padded", "das_insert_rows",
         "das_merge_sharded",
     }
@@ -904,6 +904,15 @@ def test_program_names_in_lowered_modules(monkeypatch):
     sdas = DistributedAtomSpace(database_name="zobs-s", db=sdb)
     sdas.query(q)
     sdas.query(tree)
+    # two groundings of one shape on the mesh: ONE group program (ISSUE 43)
+    from das_tpu.parallel.fused_sharded import get_sharded_executor
+
+    get_sharded_executor(sdb).execute_many([
+        compiler.plan_query(sdb, And([
+            Link("Inheritance", [Variable("$1"), Variable("$2")], True),
+            Link("Inheritance", [Variable("$2"), Node("Concept", c)], True),
+        ])) for c in ("mammal", "reptile")   # `q` itself is a cache hit
+    ])
     sdas.load_metta_text(COMMIT)
     want = {
         "fused": "jit_das_fused", "fused_tree": "jit_das_fused_tree",
@@ -912,6 +921,7 @@ def test_program_names_in_lowered_modules(monkeypatch):
         "count_batch": "jit_das_count_batch",
         "count_loop": "jit_das_count_loop",
         "sharded": "jit_das_sharded",
+        "sharded_group": "jit_das_sharded_group",
         "sharded_tree": "jit_das_sharded_tree",
     }
     for site, name in want.items():

@@ -503,5 +503,6 @@ def test_program_sites_registry_pinned():
         "fused.FusedExecutor._run_batch_group": "count_batch",
         "fused.FusedExecutor.build_count_loop": "count_loop",
         "fused_sharded._ShardedExecJob.dispatch": "sharded",
+        "fused_sharded._ShardedExecJob._build_group": "sharded_group",
         "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
     }

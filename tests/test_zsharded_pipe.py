@@ -113,12 +113,15 @@ def test_mesh_pipelined_matches_serial_answers_and_program_count(env):
     try:
         das.query_many(queries)  # warm compile + caps
 
-        serial = QueryCoalescer(max_batch=2, pipeline_depth=1)
+        # batches of ONE: a same-shape mesh batch of two is one group
+        # program (ISSUE 43), and how a backlog splits into batches is
+        # timing
+        serial = QueryCoalescer(max_batch=1, pipeline_depth=1)
         counters.reset_dispatch_counts()
         serial_answers = _drive(serial, tenant, queries)
         serial_programs = counters.DISPATCH_COUNTS["sharded"]
 
-        piped = QueryCoalescer(max_batch=2, pipeline_depth=2)
+        piped = QueryCoalescer(max_batch=1, pipeline_depth=2)
         counters.reset_dispatch_counts()
         piped_answers = _drive(piped, tenant, queries)
         piped_programs = counters.DISPATCH_COUNTS["sharded"]
