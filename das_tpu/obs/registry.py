@@ -92,7 +92,9 @@ SPAN_NAMES = (
     #: the fetched block, the stats read, the result object built or
     #: the capacities grown, the result cache's insert — attrs: `done`
     #: (false = a capacity retry rides the next round), `lanes` of the
-    #: program it rode in.  Closed BEFORE the answer is yielded: a span
+    #: program it rode in; where the job's fold holds a verified join,
+    #: `pair_left_rows` / `pair_rows` (the counters join.pair_* below,
+    #: this job's share).  Closed BEFORE the answer is yielded: a span
     #: is never open across a `yield` (the consumer's spans would nest
     #: under it, and own time go to the wrong name)
     "exec.verdict",
@@ -255,6 +257,15 @@ COUNTER_NAMES = (
     #: extractions)
     "planner.table_extractions",
     "planner.table_hits",
+    #: the verified join (ops/join.py _pair_join_impl: a whole-type
+    #: right side that shares two or more variables with the left),
+    #: read from the settled job's own stats, no extra fetch
+    #: (query/fused.py _ExecJob.verdict_attrs, one chip): rows OFFERED
+    #: to it (the left side's row count) and rows KEPT (pairs that agree on every
+    #: shared column: what its buffer is sized by); both also ride the
+    #: job's exec.verdict span as attrs of the same names
+    "join.pair_left_rows",
+    "join.pair_rows",
 )
 
 #: fixed log-bucket latency histograms (obs/metrics.py HISTOGRAMS) —
@@ -284,6 +295,16 @@ HISTOGRAM_NAMES = (
     #: the replica-fleet cold-start figure
     "dur.restore_ms",
 )
+
+#: the `jax.named_scope`s a device-trace reader keys on (ops/join.py
+#: whole_type_join): every operation of the verified join sits under
+#: the first, every operation of the posting-index join of ONE shared
+#: variable (its two searches, the prefix sum, the expansion) under the
+#: second; benchmark/layer_metrics/ops.pair_join_*.py and
+#: ops.index_join_ms_per_query.py sum the device time of the operations
+#: whose scope path holds the name
+PAIR_JOIN_SCOPE = "join.pair_verify"
+INDEX_JOIN_SCOPE = "join.index_probe"
 
 #: module names of the jitted device programs on the served and commit
 #: paths, as a device trace shows them (`jit_<name>` on the XLA Modules

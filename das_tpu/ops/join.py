@@ -26,6 +26,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from das_tpu.obs.registry import INDEX_JOIN_SCOPE, PAIR_JOIN_SCOPE
+
 _SENTINEL_L = jnp.int64(2**63 - 1)
 _SENTINEL_R = jnp.int64(2**63 - 2)
 
@@ -45,7 +47,30 @@ def _cumsum_i64(x):
     noise."""
     if x.shape[0] <= 1:
         return x
+    if x.shape[0] > ASSOC_SCAN_MAX_ROWS:
+        return _cumsum_i64_by_carries(x)
     return jax.lax.associative_scan(jnp.add, x)
+
+
+#: longest vector `_cumsum_i64` sums with the log-depth scan; a longer
+#: one (the left side of a whole-store join: 10^6 rows) is summed in
+#: two 32-bit passes, because the scan's twenty levels of 64-bit slices
+#: are 80 s of compile for the chip at a million rows (PERF.md §6 PR 44)
+ASSOC_SCAN_MAX_ROWS = 1 << 16
+
+
+def _cumsum_i64_by_carries(x):
+    """Inclusive int64 prefix sum of NON-NEGATIVE addends below 2^32
+    (row counts) from two 32-bit cumsums, which lower to plain s32/u32
+    reduce-windows: the low words summed modulo 2^32, and the number of
+    times that sum wrapped so far (a wrap shows as a decrease, every
+    addend being under 2^32) as the high word.  Exact."""
+    low = jnp.cumsum(x.astype(jnp.uint32))
+    wrapped = jnp.concatenate(
+        [jnp.zeros((1,), dtype=bool), low[1:] < low[:-1]]
+    )
+    high = jnp.cumsum(wrapped.astype(jnp.uint32))
+    return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
 
 
 def _searchsorted_method(n_queries: int, n_keys: int) -> str:
@@ -66,11 +91,24 @@ def _searchsorted_method(n_queries: int, n_keys: int) -> str:
     30) while the [queries, keys] compare compiles in 0.3 s, and a
     program first met while serving stalls every query behind it."""
     method = "sort" if n_queries > max(1024, n_keys // 16) else "scan"
+    if n_keys > SORT_SEARCH_MAX_KEYS:
+        # the co-sort of a million queries with a multi-million-row
+        # key table is two minutes of compile for the chip (an int64
+        # argsort and a scatter a side; 119 s for 1 M queries into
+        # 8.9 M keys, PERF.md §6 PR 44), past any statement deadline
+        # for the query that meets the program first; the scan's
+        # dependent gathers compile in a second and cost ~3 x the
+        # co-sort's device time there
+        method = "scan"
     if (method == "sort" and n_keys <= LANE_COMPARE_KEYS
             and getattr(_LANES, "on", False)):
         return "compare_all"
     return method
 
+
+#: widest key table a 'sort' searchsorted co-sorts with its queries;
+#: past it every search is a 'scan'
+SORT_SEARCH_MAX_KEYS = 1 << 20
 
 #: widest key table a lane-batched 'sort' searchsorted lowers as
 #: 'compare_all' (a [queries, keys] compare a lane)
@@ -258,11 +296,12 @@ def _index_join_impl(
     at `right_var_cols` positions.  For each left row, the shared
     variable's value keys a searchsorted range in `keys_sorted` (exact —
     the packed key is injective); ranges expand positionally exactly like
-    _join_tables_impl; remaining shared pairs verify against the gathered
-    target columns.  This is what makes joins against multi-million-row
+    _join_tables_impl.  ONE shared variable (`pairs` holds one pair):
+    every candidate is a match; two or more go the verified join
+    (whole_type_join).  This is what makes joins against multi-million-row
     whole-table terms (FlyBase scale) capacity- and compile-cheap: buffers
     scale with the JOIN OUTPUT, never with the table."""
-    lc0, rc0 = pairs[0]
+    ((lc0, _rc0),) = pairs
     type_key = jnp.asarray(type_key, jnp.int64)
     probe = jnp.where(
         left_valid,
@@ -293,10 +332,6 @@ def _index_join_impl(
     row_t = targets[jnp.clip(local, 0, targets.shape[0] - 1)]
 
     out_valid = (j < total) & left_valid[li_safe]
-    for lc, rc in pairs[1:]:
-        out_valid = out_valid & (
-            row_t[:, right_var_cols[rc]] == left_vals[li_safe, lc]
-        )
     parts = [left_vals[li_safe]]
     if right_extra:
         parts.append(
@@ -305,6 +340,135 @@ def _index_join_impl(
     out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
     out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
     return out_vals, out_valid, total
+
+
+def whole_type_join(
+    left_vals, left_valid, index_arrays, type_key,
+    pairs, right_var_cols, right_extra, capacity,
+):
+    """Join the left table INTO a whole-type term, the right side never
+    materialized: `index_arrays` = (sorted (type<<32|target) keys of the
+    probed position, their permutation, the arity's target matrix, its
+    type ids) as query/fused.py index_join_arrays hands them.  ONE
+    shared variable: the posting-index join (_index_join_impl, every
+    candidate is a match).  Two or more: the verified join
+    (_pair_join_impl), which counts and writes a pair only when EVERY
+    shared column agrees.  Returns (out_vals, out_valid, total), `total`
+    the exact number of rows of the join either way."""
+    keys_sorted, perm, targets, type_ids = index_arrays
+    if len(pairs) > 1:
+        return _pair_join_impl(
+            left_vals, left_valid, targets, type_ids, type_key,
+            pairs, right_var_cols, right_extra, capacity,
+        )
+    with jax.named_scope(INDEX_JOIN_SCOPE):
+        return _index_join_impl(
+            left_vals, left_valid, keys_sorted, perm, targets, type_key,
+            pairs, right_var_cols, right_extra, capacity,
+        )
+
+
+#: sorts last and marks a row that takes no part: atom row ids are
+#: indexes into tables far shorter than 2^31 - 1
+_NO_ROW = 2**31 - 1
+
+
+def _pair_join_impl(
+    left_vals, left_valid, targets, type_ids, type_key,
+    pairs, right_var_cols, right_extra, capacity,
+):
+    """L ⋈ R = {(l, r) : l[v] = r[v] for EVERY shared variable v}, R the
+    rows of one link type, for k >= 2 shared variables: a pair is
+    verified BEFORE it is counted, so `total`, the output buffer and
+    the overflow the retry ladder reads are sized by the rows of that
+    set, never by the candidates of its first variable (the 3-clause
+    whole-store conjunction at FlyBase scale 0.3: 9 M left rows, 90 M
+    candidates through the posting index of one variable, ~1.7 k rows).
+
+    One lexicographic sort of both sides together: the shared columns
+    are the sort keys as they are, no hash, so equal neighbours ARE
+    matches; which row an element was rides along as a payload.  The
+    sort is UNSTABLE and the payload no key: on the chip a sort's
+    compile time grows with every operand and every key (25.7 M
+    elements: one s32 operand 29 s, three keys 112 s, PERF.md §6 PR
+    44), so nothing may depend on the order inside a group of equal
+    keys.  A running count of right rows, read at a group's two ends,
+    gives every left row the size of its group; the kept pairs expand
+    positionally into `capacity` slots (the same offsets arithmetic as
+    _join_tables_impl, searched instead of scattered: there are
+    `capacity` slots, not left rows, to place), the r-th right row of a
+    group found by its rank in that running count.  The store's rows
+    are read in place (`targets` of the arity, `type_ids` picks the
+    type), nothing is gathered per candidate: on a v5e a sort moves a
+    row in ~3 ns where a gather through an index costs 6-26 ns.  Under
+    vmap (a group program) every step batches."""
+    with jax.named_scope(PAIR_JOIN_SCOPE):
+        n_r, n_l = targets.shape[0], left_vals.shape[0]
+        n = n_r + n_l
+        of_type = type_ids == jnp.asarray(type_key).astype(type_ids.dtype)
+        cols = []
+        for k, (lc, rc) in enumerate(pairs):
+            r = targets[:, right_var_cols[rc]]
+            l = left_vals[:, lc]
+            if k == 0:
+                r = jnp.where(of_type, r, _NO_ROW)
+                l = jnp.where(left_valid, l, _NO_ROW)
+            cols.append(jnp.concatenate([r, l]))
+        # which row an element was: right rows are tags < n_r
+        tag = jnp.arange(n, dtype=jnp.int32)
+        *cols, tag = jax.lax.sort(
+            (*cols, tag), num_keys=len(cols), is_stable=False
+        )
+        live = cols[0] != _NO_ROW
+        is_r = (live & (tag < n_r)).astype(jnp.int32)
+        is_l = live & (tag >= n_r)
+        differs = cols[0][1:] != cols[0][:-1]
+        for c in cols[1:]:
+            differs = differs | (c[1:] != c[:-1])
+        edge = jnp.ones((1,), dtype=bool)
+        first = jnp.concatenate([edge, differs])
+        last = jnp.concatenate([differs, edge])
+        # right rows seen so far; how many there were before the
+        # element's group began and when it ended: the difference is
+        # the number of right rows every left row of the group pairs
+        # with
+        seen_r = jnp.cumsum(is_r)
+        before = jax.lax.cummax(jnp.where(first, seen_r - is_r, 0))
+        after = jax.lax.cummin(
+            jnp.where(last, seen_r, _NO_ROW), reverse=True
+        )
+        cnt = jnp.where(is_l, after - before, 0)
+        # int64 total: a cross-ish join can pass 2^31; the int32
+        # offsets then wrap, and the overflow retry discards the round
+        total = cnt.astype(jnp.int64).sum()
+        offsets = jnp.cumsum(cnt)
+
+        # slot j belongs to the left element `at` and is its rank-th
+        # pair: with the right row whose running count is before + rank
+        # + 1 (right rows of other groups lie outside that window)
+        j = jnp.arange(capacity, dtype=jnp.int32)
+        method = _searchsorted_method(capacity, n)
+        at = jnp.searchsorted(offsets, j, side="right", method=method)
+        at = jnp.clip(at, 0, n - 1).astype(jnp.int32)
+        rank = j - (offsets[at] - cnt[at])
+        r_at = jnp.searchsorted(
+            seen_r, before[at] + rank + 1, side="left", method=method
+        )
+        r_at = jnp.clip(r_at, 0, n - 1).astype(jnp.int32)
+        ri = jnp.clip(tag[r_at], 0, max(n_r - 1, 0))
+        li = jnp.clip(tag[at] - n_r, 0, max(n_l - 1, 0))
+
+        # every slot below `total` holds a pair that agrees on all the
+        # shared columns: they were the sort keys
+        out_valid = j.astype(jnp.int64) < total
+        parts = [left_vals[li]]
+        if right_extra:
+            parts.append(targets[ri][:, jnp.array(
+                [right_var_cols[rc] for rc in right_extra], dtype=jnp.int32
+            )])
+        out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+        out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
+        return out_vals, out_valid, total
 
 
 def _dedup_table_impl(vals, valid):

@@ -44,9 +44,9 @@ from das_tpu.ops.join import (
     _SENTINEL_L,
     _SENTINEL_R,
     _anti_join_impl,
-    _index_join_impl,
     _join_tables_impl,
     _mix_columns,
+    whole_type_join,
 )
 from das_tpu.parallel.mesh import SHARD_AXIS
 from das_tpu.query.fused import (
@@ -303,12 +303,9 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
             # slab's posting index — union over shards is the full join
             # (each link lives in exactly one slab)
             lv_full, lm_full = _gather_packed(acc_vals, acc_valid, moved)
-            ks, perm, targets, _tid = (
-                a[0] for a in bucket_arrays[i]
-            )
-            acc_vals, acc_valid, total = _index_join_impl(
-                lv_full, lm_full, ks, perm, targets, keys[i],
-                pairs, sig.terms[i].var_cols, extra, jc,
+            acc_vals, acc_valid, total = whole_type_join(
+                lv_full, lm_full, tuple(a[0] for a in bucket_arrays[i]),
+                keys[i], pairs, sig.terms[i].var_cols, extra, jc,
             )
             exch_stats.append(jnp.int32(0))
             join_totals.append(_worst_shard(total, moved))

@@ -96,9 +96,13 @@ def join_step_cost(
     """Price one binary join: the footprint of the step at the capacity
     the estimate implies, plus the estimated materialized window, with
     the penalty when the tables or the window pass every layout.
-    `cap_rows` is the capacity-relevant row estimate (index-join
-    candidate counts included — see stats.pair_join_rows), i.e. the
-    buffer the step actually writes."""
+    `cap_rows` is the capacity-relevant row estimate (the rows of the
+    join; for a join into a whole-type term see stats.pair_join_rows),
+    i.e. the buffer the step actually writes.  The footprint holds both
+    tables whole with their sort vectors: that is also what the
+    verified join of two or more shared variables pays (ops/join.py
+    _pair_join_impl sorts the whole right table with the left rows),
+    however few rows it keeps."""
     cap = cap_for(cap_rows, max_capacity)
     stage, lowered = _join_footprint(
         int(min(left_rows, 2**31 - 1)), max(left_width, 1),

@@ -116,11 +116,16 @@ def _index_join_eligible(plan) -> bool:
 def _join_step(est, acc, right, right_plan):
     """One left-deep join step: (folded RelEstimate, capacity-relevant
     rows, shared-var count, exact?).  For an index-join-eligible right
-    side the capacity model is the single-variable candidate count
-    (stats.pair_join_rows), never below the final match estimate.
-    `exact` marks a capacity figure derived from the degree dot product
-    — a hard bound on what the overflow stats can report, so the seed
-    needs no estimate-error margin."""
+    side the capacity model is stats.pair_join_rows: on ONE shared
+    variable the posting index's candidate count, never below the
+    final match estimate; on two or more the rows that agree on all of
+    them (the join verifies a pair before it counts it), never above
+    the first variable's candidates.  The step is PRICED on the same
+    rows (cost.join_step_cost: both tables whole, which is what the
+    verified join sorts, plus the window it writes).  `exact` marks a
+    capacity figure derived from the degree dot product — a hard bound
+    on what the overflow stats can report, so the seed needs no
+    estimate-error margin."""
     shared = [v for v in acc.dv if v in right.dv]
     out = est.join_estimate(acc, right)
     cap_rows = out.rows
@@ -130,8 +135,8 @@ def _join_step(est, acc, right, right_plan):
         and est.exact_join_rows(acc.plan, right.plan, shared[0]) is not None
     )
     if shared and _index_join_eligible(right_plan):
-        pr, p_exact = est.pair_join_rows(acc, right, shared[0])
-        if pr >= cap_rows:
+        pr, p_exact = est.pair_join_rows(acc, right, shared)
+        if len(shared) > 1 or pr >= cap_rows:
             cap_rows, exact = pr, p_exact
     return out, cap_rows, len(shared), exact
 
@@ -139,8 +144,8 @@ def _join_step(est, acc, right, right_plan):
 def _chain_estimates(est, terms: List, order: Tuple[int, ...]):
     """(est_join_rows, join_cap_seeds, cost) of one left-deep order.
     est_join_rows are the CAPACITY-relevant per-join rows — the number
-    the executors' overflow stats report (candidate counts for index
-    joins, match counts for materialized joins) — so est-vs-actual
+    the executors' overflow stats report (the rows of the join, for an
+    index join and a materialized one alike) — so est-vs-actual
     telemetry compares like with like."""
     rels = [est.term_estimate(terms[i]) for i in order]
     acc = rels[0]

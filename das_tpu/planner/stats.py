@@ -273,24 +273,32 @@ class CardinalityEstimator:
         return acc.rows
 
     def pair_join_rows(
-        self, left: RelEstimate, right: RelEstimate, var: str
+        self, left: RelEstimate, right: RelEstimate, shared
     ) -> Tuple[float, bool]:
-        """(rows, exact) of the join restricted to ONE shared variable
-        — the CAPACITY model of an INDEX JOIN (query/fused.py
-        plan_index_joins): the join probes the posting index at the
-        first shared variable's position and materializes every
-        candidate BEFORE the remaining shared columns verify, so the
-        buffer (and the overflow stats the retry ladder reads) scale
-        with the single-variable candidate count, not the final match
-        count.  Exact (degree dot product) while both sides are base
-        terms; independence otherwise."""
+        """(rows, exact) of a join INTO a whole-type term (query/fused.py
+        plan_index_joins; ops/join.py whole_type_join) on the variables
+        `shared`: what the join counts, writes and reports to the retry
+        ladder, so the CAPACITY model and what the step is priced on.
+        One shared variable: every candidate of the posting index is a
+        match: the degree dot product, exact while both sides are base
+        terms, independence otherwise.  Two or more: the join verifies
+        a pair on every shared column BEFORE it counts it, so its rows
+        are those of the whole equi-join: the independence estimate
+        over ALL shared variables, never above the candidates of the
+        first, and never exact (no composite-key statistic is kept)."""
+        var = shared[0]
+        rows, exact = None, False
         if left.plan is not None and right.plan is not None:
-            exact = self.exact_join_rows(left.plan, right.plan, var)
-            if exact is not None:
-                return float(exact), True
-        return left.rows * right.rows / max(
-            left.dv.get(var, 1.0), right.dv.get(var, 1.0), 1.0
-        ), False
+            dot = self.exact_join_rows(left.plan, right.plan, var)
+            if dot is not None:
+                rows, exact = float(dot), True
+        if rows is None:
+            rows = left.rows * right.rows / max(
+                left.dv.get(var, 1.0), right.dv.get(var, 1.0), 1.0
+            )
+        if len(shared) > 1:
+            return min(self.join_estimate(left, right).rows, rows), False
+        return rows, exact
 
     def join_estimate(
         self, left: RelEstimate, right: RelEstimate

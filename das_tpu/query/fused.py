@@ -28,6 +28,7 @@ reference-identical.
 
 from __future__ import annotations
 
+import functools
 import operator
 import os
 import threading
@@ -47,9 +48,9 @@ from das_tpu.ops.join import (
     _anti_join_impl,
     _build_term_table_impl,
     _dedup_table_impl,
-    _index_join_impl,
     _join_tables_impl,
     lane_batched,
+    whole_type_join,
 )
 
 # probe index routes (static per term).  Every compiler.TermPlan pins
@@ -119,6 +120,21 @@ def plan_index_joins(sigs: Tuple[FusedTermSig, ...]):
         else:
             index_joins.append(-1)
     return tuple(index_joins), right_terms
+
+
+@functools.lru_cache(maxsize=256)
+def pair_join_steps(sigs: Tuple[FusedTermSig, ...], index_joins):
+    """`(steps, first)`: the joins n of a fold that run as the VERIFIED
+    join (ops/join.py whole_type_join: an index join whose right side
+    shares two or more variables with the left), and the term index of
+    the fold's first positive term: what _ExecJob.verdict_attrs reads a
+    settled job's stats by.  Static per signature; asked under tracing
+    only."""
+    positives, _neg, _names, join_meta, _anti = fold_join_meta(sigs)
+    return tuple(
+        n for n, p in enumerate(index_joins)
+        if p >= 0 and len(join_meta[n][0]) > 1
+    ), (positives[0] if positives else 0)
 
 
 class FusedResult:
@@ -353,6 +369,25 @@ class _ExecJob(_GroupHooks):
         return build_fused_group(
             plan_sig, self.count_only, key_axes, fval_axes
         )
+
+    def verdict_attrs(self) -> dict:
+        """What a settled job adds to its `exec.verdict` span (tracing
+        on; settle_pending_iter): for the verified joins of its fold
+        the rows OFFERED (the left side's count: the join before it,
+        or the first term's range) and the rows KEPT, summed, from the
+        stats the round fetched anyway; the same two feed the counters
+        `join.pair_left_rows` / `join.pair_rows`."""
+        if self.last_join_rows is None:
+            return {}
+        steps, first = pair_join_steps(self.sigs, self.index_joins)
+        if not steps:
+            return {}
+        rows = self.last_join_rows
+        left = sum(rows[n - 1] if n else self.last_ranges[first] for n in steps)
+        kept = sum(rows[n] for n in steps)
+        obs.counter("join.pair_left_rows").inc(left)
+        obs.counter("join.pair_rows").inc(kept)
+        return {"pair_left_rows": left, "pair_rows": kept}
 
     def settle(self, host_out, dev_out) -> bool:
         """Consume one round's fetched stats.  True = finished (result is
@@ -636,7 +671,8 @@ def settle_pending_iter(results_cache, pending, on_fetch=None):
                     if done:
                         results_cache.put(key, job.result, pending.version)
                     if traced:
-                        sp.set(done=done)
+                        more = getattr(job, "verdict_attrs", None)
+                        sp.set(done=done, **(more() if done and more else {}))
                 if done:
                     for i in idxs:
                         pending.results[i] = job.result
@@ -1012,9 +1048,8 @@ def _trace_conj(sig: FusedPlanSig, bucket_arrays, keys, fixed_vals):
         # side, and each side's rows are unique)
         with jax.named_scope("join"):
             if index_joins[n] >= 0:
-                ks, perm, targets, _tid = bucket_arrays[i]
-                acc_vals, acc_valid, total = _index_join_impl(
-                    acc_vals, acc_valid, ks, perm, targets, keys[i],
+                acc_vals, acc_valid, total = whole_type_join(
+                    acc_vals, acc_valid, bucket_arrays[i], keys[i],
                     pairs, sig.terms[i].var_cols, extra, jc,
                 )
             else:

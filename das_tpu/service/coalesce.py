@@ -149,6 +149,43 @@ _EWMA_ALPHA = 0.25
 _HISTORY_K = 64
 
 
+#: XLA compile requests seen so far on the CALLING thread.  A dispatch
+#: that builds a program (the first of a query shape, or of a capacity
+#: step) spends seconds to a minute inside the enqueue, whether the
+#: compiler runs or the persistent cache hands the program over; that
+#: is the price of the BUILD, not of a window slot, and fed to the
+#: dispatch EWMA it holds `ceil(rtt / dispatch)` at the floor for the
+#: next dozen dispatches (40 s x 0.75^n), so one system serves two
+#: ways depending on what its compile cache held (PERF.md §6 PR 44).
+_COMPILES = threading.local()
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_on = False
+
+
+def _on_compile_time(name: str, _secs: float, **_kw) -> None:
+    if name == _COMPILE_EVENT:
+        _COMPILES.n = getattr(_COMPILES, "n", 0) + 1
+
+
+def _compiles_here() -> int:
+    """Compile requests this thread has made since the first
+    coalescer was built."""
+    return getattr(_COMPILES, "n", 0)
+
+
+def _listen_for_compiles() -> None:
+    """Register the ONE process-wide jax monitoring listener behind
+    `_compiles_here` (every compile request ends in one
+    backend_compile_duration event on the thread that made it)."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    _compile_listener_on = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_time)
+
+
 def _acquire(lock) -> float:
     """Take the tenant lock on the worker; returns the wait in ms and
     observes it in `serve.lock_wait_ms`.  With tracing off: a bare
@@ -198,6 +235,7 @@ class QueryCoalescer:
                 breaker_threshold = DasConfig.breaker_failure_threshold
             if breaker_cooldown_ms is None:
                 breaker_cooldown_ms = DasConfig.breaker_cooldown_ms
+        _listen_for_compiles()
         self.max_batch = max_batch
         self.pipeline_depth = max(1, int(pipeline_depth))
         self.pipeline_depth_max = max(self.pipeline_depth,
@@ -522,7 +560,9 @@ class QueryCoalescer:
         failed dispatch read as "the per-slot cost" would drag the
         estimator toward zero and peg ceil(rtt/dispatch) at
         pipeline_depth_max exactly when deeper speculation buys nothing
-        (and maximizes the programs a racing commit can invalidate).
+        (and maximizes the programs a racing commit can invalidate) —
+        and only when it BUILT none: a compile inside the enqueue is
+        seconds that no later slot pays (`_compiles_here`).
 
         Tracing (ISSUE 12): the group gets a GROUP id published through
         the recorder's thread-local, so the executor spans recorded
@@ -574,6 +614,7 @@ class QueryCoalescer:
                 traces=[m[0] for m in marks if m is not None],
             )
         t0 = time.perf_counter()
+        compiles0 = _compiles_here()
         job = None
         try:
             # declared injection seam (das_tpu/fault): a failed enqueue
@@ -595,7 +636,8 @@ class QueryCoalescer:
         except Exception:  # noqa: BLE001 — settle's fallback isolates
             job = None
         pending = getattr(job, "pending", None)
-        if pending is not None and getattr(pending, "programs", None):
+        if (pending is not None and getattr(pending, "programs", None)
+                and _compiles_here() == compiles0):
             dispatch_ms = (time.perf_counter() - t0) * 1e3
             self._observe("dispatch_ewma_ms", dispatch_ms)
             if obs.enabled():

@@ -3,7 +3,7 @@
 The four op chains every device program is traced from — the term
 probe (`ops/posting.py range_probe -> verify_positions ->
 ops/join.py build_term_table`), the sort-merge join
-(`_join_tables_impl`), the posting-index join (`_index_join_impl`) and
+(`_join_tables_impl`), the join into a whole-type term (`whole_type_join`) and
 the anti join (`_anti_join_impl`) — checked against brute force in
 numpy over seeded random tables, on the shape classes where static
 capacities bite: empty inputs, one row, a capacity exactly met, a
@@ -26,10 +26,10 @@ import pytest
 
 from das_tpu.ops.join import (
     _anti_join_impl,
-    _index_join_impl,
     anti_join,
     build_term_table,
     join_tables,
+    whole_type_join,
 )
 from das_tpu.ops.posting import range_probe, verify_positions
 
@@ -191,24 +191,22 @@ def test_join_tables_vs_oracle(case):
 
 @partial(jax.jit, static_argnames=(
     "pairs", "right_var_cols", "right_extra", "capacity"))
-def _index_join(lv, lm, keys_sorted, perm, targets, type_key, *, pairs,
-                right_var_cols, right_extra, capacity):
-    return _index_join_impl(
-        lv, lm, keys_sorted, perm, targets, type_key,
+def _index_join(lv, lm, keys_sorted, perm, targets, type_id, type_key, *,
+                pairs, right_var_cols, right_extra, capacity):
+    return whole_type_join(
+        lv, lm, (keys_sorted, perm, targets, type_id), type_key,
         pairs, right_var_cols, right_extra, capacity)
 
 
 def _index_join_oracle(lv, lm, targets, type_id, t, pairs, var_cols, extra):
     out, total = Counter(), 0
-    lc0, rc0 = pairs[0]
     links = np.nonzero(type_id == t)[0]
     for i in np.nonzero(lm)[0]:
         for r in links:
-            if targets[r, var_cols[rc0]] != lv[i, lc0]:
-                continue
-            total += 1  # a candidate: the buffer holds it before verifying
-            if all(targets[r, var_cols[rc]] == lv[i, lc]
-                   for lc, rc in pairs[1:]):
+            # a row counts once it agrees on EVERY shared column: one
+            # pair rides the posting index, two or more the verified join
+            if all(targets[r, var_cols[rc]] == lv[i, lc] for lc, rc in pairs):
+                total += 1
                 out[tuple(int(x) for x in lv[i])
                     + tuple(int(targets[r, var_cols[rc]]) for rc in extra)
                     ] += 1
@@ -253,8 +251,8 @@ def test_index_join_vs_oracle(case):
         assert want_total <= capacity
     out_vals, out_valid, total = _index_join(
         jnp.asarray(lv), jnp.asarray(lm), jnp.asarray(keys_sorted),
-        jnp.asarray(perm), jnp.asarray(targets), np.int64(t),
-        pairs=pairs, right_var_cols=var_cols, right_extra=extra,
+        jnp.asarray(perm), jnp.asarray(targets), jnp.asarray(type_id),
+        np.int64(t), pairs=pairs, right_var_cols=var_cols, right_extra=extra,
         capacity=capacity)
     got = _rows(out_vals, out_valid)
     assert int(total) == want_total

@@ -330,6 +330,76 @@ def test_fused_group_at_cell1_shapes(compile_for_chip, cell1_jobs, shape,
     assert all(n <= max(want["term_caps"]) for n in sorted_rows), sorted_rows
 
 
+#: the benchmark's cell `mem-analytic` (`flybase-analytic`, FlyBase
+#: shape x ANALYTIC_SCALE on one chip): the arity-2 bucket there, and
+#: the capacities the planner seeds for the whole-store 3-clause
+#: conjunction (Interacts rows; Interacts x Member; the verified join's
+#: ~1,667 rows with the ladder's margin)
+ANALYTIC_SCALE = 0.1
+ANALYTIC_ARITY2_ROWS = int(27_870_000 * ANALYTIC_SCALE)
+ANALYTIC_CAPS = dict(term_caps=(1 << 19, 16, 16), join_caps=(1 << 22, 4096))
+
+
+def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
+    """The lone `das_fused` program of the all-variable 3-clause
+    conjunction (PR 44): the posting-index join on one variable, then
+    the verified join on two.  Beyond "it compiles": what keeps its
+    FIRST compile inside the cell's statement deadline.  On the chip a
+    sort's compile time grows with its operands and keys and a 64-bit
+    co-sort of a million queries takes two minutes, so the program
+    holds ONE sort a join at most, none of 64-bit keys, none stable,
+    and the verified join's is its shared columns plus one payload."""
+    import re
+
+    from das_tpu.models.bio import build_bio_atomspace
+    from das_tpu.query import compiler, fused
+    from das_tpu.query.ast import And, Link, Variable
+    from das_tpu.storage.tensor_db import TensorDB
+
+    data, _, _ = build_bio_atomspace(
+        n_genes=60, n_processes=12, members_per_gene=3, n_interactions=40,
+        seed=5)
+    db = TensorDB(data, DasConfig())
+    v = Variable
+    plans = compiler.plan_query(db, And([
+        Link("Interacts", [v("V1"), v("V2")], True),
+        Link("Member", [v("V1"), v("V3")], True),
+        Link("Member", [v("V2"), v("V3")], True),
+    ]))
+    job = fused.get_executor(db)._exec_job(list(plans), False)
+    assert job.index_joins == (0, 0)
+    assert fused.pair_join_steps(job.sigs, job.index_joins)[0] == (1,)
+    sig = dataclasses.replace(job.plan_sig(), **ANALYTIC_CAPS)
+    fn, _names = fused.build_fused(sig, False)
+    cap = capacity_class(ANALYTIC_ARITY2_ROWS)
+
+    def stretch(a):
+        shape = tuple(a.shape)
+        return _shape((cap, *shape[1:]) if shape else shape, a.dtype)
+
+    def as_shape(x):
+        x = np.asarray(x)
+        return _shape(x.shape, x.dtype)
+
+    shapes = (jax.tree.map(stretch, job.arrays),
+              jax.tree.map(as_shape, job.keys),
+              jax.tree.map(as_shape, job.fvals))
+    compiled = compile_for_chip(fn, *shapes)
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the 3 M x 10 x 10 candidates never exist: everything the program
+    # holds beside the store is a few arrays of left + right rows
+    rows = ANALYTIC_CAPS["join_caps"][0] + cap
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 4 * 12
+    lowered = fn.lower(*shapes).as_text()
+    sorts = re.findall(
+        r'"stablehlo\.sort"\(([^)]*)\) <\{([^}]*)\}>.*?\}\) : \(([^)]*)\) ->',
+        lowered, flags=re.S)
+    assert len(sorts) == 1, "the verified join sorts once; nothing else does"
+    operands, attrs, types = sorts[0]
+    assert operands.count("%") == 3 and "is_stable = false" in attrs
+    assert "i64" not in types
+
+
 @pytest.mark.parametrize("cap,dcap,key_dtype", [
     (SMOKE_ARITY2_CAPACITY, delta_class(10), jnp.int64),  # the smoke's
     (CELL2_ARITY2_CAPACITY, 64, jnp.int64),    # cell 2: 5 of a commit's 8

@@ -41,10 +41,10 @@ pytestmark = pytest.mark.pipeline
 COMMIT = '(: "platypus" Concept)\n(Inheritance "platypus" "chimp")'
 
 
-def _pair_query():
+def _pair_query(concept="mammal"):
     return And([
         Link("Inheritance", [Variable("$1"), Variable("$2")], True),
-        Link("Inheritance", [Variable("$2"), Node("Concept", "mammal")], True),
+        Link("Inheritance", [Variable("$2"), Node("Concept", concept)], True),
     ])
 
 
@@ -157,6 +157,20 @@ class _FakeTenant:
     def __init__(self, das):
         self.das = das
         self.lock = threading.RLock()
+
+
+def _build_programs_of(das, n_queries: int) -> None:
+    """Run a group of `n_queries` pair queries of ANOTHER concept once
+    on `das`, so that the programs of that shape are built (a store
+    keeps its own) and `_pair_query()` is still no cache hit: a
+    dispatch that BUILDS a program does not feed the dispatch EWMA
+    (test_a_dispatch_that_builds_a_program_is_no_dispatch_cost)."""
+    from das_tpu.api.atomspace import QueryOutputFormat
+
+    job = das.query_many_dispatch(
+        [_pair_query("reptile") for _ in range(n_queries)],
+        QueryOutputFormat.HANDLE)
+    list(job.settle_iter())
 
 
 def _drive(coalescer, tenant, queries, fmt=None):
@@ -566,6 +580,7 @@ def test_early_settles_counted_for_wide_groups():
     from das_tpu.service.coalesce import QueryCoalescer
 
     das, db = _tensor_das()
+    _build_programs_of(das, 3)
     tenant = _FakeTenant(das)
     c = QueryCoalescer(max_batch=4, pipeline_depth=1)
     fmt = QueryOutputFormat.HANDLE
@@ -590,6 +605,7 @@ def test_cache_hit_groups_do_not_feed_rtt_ewma():
     from das_tpu.service.coalesce import QueryCoalescer
 
     das, db = _tensor_das()
+    _build_programs_of(das, 1)
     tenant = _FakeTenant(das)
     c = QueryCoalescer(max_batch=4, pipeline_depth=2)
     fmt = QueryOutputFormat.HANDLE
@@ -613,6 +629,34 @@ def test_cache_hit_groups_do_not_feed_rtt_ewma():
     assert c.stats["rtt_ewma_ms"] == rtt_after_fetch
     assert c.stats["dispatch_ewma_ms"] == dispatch_after_enqueue
     assert c.stats["early_settles"] == 0  # lone answers are never early
+
+
+def test_a_dispatch_that_builds_a_program_is_no_dispatch_cost():
+    """The dispatch EWMA is the host cost of ONE window slot.  A
+    dispatch that builds a program (the first of a shape; 40 s on the
+    chip for a whole-store join, PERF.md §6 PR 44) spends its time in
+    the compiler, or in the persistent cache's load: fed to the
+    estimator it holds `ceil(rtt / dispatch)` at the floor for the next
+    dozen dispatches, and one system serves two ways depending on what
+    its compile cache held.  Such a dispatch feeds nothing; the next
+    one of the same shape, which builds nothing, does."""
+    from das_tpu.api.atomspace import QueryOutputFormat
+    from das_tpu.service import coalesce
+    from das_tpu.service.coalesce import QueryCoalescer
+
+    fmt = QueryOutputFormat.HANDLE
+    das, _db = _tensor_das()        # a store builds its own programs
+    tenant = _FakeTenant(das)
+    c = QueryCoalescer(max_batch=4, pipeline_depth=2)
+    for concept, builds in (("mammal", True), ("reptile", False)):
+        # the same shape twice, the concept a traced key: the second
+        # is neither a result-cache hit nor a new program
+        group = [(tenant, _pair_query(concept), fmt, Future())]
+        before = coalesce._compiles_here()
+        c._settle_group(c._dispatch_group(tenant, fmt, group))
+        assert isinstance(group[0][3].result(timeout=60), str)
+        assert (coalesce._compiles_here() > before) == builds
+        assert (c.stats["dispatch_ewma_ms"] == 0.0) == builds, c.stats
 
 
 def test_cancelled_futures_do_not_count_as_early_settles():
