@@ -266,6 +266,15 @@ COUNTER_NAMES = (
     #: job's exec.verdict span as attrs of the same names
     "join.pair_left_rows",
     "join.pair_rows",
+    #: the posting-index join of ONE shared variable (ops/join.py
+    #: _index_join_impl), fed beside the two above from the same stats:
+    #: left rows OFFERED to it, and those of them whose ranges came
+    #: from the slice search (ops/join.py index_search_method ==
+    #: SLICE_SEARCH at the shapes the job's program was traced at: one
+    #: 32-bit search inside the type's slice; the rest took the two
+    #: 64-bit searches of the whole index)
+    "join.index_probe_rows",
+    "join.index_slice_rows",
 )
 
 #: fixed log-bucket latency histograms (obs/metrics.py HISTOGRAMS) —
@@ -299,7 +308,8 @@ HISTOGRAM_NAMES = (
 #: the `jax.named_scope`s a device-trace reader keys on (ops/join.py
 #: whole_type_join): every operation of the verified join sits under
 #: the first, every operation of the posting-index join of ONE shared
-#: variable (its two searches, the prefix sum, the expansion) under the
+#: variable (its range lookup: two searches, or for a large left side
+#: ONE and two reads; the prefix sum; the expansion) under the
 #: second; benchmark/layer_metrics/ops.pair_join_*.py and
 #: ops.index_join_ms_per_query.py sum the device time of the operations
 #: whose scope path holds the name

@@ -89,8 +89,15 @@ def _searchsorted_method(n_queries: int, n_keys: int) -> str:
     the batched variadic sort is 23 s of such a program's 26 s compile
     for the chip (2,048 queries against a 16-row table, PERF.md §6 PR
     30) while the [queries, keys] compare compiles in 0.3 s, and a
-    program first met while serving stalls every query behind it."""
-    method = "sort" if n_queries > max(1024, n_keys // 16) else "scan"
+    program first met while serving stalls every query behind it.
+
+    Past SORT_SEARCH_MAX_KEYS keys the answer is 'scan' whatever the
+    query side (the co-sort's compile: see the constant).  Asked by
+    `_join_tables_impl`, `_anti_join_impl` and the verified join's
+    expansion, whose keys are 64-bit mixes or running counts; the
+    posting-index join asks `index_search_method`, which has one more
+    answer for a large left side."""
+    method = "sort" if _many_queries(n_queries, n_keys) else "scan"
     if n_keys > SORT_SEARCH_MAX_KEYS:
         # the co-sort of a million queries with a multi-million-row
         # key table is two minutes of compile for the chip (an int64
@@ -106,9 +113,47 @@ def _searchsorted_method(n_queries: int, n_keys: int) -> str:
     return method
 
 
+def _many_queries(n_queries: int, n_keys: int) -> bool:
+    """The relative cutover of `_searchsorted_method`: the query side is
+    large enough against the keys to be worth more than a dependent
+    gather a step and a query."""
+    return n_queries > max(1024, n_keys // 16)
+
+
 #: widest key table a 'sort' searchsorted co-sorts with its queries;
-#: past it every search is a 'scan'
+#: past it every search is a 'scan', and the posting-index join of a
+#: LARGE left side (`index_search_method`) searches its type's slice
+#: once, on 32-bit words.  A co-sort on 32-bit words was priced for that
+#: join and stays out (compiles for a described v5e, 524,288 probes into
+#: 2,961,251 keys, PERF.md §6 PR 45): the two scans compile in 4.0 s,
+#: the slice search in 6.0 s, one unstable `lax.sort` of (word, tag)
+#: over both sides plus the running counts in 21.6 s, 35.6 s with the
+#: second sort that brings the ranges back to row order, on a first
+#: request of 41.5 s that has to end inside 60 (the analytic cell's
+#: statement deadline)
 SORT_SEARCH_MAX_KEYS = 1 << 20
+
+#: `index_search_method`'s third answer (`_slice_ranges`)
+SLICE_SEARCH = "slice"
+
+
+def index_search_method(n_left: int, n_keys: int) -> str:
+    """Static per-shape choice of the posting-index join's range lookup
+    (`_index_join_impl` consults it, and query/fused.py to count the
+    rows that took it): SLICE_SEARCH exactly where the left side is
+    large against the index by `_searchsorted_method`'s own relative
+    rule AND the key cap forces that rule's 'sort' down to 'scan' (the
+    whole-store conjunction's first join: 524,288 left rows into a
+    2,961,251-row index), otherwise `_searchsorted_method`'s answer, for
+    the two 64-bit searches the join has always run.  A small left side
+    (16-2,048 rows a lane into the same index: every grounded shape)
+    keeps them: the slice search makes two passes over the whole index
+    per program, which cost more than 2 x 23 steps of a few rows'
+    gathers."""
+    if n_keys > SORT_SEARCH_MAX_KEYS and _many_queries(n_left, n_keys):
+        return SLICE_SEARCH
+    return _searchsorted_method(n_left, n_keys)
+
 
 #: widest key table a lane-batched 'sort' searchsorted lowers as
 #: 'compare_all' (a [queries, keys] compare a lane)
@@ -284,6 +329,79 @@ def _join_tables_impl(left_vals, left_valid, right_vals, right_valid, pairs, rig
     return out_vals, out_valid, total
 
 
+def _index_ranges(keys_sorted, type_key, left_vals, lc0, left_valid):
+    """`[lo, hi)`, int32: where the keys `(type_key << 32) | v` of the
+    left rows' values `v` (column `lc0`) lie in the posting index
+    `keys_sorted`; what an invalid row gets is the caller's to mask."""
+    method = index_search_method(left_vals.shape[0], keys_sorted.shape[0])
+    if method == SLICE_SEARCH:
+        return _slice_ranges(keys_sorted, type_key, left_vals[:, lc0])
+    type_key = jnp.asarray(type_key, jnp.int64)
+    probe = jnp.where(
+        left_valid,
+        (type_key << 32) | left_vals[:, lc0].astype(jnp.int64),
+        jnp.int64(-1),
+    )
+    lo = jnp.searchsorted(keys_sorted, probe, side="left", method=method).astype(jnp.int32)
+    hi = jnp.searchsorted(keys_sorted, probe, side="right", method=method).astype(jnp.int32)
+    return lo, hi
+
+
+def _slice_ranges(keys_sorted, type_key, left_col):
+    """`_index_ranges` for a LARGE left side: one binary search over
+    32-bit words inside the probed type's slice, the range's end read
+    and not searched.  The same `[lo, hi)` as the two 64-bit searches
+    for every int32 value of a left row, exact.
+
+    The index holds int64 `(type << 32) | target`, sorted, pads (int64
+    max) last; on the chip a gather from it is TWO u32 gathers, and the
+    high word is compared although every key of the probed type has the
+    same one (2 x 22 steps of them over 524,288 rows: 0.55 s of the
+    whole-store conjunction's 0.84 s program, PERF.md §5).  So, once a
+    program and elementwise over the index, each key becomes ONE word:
+    the target where the key is of the probed type, the lowest int32
+    where it is a dangling target's (storage/atom_table.py
+    _combine_type_pos turns target -1 into the key -1 whatever the
+    type: it sorts first, and a left value of -1 has always probed
+    exactly that key), the next lowest for a smaller type, the highest
+    for a larger type or a pad.  Rows of a type are contiguous and
+    sorted by target, and atom row ids lie in [0, 2^31 - 1), so the
+    words are non-decreasing over the WHOLE index: a search of them
+    returns the global position, and `perm[...]` downstream is
+    untouched.  `run_end[i]` is the position after the last word equal
+    to word i (a reverse running minimum over the index), so `hi` is
+    one read at `lo` where the word there is the probed one."""
+    n = keys_sorted.shape[0]
+    lowest = jnp.int32(-(2**31))
+    type_word = (keys_sorted >> 32).astype(jnp.int32)
+    of_type = jnp.asarray(type_key).astype(jnp.int32)
+    words = jnp.where(
+        type_word < 0, lowest,
+        jnp.where(
+            type_word < of_type, lowest + 1,
+            jnp.where(
+                type_word > of_type, _NO_ROW, keys_sorted.astype(jnp.int32)
+            ),
+        ),
+    )
+    ends_run = jnp.concatenate(
+        [words[1:] != words[:-1], jnp.ones((1,), dtype=bool)]
+    )
+    run_end = jax.lax.cummin(
+        jnp.where(ends_run, jnp.arange(1, n + 1, dtype=jnp.int32), _NO_ROW),
+        reverse=True,
+    )
+    # -1 probes the dangling targets' key; no other negative value and
+    # not 2^31 - 1 is any key's target, and their words stand for rows
+    # of other types
+    probe = jnp.where(left_col == -1, lowest, left_col)
+    is_key = (left_col >= -1) & (left_col != _NO_ROW)
+    lo = jnp.searchsorted(words, probe, side="left", method="scan").astype(jnp.int32)
+    at = jnp.clip(lo, 0, n - 1)
+    hi = jnp.where(is_key & (words[at] == probe), run_end[at], lo)
+    return lo, hi
+
+
 def _index_join_impl(
     left_vals, left_valid, keys_sorted, perm, targets, type_key,
     pairs, right_var_cols, right_extra, capacity,
@@ -300,24 +418,38 @@ def _index_join_impl(
     every candidate is a match; two or more go the verified join
     (whole_type_join).  This is what makes joins against multi-million-row
     whole-table terms (FlyBase scale) capacity- and compile-cheap: buffers
-    scale with the JOIN OUTPUT, never with the table."""
+    scale with the JOIN OUTPUT, never with the table.
+
+    The ranges come from `_index_ranges`: two 64-bit searches of the
+    whole index for a small left side, ONE 32-bit search inside the
+    type's slice for a large one (`index_search_method`, by static
+    shape); `lo`, `cnt`, `total`, the expansion and the row order are
+    the same either way."""
     ((lc0, _rc0),) = pairs
-    type_key = jnp.asarray(type_key, jnp.int64)
-    probe = jnp.where(
-        left_valid,
-        (type_key << 32) | left_vals[:, lc0].astype(jnp.int64),
-        jnp.int64(-1),
-    )
-    method = _searchsorted_method(probe.shape[0], keys_sorted.shape[0])
-    lo = jnp.searchsorted(keys_sorted, probe, side="left", method=method).astype(jnp.int32)
-    hi = jnp.searchsorted(keys_sorted, probe, side="right", method=method).astype(jnp.int32)
+    lo, hi = _index_ranges(keys_sorted, type_key, left_vals, lc0, left_valid)
     # int64: per-row ranges against an UNCAPPED whole-type term (tens of
     # millions of rows) can sum past 2^31; a wrapped total would silently
     # zero the output instead of triggering the overflow retry
     cnt = jnp.where(left_valid, hi - lo, 0).astype(jnp.int64)
     offsets = _cumsum_i64(cnt)
     total = offsets[-1] if cnt.shape[0] > 0 else jnp.int64(0)
+    return _expand_index_ranges(
+        left_vals, left_valid, lo, cnt, offsets, total, perm, targets,
+        right_var_cols, right_extra, capacity,
+    )
 
+
+def _expand_index_ranges(
+    left_vals, left_valid, lo, cnt, offsets, total, perm, targets,
+    right_var_cols, right_extra, capacity,
+):
+    """The posting-index join's second half: every left row's `cnt`
+    index positions from `lo` on, expanded positionally into `capacity`
+    slots (the offsets arithmetic of _join_tables_impl) and read
+    through `perm` into the store's rows.  Six gather passes over the
+    slots: after PR 45 the largest part of the whole-store
+    conjunction's program (scripts/index_join_parts.py times it
+    alone)."""
     j = jnp.arange(capacity, dtype=jnp.int64)
     prev_all = offsets - cnt
     row_ids = jnp.arange(cnt.shape[0], dtype=jnp.int32)
@@ -328,7 +460,7 @@ def _index_join_impl(
     li_safe = jnp.clip(li, 0, max(left_vals.shape[0] - 1, 0))
     prev = prev_all[li_safe]
     ri_sorted = lo[li_safe] + (j - prev).astype(jnp.int32)
-    local = perm[jnp.clip(ri_sorted, 0, keys_sorted.shape[0] - 1)]
+    local = perm[jnp.clip(ri_sorted, 0, perm.shape[0] - 1)]
     row_t = targets[jnp.clip(local, 0, targets.shape[0] - 1)]
 
     out_valid = (j < total) & left_valid[li_safe]

@@ -49,6 +49,8 @@ from das_tpu.ops.join import (
     _build_term_table_impl,
     _dedup_table_impl,
     _join_tables_impl,
+    SLICE_SEARCH,
+    index_search_method,
     lane_batched,
     whole_type_join,
 )
@@ -123,17 +125,22 @@ def plan_index_joins(sigs: Tuple[FusedTermSig, ...]):
 
 
 @functools.lru_cache(maxsize=256)
-def pair_join_steps(sigs: Tuple[FusedTermSig, ...], index_joins):
-    """`(steps, first)`: the joins n of a fold that run as the VERIFIED
-    join (ops/join.py whole_type_join: an index join whose right side
-    shares two or more variables with the left), and the term index of
-    the fold's first positive term: what _ExecJob.verdict_attrs reads a
-    settled job's stats by.  Static per signature; asked under tracing
-    only."""
+def whole_type_join_steps(sigs: Tuple[FusedTermSig, ...], index_joins):
+    """`(pair_steps, probe_steps, first)`: the joins n of a fold that
+    run as the VERIFIED join (ops/join.py whole_type_join: an index
+    join whose right side shares two or more variables with the left);
+    those that run as the posting-index join of ONE shared variable,
+    each as `(n, i)`, `i` the term whose arrays hold the index it
+    probes; and the term index of the fold's first positive term: what
+    _ExecJob.verdict_attrs reads a settled job's stats by.  Static per
+    signature; asked under tracing only."""
     positives, _neg, _names, join_meta, _anti = fold_join_meta(sigs)
+    shared = [len(join_meta[n][0]) for n in range(len(index_joins))]
     return tuple(
-        n for n, p in enumerate(index_joins)
-        if p >= 0 and len(join_meta[n][0]) > 1
+        n for n, p in enumerate(index_joins) if p >= 0 and shared[n] > 1
+    ), tuple(
+        (n, positives[n + 1]) for n, p in enumerate(index_joins)
+        if p >= 0 and shared[n] == 1
     ), (positives[0] if positives else 0)
 
 
@@ -376,15 +383,39 @@ class _ExecJob(_GroupHooks):
         the rows OFFERED (the left side's count: the join before it,
         or the first term's range) and the rows KEPT, summed, from the
         stats the round fetched anyway; the same two feed the counters
-        `join.pair_left_rows` / `join.pair_rows`."""
+        `join.pair_left_rows` / `join.pair_rows`.  The posting-index
+        joins of one variable feed counters alone: the rows offered to
+        them (`join.index_probe_rows`) and, of those, the rows whose
+        ranges came from the slice search (`join.index_slice_rows`):
+        which joins those are is static per signature, and whether one
+        took the slice search is ops/join.py's own rule on the two
+        shapes the program was traced at."""
         if self.last_join_rows is None:
             return {}
-        steps, first = pair_join_steps(self.sigs, self.index_joins)
-        if not steps:
-            return {}
+        pair_steps, probe_steps, first = whole_type_join_steps(
+            self.sigs, self.index_joins
+        )
         rows = self.last_join_rows
-        left = sum(rows[n - 1] if n else self.last_ranges[first] for n in steps)
-        kept = sum(rows[n] for n in steps)
+
+        def offered(n):
+            return rows[n - 1] if n else self.last_ranges[first]
+
+        probed = sliced = 0
+        for n, i in probe_steps:
+            probed += offered(n)
+            if index_search_method(
+                self.join_caps[n - 1] if n else self.term_caps[first],
+                self.arrays[i][0].shape[0],
+            ) == SLICE_SEARCH:
+                sliced += offered(n)
+        if probed:
+            obs.counter("join.index_probe_rows").inc(probed)
+        if sliced:
+            obs.counter("join.index_slice_rows").inc(sliced)
+        if not pair_steps:
+            return {}
+        left = sum(offered(n) for n in pair_steps)
+        kept = sum(rows[n] for n in pair_steps)
         obs.counter("join.pair_left_rows").inc(left)
         obs.counter("join.pair_rows").inc(kept)
         return {"pair_left_rows": left, "pair_rows": kept}

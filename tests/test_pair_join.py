@@ -114,7 +114,8 @@ def test_the_verified_joins_buffer_is_sized_by_its_rows(served):
     plans = compiler.plan_query(das.db, parse_query(DSL))
     job = ex._exec_job(list(plans), False)
     assert job is not None
-    steps, _first = fused.pair_join_steps(job.sigs, job.index_joins)
+    steps, _probes, _first = fused.whole_type_join_steps(
+        job.sigs, job.index_joins)
     assert steps == (1,)
     out = job.dispatch()
     assert job.settle(jax.device_get(out), out) and job.rounds == 1
@@ -366,8 +367,12 @@ def test_a_long_vectors_int64_cumsum_is_two_32_bit_passes():
 
 def test_a_big_key_table_is_searched_by_scan():
     big = join_ops.SORT_SEARCH_MAX_KEYS + 1
-    assert join_ops._searchsorted_method(1 << 20, big) == "scan"
-    assert join_ops._searchsorted_method(1 << 24, big) == "scan"
+    # a big left side: the posting-index join searches its type's slice
+    # once, on 32-bit words (PR 45, tests/test_index_slice_search.py);
+    # every other search of such a table stays a scan
+    for n_left in (1 << 20, 1 << 24):
+        assert join_ops.index_search_method(n_left, big) == "slice"
+        assert join_ops._searchsorted_method(n_left, big) == "scan"
     # the rule below it is what it was
     assert join_ops._searchsorted_method(2048, 16) == "sort"
     assert join_ops._searchsorted_method(2048, 8_883_562) == "scan"

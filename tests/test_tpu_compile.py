@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from das_tpu.core.config import DasConfig
+from das_tpu.obs.registry import INDEX_JOIN_SCOPE
 from das_tpu.storage.delta import capacity_class, delta_class
 
 #: chip_smoke.py's default store: links of arity 2 at --scale 0.1
@@ -368,7 +369,8 @@ def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
     ]))
     job = fused.get_executor(db)._exec_job(list(plans), False)
     assert job.index_joins == (0, 0)
-    assert fused.pair_join_steps(job.sigs, job.index_joins)[0] == (1,)
+    assert fused.whole_type_join_steps(job.sigs, job.index_joins)[:2] == (
+        (1,), ((0, 1),))
     sig = dataclasses.replace(job.plan_sig(), **ANALYTIC_CAPS)
     fn, _names = fused.build_fused(sig, False)
     cap = capacity_class(ANALYTIC_ARITY2_ROWS)
@@ -398,6 +400,47 @@ def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
     operands, attrs, types = sorts[0]
     assert operands.count("%") == 3 and "is_stable = false" in attrs
     assert "i64" not in types
+    # the FIRST join (524,288 left rows into the 2.96 M-key index)
+    # holds ONE search loop, and its body gathers no 64-bit element:
+    # on the chip an int64 gather is two u32 gathers, and two searches
+    # of them were 66 % of the program's device time (PERF.md §6 PR 45)
+    loops = _loops_under(jax.make_jaxpr(fn)(*shapes).jaxpr, INDEX_JOIN_SCOPE)
+    assert len(loops) == 1
+    (gathered,) = _gathered_in(loops[0])
+    assert gathered.dtype == jnp.int32 and gathered.shape == (cap,)
+
+
+def _inner_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _loops_under(jaxpr, scope, stack=""):
+    """The loop equations (a `fori_loop` traces as `scan` or `while`)
+    whose name stack, from the program's root down, holds `scope`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name in ("scan", "while") and scope in here:
+            found.append(eqn)
+            continue
+        for sub in _inner_jaxprs(eqn):
+            found += _loops_under(sub, scope, here)
+    return found
+
+
+def _gathered_in(eqn):
+    """The operands of every gather inside a loop equation's bodies."""
+    avals = []
+    for sub in _inner_jaxprs(eqn):
+        for inner in sub.eqns:
+            if inner.primitive.name == "gather":
+                avals.append(inner.invars[0].aval)
+            avals += _gathered_in(inner)
+    return avals
 
 
 @pytest.mark.parametrize("cap,dcap,key_dtype", [
