@@ -94,7 +94,10 @@ SPAN_NAMES = (
     #: (false = a capacity retry rides the next round), `lanes` of the
     #: program it rode in; where the job's fold holds a verified join,
     #: `pair_left_rows` / `pair_rows` (the counters join.pair_* below,
-    #: this job's share).  Closed BEFORE the answer is yielded: a span
+    #: this job's share); on a mesh job with exchange slots,
+    #: `partitioned` (its verified joins that partition both sides) and
+    #: `exchange_fill` (worst destination occupancy / slots, over its
+    #: exchanging joins).  Closed BEFORE the answer is yielded: a span
     #: is never open across a `yield` (the consumer's spans would nest
     #: under it, and own time go to the wrong name)
     "exec.verdict",
@@ -243,6 +246,22 @@ COUNTER_NAMES = (
     #: re-dispatches of a fused mesh program after a shard overflowed a
     #: capacity (a job's dispatch rounds past its first)
     "mesh.retries",
+    #: settled mesh jobs whose fold holds at least one verified join
+    #: that PARTITIONS both sides over the chips
+    #: (parallel/fused_sharded.py pair_join_partitions; fed at the
+    #: job's verdict, _ShardedExecJob.verdict_attrs)
+    "mesh.partitioned_joins",
+    #: slots all_gathered onto every shard as the LEFT side of a join
+    #: into a whole-type term, from the gathered operand's shape when
+    #: the program is traced, added per dispatch beside
+    #: mesh.collective_bytes (a join that partitions adds none)
+    "mesh.left_gathered_rows",
+    #: a settled mesh job's exchanging joins (table joins and verified
+    #: joins that hash-partition both sides): the worst destination's
+    #: occupancy, from the stats the settle fetched anyway, and the
+    #: slots a destination had; sums over jobs, their ratio is the fill
+    "mesh.exchange_rows_max",
+    "mesh.exchange_slots",
     #: answers the STAGED mesh pipeline gave because the fused mesh
     #: program declined — twin of ROUTE_COUNTS["staged"] on the mesh
     "mesh.staged_fallbacks",
@@ -315,6 +334,12 @@ HISTOGRAM_NAMES = (
 #: whose scope path holds the name
 PAIR_JOIN_SCOPE = "join.pair_verify"
 INDEX_JOIN_SCOPE = "join.index_probe"
+#: the mesh's verified join that partitions both sides
+#: (parallel/fused_sharded.py): the whole step, both exchanges (each
+#: collective in `mesh.repartition`) and the local verify (still under
+#: PAIR_JOIN_SCOPE inside it); benchmark/layer_metrics/
+#: mesh.partition_join_*.py read it
+PAIR_PARTITION_SCOPE = "mesh.pair_partition"
 
 #: module names of the jitted device programs on the served and commit
 #: paths, as a device trace shows them (`jit_<name>` on the XLA Modules

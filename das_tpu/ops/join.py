@@ -65,12 +65,48 @@ def _cumsum_i64_by_carries(x):
     reduce-windows: the low words summed modulo 2^32, and the number of
     times that sum wrapped so far (a wrap shows as a decrease, every
     addend being under 2^32) as the high word.  Exact."""
-    low = jnp.cumsum(x.astype(jnp.uint32))
+    low = _padded_scan(jnp.cumsum, x.astype(jnp.uint32), 0)
     wrapped = jnp.concatenate(
         [jnp.zeros((1,), dtype=bool), low[1:] < low[:-1]]
     )
-    high = jnp.cumsum(wrapped.astype(jnp.uint32))
+    high = _padded_scan(jnp.cumsum, wrapped.astype(jnp.uint32), 0)
     return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
+
+
+#: lengths (over the first, up to the second) at which the chip's
+#: compiler takes tens of seconds for ONE running sum, maximum or
+#: minimum (a reduce-window over [n / 128, 128]), and the length such a
+#: vector is padded to instead.  Compiles for a described v5e, one
+#: `lax.cummax` of int32 (PERF.md §6 PR 47): 131,072 elements 4.5 s,
+#: 262,144 7.5, 524,288 14, every length tried from 786,432 to
+#: 2,883,584 27-50 s (a shard's 2,220,890 index keys: 34-37 s; the
+#: 1,048,576 left rows of a mesh join, as u32 sums: 27-36 s each), then
+#: 2,961,251 6 s, 4,194,304 7-11 s, 8,388,608 2-4 s: under some 23,000
+#: rows of 128 the compiler unrolls, above it loops.  One such op is
+#: most of a first request's budget under the analytic cells' statement
+#: deadline, and a pass over 8 M elements costs the device a
+#: millisecond or two more than one over 1-2 M.  The lower bound is
+#: where one op still compiles in a quarter of a minute; programs
+#: whose vectors lie outside the interval (the one-chip cell's: 524,288
+#: left rows, 2,961,251 keys, 4,194,304 and 7,155,555 slots) are traced
+#: exactly as before.
+SLOW_SCAN_ROWS = (1 << 19, 23_000 * 128)
+FAST_SCAN_ROWS = 1 << 23
+
+
+def _padded_scan(scan, x, fill, **kwargs):
+    """`scan(x, **kwargs)` (a forward running sum or maximum, or a
+    REVERSE running minimum) with `x` padded AT ITS END by `fill`, the
+    scan's identity, to FAST_SCAN_ROWS where its length falls in
+    SLOW_SCAN_ROWS, and cut back: the first `len(x)` results are those
+    of the unpadded scan either way (a forward scan never reads ahead,
+    and a reverse one meets only identities before the last element).
+    By static shape; any other length is scanned as it is."""
+    n = x.shape[0]
+    if not SLOW_SCAN_ROWS[0] < n <= SLOW_SCAN_ROWS[1]:
+        return scan(x, **kwargs)
+    pad = jnp.full((FAST_SCAN_ROWS - n,), fill, dtype=x.dtype)
+    return scan(jnp.concatenate([x, pad]), **kwargs)[:n]
 
 
 def _searchsorted_method(n_queries: int, n_keys: int) -> str:
@@ -387,9 +423,10 @@ def _slice_ranges(keys_sorted, type_key, left_col):
     ends_run = jnp.concatenate(
         [words[1:] != words[:-1], jnp.ones((1,), dtype=bool)]
     )
-    run_end = jax.lax.cummin(
+    run_end = _padded_scan(
+        jax.lax.cummin,
         jnp.where(ends_run, jnp.arange(1, n + 1, dtype=jnp.int32), _NO_ROW),
-        reverse=True,
+        _NO_ROW, reverse=True,
     )
     # -1 probes the dangling targets' key; no other negative value and
     # not 2^31 - 1 is any key's target, and their words stand for rows
@@ -456,7 +493,7 @@ def _expand_index_ranges(
     seg = jnp.full(capacity, -1, dtype=jnp.int32).at[prev_all].max(
         jnp.where(cnt > 0, row_ids, -1), mode="drop"
     )
-    li = jax.lax.cummax(seg)
+    li = _padded_scan(jax.lax.cummax, seg, -1)
     li_safe = jnp.clip(li, 0, max(left_vals.shape[0] - 1, 0))
     prev = prev_all[li_safe]
     ri_sorted = lo[li_safe] + (j - prev).astype(jnp.int32)
@@ -510,12 +547,52 @@ def _pair_join_impl(
     pairs, right_var_cols, right_extra, capacity,
 ):
     """L ⋈ R = {(l, r) : l[v] = r[v] for EVERY shared variable v}, R the
-    rows of one link type, for k >= 2 shared variables: a pair is
-    verified BEFORE it is counted, so `total`, the output buffer and
-    the overflow the retry ladder reads are sized by the rows of that
-    set, never by the candidates of its first variable (the 3-clause
-    whole-store conjunction at FlyBase scale 0.3: 9 M left rows, 90 M
-    candidates through the posting index of one variable, ~1.7 k rows).
+    rows of one link type READ IN PLACE (`targets` of the arity,
+    `type_ids` picks the type; nothing is gathered per candidate), for
+    k >= 2 shared variables: `_verify_pairs` with the store's rows as
+    its right side.  The caller on one chip, and on the mesh wherever
+    the left side is gathered onto every shard; a mesh join that
+    PARTITIONS both sides instead (parallel/fused_sharded.py
+    pair_join_partitions) hands the rows it received to
+    `pair_join_received`: the same sort, counts and expansion."""
+    with jax.named_scope(PAIR_JOIN_SCOPE):
+        of_type = type_ids == jnp.asarray(type_key).astype(type_ids.dtype)
+        return _verify_pairs(
+            left_vals, left_valid, targets, of_type,
+            pairs, right_var_cols, right_extra, capacity,
+        )
+
+
+def pair_join_received(
+    left_vals, left_valid, right_vals, right_valid,
+    pairs, right_extra, capacity,
+):
+    """The verified join against a right TABLE (one column a variable,
+    a validity mask) in place of the store's rows: what a shard of the
+    mesh runs on the two sides an exchange brought it, its own key
+    range of the join (parallel/fused_sharded.py).  `_verify_pairs`
+    under the same device-trace scope as `_pair_join_impl`."""
+    with jax.named_scope(PAIR_JOIN_SCOPE):
+        return _verify_pairs(
+            left_vals, left_valid, right_vals, right_valid,
+            pairs, tuple(range(right_vals.shape[1])), right_extra, capacity,
+        )
+
+
+def _verify_pairs(
+    left_vals, left_valid, right_rows, right_live,
+    pairs, right_cols, right_extra, capacity,
+):
+    """The verified join's one implementation.  The right side is
+    `right_rows` [n_r, a] where `right_live`, variable `rc` of it in
+    column `right_cols[rc]`: the store's target matrix with the probed
+    type's mask (`_pair_join_impl`), or a received table
+    (`pair_join_received`).  A pair is verified BEFORE it is counted,
+    so `total`, the output buffer and the overflow the retry ladder
+    reads are sized by the rows of the join, never by the candidates of
+    its first variable (the 3-clause whole-store conjunction at FlyBase
+    scale 0.3: 9 M left rows, 90 M candidates through the posting index
+    of one variable, ~1.7 k rows).
 
     One lexicographic sort of both sides together: the shared columns
     are the sort keys as they are, no hash, so equal neighbours ARE
@@ -529,78 +606,78 @@ def _pair_join_impl(
     positionally into `capacity` slots (the same offsets arithmetic as
     _join_tables_impl, searched instead of scattered: there are
     `capacity` slots, not left rows, to place), the r-th right row of a
-    group found by its rank in that running count.  The store's rows
-    are read in place (`targets` of the arity, `type_ids` picks the
-    type), nothing is gathered per candidate: on a v5e a sort moves a
-    row in ~3 ns where a gather through an index costs 6-26 ns.  Under
-    vmap (a group program) every step batches."""
-    with jax.named_scope(PAIR_JOIN_SCOPE):
-        n_r, n_l = targets.shape[0], left_vals.shape[0]
-        n = n_r + n_l
-        of_type = type_ids == jnp.asarray(type_key).astype(type_ids.dtype)
-        cols = []
-        for k, (lc, rc) in enumerate(pairs):
-            r = targets[:, right_var_cols[rc]]
-            l = left_vals[:, lc]
-            if k == 0:
-                r = jnp.where(of_type, r, _NO_ROW)
-                l = jnp.where(left_valid, l, _NO_ROW)
-            cols.append(jnp.concatenate([r, l]))
-        # which row an element was: right rows are tags < n_r
-        tag = jnp.arange(n, dtype=jnp.int32)
-        *cols, tag = jax.lax.sort(
-            (*cols, tag), num_keys=len(cols), is_stable=False
-        )
-        live = cols[0] != _NO_ROW
-        is_r = (live & (tag < n_r)).astype(jnp.int32)
-        is_l = live & (tag >= n_r)
-        differs = cols[0][1:] != cols[0][:-1]
-        for c in cols[1:]:
-            differs = differs | (c[1:] != c[:-1])
-        edge = jnp.ones((1,), dtype=bool)
-        first = jnp.concatenate([edge, differs])
-        last = jnp.concatenate([differs, edge])
-        # right rows seen so far; how many there were before the
-        # element's group began and when it ended: the difference is
-        # the number of right rows every left row of the group pairs
-        # with
-        seen_r = jnp.cumsum(is_r)
-        before = jax.lax.cummax(jnp.where(first, seen_r - is_r, 0))
-        after = jax.lax.cummin(
-            jnp.where(last, seen_r, _NO_ROW), reverse=True
-        )
-        cnt = jnp.where(is_l, after - before, 0)
-        # int64 total: a cross-ish join can pass 2^31; the int32
-        # offsets then wrap, and the overflow retry discards the round
-        total = cnt.astype(jnp.int64).sum()
-        offsets = jnp.cumsum(cnt)
+    group found by its rank in that running count.  Nothing is
+    gathered per candidate: on a v5e a sort moves a row in ~3 ns where
+    a gather through an index costs 6-26 ns.  Under vmap (a group
+    program) every step batches."""
+    n_r, n_l = right_rows.shape[0], left_vals.shape[0]
+    n = n_r + n_l
+    cols = []
+    for k, (lc, rc) in enumerate(pairs):
+        r = right_rows[:, right_cols[rc]]
+        l = left_vals[:, lc]
+        if k == 0:
+            r = jnp.where(right_live, r, _NO_ROW)
+            l = jnp.where(left_valid, l, _NO_ROW)
+        cols.append(jnp.concatenate([r, l]))
+    # which row an element was: right rows are tags < n_r
+    tag = jnp.arange(n, dtype=jnp.int32)
+    *cols, tag = jax.lax.sort(
+        (*cols, tag), num_keys=len(cols), is_stable=False
+    )
+    live = cols[0] != _NO_ROW
+    is_r = (live & (tag < n_r)).astype(jnp.int32)
+    is_l = live & (tag >= n_r)
+    differs = cols[0][1:] != cols[0][:-1]
+    for c in cols[1:]:
+        differs = differs | (c[1:] != c[:-1])
+    edge = jnp.ones((1,), dtype=bool)
+    first = jnp.concatenate([edge, differs])
+    last = jnp.concatenate([differs, edge])
+    # right rows seen so far; how many there were before the
+    # element's group began and when it ended: the difference is
+    # the number of right rows every left row of the group pairs
+    # with
+    seen_r = _padded_scan(jnp.cumsum, is_r, 0)
+    before = _padded_scan(
+        jax.lax.cummax, jnp.where(first, seen_r - is_r, 0), 0
+    )
+    after = _padded_scan(
+        jax.lax.cummin, jnp.where(last, seen_r, _NO_ROW), _NO_ROW,
+        reverse=True,
+    )
+    cnt = jnp.where(is_l, after - before, 0)
+    # int64 total: a cross-ish join can pass 2^31; the int32
+    # offsets then wrap, and the overflow retry discards the round
+    total = cnt.astype(jnp.int64).sum()
+    offsets = _padded_scan(jnp.cumsum, cnt, 0)
 
-        # slot j belongs to the left element `at` and is its rank-th
-        # pair: with the right row whose running count is before + rank
-        # + 1 (right rows of other groups lie outside that window)
-        j = jnp.arange(capacity, dtype=jnp.int32)
-        method = _searchsorted_method(capacity, n)
-        at = jnp.searchsorted(offsets, j, side="right", method=method)
-        at = jnp.clip(at, 0, n - 1).astype(jnp.int32)
-        rank = j - (offsets[at] - cnt[at])
-        r_at = jnp.searchsorted(
-            seen_r, before[at] + rank + 1, side="left", method=method
-        )
-        r_at = jnp.clip(r_at, 0, n - 1).astype(jnp.int32)
-        ri = jnp.clip(tag[r_at], 0, max(n_r - 1, 0))
-        li = jnp.clip(tag[at] - n_r, 0, max(n_l - 1, 0))
+    # slot j belongs to the left element `at` and is its rank-th
+    # pair: with the right row whose running count is before + rank
+    # + 1 (right rows of other groups lie outside that window)
+    j = jnp.arange(capacity, dtype=jnp.int32)
+    method = _searchsorted_method(capacity, n)
+    at = jnp.searchsorted(offsets, j, side="right", method=method)
+    at = jnp.clip(at, 0, n - 1).astype(jnp.int32)
+    rank = j - (offsets[at] - cnt[at])
+    r_at = jnp.searchsorted(
+        seen_r, before[at] + rank + 1, side="left", method=method
+    )
+    r_at = jnp.clip(r_at, 0, n - 1).astype(jnp.int32)
+    ri = jnp.clip(tag[r_at], 0, max(n_r - 1, 0))
+    li = jnp.clip(tag[at] - n_r, 0, max(n_l - 1, 0))
 
-        # every slot below `total` holds a pair that agrees on all the
-        # shared columns: they were the sort keys
-        out_valid = j.astype(jnp.int64) < total
-        parts = [left_vals[li]]
-        if right_extra:
-            parts.append(targets[ri][:, jnp.array(
-                [right_var_cols[rc] for rc in right_extra], dtype=jnp.int32
-            )])
-        out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-        out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
-        return out_vals, out_valid, total
+    # every slot below `total` holds a pair that agrees on all the
+    # shared columns: they were the sort keys
+    out_valid = j.astype(jnp.int64) < total
+    parts = [left_vals[li]]
+    if right_extra:
+        parts.append(right_rows[ri][:, jnp.array(
+            [right_cols[rc] for rc in right_extra], dtype=jnp.int32
+        )])
+    out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+    out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
+    return out_vals, out_valid, total
 
 
 def _dedup_table_impl(vals, valid):

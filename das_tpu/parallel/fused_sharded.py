@@ -9,13 +9,24 @@ reduction — lowers to ONE shard_map program per plan shape:
 
   * term probes stay slab-local (zero communication), mirroring Redis
     cluster client-side slot routing except all shards probe in parallel;
-  * each join picks its collective statically by estimated size:
+  * each join picks its collective statically, from shapes and
+    estimates known where the job is built (_exec_job):
       - small right side  -> broadcast-right (one tiled `all_gather` of a
         table that fits in the broadcast budget);
-      - large right side  -> HASH-PARTITIONED join: both sides scatter
+      - large right side  -> HASH-PARTITIONED join: both sides send
         rows to `mix(join_cols) % S` via `all_to_all`, equal keys
         co-locate, and each shard joins only its key range — ICI moves
         each row once instead of S copies;
+      - a join INTO a whole-type term (index join: the right side is
+        the store's own rows, never a probed table) gathers the LEFT
+        onto every shard and lets each probe its own slab — unless it
+        shares two or more variables with a left side that, gathered,
+        would outweigh the slab (pair_join_partitions): then both
+        sides go to the key's owner and each shard VERIFIES its own
+        key range (the whole-store 3-clause conjunction's second join:
+        9 M left rows at FlyBase scale 0.3).  The join on ONE variable
+        always gathers: a key's postings lie on every slab, so every
+        shard has to see every probe;
   * negation filters broadcast the (small) tabu tables once;
   * exact counts reduce in-program (`psum` for totals, `pmax` for
     per-shard capacity checks) into one replicated stats vector — the
@@ -39,6 +50,7 @@ from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from das_tpu import obs
+from das_tpu.obs.registry import PAIR_PARTITION_SCOPE
 from das_tpu.ops.counters import record_dispatch
 from das_tpu.ops.join import (
     _SENTINEL_L,
@@ -46,6 +58,7 @@ from das_tpu.ops.join import (
     _anti_join_impl,
     _join_tables_impl,
     _mix_columns,
+    pair_join_received,
     whole_type_join,
 )
 from das_tpu.parallel.mesh import SHARD_AXIS
@@ -82,18 +95,30 @@ from das_tpu.ops.join import _dedup_table_impl
 #: larger ones hash-partition with all_to_all
 BROADCAST_LIMIT = 4096
 
+#: a probed range of this many rows a shard is sized near its even
+#: share (ShardedFusedExecutor._shard_cap)
+LARGE_RANGE_ROWS = 1 << 16
+
 
 @dataclass(frozen=True)
 class ShardedPlanSig:
     terms: Tuple[FusedTermSig, ...]
     term_caps: Tuple[int, ...]   # per-shard probe capacities
     join_caps: Tuple[int, ...]   # per-shard join output capacities
-    exch_caps: Tuple[int, ...]   # per-join per-destination slots; 0 = broadcast
+    #: per join, the slots a destination has in an exchange (both sides
+    #: of the join use the one figure); 0 = no exchange: a table join
+    #: broadcasts its right side, an index join gathers its left
+    exch_caps: Tuple[int, ...]
     n_shards: int
     #: per join: -1 = move tables (broadcast or all_to_all); else an INDEX
-    #: JOIN — broadcast the small LEFT once and let every shard probe its
-    #: own slab's (type<<32|target) posting index at this position.  The
-    #: whole-type right side never materializes; one collective per join.
+    #: JOIN into the whole-type term's own rows, which never
+    #: materialize as a table.  With no exchange slots: gather the LEFT
+    #: once and let every shard probe its own slab (the posting index
+    #: at this position for ONE shared variable, the verified join over
+    #: the slab's rows for two or more).  With exchange slots (two or
+    #: more shared variables and a large left side,
+    #: pair_join_partitions): both sides go to `mix(shared columns) %
+    #: S` and every shard verifies its own key range.
     index_joins: Tuple[int, ...] = ()
     #: the cost-based planner ordered this plan and seeded its per-shard
     #: capacities — cache-key honesty for the planner A/B
@@ -129,13 +154,20 @@ class _Moved:
     a ring) sends 2(S-1)/S of the operand from every shard.  The
     collective helpers below add to it as the program is traced;
     `_MeshProgram` adds the sum to counter `mesh.collective_bytes` per
-    dispatch."""
+    dispatch.  `left_rows`: the slots the program all_gathers onto
+    every shard as the LEFT side of an index join (a shard's operand
+    times S), for counter `mesh.left_gathered_rows`."""
 
-    __slots__ = ("S", "bytes")
+    __slots__ = ("S", "bytes", "left_rows")
 
     def __init__(self, n_shards: int):
         self.S = n_shards
+        self.reset()
+
+    def reset(self) -> None:
+        """A re-trace counts the program once."""
         self.bytes = 0
+        self.left_rows = 0
 
     def gathered(self, x) -> None:
         self.bytes += self.S * (self.S - 1) * x.size * x.dtype.itemsize
@@ -150,7 +182,8 @@ class _Moved:
 class _MeshProgram:
     """A jitted mesh program beside the tally of what its collectives
     move: a call enqueues it (async, no sync) and, with tracing on,
-    adds the tally to counter `mesh.collective_bytes`."""
+    adds the tally to counters `mesh.collective_bytes` and
+    `mesh.left_gathered_rows`."""
 
     __slots__ = ("fn", "moved")
 
@@ -162,19 +195,72 @@ class _MeshProgram:
         out = self.fn(*args)
         if obs.enabled():
             obs.counter("mesh.collective_bytes").inc(self.moved.bytes)
+            if self.moved.left_rows:
+                obs.counter("mesh.left_gathered_rows").inc(
+                    self.moved.left_rows)
         return out
 
 
-def _repartition(vals, valid, cols, sentinel, S: int, q: int, moved: _Moved):
-    """Scatter rows to shard `mix(cols) % S` via one all_to_all.
+#: most rows `_repartition` places in its send buffer by a scatter; a
+#: longer table is placed by ONE sort of 32-bit words and S slices.  On
+#: a v5e a scatter writes a row in tens to hundreds of ns (a
+#: whole-table scatter of 3 M rows was 1.27 s of a commit, PERF.md §6 PR
+#: 27) where a sort moves an element in 2-3 ns and a gather reads a row
+#: in 6-26; a short table keeps the scatter, which compiles in no time
+#: and, under the lanes of a group program, batches without a sort
+SCATTER_PLACE_MAX_ROWS = 1 << 16
 
-    Returns ([S*q, k] rows now resident on the key-owning shard, their
-    mask, and this shard's worst per-destination occupancy for overflow
-    detection).  Equal join keys always co-locate because the destination
-    is a function of the same mix the join verifies exactly."""
-    k = vals.shape[1]
+
+def _send_order(dest, valid, S: int, q: int):
+    """Which row fills which slot of the [S, q] send buffer, for a LONG
+    table: (`src` [S * q] row indexes, `live` [S * q], per-destination
+    row counts [S]).  Slot (d, s) takes the s-th valid row bound for
+    shard d in row order, as the scatter of a short table does; rows
+    past q are dropped there as here (the occupancy tells the host).
+
+    One unstable sort of ONE int32 operand: the word `(destination <<
+    bits) | row`, rows that take no part under destination S, so the
+    rows of a destination lie together in row order and start where the
+    counts of the destinations before it end: S dynamic slices of q."""
+    n = dest.shape[0]
+    bits = max(1, (n - 1).bit_length())
+    assert (S + 1) << bits <= 1 << 31, "destination and row share 31 bits"
+    d = jnp.where(valid, dest, S).astype(jnp.int32)
+    word = (d << bits) | jnp.arange(n, dtype=jnp.int32)
+    (order,) = lax.sort((word,), num_keys=1, is_stable=False)
+    counts = (
+        d[:, None] == jnp.arange(S, dtype=jnp.int32)[None, :]
+    ).sum(axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    # a slice that would run past the end is moved back by
+    # dynamic_slice: pad, so a destination's slice starts where it says
+    order = jnp.concatenate([order, jnp.zeros((q,), dtype=jnp.int32)])
+    slot = jnp.arange(q, dtype=jnp.int32)
+    src = jnp.concatenate([
+        lax.dynamic_slice(order, (starts[k],), (q,)) for k in range(S)
+    ]) & ((1 << bits) - 1)
+    live = jnp.concatenate([slot < counts[k] for k in range(S)])
+    return src, live, counts
+
+
+def _send_buffer(vals, valid, cols, sentinel, S: int, q: int):
+    """`_repartition`'s local half: the [S, q, k + 1] buffer whose block
+    d holds this shard's rows bound for shard `mix(cols) % S == d`
+    (validity as the extra column: ONE all_to_all moves the table) and
+    the per-destination row counts [S].  Filled by a scatter for a short
+    table and from one sort for a long one (SCATTER_PLACE_MAX_ROWS, by
+    static shape): the same rows in the same slots either way, rows past
+    `q` dropped."""
+    n, k = vals.shape
     key = _mix_columns(vals, cols, valid, sentinel)
     dest = ((key % S) + S) % S
+    if n > SCATTER_PLACE_MAX_ROWS:
+        src, live, dest_counts = _send_order(dest, valid, S, q)
+        buf = jnp.concatenate(
+            [jnp.where(live[:, None], vals[src], 0),
+             live.astype(vals.dtype)[:, None]], axis=1,
+        ).reshape(S, q, k + 1)
+        return buf, dest_counts
     dest = jnp.where(valid, dest, S - 1).astype(jnp.int32)
     onehot = dest[:, None] == jnp.arange(S, dtype=jnp.int32)[None, :]
     onehot = onehot & valid[:, None]
@@ -183,11 +269,22 @@ def _repartition(vals, valid, cols, sentinel, S: int, q: int, moved: _Moved):
     dest_counts = onehot.sum(axis=0, dtype=jnp.int32)
     # invalid rows or overflow slots get slot >= q -> dropped by the scatter
     slot = jnp.where(valid, slot, q)
-    # validity rides as an extra column: ONE all_to_all moves the table
     packed = jnp.concatenate([vals, valid.astype(vals.dtype)[:, None]], axis=1)
     buf = jnp.zeros((S, q, k + 1), dtype=vals.dtype).at[dest, slot].set(
         packed, mode="drop"
     )
+    return buf, dest_counts
+
+
+def _repartition(vals, valid, cols, sentinel, S: int, q: int, moved: _Moved):
+    """Send rows to shard `mix(cols) % S` via one all_to_all.
+
+    Returns ([S*q, k] rows now resident on the key-owning shard, their
+    mask, and this shard's worst per-destination occupancy for overflow
+    detection).  Equal join keys always co-locate because the destination
+    is a function of the same mix the join verifies exactly."""
+    k = vals.shape[1]
+    buf, dest_counts = _send_buffer(vals, valid, cols, sentinel, S, q)
     with jax.named_scope("mesh.repartition"):
         recv = lax.all_to_all(buf, SHARD_AXIS, split_axis=0, concat_axis=0)
     moved.exchanged(buf)
@@ -232,6 +329,73 @@ def _global_sum(n, moved: _Moved):
 def _global_count(valid, moved: _Moved):
     """Global surviving-row count of a row-sharded validity mask."""
     return _global_sum(valid.sum(dtype=jnp.int32), moved)
+
+
+def pair_join_partitions(n_pairs: int, left_slots: int, n_shards: int,
+                         right_rows: int) -> bool:
+    """Static per-shape choice of how a join INTO a whole-type term
+    brings its sides together on the mesh (_exec_job asks it where the
+    job is built, as ops/join.py index_search_method is asked at its
+    shapes; `left_slots`: the left side's capacity a shard, `right_rows`:
+    a slab's rows of the probed arity).  True = PARTITION: both sides go
+    to the key's owner by one all_to_all each and every shard verifies
+    its own key range.  False = GATHER the left onto every shard and
+    join it with the shard's own slab.
+
+    Partition exactly where the join shares two or more variables and
+    the gathered left, `n_shards x left_slots`, outweighs the slab.
+    The verified join sorts both sides together, so after a gather
+    every shard sorts the WHOLE left (the 3-clause whole-store
+    conjunction at FlyBase scale 0.3 on 4 shards: 4 x 4.2 M slots
+    against a slab of 2.2 M rows, the same work on all four chips, and
+    a sort whose first compile passes the statement deadline); after a
+    partition a shard sorts about 1/S of each side, and every row
+    crosses the interconnect once, not S - 1 times.  A small left side
+    (a grounded query's 16 to 2,048 rows a lane against the same slab)
+    keeps the gather: one small collective, no exchange buffers.  A
+    join on ONE variable gathers whatever the sizes: it reads the
+    posting index, a key's postings lie on every slab, so every shard
+    has to see every probe, and partitioning the probes would only
+    choose which shard misses which rows."""
+    return n_pairs >= 2 and n_shards * left_slots > right_rows
+
+
+def _partitioned_pair_join(
+    left_vals, left_valid, index_arrays, type_key,
+    pairs, right_var_cols, right_extra, capacity, S: int, q: int,
+    moved: _Moved,
+):
+    """One shard's part of a verified join that PARTITIONS both sides
+    (pair_join_partitions; inside a shard_map body): ops/join.py
+    whole_type_join's arguments, `index_arrays` this slab's, plus the
+    shards and the per-destination exchange slots.  Both sides go to
+    the owner of `mix(shared columns) % S` by one all_to_all each: the
+    left as it stands, the right = this slab's rows of the probed type,
+    their variable columns only.  Equal keys co-locate (the destination
+    is a function of the columns the join verifies), so each shard
+    verifies its own key range with the one-chip join's own
+    sort-count-expand (ops/join.py pair_join_received) and the union
+    over shards is the join.  Returns (vals, valid, total, occupancy):
+    this shard's rows of the join, their exact count, and its worst
+    destination's rows over both sides — past `q` the exchange dropped
+    rows, and the host grows the slots and asks again.  The whole step
+    sits in device-trace scope `mesh.pair_partition`, each exchange in
+    `mesh.repartition`, the local verify in `join.pair_verify`."""
+    _keys, _perm, targets, type_ids = index_arrays
+    with jax.named_scope(PAIR_PARTITION_SCOPE):
+        lv, lm, l_occ = _repartition(
+            left_vals, left_valid, tuple(lc for lc, _ in pairs),
+            _SENTINEL_L, S, q, moved,
+        )
+        rv, rm, r_occ = _repartition(
+            targets[:, jnp.array(right_var_cols, dtype=jnp.int32)],
+            type_ids == jnp.asarray(type_key).astype(type_ids.dtype),
+            tuple(rc for _, rc in pairs), _SENTINEL_R, S, q, moved,
+        )
+        vals, valid, total = pair_join_received(
+            lv, lm, rv, rm, pairs, right_extra, capacity
+        )
+    return vals, valid, total, jnp.maximum(l_occ, r_occ)
 
 
 def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
@@ -299,15 +463,28 @@ def _trace_sharded_conj(sig: ShardedPlanSig, bucket_arrays, keys, fixed_vals,
         jc = sig.join_caps[n]
         q = sig.exch_caps[n]
         if index_joins[n] >= 0:
-            # broadcast the SMALL left once; every shard probes its own
-            # slab's posting index — union over shards is the full join
-            # (each link lives in exactly one slab)
-            lv_full, lm_full = _gather_packed(acc_vals, acc_valid, moved)
-            acc_vals, acc_valid, total = whole_type_join(
-                lv_full, lm_full, tuple(a[0] for a in bucket_arrays[i]),
-                keys[i], pairs, sig.terms[i].var_cols, extra, jc,
-            )
-            exch_stats.append(jnp.int32(0))
+            if q > 0:
+                acc_vals, acc_valid, total, occ = _partitioned_pair_join(
+                    acc_vals, acc_valid,
+                    tuple(a[0] for a in bucket_arrays[i]), keys[i], pairs,
+                    sig.terms[i].var_cols, extra, jc, S, q, moved,
+                )
+                exch_stats.append(_worst_shard(occ, moved))
+            else:
+                # gather the left once; every shard joins it with its
+                # own slab (the posting index of ONE shared variable,
+                # the slab's rows verified on two or more) — union over
+                # shards is the full join (each link lives in exactly
+                # one slab).  Small left sides, and every join on one
+                # variable (pair_join_partitions)
+                lv_full, lm_full = _gather_packed(acc_vals, acc_valid, moved)
+                moved.left_rows += lv_full.shape[0]
+                acc_vals, acc_valid, total = whole_type_join(
+                    lv_full, lm_full,
+                    tuple(a[0] for a in bucket_arrays[i]), keys[i], pairs,
+                    sig.terms[i].var_cols, extra, jc,
+                )
+                exch_stats.append(jnp.int32(0))
             join_totals.append(_worst_shard(total, moved))
             if n < len(positives) - 2:
                 reseed = reseed | (_global_count(acc_valid, moved) == 0)
@@ -412,7 +589,7 @@ def build_fused_sharded(sig: ShardedPlanSig, mesh, count_only: bool = False,
     shard = _sharded_body(sig, count_only, moved)
 
     def body(bucket_arrays, keys, fixed_vals):
-        moved.bytes = 0  # a re-trace counts the program once
+        moved.reset()
         out = shard(bucket_arrays, keys, fixed_vals)
         if count_only:
             return out
@@ -452,10 +629,11 @@ def build_fused_sharded_group(sig: ShardedPlanSig, mesh, count_only,
     )
 
     def body(bucket_arrays, keys, fixed_vals):
-        lane_moved.bytes = 0  # a re-trace counts the program once
+        lane_moved.reset()
         out = lanes(bucket_arrays, keys, fixed_vals)
         stats = out if count_only else out[2]
         moved.bytes = stats.shape[0] * lane_moved.bytes
+        moved.left_rows = stats.shape[0] * lane_moved.left_rows
         if count_only:
             return stats
         return out[0][:, None], out[1][:, None], stats
@@ -516,7 +694,7 @@ def build_sharded_tree_fused(sig: ShardedTreeSig, mesh, count_only: bool = False
     moved = moved if moved is not None else _Moved(sig.sites[0].n_shards)
 
     def body(*site_inputs):
-        moved.bytes = 0  # a re-trace counts the program once
+        moved.reset()
         blocks = []
         parts = []
         for i, ssig in enumerate(sig.sites):
@@ -641,9 +819,35 @@ class ShardedFusedExecutor:
     def _shard_cap(self, global_est: int) -> int:
         """Per-shard probe capacity: even split plus 2x skew headroom
         (slabs are round-robin, so type/pattern ranges spread evenly; the
-        headroom plus overflow retry covers hub-heavy skew)."""
+        headroom plus overflow retry covers hub-heavy skew).  A LONG
+        range (LARGE_RANGE_ROWS a shard or more: a whole type probed
+        unbound) takes an eighth of headroom instead, as the planner's
+        long join buffers do (planner/search.py shard_cap_seed): rows
+        dealt round-robin put such a range within a per mille of even
+        on every slab, and where the range is gathered as a join's left
+        side every slot is a probe on every chip (the whole-store
+        conjunction's Interacts side at FlyBase scale 0.3: 225,000 rows
+        a shard seeded 524,288 slots, 2.1 M probes a chip for 0.9 M
+        rows)."""
         per = -(-max(global_est, 1) // self.n_shards)
+        if per >= LARGE_RANGE_ROWS:
+            return _pow2_at_least(per + per // 8)
         return _pow2_at_least(2 * per)
+
+    def _exchange_slots(self, left_rows: int, right_rows: int) -> int:
+        """Per-destination slots of a partitioned verified join, for
+        both its sides: the larger side's rows (whole-store figures:
+        the planner's estimate of the left side where there is one,
+        else its capacity; the right type's exact row count) dealt
+        over S sources x S destinations by a hash, with an eighth of
+        headroom before the power of two.  No 2x skew headroom as in
+        _shard_cap: the destination is a 64-bit mix of two or more
+        columns, so a hub of ONE column spreads, and the tables this
+        rule meets are long (a million rows a destination deviate by a
+        per mille); an overflow is a counted retry that grows the slots
+        exactly as before."""
+        per = -(-max(left_rows, right_rows, 1) // self.n_shards ** 2)
+        return _pow2_at_least(max(16, per + per // 8))
 
     # -- execution ---------------------------------------------------------
 
@@ -652,6 +856,13 @@ class ShardedFusedExecutor:
         capacity seeds incl. the per-join collective choice).  None when a
         bucket is missing or the merged caps exceed the configured ceiling
         — the caller falls back to the staged mesh path, as before.
+
+        The collective choice per join, from the job's static shapes: a
+        table join broadcasts a right side that fits the broadcast
+        budget and hash-partitions a larger one; an index join gathers
+        its left side unless pair_join_partitions says its two sides
+        are better sent to the key's owner (two or more shared
+        variables, a left side that gathered would outweigh the slab).
 
         The cost-based planner hook mirrors the single-device executor
         (query/fused.py _exec_job): behind DasConfig.use_planner it fixes
@@ -710,14 +921,35 @@ class ShardedFusedExecutor:
         else:
             join_caps = tuple([jcap0] * n_joins)
         # static per-join collective choice: index-joinable right
-        # sides broadcast the LEFT (one collective, nothing
-        # materialized); otherwise broadcast the right when its whole
-        # table fits the budget, else hash-partition
+        # sides gather the LEFT (one collective, nothing materialized)
+        # or, on two or more variables with a large left side,
+        # partition both sides (pair_join_partitions); otherwise
+        # broadcast the right when its whole table fits the budget,
+        # else hash-partition
         pos_sig_idx = [i for i, s in enumerate(sigs) if not s.negated]
+        n_pairs = [len(m[0]) for m in fold_join_meta(sigs)[3]]
         exch_caps = []
         for t in range(len(index_joins)):
             if index_joins[t] >= 0:
-                exch_caps.append(0)
+                right = pos_sig_idx[1 + t]
+                left_slots = (
+                    join_caps[t - 1] if t else term_caps[pos_sig_idx[0]]
+                )
+                if pair_join_partitions(
+                    n_pairs[t], left_slots, self.n_shards,
+                    arrays[right][2].shape[1],
+                ):
+                    if not t:
+                        left_rows = ests[pos_sig_idx[0]]
+                    elif planned is not None:
+                        left_rows = planned.est_join_rows[t - 1]
+                    else:
+                        left_rows = self.n_shards * left_slots
+                    exch_caps.append(
+                        self._exchange_slots(left_rows, ests[right])
+                    )
+                else:
+                    exch_caps.append(0)
                 continue
             right_cap = term_caps[pos_sig_idx[1 + t]]
             if right_cap * self.n_shards <= self.broadcast_limit:
@@ -739,8 +971,10 @@ class ShardedFusedExecutor:
                 index_right,
             )
             join_caps = tuple(max(a, b) for a, b in zip(join_caps, learned[1]))
+            # a table join exchanges where the learned entry did; an
+            # index join where this job's shapes say so (the rule above)
             exch_caps = tuple(
-                (0 if b == 0 or n_ij >= 0 else max(a, b))
+                (0 if (a if n_ij >= 0 else b) == 0 else max(a, b))
                 for (a, b), n_ij in zip(zip(exch_caps, learned[2]), index_joins)
             )
         if max(term_caps + join_caps, default=0) > cfg.max_result_capacity:
@@ -861,7 +1095,7 @@ class _ShardedExecJob(_GroupHooks):
         "ex", "count_only", "same_order", "sigs", "arrays", "keys", "fvals",
         "term_caps", "join_caps", "exch_caps", "index_joins",
         "names", "result", "planned", "rounds", "last_ranges",
-        "last_join_rows", "_sig",
+        "last_join_rows", "last_exch_rows", "_sig",
     )
 
     #: the mesh builds its jobs per query (_exec_job): no lane columns
@@ -891,6 +1125,7 @@ class _ShardedExecJob(_GroupHooks):
         self.rounds = 0
         self.last_ranges = None
         self.last_join_rows = None
+        self.last_exch_rows = None   # final-round worst occupancies
         self._sig = None
 
     def plan_sig(self) -> ShardedPlanSig:
@@ -976,6 +1211,29 @@ class _ShardedExecJob(_GroupHooks):
             )),
         ), moved), names
 
+    def verdict_attrs(self) -> dict:
+        """What a settled mesh job adds to its `exec.verdict` span
+        (tracing on; query/fused.py settle_pending_iter), from the
+        signature and the stats the round fetched anyway: `partitioned`,
+        its verified joins that partition both sides, and
+        `exchange_fill`, the worst destination's occupancy over the
+        slots it had, summed over the job's exchanging joins.  The same
+        figures feed counters `mesh.partitioned_joins` (jobs with at
+        least one), `mesh.exchange_rows_max` and `mesh.exchange_slots`."""
+        slots = sum(self.exch_caps)
+        if not slots or self.last_exch_rows is None:
+            return {}
+        partitioned = sum(
+            1 for q, p in zip(self.exch_caps, self.index_joins)
+            if q and p >= 0
+        )
+        rows = sum(self.last_exch_rows)
+        if partitioned:
+            obs.counter("mesh.partitioned_joins").inc()
+        obs.counter("mesh.exchange_rows_max").inc(rows)
+        obs.counter("mesh.exchange_slots").inc(slots)
+        return {"partitioned": partitioned, "exchange_fill": rows / slots}
+
     def settle(self, host_out, dev_out) -> bool:
         """Consume one round's fetched stats.  True = finished (result
         set; None result = capacity ceiling — caller falls back to the
@@ -1028,6 +1286,7 @@ class _ShardedExecJob(_GroupHooks):
         )
         self.last_ranges = [int(r) for r in ranges]
         self.last_join_rows = [int(t) for t in jtotals]
+        self.last_exch_rows = [int(o) for o in eoccs]
         if self.planned is not None:
             from das_tpu.planner import observe_settle
 

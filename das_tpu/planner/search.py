@@ -326,6 +326,46 @@ def conjunction_rule(plans):
     return pos_idx, neg_idx, method
 
 
+#: per-shard share of a join's estimated rows from which
+#: `shard_cap_seed` sizes the buffer near the share
+LARGE_SHARE_ROWS = 1 << 20
+
+
+def shard_cap_seed(cap: int, est_rows: int, n_shards: int) -> int:
+    """Per-shard capacity seed of one join buffer on `n_shards` shards,
+    from its one-chip seed `cap` (cost.cap_for: the estimate, its
+    margin unless exact, rounded up to a power of two) and the
+    estimate itself.
+
+    A short table: the even split of `cap` with 2x skew headroom, then
+    the power of two (slabs are round-robin, so ranges spread evenly;
+    the headroom plus the overflow retry covers a hub) — every grounded
+    shape, unchanged.
+
+    A LONG one (a share of LARGE_SHARE_ROWS estimated rows or more a
+    shard): the share of the ESTIMATE with the one-chip seed's own
+    margin (`cap / est_rows` below 2 says the figure was exact and
+    carries none) and an eighth on top, then the power of two, and
+    never above the rule for short tables.  `cap` has already rounded
+    the estimate up by as much as 2x, so splitting it and doubling
+    again stacks three headrooms: 2.25 M rows a shard (the whole-store
+    conjunction's first join at FlyBase scale 0.3 on 4 shards) seeded
+    8.4 M slots, and every table-long pass and every sort operand of
+    the program after it doubled with it, on a store whose shards
+    differ by a fraction of a per cent: rows dealt round-robin put a
+    share of millions of rows within a per mille of even, where a
+    16-row probe can land whole on one slab.  The power of two still
+    leaves 0 to 100 % of room, and an overflow is a counted retry that
+    grows the buffer exactly as before."""
+    legacy = pcost.pow2_at_least(max(64, 2 * (-(-cap // n_shards))))
+    share = -(-int(est_rows) // n_shards)
+    if share < LARGE_SHARE_ROWS:
+        return legacy
+    if cap >= pcost.CAP_MARGIN * int(est_rows):
+        share *= pcost.CAP_MARGIN
+    return min(legacy, pcost.pow2_at_least(share + share // 8))
+
+
 def plan_conjunction(
     db, plans, *, n_shards: int = 1, est=None, rule=None,
 ) -> Optional[PlannedProgram]:
@@ -334,8 +374,9 @@ def plan_conjunction(
     — the caller falls back to the legacy heuristics, answer-identical.
 
     `n_shards > 1` scales the capacity seeds to PER-SHARD buffers (the
-    sharded executor's join_caps unit), with the same 2x skew headroom
-    its probe capacities use.  `est`: the estimator to read (default:
+    sharded executor's join_caps unit: `shard_cap_seed`, the 2x skew
+    headroom its probe capacities use for short tables, the estimate's
+    share for long ones).  `est`: the estimator to read (default:
     the backend's live one; the job builder passes its batch's
     `BatchEstimator`), `rule`: `conjunction_rule(plans)` where the
     caller kept it.
@@ -371,8 +412,8 @@ def plan_conjunction(
 
     if n_shards > 1:
         caps = tuple(
-            pcost.pow2_at_least(max(64, 2 * (-(-c // n_shards))))
-            for c in caps
+            shard_cap_seed(c, rows, n_shards)
+            for c, rows in zip(caps, join_rows)
         )
     order = tuple(pos_idx[i] for i in order_pos) + tuple(neg_idx)
     term_rows = tuple(

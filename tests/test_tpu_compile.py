@@ -142,12 +142,14 @@ def _tiny_store_and_query(make_db, n_clauses=3):
     return db, compiler.plan_query(db, query)
 
 
-def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
-    """The fused shard_map program of `sig`, compiled against a Mesh
+def _lower_on_described_mesh(topo, job, sig, per_shard, group=None,
+                             count_only=False):
+    """The fused shard_map program of `sig`, lowered against a Mesh
     built from the described v5e:2x2 devices, the job's row-sharded
-    bucket arrays stretched to `per_shard` rows a shard.  `group`:
-    `(count_only, lanes)` for the GROUP program over `lanes` lanes of
-    the job's inputs, every lane its own gene."""
+    bucket arrays stretched to `per_shard` rows a shard.
+    `group`: `(count_only, lanes)` for the GROUP program over `lanes`
+    lanes of the job's inputs, every lane its own gene; else the lone
+    program, `count_only` or not."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from das_tpu.parallel import fused_sharded as fs
@@ -168,7 +170,7 @@ def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
 
     keys, fvals = job.keys, job.fvals
     if group is None:
-        fn, _names = fs.build_fused_sharded(sig, mesh, False)
+        fn, _names = fs.build_fused_sharded(sig, mesh, count_only)
     else:
         count_only, lanes = group
         # the lanes' inputs as dispatch_group stacks them: the grounded
@@ -187,7 +189,11 @@ def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
         jax.tree.map(slab, job.arrays),
         jax.tree.map(scalar_or_vec, keys),
         jax.tree.map(scalar_or_vec, fvals),
-    ).compile()
+    )
+
+
+def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
+    return _lower_on_described_mesh(topo, job, sig, per_shard, group).compile()
 
 
 @pytest.fixture(scope="module")
@@ -266,6 +272,45 @@ def cell1_jobs():
     return jobs
 
 
+def _as_shape(x):
+    x = np.asarray(x)
+    return _shape(x.shape, x.dtype)
+
+
+def _cell1_program(job, shape, count_only, group):
+    """(jitted fn, its argument shapes) of `das_fused` (`group` false)
+    or `das_fused_group` at the served path's lanes, for one of cell
+    1's shapes at its bucket size and capacities.  The lanes' inputs as
+    dispatch_group stacks them: every lane its own gene (the probe key
+    of the grounded terms), the whole-type term's key hoisted."""
+    from das_tpu.query import fused
+
+    sig = dataclasses.replace(job.plan_sig(), **CELL1_PROGRAMS[shape])
+    keys, fvals = job.keys, job.fvals
+    if group:
+        lanes = fused.GROUP_LANES
+        keys, key_axes, fvals, fval_axes = fused.stack_lanes(
+            [tuple(np.asarray(k)
+                   + (i if t != sig.index_joins.index(1) + 1 else 0)
+                   for t, k in enumerate(job.keys)) for i in range(lanes)],
+            [job.fvals] * lanes, lanes,
+        )
+        assert None in key_axes and 0 in key_axes
+        fn, _names = fused.build_fused_group(
+            sig, count_only, key_axes, fval_axes)
+    else:
+        fn, _names = fused.build_fused(sig, count_only)
+
+    def stretch(a):
+        shape_ = tuple(a.shape)
+        return _shape(
+            (CELL1_ARITY2_CAPACITY, *shape_[1:]) if shape_ else shape_,
+            a.dtype)
+
+    return fn, (jax.tree.map(stretch, job.arrays),
+                jax.tree.map(_as_shape, keys), jax.tree.map(_as_shape, fvals))
+
+
 @pytest.mark.parametrize("count_only", [True, False],
                          ids=["count_program", "result_program"])
 @pytest.mark.parametrize("shape", sorted(CELL1_PROGRAMS))
@@ -284,32 +329,9 @@ def test_fused_group_at_cell1_shapes(compile_for_chip, cell1_jobs, shape,
     job = cell1_jobs[shape]
     want = CELL1_PROGRAMS[shape]
     assert job.plan_sig().index_joins == want["index_joins"]
-    sig = dataclasses.replace(job.plan_sig(), **want)
-    # the lanes' inputs as dispatch_group stacks them: every lane its
-    # own gene (the probe key of the grounded terms), the whole-type
-    # term's key hoisted
-    keys, key_axes, fvals, fval_axes = fused.stack_lanes(
-        [tuple(np.asarray(k) + (i if t != sig.index_joins.index(1) + 1 else 0)
-               for t, k in enumerate(job.keys)) for i in range(lanes)],
-        [job.fvals] * lanes, lanes,
-    )
-    assert None in key_axes and 0 in key_axes
-    fn, _names = fused.build_fused_group(sig, count_only, key_axes, fval_axes)
-
-    def stretch(a):
-        shape_ = tuple(a.shape)
-        return _shape(
-            (CELL1_ARITY2_CAPACITY, *shape_[1:]) if shape_ else shape_,
-            a.dtype)
-
-    def as_shape(x):
-        x = np.asarray(x)
-        return _shape(x.shape, x.dtype)
-
-    compiled = compile_for_chip(
-        fn, jax.tree.map(stretch, job.arrays),
-        jax.tree.map(as_shape, keys), jax.tree.map(as_shape, fvals),
-    )
+    fn, shapes = _cell1_program(job, shape, count_only, group=True)
+    keys, fvals = shapes[1:]
+    compiled = compile_for_chip(fn, *shapes)
     assert "tpu_custom_call" not in compiled.as_text()
     # das_fused alone holds 38.7 MB of temporaries there (the u32 halves
     # of a key array); 32 lanes add 1 MB.  One lane-batched copy of a
@@ -341,33 +363,33 @@ ANALYTIC_ARITY2_ROWS = int(27_870_000 * ANALYTIC_SCALE)
 ANALYTIC_CAPS = dict(term_caps=(1 << 19, 16, 16), join_caps=(1 << 22, 4096))
 
 
-def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
-    """The lone `das_fused` program of the all-variable 3-clause
-    conjunction (PR 44): the posting-index join on one variable, then
-    the verified join on two.  Beyond "it compiles": what keeps its
-    FIRST compile inside the cell's statement deadline.  On the chip a
-    sort's compile time grows with its operands and keys and a 64-bit
-    co-sort of a million queries takes two minutes, so the program
-    holds ONE sort a join at most, none of 64-bit keys, none stable,
-    and the verified join's is its shared columns plus one payload."""
-    import re
-
+def _three_var_plans(make_db):
+    """A tiny store and the all-variable 3-clause conjunction's plans
+    on it (the benchmark's `three_var`)."""
     from das_tpu.models.bio import build_bio_atomspace
-    from das_tpu.query import compiler, fused
+    from das_tpu.query import compiler
     from das_tpu.query.ast import And, Link, Variable
-    from das_tpu.storage.tensor_db import TensorDB
 
     data, _, _ = build_bio_atomspace(
         n_genes=60, n_processes=12, members_per_gene=3, n_interactions=40,
         seed=5)
-    db = TensorDB(data, DasConfig())
+    db = make_db(data)
     v = Variable
-    plans = compiler.plan_query(db, And([
+    return db, list(compiler.plan_query(db, And([
         Link("Interacts", [v("V1"), v("V2")], True),
         Link("Member", [v("V1"), v("V3")], True),
         Link("Member", [v("V2"), v("V3")], True),
-    ]))
-    job = fused.get_executor(db)._exec_job(list(plans), False)
+    ])))
+
+
+def _analytic_program():
+    """(jitted `das_fused`, its argument shapes, the bucket's capacity)
+    of the all-variable conjunction at cell `mem-analytic`'s shapes."""
+    from das_tpu.query import fused
+    from das_tpu.storage.tensor_db import TensorDB
+
+    db, plans = _three_var_plans(lambda data: TensorDB(data, DasConfig()))
+    job = fused.get_executor(db)._exec_job(plans, False)
     assert job.index_joins == (0, 0)
     assert fused.whole_type_join_steps(job.sigs, job.index_joins)[:2] == (
         (1,), ((0, 1),))
@@ -379,13 +401,23 @@ def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
         shape = tuple(a.shape)
         return _shape((cap, *shape[1:]) if shape else shape, a.dtype)
 
-    def as_shape(x):
-        x = np.asarray(x)
-        return _shape(x.shape, x.dtype)
+    return fn, (jax.tree.map(stretch, job.arrays),
+                jax.tree.map(_as_shape, job.keys),
+                jax.tree.map(_as_shape, job.fvals)), cap
 
-    shapes = (jax.tree.map(stretch, job.arrays),
-              jax.tree.map(as_shape, job.keys),
-              jax.tree.map(as_shape, job.fvals))
+
+def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
+    """The lone `das_fused` program of the all-variable 3-clause
+    conjunction (PR 44): the posting-index join on one variable, then
+    the verified join on two.  Beyond "it compiles": what keeps its
+    FIRST compile inside the cell's statement deadline.  On the chip a
+    sort's compile time grows with its operands and keys and a 64-bit
+    co-sort of a million queries takes two minutes, so the program
+    holds ONE sort a join at most, none of 64-bit keys, none stable,
+    and the verified join's is its shared columns plus one payload."""
+    import re
+
+    fn, shapes, cap = _analytic_program()
     compiled = compile_for_chip(fn, *shapes)
     assert "tpu_custom_call" not in compiled.as_text()
     # the 3 M x 10 x 10 candidates never exist: everything the program
@@ -570,3 +602,166 @@ def test_cell3_mesh_group_programs_on_described_2x2_mesh(
     slab_bytes = per_shard * 8                  # one int64 key array
     assert (compiled.memory_analysis().temp_size_in_bytes
             < lone.memory_analysis().temp_size_in_bytes + slab_bytes)
+
+
+# -- the accepted cells' programs, letter for letter ----------------------
+
+#: sha256 of the text LOWERED FOR THE DESCRIBED v5e (StableHLO, before
+#: the chip's compiler; a lowering rule may differ by platform, and the
+#: chip's is the one the cells run) on the parent of PR 47, tree
+#: bf5006f: `das_fused` / `das_fused_group` at cell 1's shapes (cells 2
+#: and 4 run the same two), `das_sharded` / `das_sharded_group` at cell
+#: 3's, each `count_only` and not, for `grounded3` and `shared2`, and
+#: the lone `das_fused` of `three_var` at cell 5's.  PR 47 gave the mesh
+#: a second way to run a verified join (partition both sides) and
+#: `ops/join.py _pair_join_impl` a second caller: small left sides keep
+#: the gather and the one-chip program reads the store in place, so
+#: none of the seventeen may move.  Regenerate only when a PR means to
+#: change those programs, and says so: LOWERED_PRINT=1 prints the dict.
+PARENT_LOWERED = {
+    "das_fused.grounded3.count":
+        "42fe0ed913056455928201ab3595aaf238623dc24bf227003efaecddd9851db4",
+    "das_fused.grounded3.result":
+        "b8bd64feeb949194266f7b174c96b77fd740009522864416a9638cc88ad952ee",
+    "das_fused.shared2.count":
+        "99732c7bae77871bfcc257803fb53db732cdc3fd627ae02a875c7b471bf697d6",
+    "das_fused.shared2.result":
+        "5e44be30cb8e857c7afb963b03db822e4363e8d04de4648bfe2a7fd9f4e33b46",
+    "das_fused_group.grounded3.count":
+        "91142e96d21491465928d797d8fbd5732cafa9fc288130228b1ef684ad2b67d0",
+    "das_fused_group.grounded3.result":
+        "f722d9961834851e40a1fc0c586a21f4b1c231786677eaed37bb8695f0a45745",
+    "das_fused_group.shared2.count":
+        "098c56ae5e4e42fedbd3ede55abc2af4c8e0ef2f7eef46799081d7bfe40553ae",
+    "das_fused_group.shared2.result":
+        "f8466868f71cd99f4a814586c0f470f66f7e445234c20fd54dbd94827e23bd25",
+    "das_sharded.grounded3.count":
+        "1ef455b0682fbdd774e0d16454aa1bcc1580b38071b4335450797e239b8f7cae",
+    "das_sharded.grounded3.result":
+        "0a4eda97b34ea32d77de080762e1bc6b5d7d58f662d0bba3f6e5d4758b2c49ff",
+    "das_sharded.shared2.count":
+        "3e524fd9887b5f24f07836b6c1cc8d457c3e8e6a81fe638e36ff4d20fa19291f",
+    "das_sharded.shared2.result":
+        "41673e19b8e0722d5b902a105a3a83d5d6d7e946c3201b87cd3f29287882c7c4",
+    "das_sharded_group.grounded3.count":
+        "c9d253537dff0da961a604b6d64037947f1def381e1a0567c5daea32a1344180",
+    "das_sharded_group.grounded3.result":
+        "068236d0ecd31010380e87601a095c2ec3779859e67a519630f4e58081acea56",
+    "das_sharded_group.shared2.count":
+        "e9d37e2ade0bcb16c092a0c6b73c425c710293e1215b0ac66315edf485884f52",
+    "das_sharded_group.shared2.result":
+        "5452857022626f68fe5058e04c6ca34174f2ccc67ba5336274e3809089b66730",
+    "das_fused.three_var.result":
+        "a786da21fb168f0642473f114281169ab96692eb64c59fd53ddc4d3056cf166c",
+}
+
+
+def _lowered_for_chip(topo, one_chip, name):
+    from das_tpu.query import fused
+
+    program, shape, what = name.split(".")
+    count_only = what == "count"
+    if program == "das_fused" and shape == "three_var":
+        fn, shapes, _cap = _analytic_program()
+    elif program.startswith("das_fused"):
+        from das_tpu.query.fused import get_executor
+        from das_tpu.storage.tensor_db import TensorDB
+
+        db, plans = _tiny_store_and_query(
+            lambda data: TensorDB(data, DasConfig()),
+            n_clauses=3 if shape == "grounded3" else 2)
+        fn, shapes = _cell1_program(
+            get_executor(db)._exec_job(plans, False), shape, count_only,
+            group=program.endswith("_group"))
+    else:
+        job, sig, per_shard = _cell3_job(shape)
+        group = ((count_only, fused.GROUP_LANES)
+                 if program.endswith("_group") else None)
+        return _lower_on_described_mesh(
+            topo, job, sig, per_shard, group, count_only).as_text()
+    placed = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    return fn.lower(*placed).as_text()
+
+
+LOWERED_CASES = [
+    f"{program}.{shape}.{what}"
+    for program in ("das_fused", "das_fused_group", "das_sharded",
+                    "das_sharded_group")
+    for shape in ("grounded3", "shared2")
+    for what in ("count", "result")
+] + ["das_fused.three_var.result"]
+
+
+@pytest.mark.parametrize("name", LOWERED_CASES)
+def test_the_accepted_cells_programs_are_the_parents(
+        topo, one_chip, no_persistent_cache, name):
+    import hashlib
+
+    digest = hashlib.sha256(
+        _lowered_for_chip(topo, one_chip, name).encode()).hexdigest()
+    if os.environ.get("LOWERED_PRINT"):
+        print(f'\n    "{name}":\n        "{digest}",')
+        return
+    assert digest == PARENT_LOWERED[name]
+
+
+# -- cell 6: the whole-store conjunction on the mesh ----------------------
+
+#: cell `sharded4-analytic` (`flybase-sharded4-analytic`, FlyBase shape
+#: x 0.3 on 4 shards, cell 3's store): the capacities the mesh executor
+#: seeds for the all-variable conjunction there (Interacts rows a shard
+#: near their share; Interacts x Member a shard; the verified join's
+#: rows; the exchange slots of the second join, which PARTITIONS), read
+#: from the executor's own job on a CPU build of the store at 0.3 (PR
+#: 47; tests/test_mesh_analytic.py holds the rules' arithmetic)
+CELL6_CAPS = dict(term_caps=(262_144, 16, 16), join_caps=(4_194_304, 2048),
+                  exch_caps=(0, 1_048_576))
+
+
+def test_mesh_three_var_at_cell6_shapes(topo, no_persistent_cache):
+    """`das_sharded` of the all-variable conjunction at cell 6's
+    per-shard shapes, compiled for the described v5e:2x2.  What keeps
+    its FIRST compile inside the statement deadline, beyond "it
+    compiles": the verified join sorts ONCE (its two shared columns and
+    one payload), each exchange once (ONE 32-bit operand), nothing is
+    stable, no operand is 64-bit; the only 64-bit all-reduce is a Sum
+    (the chip's compiler lowers no other); and no shard holds the
+    gathered left side of the second join (4 x 4.2 M slots: 0.6 GB of
+    temporaries where the partition needs under 0.2)."""
+    import re
+
+    from das_tpu.parallel.fused_sharded import get_sharded_executor
+    from das_tpu.parallel.mesh import make_mesh
+    from das_tpu.parallel.sharded_db import ShardedDB
+
+    db, plans = _three_var_plans(
+        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4)))
+    job = get_sharded_executor(db)._exec_job(plans, False)
+    assert job.index_joins == (0, 0)
+    # at any size the first join gathers, the second partitions
+    assert job.exch_caps[0] == 0 and job.exch_caps[1] > 0
+    sig = dataclasses.replace(job.plan_sig(), **CELL6_CAPS)
+    per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
+    lowered = _lower_on_described_mesh(topo, job, sig, per_shard)
+    text = lowered.as_text()
+    sorts = re.findall(
+        r'"stablehlo\.sort"\(([^)]*)\) <\{([^}]*)\}>.*?\}\) : \(([^)]*)\) ->',
+        text, flags=re.S)
+    assert sorted(ops.count("%") for ops, _a, _t in sorts) == [1, 1, 3]
+    for _operands, attrs, types in sorts:
+        assert "is_stable = false" in attrs and "i64" not in types
+    assert text.count('"stablehlo.all_to_all"') == 2
+    reduces = re.findall(
+        r'"stablehlo\.all_reduce"\(.*?\^bb0\((.*?)\):\s*(.*?)stablehlo\.return',
+        text, flags=re.S)
+    assert reduces
+    for args, body in reduces:
+        if "i64" in args:
+            assert "stablehlo.add" in body
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "all-to-all" in hlo and "all-gather" in hlo
+    assert "tpu_custom_call" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
