@@ -329,11 +329,15 @@ HISTOGRAM_NAMES = (
 #: the first, every operation of the posting-index join of ONE shared
 #: variable (its range lookup: two searches, or for a large left side
 #: ONE and two reads; the prefix sum; the expansion) under the
-#: second; benchmark/layer_metrics/ops.pair_join_*.py and
-#: ops.index_join_ms_per_query.py sum the device time of the operations
-#: whose scope path holds the name
+#: second, and the expansion (ops/join.py _expand_index_ranges: ranges
+#: -> output rows) NESTED in it under the third;
+#: benchmark/layer_metrics/ops.pair_join_*.py,
+#: ops.index_join_ms_per_query.py and ops.index_expand_ms_per_query.py
+#: sum the device time of the operations whose scope path holds the
+#: name
 PAIR_JOIN_SCOPE = "join.pair_verify"
 INDEX_JOIN_SCOPE = "join.index_probe"
+INDEX_EXPAND_SCOPE = "join.index_expand"
 #: the mesh's verified join that partitions both sides
 #: (parallel/fused_sharded.py): the whole step, both exchanges (each
 #: collective in `mesh.repartition`) and the local verify (still under

@@ -26,7 +26,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from das_tpu.obs.registry import INDEX_JOIN_SCOPE, PAIR_JOIN_SCOPE
+from das_tpu.obs.registry import (
+    INDEX_EXPAND_SCOPE,
+    INDEX_JOIN_SCOPE,
+    PAIR_JOIN_SCOPE,
+)
 
 _SENTINEL_L = jnp.int64(2**63 - 1)
 _SENTINEL_R = jnp.int64(2**63 - 2)
@@ -190,6 +194,18 @@ def index_search_method(n_left: int, n_keys: int) -> str:
         return SLICE_SEARCH
     return _searchsorted_method(n_left, n_keys)
 
+
+#: fewest output slots at which the posting-index join's expansion
+#: (`_expand_index_ranges`) reads a left row as ONE packed row, by
+#: static shape.  The whole-store conjunction's first join holds
+#: 4,194,304 slots and its expansion fell 250 -> 74 ms there; a lane of
+#: the grounded shapes holds 64-2,048 (65,536 at the top of its
+#: ladder), and compiled for the described v5e the 32-lane group
+#: program of those reported 102-175 MB of temporaries with the packed
+#: reads for 39 MB without (PERF.md section 6, PR 48; the guard is
+#: tests/test_tpu_compile.py test_fused_group_at_cell1_shapes): under
+#: the rule every grounded program stays what it was, letter for letter
+PACKED_EXPAND_MIN_SLOTS = 1 << 20
 
 #: widest key table a lane-batched 'sort' searchsorted lowers as
 #: 'compare_all' (a [queries, keys] compare a lane)
@@ -483,32 +499,80 @@ def _expand_index_ranges(
     """The posting-index join's second half: every left row's `cnt`
     index positions from `lo` on, expanded positionally into `capacity`
     slots (the offsets arithmetic of _join_tables_impl) and read
-    through `perm` into the store's rows.  Six gather passes over the
-    slots: after PR 45 the largest part of the whole-store
-    conjunction's program (scripts/index_join_parts.py times it
-    alone)."""
-    j = jnp.arange(capacity, dtype=jnp.int64)
-    prev_all = offsets - cnt
-    row_ids = jnp.arange(cnt.shape[0], dtype=jnp.int32)
-    seg = jnp.full(capacity, -1, dtype=jnp.int32).at[prev_all].max(
-        jnp.where(cnt > 0, row_ids, -1), mode="drop"
-    )
-    li = _padded_scan(jax.lax.cummax, seg, -1)
-    li_safe = jnp.clip(li, 0, max(left_vals.shape[0] - 1, 0))
-    prev = prev_all[li_safe]
-    ri_sorted = lo[li_safe] + (j - prev).astype(jnp.int32)
-    local = perm[jnp.clip(ri_sorted, 0, perm.shape[0] - 1)]
-    row_t = targets[jnp.clip(local, 0, targets.shape[0] - 1)]
+    through `perm` into the store's rows.  Slot j belongs to the last
+    left row with `cnt > 0` whose first slot `prev = offsets - cnt` is
+    at or before j (a scatter of row ids, a running maximum); `perm` and
+    `targets` are the two reads the join is for
+    (scripts/index_join_parts.py times the expansion alone; device-trace
+    scope `join.index_expand`).
 
-    out_valid = (j < total) & left_valid[li_safe]
-    parts = [left_vals[li_safe]]
-    if right_extra:
-        parts.append(
-            row_t[:, jnp.array([right_var_cols[rc] for rc in right_extra], dtype=jnp.int32)]
+    From `PACKED_EXPAND_MIN_SLOTS` slots on (by static shape) a left row
+    is read ONCE per slot, as one packed int32 row
+    `[lo - prev, left_vals...]`, `left_valid` is not read per slot and
+    the slot arithmetic is 32-bit; under it the row is read four times
+    through the owner (`prev`, `lo`, `left_valid`, `left_vals`) on
+    64-bit slots.  Same outputs either way.
+
+    The packed reads' precondition: `cnt > 0` only where `left_valid`
+    (_index_join_impl builds `cnt` as `where(left_valid, hi - lo, 0)`).
+    The segments of the rows with `cnt > 0` tile `[0, total)` exactly
+    and only such a row is scattered, so a slot is valid where
+    `j < total`.
+
+    Their slot arithmetic is 32-bit although `offsets` and `total` are
+    int64 (ranges into an uncapped type can sum past 2^31, and the
+    overflow retry reads the exact `total`): a slot
+    `j < min(total, capacity)` is owned by a row with
+    `prev <= j < 2^31`, and `lo + (j - prev)` is an index position, so
+    `(lo - prev) mod 2^32 + j` IS that position; a row whose `prev`
+    lies at or past `capacity` owns no slot (the scatter drops it) and
+    nothing reads its wrapped base."""
+    if capacity >= 2**31:
+        raise ValueError(
+            f"join capacity {capacity} does not fit 32-bit slot arithmetic"
         )
-    out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-    out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
-    return out_vals, out_valid, total
+    packed = capacity >= PACKED_EXPAND_MIN_SLOTS
+    # the reads keep their order on both sides of the rule: the lowered
+    # text of every accepted cell's program is pinned
+    # (tests/test_tpu_compile.py PARENT_LOWERED)
+    with jax.named_scope(INDEX_EXPAND_SCOPE):
+        j = jnp.arange(capacity, dtype=jnp.int32 if packed else jnp.int64)
+        prev_all = offsets - cnt
+        row_ids = jnp.arange(cnt.shape[0], dtype=jnp.int32)
+        seg = jnp.full(capacity, -1, dtype=jnp.int32)
+        # packed: an index is cut to 32 bits before the scatter looks at
+        # it; a first slot past the buffer goes to `capacity`, which it
+        # drops
+        first = (
+            jnp.minimum(prev_all, capacity).astype(jnp.int32)
+            if packed else prev_all
+        )
+        seg = seg.at[first].max(jnp.where(cnt > 0, row_ids, -1), mode="drop")
+        li = _padded_scan(jax.lax.cummax, seg, -1)
+        li_safe = jnp.clip(li, 0, max(left_vals.shape[0] - 1, 0))
+        if packed:
+            base = lo - prev_all.astype(jnp.int32)
+            row = jnp.concatenate([base[:, None], left_vals], axis=1)[li_safe]
+            ri_sorted = row[:, 0] + j
+        else:
+            prev = prev_all[li_safe]
+            ri_sorted = lo[li_safe] + (j - prev).astype(jnp.int32)
+        local = perm[jnp.clip(ri_sorted, 0, perm.shape[0] - 1)]
+        row_t = targets[jnp.clip(local, 0, targets.shape[0] - 1)]
+
+        if packed:
+            out_valid = j < jnp.minimum(total, capacity).astype(jnp.int32)
+            parts = [row[:, 1:]]
+        else:
+            out_valid = (j < total) & left_valid[li_safe]
+            parts = [left_vals[li_safe]]
+        if right_extra:
+            parts.append(
+                row_t[:, jnp.array([right_var_cols[rc] for rc in right_extra], dtype=jnp.int32)]
+            )
+        out_vals = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+        out_vals = jnp.where(out_valid[:, None], out_vals, jnp.int32(0))
+        return out_vals, out_valid, total
 
 
 def whole_type_join(

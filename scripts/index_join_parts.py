@@ -10,14 +10,17 @@ posting index, 4,194,304 output slots.
   lookup_slice       the slice search: one 32-bit search inside the
                      type's slice, the range's end read
   prefix_sum         the int64 prefix sum of the row counts
-  expansion          ranges -> output rows (the six gather passes)
+  expansion          ranges -> output rows (ops/join.py
+                     _expand_index_ranges: the slot owner's scatter and
+                     running maximum, ONE packed row gather of the left
+                     side, the reads through `perm` and `targets`)
   join_two_scans     the whole join, each way
   join_slice
 
 One JSON line a part: seconds of its compile (persistent cache off, so
 every compile is from nothing) and milliseconds a call (median and
-minimum of 10).  PERF.md section 6 has the chip's reading; the next
-issue (the expansion) starts from it.
+minimum of 10).  PERF.md section 6 has the chip's readings (PRs 45
+and 48).
 
     chiprun --chips 1 -- python3 scripts/index_join_parts.py [scale]
 

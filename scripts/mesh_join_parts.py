@@ -6,7 +6,9 @@ slab's 2,220,890-key posting index, 4,194,304 output slots, 1,048,576
 exchange slots a destination.
 
   first_join    the one-variable join (ops/join.py _index_join_impl):
-                the slice search, the prefix sum, the expansion
+                the slice search, the prefix sum, the expansion (one
+                packed row gather of the left side, then `perm` and
+                `targets`)
   send_left     the left side's send buffer (parallel/fused_sharded.py
                 _send_buffer: the mix, ONE sort of (destination, row)
                 words, S slices, a row gather into S x q slots)

@@ -604,20 +604,81 @@ def test_cell3_mesh_group_programs_on_described_2x2_mesh(
             < lone.memory_analysis().temp_size_in_bytes + slab_bytes)
 
 
+# -- the first join's expansion, alone ------------------------------------
+
+#: (left slots, index keys) of the whole-store conjunction's FIRST join:
+#: cell 5's, and a shard's of cell 6; 4,194,304 output slots in both
+EXPANSION_SHAPES = {"cell5": (524_288, 2_961_251),
+                    "cell6_shard": (1_048_576, 2_220_890)}
+EXPANSION_SLOTS = 1 << 22
+#: the parent's expansion (tree ed23f55) compiled for the described v5e
+#: holds four `reduce-window`s (the running maximum over the slots) and
+#: no sort, at both shapes
+EXPANSION_PARENT_SCANS = 4
+
+
+@pytest.mark.parametrize("shape", sorted(EXPANSION_SHAPES))
+def test_the_expansion_reads_a_left_row_once(compile_for_chip, shape):
+    """`_expand_index_ranges` alone at the analytic cells' shapes (PR
+    48): over the 4.2 M slots the optimized text holds the owner's
+    scatter, ONE packed row gather of the left side, `perm` and
+    `targets`: three slot-long gather fusions where the parent's held
+    six.  And no scan or sort the parent's lacks: the cells' first
+    request compiles the program under a statement deadline, one more
+    running maximum over the slots is 7-11 s of compile there and a
+    sort operand 15-40 s (PERF.md section 6, PR 47)."""
+    import re
+
+    from das_tpu.obs.registry import INDEX_EXPAND_SCOPE
+    from das_tpu.ops.join import _expand_index_ranges
+
+    n_left, n_keys = EXPANSION_SHAPES[shape]
+
+    def expansion(lv, lm, lo, cnt, offsets, perm, targets):
+        return _expand_index_ranges(
+            lv, lm, lo, cnt, offsets, offsets[-1], perm, targets,
+            (0, 1), (1,), EXPANSION_SLOTS)
+
+    lv, lm = _table(n_left, 2)
+    text = compile_for_chip(
+        expansion, lv, lm, _shape((n_left,), jnp.int32),
+        _shape((n_left,), jnp.int64), _shape((n_left,), jnp.int64),
+        _shape((n_keys,), jnp.int32), _shape((n_keys, 2), jnp.int32),
+    ).as_text()
+    slot_long = [
+        line for line in text.splitlines()
+        if "kind=kCustom" in line
+        and re.match(rf"\s*(ROOT )?%\S+ = \w+\[{EXPANSION_SLOTS}[\],]", line)
+    ]
+    assert all(INDEX_EXPAND_SCOPE in line for line in slot_long)
+    gathers = [line for line in slot_long if '/gather"' in line]
+    assert len(gathers) == 3
+    assert sum(f"s32[{EXPANSION_SLOTS},3]" in line for line in gathers) == 1
+    # the rest is the owner's scatter: a loop and the fusion that holds it
+    scatters = [line for line in slot_long if line not in gathers]
+    assert len(scatters) <= 2
+    assert all('/scatter-max"' in line for line in scatters)
+    assert len(re.findall(r"= \S+ reduce-window\(", text)) <= EXPANSION_PARENT_SCANS
+    assert not re.findall(r"= \S+ sort\(", text)
+
+
 # -- the accepted cells' programs, letter for letter ----------------------
 
 #: sha256 of the text LOWERED FOR THE DESCRIBED v5e (StableHLO, before
 #: the chip's compiler; a lowering rule may differ by platform, and the
-#: chip's is the one the cells run) on the parent of PR 47, tree
-#: bf5006f: `das_fused` / `das_fused_group` at cell 1's shapes (cells 2
-#: and 4 run the same two), `das_sharded` / `das_sharded_group` at cell
-#: 3's, each `count_only` and not, for `grounded3` and `shared2`, and
-#: the lone `das_fused` of `three_var` at cell 5's.  PR 47 gave the mesh
-#: a second way to run a verified join (partition both sides) and
-#: `ops/join.py _pair_join_impl` a second caller: small left sides keep
-#: the gather and the one-chip program reads the store in place, so
-#: none of the seventeen may move.  Regenerate only when a PR means to
-#: change those programs, and says so: LOWERED_PRINT=1 prints the dict.
+#: chip's is the one the cells run): `das_fused` / `das_fused_group` at
+#: cell 1's shapes (cells 2 and 4 run the same two), `das_sharded` /
+#: `das_sharded_group` at cell 3's, each `count_only` and not, for
+#: `grounded3` and `shared2`, and the lone `das_fused` of `three_var`
+#: at cell 5's.  The sixteen grounded programs are those of tree
+#: bf5006f (PR 47's parent) and no PR since has moved them; `three_var`
+#: is PR 48's, which MEANT to change it: its first join holds 4,194,304
+#: slots, and from `ops/join.py PACKED_EXPAND_MIN_SLOTS` on the
+#: expansion reads a left row once, as a packed row (tree ed23f55 read
+#: it four times: a786da21...).  A lane of the grounded shapes holds
+#: 64-2,048 slots and stays under that rule, so none of the sixteen may
+#: move.  Regenerate only when a PR means to change a program, and says
+#: so: LOWERED_PRINT=1 prints the dict.
 PARENT_LOWERED = {
     "das_fused.grounded3.count":
         "42fe0ed913056455928201ab3595aaf238623dc24bf227003efaecddd9851db4",
@@ -652,7 +713,7 @@ PARENT_LOWERED = {
     "das_sharded_group.shared2.result":
         "5452857022626f68fe5058e04c6ca34174f2ccc67ba5336274e3809089b66730",
     "das_fused.three_var.result":
-        "a786da21fb168f0642473f114281169ab96692eb64c59fd53ddc4d3056cf166c",
+        "0a2e31e7d3f1593874c20f0f3224e7f67957bc006cfb475795abef7987d7d800",
 }
 
 
