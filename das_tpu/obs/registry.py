@@ -328,16 +328,19 @@ HISTOGRAM_NAMES = (
 #: whole_type_join): every operation of the verified join sits under
 #: the first, every operation of the posting-index join of ONE shared
 #: variable (its range lookup: two searches, or for a large left side
-#: ONE and two reads; the prefix sum; the expansion) under the
-#: second, and the expansion (ops/join.py _expand_index_ranges: ranges
-#: -> output rows) NESTED in it under the third;
-#: benchmark/layer_metrics/ops.pair_join_*.py,
-#: ops.index_join_ms_per_query.py and ops.index_expand_ms_per_query.py
-#: sum the device time of the operations whose scope path holds the
-#: name
+#: ONE and a read; the prefix sum; the expansion) under the second,
+#: and NESTED in it the expansion (ops/join.py _expand_index_ranges:
+#: ranges -> output rows) under the third and the large left side's
+#: search proper (ops/join.py _search_words: the levels of separators
+#: and the descent, a row gather a level; no loop carries its name any
+#: more) under the fourth; benchmark/layer_metrics/ops.pair_join_*.py,
+#: ops.index_join_ms_per_query.py, ops.index_expand_ms_per_query.py
+#: and ops.index_search_ms_per_query.py sum the device time of the
+#: operations whose scope path holds the name
 PAIR_JOIN_SCOPE = "join.pair_verify"
 INDEX_JOIN_SCOPE = "join.index_probe"
 INDEX_EXPAND_SCOPE = "join.index_expand"
+INDEX_SEARCH_SCOPE = "join.index_search"
 #: the mesh's verified join that partitions both sides
 #: (parallel/fused_sharded.py): the whole step, both exchanges (each
 #: collective in `mesh.repartition`) and the local verify (still under

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from das_tpu.obs.registry import (
     INDEX_EXPAND_SCOPE,
     INDEX_JOIN_SCOPE,
+    INDEX_SEARCH_SCOPE,
     PAIR_JOIN_SCOPE,
 )
 
@@ -175,6 +176,35 @@ SORT_SEARCH_MAX_KEYS = 1 << 20
 
 #: `index_search_method`'s third answer (`_slice_ranges`)
 SLICE_SEARCH = "slice"
+
+#: fan-out of the slice search's descent (`_search_words`): the words a
+#: step reads as ONE row, and the most words its root holds (the level
+#: every probe compares whole, with no gather).  Chosen on the chip, a
+#: property of this compiler version like SLOW_SCAN_ROWS (PERF.md
+#: section 6, PR 49; v5e, `_slice_ranges` alone, ms a call | compile s,
+#: at cell 5's shapes, 524,288 probes into 2,961,251 keys, and a shard's
+#: of cell 6, 1,048,576 into 2,220,890; the binary search it replaces:
+#: 101.1 | 7.9 and 200.7 | 3.3):
+#:   F = 8 (7 gathered levels)  17.2 | 26.2   41.0 | 14.5
+#:   F = 16 (5)                 13.3 | 11.4   25.8 | 7.4
+#:   F = 32 (4)                 11.7 | 4.7    24.5 | 2.8
+#:   F = 64 (3)                 11.2 | 7.0    21.9 | 2.9
+#:   F = 128 (3)                11.2 | 6.1    21.8 | 2.9
+#:   F = 256 (2)                11.7 | 4.5    22.8 | 2.8
+#:   F = 512 (2)                14.9 | 4.3    28.5 | 2.8
+#:   F = 128, a root of 256 (2)  9.7 | 5.3    19.1 | 2.9
+#:   F = 64, a root of 768 (2)  10.6 | 4.4    18.8 | 2.8
+#: A gathered level costs 1.4 ms for 524,288 probes at any width up to
+#: 128 words (2.7 ns a row: a row of 128 costs what ONE word of the
+#: binary search cost a step, 7.2 ns, and less), so the widest row that
+#: is still one tile row wins, and past 128 words a row costs by its
+#: bytes.  The gathered rows are a temporary of `probes x 128 x 4`
+#: bytes a level whatever F <= 128 is (a narrower row is padded to the
+#: tile's 128 lanes): 268 MB in cell 5, 537 MB a chip in cell 6.  The
+#: root of two rows saves the cells' third gathered level (181 and 136
+#: separators: a compare of 256 words a probe is 0.1 ms).
+SEARCH_FANOUT = 128
+SEARCH_ROOT_WORDS = 256
 
 
 def index_search_method(n_left: int, n_keys: int) -> str:
@@ -400,10 +430,10 @@ def _index_ranges(keys_sorted, type_key, left_vals, lc0, left_valid):
 
 
 def _slice_ranges(keys_sorted, type_key, left_col):
-    """`_index_ranges` for a LARGE left side: one binary search over
-    32-bit words inside the probed type's slice, the range's end read
-    and not searched.  The same `[lo, hi)` as the two 64-bit searches
-    for every int32 value of a left row, exact.
+    """`_index_ranges` for a LARGE left side: one wide-fan-out search
+    over 32-bit words inside the probed type's slice, the range's end
+    read and not searched.  The same `[lo, hi)` as the two 64-bit
+    searches for every int32 value of a left row, exact.
 
     The index holds int64 `(type << 32) | target`, sorted, pads (int64
     max) last; on the chip a gather from it is TWO u32 gathers, and the
@@ -419,23 +449,14 @@ def _slice_ranges(keys_sorted, type_key, left_col):
     for a larger type or a pad.  Rows of a type are contiguous and
     sorted by target, and atom row ids lie in [0, 2^31 - 1), so the
     words are non-decreasing over the WHOLE index: a search of them
-    returns the global position, and `perm[...]` downstream is
-    untouched.  `run_end[i]` is the position after the last word equal
-    to word i (a reverse running minimum over the index), so `hi` is
-    one read at `lo` where the word there is the probed one."""
+    (`_search_words`) returns the global position, and `perm[...]`
+    downstream is untouched.  `run_end[i]` is the position after the
+    last word equal to word i (a reverse running minimum over the
+    index), so `hi` is one read at `lo` where the word there is the
+    probed one."""
     n = keys_sorted.shape[0]
     lowest = jnp.int32(-(2**31))
-    type_word = (keys_sorted >> 32).astype(jnp.int32)
-    of_type = jnp.asarray(type_key).astype(jnp.int32)
-    words = jnp.where(
-        type_word < 0, lowest,
-        jnp.where(
-            type_word < of_type, lowest + 1,
-            jnp.where(
-                type_word > of_type, _NO_ROW, keys_sorted.astype(jnp.int32)
-            ),
-        ),
-    )
+    words = _slice_words(keys_sorted, type_key)
     ends_run = jnp.concatenate(
         [words[1:] != words[:-1], jnp.ones((1,), dtype=bool)]
     )
@@ -449,10 +470,88 @@ def _slice_ranges(keys_sorted, type_key, left_col):
     # of other types
     probe = jnp.where(left_col == -1, lowest, left_col)
     is_key = (left_col >= -1) & (left_col != _NO_ROW)
-    lo = jnp.searchsorted(words, probe, side="left", method="scan").astype(jnp.int32)
+    with jax.named_scope(INDEX_SEARCH_SCOPE):
+        lo, found = _search_words(words, probe)
     at = jnp.clip(lo, 0, n - 1)
-    hi = jnp.where(is_key & (words[at] == probe), run_end[at], lo)
+    hi = jnp.where(is_key & found, run_end[at], lo)
     return lo, hi
+
+
+def _slice_words(keys_sorted, type_key):
+    """The posting index as ONE non-decreasing int32 word a key, for
+    the probed type (`_slice_ranges` says which word stands for what)."""
+    lowest = jnp.int32(-(2**31))
+    type_word = (keys_sorted >> 32).astype(jnp.int32)
+    of_type = jnp.asarray(type_key).astype(jnp.int32)
+    return jnp.where(
+        type_word < 0, lowest,
+        jnp.where(
+            type_word < of_type, lowest + 1,
+            jnp.where(
+                type_word > of_type, _NO_ROW, keys_sorted.astype(jnp.int32)
+            ),
+        ),
+    )
+
+
+def _search_levels(n: int) -> Tuple[int, ...]:
+    """Rows of SEARCH_FANOUT words a level of `_search_words` holds over
+    `n` words, the leaves first, the root last: the first level that
+    fits SEARCH_ROOT_WORDS words, or is down to one row."""
+    fanout = SEARCH_FANOUT
+    rows = [n // fanout + 1]          # the leaves end in 1..fanout pads
+    while rows[-1] > max(1, SEARCH_ROOT_WORDS // fanout):
+        rows.append(-(-rows[-1] // fanout))
+    return tuple(rows)
+
+
+def _search_words(words, probe):
+    """`(searchsorted(words, probe, side="left"), whether the word there
+    is the probe)` for non-decreasing int32 `words`, by a descent of
+    fan-out SEARCH_FANOUT: a step reads a ROW of separators where a
+    binary search reads one word (device-trace scope
+    `join.index_search`; scripts/index_join_parts.py times it alone).
+
+    Levels, by static shape (`_search_levels`): the leaves are `words`
+    with 1 to F pads (`_NO_ROW`, the highest int32) at their end, seen
+    as rows of F; a level above holds the LAST word of every row of the
+    one below, padded to rows of F the same way, up to a root of at
+    most SEARCH_ROOT_WORDS words (2,961,251 words at F = 128: 23,135 /
+    181 / 2 rows).
+
+    Why the descent is the exact lower bound.  A row's separator is its
+    greatest word, and rows follow each other in order, so
+    `c = count(separators < probe)` over the separators of consecutive
+    rows is the number of rows that lie WHOLLY below the probe: row `c`
+    is the first that holds a word `>= probe`, and inside it the count
+    of words below the probe is the offset of the first such word.
+    Counting `<` and never `<=` lands on the FIRST of a run of equal
+    words, also where the run crosses a row or a level.  The last leaf
+    ends in a pad, which no int32 is above, so every level's last
+    separator is a pad: a count never reaches past the row it is taken
+    over (`c < F` under the root), no index needs a clamp and
+    `lo <= n`.  The root
+    is the same words for every probe and is compared whole (a count
+    over all its rows is the row below to go to); every level under it
+    is ONE gather of a row a probe.  The row the descent ends in holds
+    `words[lo]`, so "the word at `lo` is the probe" is a second count
+    over the leaf row and no read of its own (past the table that word
+    is a pad: only the probe `_NO_ROW` equals it, which is no key)."""
+    fanout = SEARCH_FANOUT
+    levels = []
+    level = words
+    for rows in _search_levels(words.shape[0]):
+        pad = jnp.full((rows * fanout - level.shape[0],), _NO_ROW, jnp.int32)
+        level = jnp.concatenate([level, pad]).reshape(rows, fanout)
+        levels.append(level)
+        level = level[:, fanout - 1]
+    below = probe[:, None]
+    row = levels[-1].reshape(1, -1)
+    node = jnp.sum(row < below, axis=1, dtype=jnp.int32)
+    for level in levels[-2::-1]:
+        row = level[node]
+        node = node * fanout + jnp.sum(row < below, axis=1, dtype=jnp.int32)
+    return node, jnp.any(row == below, axis=1)
 
 
 def _index_join_impl(

@@ -7,8 +7,17 @@ posting index, 4,194,304 output slots.
   lookup_two_scans   the range lookup as it was: two 64-bit binary
                      searches of the whole index (what a small left
                      side still runs)
-  lookup_slice       the slice search: one 32-bit search inside the
-                     type's slice, the range's end read
+  lookup_slice       the slice lookup (ops/join.py _slice_ranges): two
+                     passes over the index (a key -> ONE 32-bit word;
+                     `run_end`, a reverse running minimum), the search
+                     of the words, the range's end read at `lo`
+  search             of it the search proper, alone (ops/join.py
+                     _search_words, device-trace scope
+                     `join.index_search`): the levels of separators
+                     (strided slices of the words) and the descent, ONE
+                     gather of a row of SEARCH_FANOUT words a level and
+                     a probe (a binary search's 22 dependent one-word
+                     gathers before PR 49)
   prefix_sum         the int64 prefix sum of the row counts
   expansion          ranges -> output rows (ops/join.py
                      _expand_index_ranges: the slot owner's scatter and
@@ -19,8 +28,8 @@ posting index, 4,194,304 output slots.
 
 One JSON line a part: seconds of its compile (persistent cache off, so
 every compile is from nothing) and milliseconds a call (median and
-minimum of 10).  PERF.md section 6 has the chip's readings (PRs 45
-and 48).
+minimum of 10).  PERF.md section 6 has the chip's readings (PRs 45,
+48 and 49).
 
     chiprun --chips 1 -- python3 scripts/index_join_parts.py [scale]
 
@@ -89,6 +98,10 @@ def lookup(lv, lm, keys):
     return J._index_ranges(keys, TYPE, lv, 0, lm)
 
 
+def search(words, lv):
+    return J._search_words(words, lv[:, 0])
+
+
 def counts(lm, lo, hi):
     return jnp.where(lm, hi - lo, 0).astype(jnp.int64)
 
@@ -121,14 +134,18 @@ def timed(name, fn, *args):
 dev = jax.devices()[0]
 print(json.dumps({"device": dev.device_kind, "platform": dev.platform, "scale": SCALE,
                   "keys": n_keys, "left_slots": N_LEFT, "left_rows": N_INTERACTS,
-                  "slots": CAP, "rule_here": J.index_search_method(N_LEFT, n_keys)}),
+                  "slots": CAP, "rule_here": J.index_search_method(N_LEFT, n_keys),
+                  "search_fanout": J.SEARCH_FANOUT,
+                  "search_root_words": J.SEARCH_ROOT_WORDS,
+                  "search_levels": J._search_levels(n_keys)}),
       flush=True)
 d_lv, d_lm, d_keys, d_perm, d_targets = (jnp.asarray(a) for a in (lv, lm, keys, perm, targets))
 lo_s, hi_s = timed("lookup_two_scans", forced("scan", lookup), d_lv, d_lm, d_keys)
 lo, hi = timed("lookup_slice", forced(J.SLICE_SEARCH, lookup), d_lv, d_lm, d_keys)
+lo_w, _found = timed("search", search, jax.jit(J._slice_words)(d_keys, TYPE), d_lv)
 cnt = jax.jit(counts)(d_lm, lo, hi)
 same = bool((cnt == jax.jit(counts)(d_lm, lo_s, hi_s)).all()) and bool(
-    jnp.where(cnt > 0, lo == lo_s, True).all())
+    jnp.where(cnt > 0, lo == lo_s, True).all()) and bool((lo_w == lo).all())
 offsets = timed("prefix_sum", J._cumsum_i64, cnt)
 timed("expansion", expansion, d_lv, d_lm, lo, cnt, offsets, d_perm, d_targets)
 a = timed("join_two_scans", forced("scan", join), d_lv, d_lm, d_keys, d_perm, d_targets)

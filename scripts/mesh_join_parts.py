@@ -6,9 +6,18 @@ slab's 2,220,890-key posting index, 4,194,304 output slots, 1,048,576
 exchange slots a destination.
 
   first_join    the one-variable join (ops/join.py _index_join_impl):
-                the slice search, the prefix sum, the expansion (one
+                the slice lookup, the prefix sum, the expansion (one
                 packed row gather of the left side, then `perm` and
                 `targets`)
+  lookup_slice  of it the slice lookup (ops/join.py _slice_ranges): two
+                passes over the slab's index (a key -> ONE 32-bit word;
+                `run_end`), the search of the words, the range's end
+                read at `lo`
+  search        of that the search proper, alone (ops/join.py
+                _search_words, device-trace scope `join.index_search`):
+                the levels of separators and the descent, ONE gather of
+                a row of SEARCH_FANOUT words a level and a probe, over
+                all 1,048,576 gathered left slots
   send_left     the left side's send buffer (parallel/fused_sharded.py
                 _send_buffer: the mix, ONE sort of (destination, row)
                 words, S slices, a row gather into S x q slots)
@@ -99,6 +108,14 @@ def first_join(lv, lm, keys, perm, targets):
     return J._index_join_impl(lv, lm, keys, perm, targets, TYPE, ((0, 0),), (0, 1), (1,), CAP)
 
 
+def lookup_slice(lv, keys):
+    return J._slice_ranges(keys, TYPE, lv[:, 0])
+
+
+def search(words, lv):
+    return J._search_words(words, lv[:, 0])
+
+
 def send_left(vals, valid):
     return fs._send_buffer(vals, valid, (1, 2), J._SENTINEL_L, S, Q)
 
@@ -139,14 +156,21 @@ dev = jax.devices()[0]
 print(json.dumps({"device": dev.device_kind, "platform": dev.platform, "scale": SCALE,
                   "cpu_count": os.cpu_count(), "keys": n_keys, "left_slots": N_LEFT,
                   "left_rows": S * m_interacts, "slots": CAP, "exchange_slots": Q,
-                  "rule_here": J.index_search_method(N_LEFT, n_keys)}), flush=True)
+                  "rule_here": J.index_search_method(N_LEFT, n_keys),
+                  "search_fanout": J.SEARCH_FANOUT,
+                  "search_root_words": J.SEARCH_ROOT_WORDS,
+                  "search_levels": J._search_levels(n_keys)}), flush=True)
 d = [jnp.asarray(a) for a in (lv, lm, keys, perm, targets, type_ids)]
 vals, valid, total = timed("first_join", first_join, *d[:5])
+lo, hi = timed("lookup_slice", lookup_slice, d[0], d[2])
+lo_w, _found = timed("search", search, jax.jit(J._slice_words)(d[2], TYPE), d[0])
 lbuf, l_counts = timed("send_left", send_left, vals, valid)
 rbuf, r_counts = timed("send_right", send_right, d[4], d[5])
 out, out_valid, rows = timed("verify", verify, lbuf, rbuf)
 (c_out, c_valid, c_rows), c_total, c_l, c_r = timed("chain", chain, *d)
 same = (int(c_total) == int(total) and int(c_rows) == int(rows)
+        and bool((lo_w == lo).all())
+        and int(jnp.where(d[1], hi - lo, 0).sum()) == int(total)
         and bool((c_l == l_counts).all()) and bool((c_r == r_counts).all()))
 print(json.dumps({
     "first_join_rows": int(total), "left_worst_destination": int(l_counts.max()),

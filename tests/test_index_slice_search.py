@@ -1,12 +1,19 @@
-"""The posting-index join's range lookup for a LARGE left side (PR 45).
+"""The posting-index join's range lookup for a LARGE left side (PR 45;
+since PR 49 a wide-fan-out descent where a binary search ran).
 
 Where the left side is large against a big index (`ops/join.py
 index_search_method`: the whole-store conjunction's first join, 524,288
-rows into 2,961,251 keys) a left row's `[lo, hi)` comes from ONE binary
-search over 32-bit words inside the probed type's slice, and the
-range's end is read (`_slice_ranges`).  Small left sides (every shape
-of the grounded cells) keep the two 64-bit searches.
+rows into 2,961,251 keys) a left row's `[lo, hi)` comes from ONE search
+over 32-bit words inside the probed type's slice, and the range's end
+is read (`_slice_ranges`).  The search (`_search_words`) is a descent
+of fan-out `SEARCH_FANOUT`: a step gathers a ROW of separators and
+counts those below the probe.  Small left sides (every shape of the
+grounded cells) keep the two 64-bit searches.
 
+  * the descent alone against `np.searchsorted(words, probe, "left")`
+    at fan-outs 2 to 128: fewer words than a row, one under / at / one
+    over a row and a row of rows, runs of equal words across a row and
+    a level, probes below, inside and above the table;
   * the lookup alone against `np.searchsorted` on the int64 keys: rows
     of other types on both sides of the slice, pads, the first and the
     last type, a type with no row, absent values, repeated probes, a
@@ -90,14 +97,25 @@ TABLES = {
 }
 
 
+#: fan-outs the lookup is held at beside the module's own: a narrow
+#: one makes the hand-made indexes (a few hundred keys) four or five
+#: levels deep
+FANOUTS = [None, 4]
+
+
+@pytest.mark.parametrize("fanout", FANOUTS)
 @pytest.mark.parametrize("name", TABLES)
-def test_the_lookup_is_np_searchsorted_on_the_int64_keys(name):
+def test_the_lookup_is_np_searchsorted_on_the_int64_keys(
+        name, fanout, monkeypatch):
+    if fanout:
+        monkeypatch.setattr(join_ops, "SEARCH_FANOUT", fanout)
+        monkeypatch.setattr(join_ops, "SEARCH_ROOT_WORDS", fanout)
     rows_by_type, tid, dangling, pads = TABLES[name]
     rng = np.random.default_rng(sorted(TABLES).index(name))
     n_targets = 50           # few targets: long runs of equal keys
     keys, _perm = _index(rng, rows_by_type, n_targets, dangling, pads)
     vals = _probes(rng, keys, tid, n_targets, 40)
-    lo, hi = jax.jit(join_ops._slice_ranges)(
+    lo, hi = jax.jit(lambda k, t, v: join_ops._slice_ranges(k, t, v))(
         jnp.asarray(keys), np.int32(tid), jnp.asarray(vals))
     want_lo, want_hi = _numpy_ranges(keys, tid, vals)
     assert lo.dtype == hi.dtype == jnp.int32
@@ -112,6 +130,132 @@ def test_the_lookup_is_np_searchsorted_on_the_int64_keys(name):
         at = np.flatnonzero(vals == -1)
         assert (want_hi - want_lo)[at].tolist() == [dangling] * len(at)
         assert (np.asarray(hi) - np.asarray(lo))[at].tolist() == [6, 6]
+
+
+# -- the descent alone ------------------------------------------------------
+
+#: name -> the words of a table as a function of the fan-out F: how many
+#: words against a row of F and a row of rows, and where runs of equal
+#: words fall
+WORDS = {
+    "one_word": lambda F, rng: [7],
+    "fewer_than_a_row": lambda F, rng: _runs(rng, max(1, F // 2)),
+    "one_under_a_row": lambda F, rng: _runs(rng, F - 1),
+    "a_row": lambda F, rng: _runs(rng, F),
+    "one_over_a_row": lambda F, rng: _runs(rng, F + 1),
+    "one_under_a_row_of_rows": lambda F, rng: _runs(rng, F * F - 1),
+    "a_row_of_rows": lambda F, rng: _runs(rng, F * F),
+    "one_over_a_row_of_rows": lambda F, rng: _runs(rng, F * F + 1),
+    "three_levels_and_a_bit": lambda F, rng: _runs(rng, 2 * F * F + 3),
+    # every word the same: ONE run across every row and level
+    "one_run": lambda F, rng: [11] * (F * F + 1),
+    # a run that starts on a row's first word and crosses a level
+    "a_run_from_a_rows_edge": lambda F, rng: [5] * F + [9] * (F * F) + [12],
+    # a run that ends on a row's last word, the next begins the next row
+    "a_run_to_a_rows_edge": lambda F, rng: [5] * (2 * F) + [6] * (3 * F),
+    # as `_slice_ranges` makes them: other types' words on both sides,
+    # the slice between, nothing but pads (the highest word) after it
+    "a_slice_between_other_types": lambda F, rng: (
+        [I32_MIN] * 3 + [I32_MIN + 1] * (F + 2) + _runs(rng, 3 * F)
+        + [I32_MAX] * (F * F)),
+    "all_pads": lambda F, rng: [I32_MAX] * (F + 3),
+    "distinct_words": lambda F, rng: list(range(0, 3 * (F * F + F), 3)),
+}
+
+
+def _runs(rng, n):
+    """`n` sorted words of few distinct values: runs of equal words
+    longer than a row at the small fan-outs."""
+    return np.sort(rng.integers(-3, max(2, n // 7), n)).tolist()
+
+
+@pytest.mark.parametrize("fanout,root", [(2, 2), (4, 4), (4, 16), (16, 16),
+                                         (16, 64), (128, 128), (128, 256)])
+@pytest.mark.parametrize("name", WORDS)
+def test_the_descent_is_np_searchsorted_left(name, fanout, root, monkeypatch):
+    """`_search_words` against numpy for every word of the table, its
+    neighbours, every row's LAST word (a separator) and first, and the
+    probes no key holds: below every word, above every word, -1, -2,
+    the lowest int32 and 2^31 - 1."""
+    monkeypatch.setattr(join_ops, "SEARCH_FANOUT", fanout)
+    monkeypatch.setattr(join_ops, "SEARCH_ROOT_WORDS", root)
+    rng = np.random.default_rng(fanout + sorted(WORDS).index(name))
+    words = np.asarray(WORDS[name](fanout, rng), np.int32)
+    n = len(words)
+    assert (np.diff(words.astype(np.int64)) >= 0).all()
+    near = words.astype(np.int64)[:, None] + np.array([-1, 0, 1])
+    probes = np.concatenate([
+        near.ravel().clip(I32_MIN, I32_MAX),
+        words[fanout - 1::fanout], words[::fanout],
+        [-1, -2, I32_MIN, I32_MIN + 1, I32_MAX, I32_MAX - 1,
+         int(words[0]), int(words[-1])],
+    ]).astype(np.int32)
+    lo, found = jax.jit(lambda w, p: join_ops._search_words(w, p))(
+        jnp.asarray(words), jnp.asarray(probes))
+    want = np.searchsorted(words, probes, side="left")
+    assert lo.dtype == jnp.int32 and found.dtype == jnp.bool_
+    assert (np.asarray(lo) == want).all()
+    # the word at `lo`; past the table it is a pad, which only the
+    # probe 2^31 - 1 equals (no key: `_slice_ranges` masks it)
+    padded = np.append(words, np.int32(I32_MAX))
+    assert (np.asarray(found) == (padded[want] == probes)).all()
+    # some probe lies below every word, and one above unless the table
+    # ends in the highest word there is
+    assert 0 in want and (n in want or words[-1] == I32_MAX)
+
+
+@pytest.mark.parametrize("n,fanout,root,rows", [
+    (2_961_251, 128, 128, (23_135, 181, 2, 1)),     # cell 5's index
+    (2_961_251, 128, 256, (23_135, 181, 2)),        # a root of two rows
+    (2_961_251, 16, 16, (185_079, 11_568, 723, 46, 3, 1)),
+    (2_220_890, 128, 128, (17_351, 136, 2, 1)),     # a shard's of cell 6
+    (2_220_890, 128, 256, (17_351, 136, 2)),
+    (127, 128, 128, (1,)),      # fewer words than a row: the root alone
+    (128, 128, 128, (2, 1)),    # a full row: its pad starts a second
+    (128, 128, 256, (2,)),
+    (1, 2, 2, (1,)),
+    (4, 2, 2, (3, 2, 1)),
+    (4, 2, 1, (3, 2, 1)),       # a root is one row at least
+    (4, 2, 4, (3, 2)),
+])
+def test_the_levels_follow_from_the_key_count(n, fanout, root, rows,
+                                              monkeypatch):
+    monkeypatch.setattr(join_ops, "SEARCH_FANOUT", fanout)
+    monkeypatch.setattr(join_ops, "SEARCH_ROOT_WORDS", root)
+    assert join_ops._search_levels(n) == rows
+    assert rows[0] * fanout > n >= (rows[0] - 1) * fanout   # 1..F pads
+    assert rows[-1] * fanout <= max(root, fanout)
+
+
+@pytest.mark.parametrize("fanout,root", [(4, 4), (4, 16), (128, 256)])
+def test_the_search_is_row_gathers_under_its_scope_and_no_loop(
+        fanout, root, monkeypatch):
+    """What the traced lookup holds: no loop anywhere, under
+    `join.index_search` ONE gather of a row a level below the root, and
+    outside it one read (`run_end` at `lo`)."""
+    from das_tpu.obs.registry import INDEX_SEARCH_SCOPE
+
+    monkeypatch.setattr(join_ops, "SEARCH_FANOUT", fanout)
+    monkeypatch.setattr(join_ops, "SEARCH_ROOT_WORDS", root)
+    n_keys, n_left = 1000, 64
+    jaxpr = jax.make_jaxpr(
+        lambda k, v: join_ops._slice_ranges(k, np.int32(4), v))(
+        jnp.zeros((n_keys,), jnp.int64), jnp.zeros((n_left,), jnp.int32))
+    assert INDEX_SEARCH_SCOPE == "join.index_search"
+    prims = [(e.primitive.name, INDEX_SEARCH_SCOPE in str(e.source_info.name_stack),
+              e) for e in jaxpr.jaxpr.eqns]
+    names = {p for p, _in, _e in prims}
+    assert not names & {"while", "scan", "sort"}
+    gathers = [(inside, e) for p, inside, e in prims if p == "gather"]
+    levels = join_ops._search_levels(n_keys)
+    searched = [e for inside, e in gathers if inside]
+    assert len(searched) == len(levels) - 1
+    for e, rows in zip(searched, levels[-2::-1]):      # from the top down
+        assert e.invars[0].aval.shape == (rows, fanout)
+        assert e.outvars[0].aval.shape == (n_left, fanout)
+        assert e.outvars[0].aval.dtype == jnp.int32
+    (outside,) = [e for inside, e in gathers if not inside]
+    assert outside.invars[0].aval.shape == (n_keys,)
 
 
 # -- the join, slice search against the two scans ---------------------------

@@ -19,6 +19,7 @@ skip in silence); the persistent compilation cache is off around the
 compiles (an entry written for a described device cannot be read back).
 """
 
+import collections
 import dataclasses
 import os
 
@@ -28,7 +29,7 @@ import numpy as np
 import pytest
 
 from das_tpu.core.config import DasConfig
-from das_tpu.obs.registry import INDEX_JOIN_SCOPE
+from das_tpu.obs.registry import INDEX_JOIN_SCOPE, INDEX_SEARCH_SCOPE
 from das_tpu.storage.delta import capacity_class, delta_class
 
 #: chip_smoke.py's default store: links of arity 2 at --scale 0.1
@@ -144,7 +145,13 @@ def _tiny_store_and_query(make_db, n_clauses=3):
 
 def _lower_on_described_mesh(topo, job, sig, per_shard, group=None,
                              count_only=False):
-    """The fused shard_map program of `sig`, lowered against a Mesh
+    return _trace_on_described_mesh(
+        topo, job, sig, per_shard, group, count_only).lower()
+
+
+def _trace_on_described_mesh(topo, job, sig, per_shard, group=None,
+                             count_only=False):
+    """The fused shard_map program of `sig`, traced against a Mesh
     built from the described v5e:2x2 devices, the job's row-sharded
     bucket arrays stretched to `per_shard` rows a shard.
     `group`: `(count_only, lanes)` for the GROUP program over `lanes`
@@ -185,7 +192,7 @@ def _lower_on_described_mesh(topo, job, sig, per_shard, group=None,
         assert None in key_axes and 0 in key_axes
         fn, _names = fs.build_fused_sharded_group(
             sig, mesh, count_only, key_axes, fval_axes)
-    return jax.jit(fn).lower(
+    return jax.jit(fn).trace(
         jax.tree.map(slab, job.arrays),
         jax.tree.map(scalar_or_vec, keys),
         jax.tree.map(scalar_or_vec, fvals),
@@ -432,14 +439,50 @@ def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
     operands, attrs, types = sorts[0]
     assert operands.count("%") == 3 and "is_stable = false" in attrs
     assert "i64" not in types
-    # the FIRST join (524,288 left rows into the 2.96 M-key index)
-    # holds ONE search loop, and its body gathers no 64-bit element:
-    # on the chip an int64 gather is two u32 gathers, and two searches
-    # of them were 66 % of the program's device time (PERF.md §6 PR 45)
-    loops = _loops_under(jax.make_jaxpr(fn)(*shapes).jaxpr, INDEX_JOIN_SCOPE)
-    assert len(loops) == 1
-    (gathered,) = _gathered_in(loops[0])
-    assert gathered.dtype == jnp.int32 and gathered.shape == (cap,)
+    _assert_the_first_join_searches_by_rows(
+        jax.make_jaxpr(fn)(*shapes).jaxpr, cap, ANALYTIC_CAPS["term_caps"][0])
+
+
+#: the running sums, maxima and minima the parent's first join holds
+#: (tree 6fda653, the one-chip program and a shard's alike): the
+#: reverse minimum behind `run_end`, the prefix sum's two 32-bit sums,
+#: the slot owner's maximum.  One more over 0.5-4 M elements is 7-50 s
+#: of a first request's compile (ops/join.py SLOW_SCAN_ROWS)
+PARENT_FIRST_JOIN_SCANS = {"cummin": 1, "cumsum": 2, "cummax": 1}
+
+
+def _assert_the_first_join_searches_by_rows(jaxpr, n_keys, n_left):
+    """The FIRST join (524,288 left rows into the 2.96 M-key index; a
+    shard's 1,048,576 into 2.22 M) holds NO loop: the 22 dependent
+    one-word gathers of its binary search were 39-44 % of the program
+    (PERF.md section 6, PR 49).  Under `join.index_search` it holds ONE
+    gather of a row of `SEARCH_FANOUT` int32 words a level below the
+    root and nothing else that reads by index, and the join as a whole
+    no sort and no running sum, maximum or minimum the parent's
+    lacks."""
+    from das_tpu.ops.join import SEARCH_FANOUT, _search_levels
+
+    joined = _primitives_under(jaxpr, INDEX_JOIN_SCOPE)
+    counts = collections.Counter(eqn.primitive.name for eqn in joined)
+    assert not {"while", "scan", "sort"} & set(counts)
+    assert {name: counts[name] for name in counts
+            if name.startswith("cum")} == PARENT_FIRST_JOIN_SCANS
+    searched = _primitives_under(jaxpr, INDEX_SEARCH_SCOPE)
+    assert {id(eqn) for eqn in searched} <= {id(eqn) for eqn in joined}
+    gathers = [eqn for eqn in searched if eqn.primitive.name == "gather"]
+    levels = _search_levels(n_keys)
+    assert len(gathers) == len(levels) - 1 and len(levels) <= 6
+    for eqn, rows in zip(gathers, levels[-2::-1]):      # from the top down
+        assert eqn.invars[0].aval.shape == (rows, SEARCH_FANOUT)
+        assert eqn.invars[0].aval.dtype == jnp.int32
+        assert eqn.outvars[0].aval.shape == (n_left, SEARCH_FANOUT)
+    assert not [eqn for eqn in searched
+                if eqn.primitive.name in ("scatter", "dynamic_slice")]
+    # no 64-bit element is read by index anywhere in the join: on the
+    # chip an int64 gather is two u32 gathers (PERF.md section 6, PR 45)
+    for eqn in joined:
+        if eqn.primitive.name == "gather":
+            assert eqn.invars[0].aval.dtype != jnp.int64
 
 
 def _inner_jaxprs(eqn):
@@ -450,29 +493,18 @@ def _inner_jaxprs(eqn):
                 yield sub
 
 
-def _loops_under(jaxpr, scope, stack=""):
-    """The loop equations (a `fori_loop` traces as `scan` or `while`)
-    whose name stack, from the program's root down, holds `scope`."""
+def _primitives_under(jaxpr, scope, stack=""):
+    """The equations whose name stack, from the program's root down,
+    holds `scope`; a call's own equation (`pjit`, `shard_map`, a loop)
+    and what its bodies hold both count where they lie under it."""
     found = []
     for eqn in jaxpr.eqns:
         here = f"{stack}/{eqn.source_info.name_stack}"
-        if eqn.primitive.name in ("scan", "while") and scope in here:
+        if scope in here:
             found.append(eqn)
-            continue
         for sub in _inner_jaxprs(eqn):
-            found += _loops_under(sub, scope, here)
+            found += _primitives_under(sub, scope, here)
     return found
-
-
-def _gathered_in(eqn):
-    """The operands of every gather inside a loop equation's bodies."""
-    avals = []
-    for sub in _inner_jaxprs(eqn):
-        for inner in sub.eqns:
-            if inner.primitive.name == "gather":
-                avals.append(inner.invars[0].aval)
-            avals += _gathered_in(inner)
-    return avals
 
 
 @pytest.mark.parametrize("cap,dcap,key_dtype", [
@@ -672,13 +704,15 @@ def test_the_expansion_reads_a_left_row_once(compile_for_chip, shape):
 #: `grounded3` and `shared2`, and the lone `das_fused` of `three_var`
 #: at cell 5's.  The sixteen grounded programs are those of tree
 #: bf5006f (PR 47's parent) and no PR since has moved them; `three_var`
-#: is PR 48's, which MEANT to change it: its first join holds 4,194,304
-#: slots, and from `ops/join.py PACKED_EXPAND_MIN_SLOTS` on the
-#: expansion reads a left row once, as a packed row (tree ed23f55 read
-#: it four times: a786da21...).  A lane of the grounded shapes holds
-#: 64-2,048 slots and stays under that rule, so none of the sixteen may
-#: move.  Regenerate only when a PR means to change a program, and says
-#: so: LOWERED_PRINT=1 prints the dict.
+#: is PR 49's, which MEANT to change it: its first join takes the slice
+#: search, whose 22-step binary search became a descent of two gathered
+#: rows of 128 separators (tree 6fda653, PR 48's, held the loop:
+#: 0a2e31e7...; PR 48 had changed it too: from `ops/join.py
+#: PACKED_EXPAND_MIN_SLOTS` output slots on the expansion reads a left
+#: row once).  A lane of the grounded shapes holds 16-2,048 left rows
+#: and 64-2,048 slots and stays under both rules, so none of the
+#: sixteen may move.  Regenerate only when a PR means to change a
+#: program, and says so: LOWERED_PRINT=1 prints the dict.
 PARENT_LOWERED = {
     "das_fused.grounded3.count":
         "42fe0ed913056455928201ab3595aaf238623dc24bf227003efaecddd9851db4",
@@ -713,7 +747,7 @@ PARENT_LOWERED = {
     "das_sharded_group.shared2.result":
         "5452857022626f68fe5058e04c6ca34174f2ccc67ba5336274e3809089b66730",
     "das_fused.three_var.result":
-        "0a2e31e7d3f1593874c20f0f3224e7f67957bc006cfb475795abef7987d7d800",
+        "03b0e41c5045b38374d16c0e0db988bc948d3335d40d0c29ea5de61783275a8e",
 }
 
 
@@ -790,7 +824,8 @@ def test_mesh_three_var_at_cell6_shapes(topo, no_persistent_cache):
     stable, no operand is 64-bit; the only 64-bit all-reduce is a Sum
     (the chip's compiler lowers no other); and no shard holds the
     gathered left side of the second join (4 x 4.2 M slots: 0.6 GB of
-    temporaries where the partition needs under 0.2)."""
+    temporaries where the partition needs under 0.2).  And the first
+    join of a shard searches as cell 5's does: by rows, with no loop."""
     import re
 
     from das_tpu.parallel.fused_sharded import get_sharded_executor
@@ -805,7 +840,11 @@ def test_mesh_three_var_at_cell6_shapes(topo, no_persistent_cache):
     assert job.exch_caps[0] == 0 and job.exch_caps[1] > 0
     sig = dataclasses.replace(job.plan_sig(), **CELL6_CAPS)
     per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
-    lowered = _lower_on_described_mesh(topo, job, sig, per_shard)
+    traced = _trace_on_described_mesh(topo, job, sig, per_shard)
+    # every shard probes its slab's index with the gathered left side
+    _assert_the_first_join_searches_by_rows(
+        traced.jaxpr.jaxpr, per_shard, 4 * CELL6_CAPS["term_caps"][0])
+    lowered = traced.lower()
     text = lowered.as_text()
     sorts = re.findall(
         r'"stablehlo\.sort"\(([^)]*)\) <\{([^}]*)\}>.*?\}\) : \(([^)]*)\) ->',
@@ -825,4 +864,12 @@ def test_mesh_three_var_at_cell6_shapes(topo, no_persistent_cache):
     hlo = compiled.as_text()
     assert "all-to-all" in hlo and "all-gather" in hlo
     assert "tpu_custom_call" not in hlo
-    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    # beside ONE level of the first join's search: a row of
+    # SEARCH_FANOUT words a gathered left slot, live a level at a time
+    # (0.54 GB at 128; the second join's gathered left side would be
+    # 0.6 GB MORE)
+    from das_tpu.ops.join import SEARCH_FANOUT
+
+    search_rows = 4 * CELL6_CAPS["term_caps"][0] * SEARCH_FANOUT * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 300e6 + search_rows)
