@@ -40,7 +40,7 @@ import sys
 import tempfile
 import time
 
-#: the reference-scale KB shape (bench.py FLYBASE: 2.58 M nodes /
+#: the reference-scale KB shape (BASELINE.json: 2.58 M nodes /
 #: 27.9 M links, SimplePatternMiner.ipynb cell 0), multiplied by --scale
 FLYBASE = dict(
     n_genes=2_400_000, n_processes=180_000, members_per_gene=10,
@@ -454,8 +454,8 @@ def phase_queries(s: Smoke) -> dict:
                   f"(got {len(got[1])} rows neg={got[0]}, "
                   f"want {len(want[1])} rows neg={want[0]})")
 
-    # 8 grounded 3-clause conjunctions (bench.py three_var_query with
-    # $1 bound), then each again: the second answer is a result-cache hit
+    # 8 grounded 3-clause conjunctions (the reference's scripts/benchmark.py
+    # QUERY_1 shape with $1 bound), then each again: the second answer is a result-cache hit
     for rnd in ("first", "repeat"):
         for g in s.genes:
             compare(f"grounded3[{g}] {rnd}", s.rpc_query(dsl_grounded3(g)),
@@ -531,10 +531,10 @@ def phase_queries(s: Smoke) -> dict:
 
 
 def phase_counts(s: Smoke) -> None:
-    """The checks of the two old live-device tests of
-    tests/test_tpu_compile.py: the fori_loop count program and an
-    all-variable conjunction agree with the per-query counts (and with
-    the plain sets)."""
+    """Counts without materialization: the miner's batched count
+    program (`FusedExecutor.count_batch`, one dispatch for the eight
+    grounded conjunctions) and an all-variable conjunction agree with
+    the per-query counts (and with the plain sets)."""
     from das_tpu.query import compiler
     from das_tpu.query.ast import And, Link, Node, Variable
     from das_tpu.query.fused import get_executor
@@ -552,10 +552,8 @@ def phase_counts(s: Smoke) -> None:
     per_query = [compiler.count_matches(db, grounded(g)) for g in s.genes]
     check(per_query == want, f"count_matches {per_query} != plain {want}")
     plans = [compiler.plan_query(db, grounded(g)) for g in s.genes]
-    run, width = get_executor(db).build_count_loop(plans)
-    counts, _mx = run()
-    check(width == N_GROUNDED and [int(c) for c in counts] == want,
-          f"count loop {list(counts)} != plain {want}")
+    batched = get_executor(db).count_batch(plans)
+    check(batched == want, f"count_batch {batched} != plain {want}")
     # the all-variable conjunction of the query phase, counted without
     # materialization (whole-table Member side through the index join)
     all_var = And([
@@ -565,8 +563,7 @@ def phase_counts(s: Smoke) -> None:
     n = compiler.count_matches(db, all_var)
     want_all = len(s.plain.list_member())
     check(n == want_all, f"all-variable count {n} != plain {want_all}")
-    emit("counts", grounded_counts=want, count_loop_width=width,
-         all_variable_count=n)
+    emit("counts", grounded_counts=want, all_variable_count=n)
 
 
 def phase_commit(s: Smoke) -> None:

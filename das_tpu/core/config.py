@@ -111,9 +111,6 @@ ENV_REGISTRY: Dict[str, Tuple[Optional[str], str]] = {
     "DAS_TPU_HOST_COUNT": (
         None, "=0 disables the host-side count shortcut in the fused "
               "executor (query/fused.py)"),
-    "DAS_TPU_LOOP_BARRIER": (
-        None, "=1 inserts a debug barrier between fused-loop stages "
-              "(query/fused.py)"),
     "DAS_TPU_COLUMNAR": (
         None, "=0 disables the columnar ingest fast path "
               "(ingest/pipeline.py)"),
@@ -223,7 +220,7 @@ class DasConfig:
 
     # --- serving edge -----------------------------------------------------
     # widest batch one coalescer drain may form (service/coalesce.py); the
-    # served path's throughput knob — BENCH_r05 showed per-query cost
+    # served path's throughput knob — the pre-PR-1 chip records showed per-query cost
     # halving as concurrency doubles, so deployments need to tune this
     coalesce_max_batch: int = 256
     # coalescer execution pipelining (service/coalesce.py): the FLOOR of

@@ -278,12 +278,6 @@ def maybe_fail(site: str) -> None:
     armed.check(site)
 
 
-def reset_counts() -> None:
-    """Zero INJECT_COUNTS (bench/test arms start from a clean window)."""
-    for site in INJECT_COUNTS:
-        INJECT_COUNTS[site] = 0
-
-
 # -- retry / backoff ---------------------------------------------------------
 
 

@@ -231,8 +231,8 @@ def write_bio_canonical(
     building an intermediate AtomSpaceData.  The rng draw order mirrors the
     builder exactly, so loading the file reproduces the identical handle
     set (differentially asserted in tests/test_native.py).  This is the
-    input generator for the end-to-end ingest benchmark at reference scale
-    (bench.py flybase section, VERDICT r02 item 4).  Returns the number of
+    input generator of the start-up proof (chip_smoke.py, reference shape
+    times --scale).  Returns the number of
     expression lines written."""
     rng = random.Random(seed)
     lines = 0

@@ -1,6 +1,6 @@
 """dasprof — the program ledger (ISSUE 14 tentpole).
 
-Every PR since BENCH_r05 has been held on CPU A/Bs: the engine compiles
+The engine compiles
 whole-plan programs and records nothing about what XLA actually did — compile wall time, FLOPs,
 bytes accessed, HBM footprint are all dark.  This module closes the
 device side of the observability story (dastrace, ARCHITECTURE §13,
@@ -13,8 +13,8 @@ bytes — keyed by the plan-signature digest the executor caches already
 use.
 
 How instrumentation works: the program builders (`build_fused`,
-`build_fused_tree`, `build_fused_exact`, the count-batch/count-loop
-sites, and the sharded twins) pass their freshly-jitted callable through
+`build_fused_tree`, `build_fused_exact`, the count-batch site, and the
+sharded twins) pass their freshly-jitted callable through
 `instrument(site, digest, fn)`.  Disabled (`DAS_TPU_PROFLOG` unset — the
 default), `instrument` returns `fn` ITSELF: the serving path is
 byte-for-byte the pre-ledger path (tests/test_zprof.py pins the
@@ -27,8 +27,8 @@ from the compiled object (a "ledger hit").  Any AOT failure — an
 exotic argument tree, a backend without AOT support — falls back to the
 plain jitted path and records the error string instead of raising:
 the ledger can cost accuracy, never answers.  Calls that arrive with
-TRACER arguments (the count-loop body re-enters `build_fused`'s program
-inside its own jit; `jax.eval_shape` probes it) delegate straight to
+TRACER arguments (a program re-entered inside another program's trace;
+a `jax.eval_shape` probe) delegate straight to
 the jitted fn — a program nested inside another program is priced by
 its parent's ledger entry.
 
@@ -63,8 +63,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from das_tpu.obs.recorder import TRUTHY
 
 #: daslint DL006 — post-__init__ ledger state owners.  Everything is
-#: serialized on `_lock`; `enabled` flips only via configure() (tests /
-#: bench arms).
+#: serialized on `_lock`; `enabled` flips only via configure() (tests,
+#: scripts/dump_trace.py).
 LOCK_DISCIPLINE = {
     "ProgramLedger.enabled": "_lock",
     "ProgramLedger.capacity": "_lock",
@@ -98,7 +98,6 @@ PROGRAM_SITES: Dict[str, Optional[str]] = {
     "fused.build_fused_tree": "fused_tree",
     "fused.build_fused_exact": "fused_exact",
     "fused.FusedExecutor._run_batch_group": "count_batch",
-    "fused.FusedExecutor.build_count_loop": "count_loop",
     "fused_sharded._ShardedExecJob.dispatch": "sharded",
     "fused_sharded._ShardedExecJob._build_group": "sharded_group",
     "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
@@ -116,6 +115,10 @@ PROGRAM_SITES: Dict[str, Optional[str]] = {
     "join._anti_join_jit": None,
     "join._build_term_table_jit": None,
     "join._dedup_table_jit": None,
+    # -- declared-exempt: the mesh's per-op table programs (the tree
+    #    evaluator's op layer and the staged route: one jitted shard_map
+    #    per table operation, each cached by its statics) ----------------
+    "mesh.table_program": None,
     # -- declared-exempt: star-count degree fold programs (count-only
     #    fast path, host-side fold by default — query/starcount.py) -----
     "starcount._deg_vector": None,
@@ -358,7 +361,7 @@ class ProgramLedger:
             }
 
 
-#: THE process ledger — env-initialized, reconfigurable (tests/bench)
+#: THE process ledger — env-initialized, reconfigurable (tests, scripts/dump_trace.py)
 LEDGER = ProgramLedger()
 
 
@@ -381,22 +384,6 @@ def snapshot() -> Dict[str, Any]:
 def rows(site: Optional[str] = None,
          digest: Optional[str] = None) -> List[Dict[str, Any]]:
     return LEDGER.rows(site=site, digest=digest)
-
-
-def compile_totals() -> Tuple[int, float]:
-    """(compiles, compile seconds) — the bench sections' delta basis."""
-    return LEDGER.compiles, LEDGER.compile_s
-
-
-def compile_delta(before: Tuple[int, float]) -> Dict[str, Any]:
-    """Per-section ledger delta for the bench records: programs
-    compiled and compile seconds paid since `before`
-    (= compile_totals() at section start)."""
-    c0, s0 = before
-    return {
-        "programs_compiled": LEDGER.compiles - c0,
-        "compile_s": round(LEDGER.compile_s - s0, 3),
-    }
 
 
 class _InstrumentedProgram:

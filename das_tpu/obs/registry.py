@@ -361,7 +361,6 @@ PROGRAM_NAMES = (
     "das_fused_tree",
     "das_fused_exact",
     "das_count_batch",
-    "das_count_loop",
     "das_sharded",
     "das_sharded_group",
     "das_sharded_tree",

@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 SHARD_AXIS = "shards"
@@ -46,6 +47,27 @@ def make_mesh(n_devices: Optional[int] = None, axis_name: str = SHARD_AXIS) -> M
             )
         devices = devices[:n_devices]
     return Mesh(np.array(devices), (axis_name,))
+
+
+def table_program(mesh: Mesh, fn, n_in: int, n_out: int, replicated_in=(),
+                  replicated_out: bool = False, check_vma: bool = True):
+    """ONE compiled program of a mesh table operation: `fn` under
+    `shard_map` over operands sharded on their leading axis (replicated
+    where `replicated_in` says; every output replicated with
+    `replicated_out`), jitted whole.  A bare `shard_map` is not a
+    compiled program: its body dispatches primitive by primitive on
+    every call.  The caller keeps the result under the statics `fn`
+    closes over (`ShardedTreeOps._fn_cache`, `ShardedDB._staged_programs`)."""
+    spec = PartitionSpec(SHARD_AXIS)
+    in_specs = tuple(
+        PartitionSpec() if i in replicated_in else spec for i in range(n_in)
+    )
+    out_spec = PartitionSpec() if replicated_out else spec
+    return jax.jit(shard_map(
+        fn, mesh=mesh, in_specs=in_specs,
+        out_specs=(out_spec,) * n_out if n_out > 1 else out_spec,
+        check_vma=check_vma,
+    ))
 
 
 def row_sharding(mesh: Mesh, axis_name: str = SHARD_AXIS) -> NamedSharding:

@@ -40,7 +40,7 @@ from das_tpu import obs
 from das_tpu.core.config import DasConfig
 from das_tpu.core.exceptions import CapacityOverflowError
 from das_tpu.ops.join import _anti_join_impl, _join_tables_impl, _build_term_table_impl
-from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+from das_tpu.parallel.mesh import SHARD_AXIS, make_mesh, table_program
 from das_tpu.query import compiler as qc
 from das_tpu.query.ast import LogicalExpression, PatternMatchingAnswer
 from das_tpu.storage.atom_table import AtomSpaceData, Finalized
@@ -360,9 +360,12 @@ def _shard_rows(vals, valid) -> np.ndarray:
     return distinct
 
 
-def _probe_kernel(key_sorted, perm, targets, type_id, probe_key, fixed, cap, var_cols, eq_pairs):
+def _probe_kernel(key_sorted, perm, targets, type_id, probe_key, fixed_vals,
+                  *, fixed_pos, cap, var_cols, eq_pairs):
     """Shard-local probe + term-table build.  Runs inside shard_map: blocks
-    arrive as [1, m(, a)] slabs; outputs carry the same leading block dim."""
+    arrive as [1, m(, a)] slabs; outputs carry the same leading block dim.
+    The probed key and the grounded values are replicated OPERANDS, so a
+    program is keyed by shapes and positions, not by the atom asked for."""
     key_sorted, perm, targets = key_sorted[0], perm[0], targets[0]
     lo = jnp.searchsorted(key_sorted, probe_key, side="left")
     hi = jnp.searchsorted(key_sorted, probe_key, side="right")
@@ -373,8 +376,8 @@ def _probe_kernel(key_sorted, perm, targets, type_id, probe_key, fixed, cap, var
     local = perm[idx]
     safe = jnp.clip(local, 0, targets.shape[0] - 1)
     mask = valid
-    for pos, val in fixed:
-        mask = mask & (targets[safe, pos] == val)
+    for i, pos in enumerate(fixed_pos):
+        mask = mask & (targets[safe, pos] == fixed_vals[i])
     vals, mask = _build_term_table_impl(targets, local, mask, var_cols, eq_pairs)
     return vals[None], mask[None], range_count[None]
 
@@ -406,6 +409,10 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
                 self.config.checkpoint_path, self.fin, self.mesh
             )
         self.tables = tables or ShardedTables(self.fin, self.mesh)
+        #: statics -> ONE jitted shard_map program of the staged route
+        #: (`_staged_program`); shapes are the jit's own cache key, so
+        #: the programs outlive a commit and a re-partition
+        self._staged_programs: Dict[Tuple, object] = {}
         self._reset_delta_state()
 
     def __repr__(self):
@@ -484,6 +491,18 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
 
     # -- sharded pipeline --------------------------------------------------
 
+    def _staged_program(self, key, kernel, n_in, n_out, replicated_in=()):
+        """The staged route's one door to a mesh program: `kernel` over
+        slab-stacked operands (parallel/mesh.py `table_program`), kept
+        under `key`, which holds every static the kernel closes over: a
+        capacity retry and a second query of a shape run the program the
+        first one compiled."""
+        fn = self._staged_programs.get(key)
+        if fn is None:
+            fn = table_program(self.mesh, kernel, n_in, n_out, replicated_in)
+            self._staged_programs[key] = fn
+        return fn
+
     def _term_table(self, plan: qc.TermPlan) -> Optional[ShardedTable]:
         sb = self.tables.buckets.get(plan.arity)
         if sb is None:
@@ -504,22 +523,24 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
             fixed = ()
 
         cap = min(self.config.initial_result_capacity, max(sb.m_local, 16))
-        spec = P(SHARD_AXIS)
+        fixed_pos = tuple(p for p, _ in fixed)
+        probe_key = np.asarray(probe_key, dtype=np.int64)
+        fixed_vals = np.asarray([v for _, v in fixed], dtype=np.int32)
         while True:
-            fn = shard_map(
+            fn = self._staged_program(
+                ("term", fixed_pos, cap, plan.var_cols, plan.eq_pairs),
                 partial(
                     _probe_kernel,
-                    probe_key=probe_key,
-                    fixed=fixed,
+                    fixed_pos=fixed_pos,
                     cap=cap,
                     var_cols=plan.var_cols,
                     eq_pairs=plan.eq_pairs,
                 ),
-                mesh=self.mesh,
-                in_specs=(spec, spec, spec, spec),
-                out_specs=(spec, spec, spec),
+                n_in=6, n_out=3, replicated_in=(4, 5),
             )
-            vals, mask, range_counts = fn(key_sorted, perm, sb.targets, sb.type_id)
+            vals, mask, range_counts = fn(
+                key_sorted, perm, sb.targets, sb.type_id, probe_key, fixed_vals
+            )
             worst = int(np.max(np.asarray(range_counts)))
             if worst <= cap:
                 count = int(np.asarray(mask).sum())
@@ -545,10 +566,11 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
         out_names = left.var_names + tuple(
             v for v in right.var_names if v not in left.var_names
         )
-        spec = P(SHARD_AXIS)
         cap = max(64, min(left.count * right.count, self.config.initial_result_capacity))
         while True:
-            def kernel(lv, lm, rv, rm):
+            # `cap` is bound here, not read from this frame: the kept
+            # program may trace again (a new shape) after the loop moved on
+            def kernel(lv, lm, rv, rm, cap=cap):
                 # broadcast-right: gather the full right table to this shard
                 with jax.named_scope("mesh.all_gather_right"):
                     rv_full = jax.lax.all_gather(rv[0], SHARD_AXIS, tiled=True)
@@ -558,11 +580,8 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
                 )
                 return vals[None], valid[None], total[None]
 
-            fn = shard_map(
-                kernel,
-                mesh=self.mesh,
-                in_specs=(spec, spec, spec, spec),
-                out_specs=(spec, spec, spec),
+            fn = self._staged_program(
+                ("join", pairs, extra, cap), kernel, n_in=4, n_out=3
             )
             vals, valid, totals = fn(left.vals, left.valid, right.vals, right.valid)
             worst = int(np.max(np.asarray(totals)))
@@ -581,7 +600,6 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
             (left.var_names.index(v), tabu.var_names.index(v))
             for v in tabu.var_names
         )
-        spec = P(SHARD_AXIS)
 
         def kernel(lv, lm, rv, rm):
             with jax.named_scope("mesh.all_gather_tabu"):
@@ -589,9 +607,7 @@ class ShardedDB(IncrementalCommitMixin, MemoryDB):
                 rm_full = jax.lax.all_gather(rm[0], SHARD_AXIS, tiled=True)
             return _anti_join_impl(lv[0], lm[0], rv_full, rm_full, pairs)[None]
 
-        fn = shard_map(
-            kernel, mesh=self.mesh, in_specs=(spec, spec, spec, spec), out_specs=spec
-        )
+        fn = self._staged_program(("anti", pairs), kernel, n_in=4, n_out=1)
         valid = fn(left.vals, left.valid, tabu.vals, tabu.valid)
         return ShardedTable(
             left.var_names, left.vals, valid, int(np.asarray(valid).sum())

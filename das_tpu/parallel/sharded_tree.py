@@ -12,8 +12,13 @@ Representation: a sharded CTable holds GLOBAL jax.Arrays of shape
 [S*cap, k] with `NamedSharding(mesh, P("shards"))` on the row axis — each
 shard owns a contiguous [cap, k] block.  Row-wise mask algebra
 (ops/composite.py) runs eagerly on these arrays with sharding propagation
-(no collectives: every mask is per-row).  Cross-row combinators go through
-shard_map:
+(no collectives: every mask is per-row; these are the only per-primitive
+dispatches left on this route).  Cross-row combinators and leaf probes are
+COMPILED programs: each is one `jax.jit(shard_map(...))` built by `_smap`
+(parallel/mesh.py `table_program`) and kept in `_fn_cache` under its
+statics (pairs, extra, cap, perm, arity, counts), so a second query of a
+shape, and a capacity retry back at a capacity already seen, dispatch one
+cached executable:
 
   * leaf probes  — slab-local searchsorted over the ShardedBucket probe
                    indexes (ZERO communication; each link lives on exactly
@@ -47,11 +52,9 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from das_tpu.core.exceptions import CapacityOverflowError
-from das_tpu.parallel.mesh import SHARD_AXIS
+from das_tpu.parallel.mesh import SHARD_AXIS, table_program
 from das_tpu.ops import composite as comp_ops
 from das_tpu.ops import posting
 from das_tpu.ops.join import _anti_join_impl, _dedup_table_impl, _join_tables_impl
@@ -71,23 +74,20 @@ class ShardedTreeOps(TreeOps):
         #: freed id can never be recycled onto a different table (a bare
         #: id-keyed cache silently returned the previous query's rows)
         self._replicated: Dict[int, Tuple[CTable, CTable]] = {}
-        #: static-params -> shard_map-wrapped callable; a fresh closure per
-        #: call would defeat JAX's function-identity dispatch cache on every
-        #: join/dedup/anti/replicate of every query node
+        #: static-params -> ONE jitted shard_map program (`_smap`).  The
+        #: key holds every static the body closes over; data rides as
+        #: operands.  A bare shard_map is not compiled as a whole (its
+        #: body dispatches primitive by primitive), and a fresh jit per
+        #: call would compile on every join/dedup/anti/replicate of
+        #: every query node
         self._fn_cache: Dict[Tuple, object] = {}
 
     # -- shard_map plumbing ------------------------------------------------
 
-    def _smap(self, fn, n_in, n_out, replicated_in=()):
-        spec = P(SHARD_AXIS)
-        in_specs = tuple(
-            P() if i in replicated_in else spec for i in range(n_in)
-        )
-        out_specs = tuple(spec for _ in range(n_out))
-        return shard_map(
-            fn, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs if n_out > 1 else out_specs[0],
-        )
+    def _smap(self, fn, n_in, n_out, **specs):
+        """The one door to a mesh table program (parallel/mesh.py
+        `table_program`: `fn` over row-sharded operands, jitted whole)."""
+        return table_program(self.mesh, fn, n_in, n_out, **specs)
 
     def _cached(self, key, build):
         fn = self._fn_cache.get(key)
@@ -371,13 +371,10 @@ class ShardedTreeOps(TreeOps):
                     full = jax.lax.all_gather(packed, SHARD_AXIS, tiled=True)
                 return full[:, :-1], full[:, -1] != 0
 
-            spec = P(SHARD_AXIS)
-            return shard_map(
-                body, mesh=self.mesh, in_specs=(spec, spec),
-                out_specs=(P(), P()),
-                # tiled all_gather IS replication; the static VMA checker
-                # just cannot prove it — outputs are identical per shard
-                check_vma=False,
+            # tiled all_gather IS replication; the static VMA checker
+            # just cannot prove it — outputs are identical per shard
+            return self._smap(
+                body, 2, 2, replicated_out=True, check_vma=False
             )
 
         return self._cached(("replicate",), build)

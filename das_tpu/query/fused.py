@@ -783,8 +783,7 @@ def settle_pending(results_cache, pending) -> List:
 EXACT_TERM_CAP_LIMIT = 1 << 20
 
 #: host fetches of device results — each one is a host sync that waits
-#: for the device, so bench.py reports fetches-per-query to decompose
-#: host-visible latency
+#: for the device; fetches per query decompose host-visible latency
 FETCH_COUNTS = {"n": 0}
 
 #: the CLOSED set of scopes allowed to call jax.device_get (daslint
@@ -792,7 +791,7 @@ FETCH_COUNTS = {"n": 0}
 #: attribute to their outermost enclosing function, qualified by module
 #: stem (package name for __init__ modules).  Every entry must both
 #: contain a device_get AND tally FETCH_COUNTS (starcount tallies its
-#: own FETCHES, folded into bench the same way) — "one transfer per
+#: own FETCHES, read the same way) — "one transfer per
 #: settle round" is only a checkable contract if the transfer sites are
 #: enumerable and the telemetry cannot undercount.  Adding a fetch site
 #: means adding it here, under review, with its RTT story.
@@ -2954,203 +2953,6 @@ class FusedExecutor:
             if max(new_tc + new_cc) > cfg.max_result_capacity:
                 return None, term_caps, caps
             term_caps, caps = new_tc, new_cc
-
-    def build_count_loop(self, plans_list):
-        """ONE device program that runs the given same-shape count queries
-        SEQUENTIALLY (`lax.fori_loop`) and returns every count — a single
-        dispatch and a single host fetch regardless of the loop width.
-
-        This is the device-latency probe: a host-visible per-query timing
-        includes dispatch, the host sync and the transfer.  Here the wall
-        time of two different loop widths differs only by device compute:
-        (t_W2 - t_W1) / (W2 - W1) is per-query device latency with the
-        host's share excluded.  A loop-carried zero (`counts.sum() & 0`) is
-        mixed into constant probe keys so XLA cannot hoist iterations of
-        identical queries out of the loop.
-
-        Returns (run, W): run() dispatches once and fetches (counts[W],
-        stats_max) as host arrays; stats_max lets the caller verify no
-        in-loop capacity overflow or reseed flag invalidated the counts.
-        Raises ValueError when the queries do not share one fused shape.
-        """
-        prepared = []
-        same_order = []
-        for plans in plans_list:
-            ordered = self._count_order(plans)
-            mapped = [self._term_args(p) for p in self._canonical_plans(ordered)]
-            if any(m is None for m in mapped):
-                raise ValueError("plan not fused-executable")
-            same_order.append(self._same_positive_order(ordered, plans))
-            prepared.append((
-                tuple(m[0] for m in mapped),
-                tuple(m[1] for m in mapped),
-                tuple(m[2] for m in mapped),
-                tuple(m[3] for m in mapped),
-                tuple(self._estimate(p) for p in ordered),
-            ))
-        sigs = prepared[0][0]
-        if any(p[0] != sigs for p in prepared):
-            raise ValueError("queries must share one fused shape")
-        n_terms = len(sigs)
-        term_caps = tuple(
-            _pow2_at_least(max(p[4][t] for p in prepared))
-            for t in range(n_terms)
-        )
-        index_joins, index_right, arrays, term_caps = self._apply_index_joins(
-            sigs, prepared[0][1], term_caps
-        )
-        n_joins = max(0, sum(1 for s in sigs if not s.negated) - 1)
-        cap0 = self._group_cap_seed(sigs, [p[4] for p in prepared])
-        join_caps = tuple([cap0] * n_joins)
-        learned = self._learned_caps(
-            self._caps, self._cap_store, sigs,
-            (len(term_caps), len(join_caps)),
-        )
-        if learned is not None:
-            term_caps = self._clamp_index_terms(
-                tuple(max(a, b) for a, b in zip(term_caps, learned[0])),
-                index_right,
-            )
-            join_caps = tuple(max(a, b) for a, b in zip(join_caps, learned[1]))
-        # same ceiling rule as execute(): merged caps (incl. CapStore
-        # imports from a process with a larger configured maximum) must
-        # not build an oversized program
-        if max(term_caps + join_caps, default=0) > self.db.config.max_result_capacity:
-            raise ValueError("count loop exceeds max_result_capacity")
-        W = len(prepared)
-        keys_stacked, key_axes = zip(*(
-            stack_or_const([p[2][t] for p in prepared])
-            for t in range(n_terms)
-        ))
-        fvals_stacked, fval_axes = zip(*(
-            stack_or_const([p[3][t] for p in prepared])
-            for t in range(n_terms)
-        ))
-        keys_elem = tuple(
-            k if ax is None else k[:1][0]
-            for k, ax in zip(keys_stacked, key_axes)
-        )
-        fvals_elem = tuple(
-            f if ax is None else f[:1][0]
-            for f, ax in zip(fvals_stacked, fval_axes)
-        )
-
-        def make_run(term_caps, join_caps, barrier=False):
-            plan_sig = FusedPlanSig(sigs, term_caps, join_caps, index_joins)
-            fn, _ = build_fused(plan_sig, count_only=True)
-            if barrier:
-                # explicit optimization barriers split the loop body's
-                # fused cluster: the TPU compiler's scoped-vmem budget can
-                # overflow when the whole count body fuses INSIDE a
-                # fori_loop even though the identical body compiles
-                # standalone.  (jax.checkpoint is a no-op here — remat
-                # emits its barrier only under differentiation.)
-                inner = fn
-
-                def fn(arrays_, keys_, fvals_):
-                    keys_ = jax.lax.optimization_barrier(keys_)
-                    fvals_ = jax.lax.optimization_barrier(fvals_)
-                    return jax.lax.optimization_barrier(
-                        inner(arrays_, keys_, fvals_)
-                    )
-
-            n_stats = int(
-                jax.eval_shape(fn, arrays, keys_elem, fvals_elem).shape[0]
-            )
-
-            @jax.jit
-            @obs.named_program("das_count_loop")
-            def looped(arrays, keys_stacked, fvals_stacked):
-                def body(i, carry):
-                    counts, flags, mx = carry
-                    dep = counts.sum() & jnp.int64(0)  # loop-carried zero
-                    keys_i = tuple(
-                        k[i] if ax is not None
-                        else jnp.asarray(k) + dep.astype(jnp.asarray(k).dtype)
-                        for k, ax in zip(keys_stacked, key_axes)
-                    )
-                    fv_i = tuple(
-                        f[i] if ax is not None else f
-                        for f, ax in zip(fvals_stacked, fval_axes)
-                    )
-                    stats = fn(arrays, keys_i, fv_i)
-                    counts = counts.at[i].set(stats[0].astype(jnp.int64))
-                    flags = flags.at[i].set(
-                        (stats[1] + 2 * stats[2]).astype(jnp.int32)
-                    )
-                    mx = jnp.maximum(mx, stats.astype(jnp.int64))
-                    return counts, flags, mx
-
-                init = (
-                    jnp.zeros(W, dtype=jnp.int64),
-                    jnp.zeros(W, dtype=jnp.int32),
-                    jnp.zeros(n_stats, dtype=jnp.int64),
-                )
-                return jax.lax.fori_loop(0, W, body, init)
-
-            looped = obs.proflog.instrument(
-                "count_loop",
-                obs.proflog.sig_digest(plan_sig, W, barrier),
-                looped,
-            )
-
-            def run():
-                FETCH_COUNTS["n"] += 1
-                counts, flags, mx = looped(arrays, keys_stacked, fvals_stacked)
-                return np.asarray(counts), np.asarray(flags), np.asarray(mx)
-
-            return run
-
-        # settle capacities like execute()'s retry loop — but ACROSS the
-        # whole width, so the timed runs never truncate a join silently
-        barrier = os.environ.get("DAS_TPU_LOOP_BARRIER", "0") == "1"
-        while True:
-            # no retry around the compile: on a local chip the compiler's
-            # own message arrives (the r03 scoped-vmem overflow would name
-            # itself), and the un-barriered loop compiled and ran on the
-            # v5e at the smoke's shapes (chip_smoke.py counts phase, PR 22);
-            # DAS_TPU_LOOP_BARRIER=1 remains the explicit debug switch
-            runner = make_run(term_caps, join_caps, barrier=barrier)
-            counts, flags, mx = runner()
-            ranges = mx[3 : 3 + n_terms]
-            totals = mx[3 + n_terms :]
-            new_tc = tuple(
-                _pow2_at_least(int(r)) if int(r) > c else c
-                for r, c in zip(ranges, term_caps)
-            ) if ranges.size else term_caps
-            new_jc = tuple(
-                _pow2_at_least(int(t)) if int(t) > c else c
-                for t, c in zip(totals, join_caps)
-            ) if totals.size else join_caps
-            if new_tc == term_caps and new_jc == join_caps:
-                break
-            if max(new_tc + new_jc, default=0) > self.db.config.max_result_capacity:
-                raise ValueError("count loop exceeds max_result_capacity")
-            term_caps, join_caps = new_tc, new_jc
-        # reference-semantics guard — the same per-row verdicts
-        # count_batch honors: a raised reseed flag, or a zero count the
-        # greedy reordering cannot certify (no empty positive term and not
-        # reference order), means the loop would time a program computing
-        # WRONG numbers — refuse instead
-        n_positive = sum(1 for s in sigs if not s.negated)
-        for i in range(W):
-            reseed, pos_empty = bool(flags[i] & 1), bool(flags[i] & 2)
-            if reseed:
-                raise ValueError("count loop hit the reseed quirk; not loopable")
-            if (
-                int(counts[i]) == 0
-                and n_positive > 1
-                and not pos_empty
-                and not same_order[i]
-            ):
-                raise ValueError("count loop has an ambiguous zero; not loopable")
-
-        def run():
-            counts, _flags, mx = runner()
-            return counts, mx
-
-        self._remember_caps(sigs, term_caps, join_caps)
-        return run, W
 
     @staticmethod
     def _structural_key(p):

@@ -210,7 +210,7 @@ class QueryCoalescer:
         # DAS_TPU_PIPELINE_DEPTH / DAS_TPU_PIPELINE_DEPTH_MAX /
         # DAS_TPU_COALESCE_QUEUE_MAX / DAS_TPU_DEADLINE_MS /
         # DAS_TPU_BREAKER_*) — ONE source of truth for the
-        # served path's throughput knobs (BENCH_r05: per-query cost
+        # served path's throughput knobs (pre-PR-1 chip records: per-query cost
         # halves as concurrency doubles, so the ceiling decides the
         # batched regime; the depth window decides how full the device
         # queue stays); a bare QueryCoalescer() therefore tracks the

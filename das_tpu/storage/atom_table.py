@@ -99,7 +99,7 @@ class LinkBucket:
         computed once per segment and cached, so grounded trivial counts
         (fused.py trivial_plan_count) skip their per-row dangling scan for
         segments known clean even when dangling hexes exist elsewhere in
-        the store (ADVICE r4).  Segments are rebuilt on commit, so the
+        the store (round-4 review).  Segments are rebuilt on commit, so the
         cache can never go stale."""
         return bool((self.targets < 0).any())
 

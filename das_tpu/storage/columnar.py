@@ -521,7 +521,7 @@ def attach_columnar(data: AtomSpaceData, core: ColumnarCore) -> AtomSpaceData:
 
         # one probe per type name: amortize the blocking index build up
         # front rather than risk O(types x nodes) linear scans when the
-        # background build has not landed yet (ADVICE r4)
+        # background build has not landed yet (round-4 review)
         core.wait_indexes()
         best = None  # (node row, type name)
         for tname in core.type_names:

@@ -140,14 +140,6 @@ def snapshot_stats() -> Dict[str, object]:
     return dict(DUR_STATS)
 
 
-def reset_stats() -> None:
-    """Zero the counters (bench/test arms start from a clean window)."""
-    DUR_STATS.update(
-        generation=0, snapshots=0, wal_records=0, recovery_replayed=0,
-        torn_tail_truncations=0, corrupt_generations=0, last_restore_s=None,
-    )
-
-
 # -- atomic write ------------------------------------------------------------
 
 

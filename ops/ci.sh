@@ -37,14 +37,7 @@ echo "daslint SARIF: $SARIF_OUT"
 #    exporters, DL014; fault: chaos-parity sweep, deadlines, breaker
 #    lifecycle, commit atomicity, DL015; prof: program-ledger
 #    lifecycle, explain(compile=True), byte-model calibration,
-#    bench_diff gate, DL016; dur: crash-point matrix over the persist
+#    DL016; dur: crash-point matrix over the persist
 #    fault sites, torn-tail WAL truncation, corrupt-generation
 #    fallback, warm-restore pins, DL017)
 python -m pytest tests/ -q -m "lint or obs or fault or prof or dur"
-
-# 3. the bench-history regression gate (ISSUE 14): the newest committed
-#    record must pass against its own prior trajectory, proving the
-#    parser reads every record and the committed history is
-#    self-consistent — a fresh device record is gated the same way
-#    before it lands
-python scripts/bench_diff.py --self-check

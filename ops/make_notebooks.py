@@ -209,8 +209,8 @@ SIMPLE_PATTERN_MINER = [
      "db = TensorDB(data, DasConfig())\n"
      "db.prefetch()"),
     ("md", "Atom counts for this run (the reference's cell 0 prints its "
-     "FlyBase store: `(2584508, 27871440)`; bench.py's flybase section "
-     "measures that scale on real hardware):"),
+     "FlyBase store: `(2584508, 27871440)`; chip_smoke.py loads that "
+     "shape times `--scale` on the chip):"),
     ("code", "db.count_atoms()"),
     ("md",
      "**Halo expansion** — all links within 2 hops of three seed genes.  "

@@ -60,8 +60,8 @@ if [[ "${1:-}" == "fault" ]]; then
 fi
 # `ops/pytests.sh prof` runs the dasprof program-ledger suite standalone
 # (ledger lifecycle on both backends, disabled-path identity pin,
-# explain(compile=True) shape, byte-model calibration sanity, the
-# bench_diff regression-gate unit cases, DL016 fixtures).
+# explain(compile=True) shape, byte-model calibration sanity, DL016
+# fixtures).
 if [[ "${1:-}" == "prof" ]]; then
   shift
   exec python -m pytest tests/ -q -m prof "$@"

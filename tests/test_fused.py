@@ -277,39 +277,6 @@ def test_index_join_routing_and_parity(tdb, ex):
     assert res is not None and res.count == len(host.assignments)
 
 
-def test_count_loop_matches_individual(tdb, ex):
-    """The single-dispatch fori_loop count program (bench.py's device-only
-    latency probe) returns exactly the per-query device counts for both
-    distinct grounded queries and identical repeated queries, with
-    capacities settled in-builder (no silent truncation)."""
-    grounded = [
-        And([
-            Link("Inheritance", [Node("Concept", name), Variable("V1")], True),
-            Link("Inheritance", [Variable("V1"), Variable("V2")], True),
-        ])
-        for name in ("human", "monkey", "chimp", "rhino")
-    ]
-    plans_list = [compiler.plan_query(tdb, q) for q in grounded]
-    assert all(p is not None for p in plans_list)
-    run, w = ex.build_count_loop(plans_list)
-    counts, mx = run()
-    assert w == 4
-    for got, q in zip(counts, grounded):
-        assert got == compiler.count_matches(tdb, q)
-
-    # identical repeats: the loop-carried dependence defeats hoisting and
-    # every iteration reports the same exact count
-    q = And([
-        Link("Inheritance", [Variable("V1"), Variable("V2")], True),
-        Link("Inheritance", [Variable("V2"), Variable("V3")], True),
-    ])
-    p = compiler.plan_query(tdb, q)
-    expected = compiler.count_matches(tdb, q)
-    run, w = ex.build_count_loop([p] * 8)
-    counts, _ = run()
-    assert list(counts) == [expected] * 8
-
-
 # -- host single-term counting (the miner's candidate shape) ----------------
 
 

@@ -412,7 +412,7 @@ def test_skewed_kb_star_counts_match_host(monkeypatch):
 
 
 def test_evict_oldest_is_fifo_and_partial():
-    """ADVICE r4: cache eviction keeps the newest entries of the matching
+    """Round-4 review: cache eviction keeps the newest entries of the matching
     class (FIFO over dict insertion order) instead of wiping the class,
     and never touches non-matching keys."""
     cache = {}

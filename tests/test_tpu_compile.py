@@ -1,25 +1,28 @@
-"""AOT compiles for a DESCRIBED TPU v5e — the one file of this kind.
+"""AOT compiles for a DESCRIBED TPU v5e: the one-chip query programs.
 
 The CPU suite cannot see what the chip's compiler refuses: r03's
 fori_loop count body died on the chip with "reduce-window ... exceeded
 scoped vmem limit" while the identical program ran everywhere else, and
 every Pallas kernel the repo once had passed its interpret-mode tests
 while Mosaic refused all of them (they were deleted in PR 31; the
-verdict test is in this file's history).  The TPU compiler is installed in the sandbox and
-compiles for a chip that is described, not attached; these cases hand it
-the programs of the served path at the shapes `chip_smoke.py` runs
-(FlyBase shape x 0.1) — shapes only, nothing executes, no chip needed.
+verdict test is in this file's history).  These cases hand the chip's
+compiler the programs of the served path at the shapes `chip_smoke.py`
+and the benchmark's cells run: shapes only, nothing executes, no chip
+needed.
 
-Rules this file keeps (on-chip-measurement guide, section 2): the
-topology is described inside a module-scoped fixture that skips when it
-cannot be — never while a module is imported, not autouse, not in
-conftest.py; no child process; all such tests live in THIS one file (a
-second file could land on another xdist worker, whose fixture would then
-skip in silence); the persistent compilation cache is off around the
-compiles (an entry written for a described device cannot be read back).
+Three files by what they compile, so that no one file is the suite's
+wall under `--dist loadfile` (PR 50): this one (the one-chip query
+programs, the first join's expansion alone, and the accepted cells'
+programs letter for letter), `test_tpu_compile_mesh.py` (the mesh
+programs) and `test_tpu_compile_ops.py` (the staged join at the largest
+capacity, the commit's programs).  `tests/described_v5e.py` holds the
+fixtures and the shape helpers they share, and the rules they keep.
+Only one process at a time may load the TPU's library: under several
+xdist workers the files can land on different ones, and then only the
+first describes the topology and the others SKIP, unless the run sets
+`ALLOW_MULTIPLE_LIBTPU_LOAD=1` as the driver's tier-1 command does.
 """
 
-import collections
 import dataclasses
 import os
 
@@ -29,178 +32,27 @@ import numpy as np
 import pytest
 
 from das_tpu.core.config import DasConfig
-from das_tpu.obs.registry import INDEX_JOIN_SCOPE, INDEX_SEARCH_SCOPE
-from das_tpu.storage.delta import capacity_class, delta_class
+from das_tpu.storage.delta import capacity_class
+from tests.described_v5e import (  # noqa: F401  (fixtures by name)
+    SMOKE_ARITY2_CAPACITY,
+    _as_shape,
+    _assert_the_first_join_searches_by_rows,
+    _lower_on_described_mesh,
+    _shape,
+    _table,
+    _three_var_plans,
+    _tiny_store_and_query,
+    compile_for_chip,
+    no_persistent_cache,
+    one_chip,
+    topo,
+)
+from tests.test_tpu_compile_mesh import _cell3_job
 
-#: chip_smoke.py's default store: links of arity 2 at --scale 0.1
-#: (2.4 M Member + ~0.3 M Interacts + 43.5 k List + 43.5 k Evaluation)
-SMOKE_ARITY2_ROWS = 2_786_998
-SMOKE_ARITY2_CAPACITY = capacity_class(SMOKE_ARITY2_ROWS)
 #: capacities the executor settles on there for the grounded 3-clause
 #: conjunction (recorded from a CPU run of chip_smoke's phases at 0.1)
 SMOKE_TERM_CAPS = (16, 16, 16)
 SMOKE_JOIN_CAPS = (2048, 64)
-#: cell 2 of the benchmark (`wal-mixed95-closed`, FlyBase shape x 0.1):
-#: the arity-2 bucket's capacity there
-CELL2_ARITY2_CAPACITY = 2_961_251
-
-
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    from jax.sharding import SingleDeviceSharding
-
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def no_persistent_cache():
-    """A compile for a described device is written to the persistent
-    cache but cannot be read back without a chip — keep it off here."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def compile_for_chip(one_chip, no_persistent_cache):
-    def compile_(fn, *shapes):
-        placed = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-            shapes,
-        )
-        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-        return jitted.lower(*placed).compile()
-
-    return compile_
-
-
-def _shape(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _table(rows, cols):
-    return _shape((rows, cols), jnp.int32), _shape((rows,), jnp.bool_)
-
-
-# -- the lowered route: the one that must compile -------------------------
-
-
-def test_lowered_join_at_max_capacity(compile_for_chip):
-    """The pair-expansion join at the largest capacity class the config
-    allows (the scoped-vmem-sensitive int64 cumsum scales with the LEFT
-    table, the cummax with the output capacity — the r03 failure mode,
-    das_tpu/ops/join.py)."""
-    from das_tpu.ops.join import _join_tables_impl
-
-    cap = int(DasConfig().max_result_capacity)
-    lv, lm = _table(1 << 16, 3)
-    rv, rm = _table(1 << 20, 2)
-
-    def f(lv, lm, rv, rm):
-        return _join_tables_impl(lv, lm, rv, rm, ((0, 0),), (1,), cap)
-
-    compile_for_chip(f, lv, lm, rv, rm)
-
-
-def _tiny_store_and_query(make_db, n_clauses=3):
-    """A tiny CPU store and the smoke's grounded 3-clause conjunction on
-    it (or its first `n_clauses`: two are the benchmark's `shared2`): the
-    plan signature is scale-free, only capacities and bucket lengths
-    grow with the KB."""
-    from das_tpu.models.bio import build_bio_atomspace
-    from das_tpu.query import compiler
-    from das_tpu.query.ast import And, Link, Node, Variable
-
-    data, _, _ = build_bio_atomspace(
-        n_genes=400, n_processes=40, members_per_gene=10,
-        n_interactions=300, n_evaluations=60, seed=0,
-    )
-    db = make_db(data)
-    g = db.get_all_nodes("Gene", names=True)[0]
-    query = And([
-        Link("Member", [Node("Gene", g), Variable("V3")], True),
-        Link("Member", [Variable("V2"), Variable("V3")], True),
-        Link("Interacts", [Node("Gene", g), Variable("V2")], True),
-    ][:n_clauses])
-    return db, compiler.plan_query(db, query)
-
-
-def _lower_on_described_mesh(topo, job, sig, per_shard, group=None,
-                             count_only=False):
-    return _trace_on_described_mesh(
-        topo, job, sig, per_shard, group, count_only).lower()
-
-
-def _trace_on_described_mesh(topo, job, sig, per_shard, group=None,
-                             count_only=False):
-    """The fused shard_map program of `sig`, traced against a Mesh
-    built from the described v5e:2x2 devices, the job's row-sharded
-    bucket arrays stretched to `per_shard` rows a shard.
-    `group`: `(count_only, lanes)` for the GROUP program over `lanes`
-    lanes of the job's inputs, every lane its own gene; else the lone
-    program, `count_only` or not."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from das_tpu.parallel import fused_sharded as fs
-    from das_tpu.parallel.mesh import SHARD_AXIS
-    from das_tpu.query import fused
-
-    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
-    sharded, replicated = NamedSharding(mesh, P(SHARD_AXIS)), NamedSharding(mesh, P())
-
-    def slab(a):  # [S, m(, a)] -> the store's per-shard rows
-        return jax.ShapeDtypeStruct(
-            (4, per_shard, *a.shape[2:]), a.dtype, sharding=sharded
-        )
-
-    def scalar_or_vec(x):
-        x = np.asarray(x)
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated)
-
-    keys, fvals = job.keys, job.fvals
-    if group is None:
-        fn, _names = fs.build_fused_sharded(sig, mesh, count_only)
-    else:
-        count_only, lanes = group
-        # the lanes' inputs as dispatch_group stacks them: the grounded
-        # terms' probe keys differ a lane, the whole-type term's key is
-        # hoisted
-        hoisted = sig.index_joins.index(1) + 1
-        keys, key_axes, fvals, fval_axes = fused.stack_lanes(
-            [tuple(np.asarray(k) + (i if t != hoisted else 0)
-                   for t, k in enumerate(job.keys)) for i in range(lanes)],
-            [job.fvals] * lanes, lanes,
-        )
-        assert None in key_axes and 0 in key_axes
-        fn, _names = fs.build_fused_sharded_group(
-            sig, mesh, count_only, key_axes, fval_axes)
-    return jax.jit(fn).trace(
-        jax.tree.map(slab, job.arrays),
-        jax.tree.map(scalar_or_vec, keys),
-        jax.tree.map(scalar_or_vec, fvals),
-    )
-
-
-def _compile_on_described_mesh(topo, job, sig, per_shard, group=None):
-    return _lower_on_described_mesh(topo, job, sig, per_shard, group).compile()
 
 
 @pytest.fixture(scope="module")
@@ -277,11 +129,6 @@ def cell1_jobs():
         jobs[shape] = get_executor(db)._exec_job(plans, False)
         assert jobs[shape] is not None
     return jobs
-
-
-def _as_shape(x):
-    x = np.asarray(x)
-    return _shape(x.shape, x.dtype)
 
 
 def _cell1_program(job, shape, count_only, group):
@@ -370,25 +217,6 @@ ANALYTIC_ARITY2_ROWS = int(27_870_000 * ANALYTIC_SCALE)
 ANALYTIC_CAPS = dict(term_caps=(1 << 19, 16, 16), join_caps=(1 << 22, 4096))
 
 
-def _three_var_plans(make_db):
-    """A tiny store and the all-variable 3-clause conjunction's plans
-    on it (the benchmark's `three_var`)."""
-    from das_tpu.models.bio import build_bio_atomspace
-    from das_tpu.query import compiler
-    from das_tpu.query.ast import And, Link, Variable
-
-    data, _, _ = build_bio_atomspace(
-        n_genes=60, n_processes=12, members_per_gene=3, n_interactions=40,
-        seed=5)
-    db = make_db(data)
-    v = Variable
-    return db, list(compiler.plan_query(db, And([
-        Link("Interacts", [v("V1"), v("V2")], True),
-        Link("Member", [v("V1"), v("V3")], True),
-        Link("Member", [v("V2"), v("V3")], True),
-    ])))
-
-
 def _analytic_program():
     """(jitted `das_fused`, its argument shapes, the bucket's capacity)
     of the all-variable conjunction at cell `mem-analytic`'s shapes."""
@@ -441,199 +269,6 @@ def test_fused_three_var_at_the_analytic_cells_shapes(compile_for_chip):
     assert "i64" not in types
     _assert_the_first_join_searches_by_rows(
         jax.make_jaxpr(fn)(*shapes).jaxpr, cap, ANALYTIC_CAPS["term_caps"][0])
-
-
-#: the running sums, maxima and minima the parent's first join holds
-#: (tree 6fda653, the one-chip program and a shard's alike): the
-#: reverse minimum behind `run_end`, the prefix sum's two 32-bit sums,
-#: the slot owner's maximum.  One more over 0.5-4 M elements is 7-50 s
-#: of a first request's compile (ops/join.py SLOW_SCAN_ROWS)
-PARENT_FIRST_JOIN_SCANS = {"cummin": 1, "cumsum": 2, "cummax": 1}
-
-
-def _assert_the_first_join_searches_by_rows(jaxpr, n_keys, n_left):
-    """The FIRST join (524,288 left rows into the 2.96 M-key index; a
-    shard's 1,048,576 into 2.22 M) holds NO loop: the 22 dependent
-    one-word gathers of its binary search were 39-44 % of the program
-    (PERF.md section 6, PR 49).  Under `join.index_search` it holds ONE
-    gather of a row of `SEARCH_FANOUT` int32 words a level below the
-    root and nothing else that reads by index, and the join as a whole
-    no sort and no running sum, maximum or minimum the parent's
-    lacks."""
-    from das_tpu.ops.join import SEARCH_FANOUT, _search_levels
-
-    joined = _primitives_under(jaxpr, INDEX_JOIN_SCOPE)
-    counts = collections.Counter(eqn.primitive.name for eqn in joined)
-    assert not {"while", "scan", "sort"} & set(counts)
-    assert {name: counts[name] for name in counts
-            if name.startswith("cum")} == PARENT_FIRST_JOIN_SCANS
-    searched = _primitives_under(jaxpr, INDEX_SEARCH_SCOPE)
-    assert {id(eqn) for eqn in searched} <= {id(eqn) for eqn in joined}
-    gathers = [eqn for eqn in searched if eqn.primitive.name == "gather"]
-    levels = _search_levels(n_keys)
-    assert len(gathers) == len(levels) - 1 and len(levels) <= 6
-    for eqn, rows in zip(gathers, levels[-2::-1]):      # from the top down
-        assert eqn.invars[0].aval.shape == (rows, SEARCH_FANOUT)
-        assert eqn.invars[0].aval.dtype == jnp.int32
-        assert eqn.outvars[0].aval.shape == (n_left, SEARCH_FANOUT)
-    assert not [eqn for eqn in searched
-                if eqn.primitive.name in ("scatter", "dynamic_slice")]
-    # no 64-bit element is read by index anywhere in the join: on the
-    # chip an int64 gather is two u32 gathers (PERF.md section 6, PR 45)
-    for eqn in joined:
-        if eqn.primitive.name == "gather":
-            assert eqn.invars[0].aval.dtype != jnp.int64
-
-
-def _inner_jaxprs(eqn):
-    for value in eqn.params.values():
-        for sub in value if isinstance(value, (list, tuple)) else [value]:
-            sub = getattr(sub, "jaxpr", sub)
-            if hasattr(sub, "eqns"):
-                yield sub
-
-
-def _primitives_under(jaxpr, scope, stack=""):
-    """The equations whose name stack, from the program's root down,
-    holds `scope`; a call's own equation (`pjit`, `shard_map`, a loop)
-    and what its bodies hold both count where they lie under it."""
-    found = []
-    for eqn in jaxpr.eqns:
-        here = f"{stack}/{eqn.source_info.name_stack}"
-        if scope in here:
-            found.append(eqn)
-        for sub in _inner_jaxprs(eqn):
-            found += _primitives_under(sub, scope, here)
-    return found
-
-
-@pytest.mark.parametrize("cap,dcap,key_dtype", [
-    (SMOKE_ARITY2_CAPACITY, delta_class(10), jnp.int64),  # the smoke's
-    (CELL2_ARITY2_CAPACITY, 64, jnp.int64),    # cell 2: 5 of a commit's 8
-    (CELL2_ARITY2_CAPACITY, 64, jnp.int32),    # cell 2: the other 3
-    (CELL2_ARITY2_CAPACITY, 65536, jnp.int64),  # the widest delta class
-    (CELL2_ARITY2_CAPACITY, 65536, jnp.int32),
-])
-def test_commit_merge_programs(compile_for_chip, cap, dcap, key_dtype):
-    """The fixed-shape sorted-index merge of one delta class into the
-    capacity-padded base (storage/tensor_db.py).  The merge builds every
-    slot by reading: the compiled program holds no scatter at any delta
-    class (a whole-table scatter was 1.27 s of device time per commit,
-    PERF.md PR 27)."""
-    from das_tpu.storage.tensor_db import _merge_padded
-
-    merge = compile_for_chip(
-        _merge_padded,
-        _shape((cap,), key_dtype), _shape((cap,), jnp.int32),
-        _shape((dcap,), key_dtype), _shape((dcap,), jnp.int32),
-    )
-    assert "scatter" not in merge.as_text()
-
-
-def test_commit_insert_program(compile_for_chip):
-    """The commit's row-block insert at a traced offset."""
-    from das_tpu.storage.tensor_db import _insert_rows
-
-    cap, dcap = SMOKE_ARITY2_CAPACITY, delta_class(10)
-    compile_for_chip(
-        _insert_rows,
-        _shape((cap, 2), jnp.int32), _shape((dcap, 2), jnp.int32),
-        _shape((), jnp.int32),
-    )
-
-
-def test_sharded_grounded3_on_described_2x2_mesh(topo, no_persistent_cache):
-    """`chip_smoke.py --chips 4`: the fused shard_map program of the
-    grounded conjunction, compiled against a Mesh built from the
-    described v5e:2x2 devices with the row-sharded bucket arrays at the
-    smoke store's per-shard size.  The collectives must be there."""
-    from das_tpu.parallel.fused_sharded import get_sharded_executor
-    from das_tpu.parallel.mesh import make_mesh
-    from das_tpu.parallel.sharded_db import ShardedDB
-
-    db, plans = _tiny_store_and_query(
-        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4))
-    )
-    job = get_sharded_executor(db)._exec_job(plans, False)
-    assert job is not None
-    sig = job.plan_sig()
-    assert sig.n_shards == 4
-    text = _compile_on_described_mesh(
-        topo, job, sig, capacity_class(-(-SMOKE_ARITY2_ROWS // 4))
-    ).as_text()
-    assert "all-gather" in text or "all-reduce" in text or "all-to-all" in text
-
-
-#: cell 3 of the benchmark (`sharded4-uniform-closed`, FlyBase shape x
-#: 0.3 on 4 shards): 8,361,000 links of arity 2 dealt round-robin, and the
-#: capacities the mesh executor holds after the cell's warm-up (recorded
-#: from a CPU run of the served path at scale 0.3 on 4 virtual devices)
-CELL3_ARITY2_ROWS = 8_361_000
-CELL3_PROGRAMS = {
-    "grounded3": dict(term_caps=(16, 16, 16), join_caps=(1024, 64),
-                      exch_caps=(0, 0), index_joins=(1, -1)),
-    "shared2": dict(term_caps=(16, 16), join_caps=(1024,),
-                    exch_caps=(0,), index_joins=(1,)),
-}
-
-
-def _cell3_job(shape):
-    """The mesh executor's own job for one of cell 3's shapes (tiny
-    store) and its signature at the cell's capacities."""
-    from das_tpu.parallel.fused_sharded import get_sharded_executor
-    from das_tpu.parallel.mesh import make_mesh
-    from das_tpu.parallel.sharded_db import ShardedDB
-
-    db, plans = _tiny_store_and_query(
-        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4)),
-        n_clauses=3 if shape == "grounded3" else 2,
-    )
-    job = get_sharded_executor(db)._exec_job(plans, False)
-    assert job is not None
-    want = CELL3_PROGRAMS[shape]
-    assert job.plan_sig().index_joins == want["index_joins"]
-    sig = dataclasses.replace(job.plan_sig(), **want)
-    assert sig.n_shards == 4
-    per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
-    assert per_shard == 2_220_890
-    return job, sig, per_shard
-
-
-@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
-def test_cell3_mesh_programs_on_described_2x2_mesh(topo, no_persistent_cache,
-                                                   shape):
-    """The two mesh programs the warm-up of `sharded4-uniform-closed`
-    builds for a job alone in its signature, at the cell's per-shard
-    table size and capacities, compiled for the described v5e:2x2: the
-    gathers of the index joins and the stats reductions (int32 `pmax`,
-    `psum`) must lower."""
-    job, sig, per_shard = _cell3_job(shape)
-    text = _compile_on_described_mesh(topo, job, sig, per_shard).as_text()
-    assert "all-gather" in text and "all-reduce" in text
-
-
-@pytest.mark.parametrize("count_only", [True, False],
-                         ids=["count_program", "result_program"])
-@pytest.mark.parametrize("shape", sorted(CELL3_PROGRAMS))
-def test_cell3_mesh_group_programs_on_described_2x2_mesh(
-        topo, no_persistent_cache, shape, count_only):
-    """`das_sharded_group` (ISSUE 43), the program a batch's
-    same-signature mesh jobs ride, at the served path's lanes and cell
-    3's shapes: the collectives lower with the lanes axis on them, and
-    the lanes add no table-sized temporary (the bucket arrays ride
-    unbatched inside the shard_map: no `[lanes, slab]` intermediate)."""
-    from das_tpu.query import fused
-
-    job, sig, per_shard = _cell3_job(shape)
-    compiled = _compile_on_described_mesh(
-        topo, job, sig, per_shard, group=(count_only, fused.GROUP_LANES))
-    text = compiled.as_text()
-    assert "all-gather" in text and "all-reduce" in text
-    assert "tpu_custom_call" not in text
-    lone = _compile_on_described_mesh(topo, job, sig, per_shard)
-    slab_bytes = per_shard * 8                  # one int64 key array
-    assert (compiled.memory_analysis().temp_size_in_bytes
-            < lone.memory_analysis().temp_size_in_bytes + slab_bytes)
 
 
 # -- the first join's expansion, alone ------------------------------------
@@ -800,76 +435,3 @@ def test_the_accepted_cells_programs_are_the_parents(
         print(f'\n    "{name}":\n        "{digest}",')
         return
     assert digest == PARENT_LOWERED[name]
-
-
-# -- cell 6: the whole-store conjunction on the mesh ----------------------
-
-#: cell `sharded4-analytic` (`flybase-sharded4-analytic`, FlyBase shape
-#: x 0.3 on 4 shards, cell 3's store): the capacities the mesh executor
-#: seeds for the all-variable conjunction there (Interacts rows a shard
-#: near their share; Interacts x Member a shard; the verified join's
-#: rows; the exchange slots of the second join, which PARTITIONS), read
-#: from the executor's own job on a CPU build of the store at 0.3 (PR
-#: 47; tests/test_mesh_analytic.py holds the rules' arithmetic)
-CELL6_CAPS = dict(term_caps=(262_144, 16, 16), join_caps=(4_194_304, 2048),
-                  exch_caps=(0, 1_048_576))
-
-
-def test_mesh_three_var_at_cell6_shapes(topo, no_persistent_cache):
-    """`das_sharded` of the all-variable conjunction at cell 6's
-    per-shard shapes, compiled for the described v5e:2x2.  What keeps
-    its FIRST compile inside the statement deadline, beyond "it
-    compiles": the verified join sorts ONCE (its two shared columns and
-    one payload), each exchange once (ONE 32-bit operand), nothing is
-    stable, no operand is 64-bit; the only 64-bit all-reduce is a Sum
-    (the chip's compiler lowers no other); and no shard holds the
-    gathered left side of the second join (4 x 4.2 M slots: 0.6 GB of
-    temporaries where the partition needs under 0.2).  And the first
-    join of a shard searches as cell 5's does: by rows, with no loop."""
-    import re
-
-    from das_tpu.parallel.fused_sharded import get_sharded_executor
-    from das_tpu.parallel.mesh import make_mesh
-    from das_tpu.parallel.sharded_db import ShardedDB
-
-    db, plans = _three_var_plans(
-        lambda data: ShardedDB(data, DasConfig(), mesh=make_mesh(4)))
-    job = get_sharded_executor(db)._exec_job(plans, False)
-    assert job.index_joins == (0, 0)
-    # at any size the first join gathers, the second partitions
-    assert job.exch_caps[0] == 0 and job.exch_caps[1] > 0
-    sig = dataclasses.replace(job.plan_sig(), **CELL6_CAPS)
-    per_shard = capacity_class(-(-CELL3_ARITY2_ROWS // 4))
-    traced = _trace_on_described_mesh(topo, job, sig, per_shard)
-    # every shard probes its slab's index with the gathered left side
-    _assert_the_first_join_searches_by_rows(
-        traced.jaxpr.jaxpr, per_shard, 4 * CELL6_CAPS["term_caps"][0])
-    lowered = traced.lower()
-    text = lowered.as_text()
-    sorts = re.findall(
-        r'"stablehlo\.sort"\(([^)]*)\) <\{([^}]*)\}>.*?\}\) : \(([^)]*)\) ->',
-        text, flags=re.S)
-    assert sorted(ops.count("%") for ops, _a, _t in sorts) == [1, 1, 3]
-    for _operands, attrs, types in sorts:
-        assert "is_stable = false" in attrs and "i64" not in types
-    assert text.count('"stablehlo.all_to_all"') == 2
-    reduces = re.findall(
-        r'"stablehlo\.all_reduce"\(.*?\^bb0\((.*?)\):\s*(.*?)stablehlo\.return',
-        text, flags=re.S)
-    assert reduces
-    for args, body in reduces:
-        if "i64" in args:
-            assert "stablehlo.add" in body
-    compiled = lowered.compile()
-    hlo = compiled.as_text()
-    assert "all-to-all" in hlo and "all-gather" in hlo
-    assert "tpu_custom_call" not in hlo
-    # beside ONE level of the first join's search: a row of
-    # SEARCH_FANOUT words a gathered left slot, live a level at a time
-    # (0.54 GB at 128; the second join's gathered left side would be
-    # 0.6 GB MORE)
-    from das_tpu.ops.join import SEARCH_FANOUT
-
-    search_rows = 4 * CELL6_CAPS["term_caps"][0] * SEARCH_FANOUT * 4
-    assert (compiled.memory_analysis().temp_size_in_bytes
-            < 300e6 + search_rows)

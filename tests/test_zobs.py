@@ -517,7 +517,7 @@ def test_obs_registries_pinned():
     # benchmark's device-time readers (benchmark/harness/readers.py)
     assert set(obs.PROGRAM_NAMES) == {
         "das_fused", "das_fused_group", "das_fused_tree",
-        "das_fused_exact", "das_count_batch", "das_count_loop",
+        "das_fused_exact", "das_count_batch",
         "das_sharded", "das_sharded_group",
         "das_sharded_tree", "das_merge_padded", "das_insert_rows",
         "das_merge_sharded",
@@ -898,8 +898,6 @@ def test_program_names_in_lowered_modules(monkeypatch):
     # two groundings of one shape: ONE group program (ISSUE 30)
     ex.execute_many([plans, compiler.plan_query(db, _pair_query())])
     ex.count_batch([plans, plans])
-    run, _w = ex.build_count_loop([plans, plans])
-    run()
     sdb = ShardedDB(load_metta_text(animals_metta()), DasConfig())
     sdas = DistributedAtomSpace(database_name="zobs-s", db=sdb)
     sdas.query(q)
@@ -919,7 +917,6 @@ def test_program_names_in_lowered_modules(monkeypatch):
         "fused_group": "jit_das_fused_group",
         "fused_exact": "jit_das_fused_exact",
         "count_batch": "jit_das_count_batch",
-        "count_loop": "jit_das_count_loop",
         "sharded": "jit_das_sharded",
         "sharded_group": "jit_das_sharded_group",
         "sharded_tree": "jit_das_sharded_tree",

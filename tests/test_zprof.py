@@ -1,6 +1,6 @@
 """dasprof program ledger (ISSUE 14): compile/cost/memory telemetry,
-byte-model calibration, the bench-history regression gate, and the
-DL016 program-site registry discipline.
+byte-model calibration, and the DL016 program-site registry
+discipline.
 
 Pins, in one place (marker `prof`, standalone via `ops/pytests.sh
 prof`):
@@ -19,9 +19,6 @@ prof`):
     `explain(compile=True)` renders it by digest;
   * cold-start accounting: a persistent-XLA-cache-served compile is
     classified as a hit and excluded from cold_start_s;
-  * scripts/bench_diff.py: the committed trajectory passes its own
-    gate, a synthetically regressed headline exits nonzero, and the
-    honesty rule (interpret records never gate device records) holds;
   * daslint DL016 — clean tree, bad/good fixtures, and a mutated-copy
     regression deleting the real build_fused instrument hook.
 
@@ -30,11 +27,6 @@ shapes (the test_zpipeline idiom); the bio acceptance case runs ONE
 3-var shape.
 """
 
-import importlib.util
-import json
-import subprocess
-import sys
-import time
 from pathlib import Path
 
 import pytest
@@ -77,16 +69,6 @@ def ledger():
     yield
     proflog.reset()
     proflog.configure(enabled=False)
-
-
-def _bench_diff():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", REPO / "scripts" / "bench_diff.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["bench_diff"] = mod  # dataclass annotations need this
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # -- disabled path ---------------------------------------------------------
@@ -327,82 +309,6 @@ def test_compile_span_lands_in_trace_ring(ledger):
         obs.configure(enabled=False)
 
 
-# -- bench integration -----------------------------------------------------
-
-
-def test_bench_section_delta_helper(ledger):
-    sys.path.insert(0, str(REPO))
-    import bench
-
-    das, _db = _tensor_das()
-
-    def section():
-        das.query_answer(_inherit_query())
-        return {"x": 1}
-
-    out = bench._with_programs(section)
-    assert out["x"] == 1
-    assert out["programs_compiled"] >= 1
-    assert out["compile_s"] > 0
-
-
-# -- bench_diff: the regression gate ---------------------------------------
-
-
-def test_bench_diff_committed_trajectory_passes():
-    bd = _bench_diff()
-    assert bd.main(["--self-check"]) == 0
-
-
-def test_bench_diff_synthetic_regression_fails(tmp_path):
-    bd = _bench_diff()
-    rec = json.loads((REPO / "BENCH_SELF_r05.json").read_text())
-    rec["value"] = rec["value"] * 10  # 10x the headline latency
-    p = tmp_path / "regressed.json"
-    p.write_text(json.dumps(rec))
-    assert bd.main(["--candidate", str(p)]) == 1
-
-
-def test_bench_diff_throughput_and_identity_gates(tmp_path):
-    bd = _bench_diff()
-    rec = json.loads((REPO / "BENCH_SELF_r05.json").read_text())
-    rec["extra"]["pattern_matches_per_sec"] = 10  # collapse throughput
-    rec["extra"]["matches"] = 9999                # changed answer count
-    p = tmp_path / "regressed2.json"
-    p.write_text(json.dumps(rec))
-    assert bd.main(["--candidate", str(p)]) == 1
-
-
-def test_bench_diff_honesty_interpret_never_gates_device(tmp_path):
-    bd = _bench_diff()
-    rec = json.loads((REPO / "BENCH_SELF_r05.json").read_text())
-    rec["value"] = rec["value"] * 100
-    rec["extra"]["platform"] = "cpu"  # interpret-class record
-    p = tmp_path / "cpu.json"
-    p.write_text(json.dumps(rec))
-    assert bd.main(["--candidate", str(p)]) == 0
-
-
-def test_bench_diff_parse_errors_exit_2(tmp_path):
-    bd = _bench_diff()
-    p = tmp_path / "garbage.json"
-    p.write_text("{not json")
-    assert bd.main(["--candidate", str(p)]) == 2
-    q = tmp_path / "notarecord.json"
-    q.write_text(json.dumps({"hello": 1}))
-    assert bd.main(["--candidate", str(q)]) == 2
-
-
-def test_bench_diff_cli_subprocess():
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "bench_diff.py"),
-         "--self-check"],
-        capture_output=True, text=True, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "pass" in proc.stdout
-
-
 # -- DL016 -----------------------------------------------------------------
 
 
@@ -501,7 +407,6 @@ def test_program_sites_registry_pinned():
         "fused.build_fused_tree": "fused_tree",
         "fused.build_fused_exact": "fused_exact",
         "fused.FusedExecutor._run_batch_group": "count_batch",
-        "fused.FusedExecutor.build_count_loop": "count_loop",
         "fused_sharded._ShardedExecJob.dispatch": "sharded",
         "fused_sharded._ShardedExecJob._build_group": "sharded_group",
         "fused_sharded._ShardedTreeExecJob._build": "sharded_tree",
